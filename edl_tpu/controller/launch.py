@@ -6,7 +6,7 @@ skip-if-SUCCEED → Pod.from_env → Launcher.init/launch).
 
 import sys
 
-from edl_tpu.controller import constants, status
+from edl_tpu.controller import constants, status, train_process
 from edl_tpu.controller.args import parse_args
 from edl_tpu.controller.env import JobEnv
 from edl_tpu.controller.launcher import Launcher
@@ -37,6 +37,7 @@ def main(argv=None):
             coord._call("store_delete_prefix", coord.service_prefix(service))
 
     pod = Pod.from_env(job_env)
+    train_process.check_chip_ownership(pod)
     launcher = Launcher(job_env, pod, coord, args.training_script,
                         args.training_script_args).init()
     ok = launcher.launch()

@@ -12,7 +12,10 @@ The env contract (read back by edl_tpu.controller.env.TrainerEnv):
                                              jax.distributed.initialize
   EDL_TPU_COORDINATOR                        rank-0 trainer endpoint
   EDL_TPU_TRAINER_ENDPOINTS                  all trainer endpoints (csv)
-  EDL_TPU_LOCAL_DEVICES                      local chip indices (csv)
+  EDL_TPU_LOCAL_DEVICES                      local chip indices (csv;
+                                             bookkeeping — nothing
+                                             confines a process to them,
+                                             see check_chip_ownership)
   EDL_TPU_CLUSTER_STAGE                      stage uuid of this incarnation
   EDL_TPU_MESH                               planned (dp, tp, pp, ep)
                                              factorization (json), when
@@ -27,7 +30,30 @@ import time
 
 import psutil
 
+from edl_tpu.utils.errors import TrainProcessError
 from edl_tpu.utils.logger import logger
+
+
+def check_chip_ownership(pod, environ=None):
+    """Refuse a pod whose trainer processes would claim the same chips.
+
+    A chip belongs to one process at a time, and a JAX process claims
+    EVERY chip of its host unless libtpu is told otherwise. The launcher
+    does not tell it: confining a process needs the host's chip grid and
+    a process grid across hosts (TPU_CHIPS_PER_PROCESS_BOUNDS,
+    TPU_PROCESS_BOUNDS, TPU_PROCESS_ADDRESSES, ... — established on a
+    2x2 v5e host, PR 21), which the pod model does not carry. So more
+    than one trainer per pod is accepted only where the trainers cannot
+    reach a chip at all — the CPU harness, JAX_PLATFORMS=cpu in the
+    environment they inherit. Raises TrainProcessError otherwise."""
+    environ = os.environ if environ is None else environ
+    if len(pod.trainers) > 1 and environ.get("JAX_PLATFORMS") != "cpu":
+        raise TrainProcessError(
+            "%d trainer processes on one host would each claim every "
+            "local chip (JAX_PLATFORMS=%r): run one trainer per host "
+            "(--nproc_per_node 1, the JAX process model) — several per "
+            "host is the CPU harness and needs JAX_PLATFORMS=cpu"
+            % (len(pod.trainers), environ.get("JAX_PLATFORMS")))
 
 
 class TrainerProc(object):

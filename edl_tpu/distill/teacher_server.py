@@ -34,13 +34,14 @@ import time
 import numpy as np
 
 from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.parallel.costmodel import device_identity
 from edl_tpu.robustness import faults
 from edl_tpu.robustness.policy import Deadline
 from edl_tpu.rpc import ndarray as nd
 from edl_tpu.rpc.server import FEATURES as _RPC_FEATURES
 from edl_tpu.rpc.server import RpcServer
 from edl_tpu.serve.admission import AdmissionController
-from edl_tpu.utils import errors
+from edl_tpu.utils import compile_cache, errors
 from edl_tpu.utils.logger import logger
 
 _DEVICE_BATCHES = obs_metrics.counter(
@@ -111,8 +112,12 @@ class TeacherServer(object):
     def __init__(self, predict_fn, feed_specs, fetch_specs, max_batch=128,
                  host="0.0.0.0", port=0, adaptive_batch=True,
                  batch_timeout_ms=0.0, admission=None,
-                 decode_engine=None):
+                 decode_engine=None, device=None):
         self._fn = predict_fn
+        # identity of the accelerator predict_fn runs on
+        # (costmodel.device_identity()), reported by stats(); None for
+        # host-only backends (the NOP teacher must not claim a chip)
+        self._device = device
         # optional autoregressive plane (serve/decode_engine.py): adds
         # the lm_generate / lm_submit / lm_poll RPCs, folds engine
         # stats into stats(), and joins the drain protocol
@@ -263,7 +268,10 @@ class TeacherServer(object):
             out.update(self._admission.stats())
         if self._decode is not None:
             out.update(self._decode.stats())
-        return obs_metrics.mirror_stats("edl_teacher", out)
+        out = obs_metrics.mirror_stats("edl_teacher", out)
+        if self._device is not None:
+            out.update(self._device)
+        return out
 
     def drain(self, deadline_s=30.0):
         """Drain-safe shutdown, step 3 of the decommission protocol
@@ -588,6 +596,8 @@ def resnet_teacher(depth=50, num_classes=1000, image_size=224,
 
     from edl_tpu.models import resnet
 
+    compile_cache.enable()
+
     model = resnet.ResNet(depth=depth, num_classes=num_classes, vd=vd,
                           groups=groups, base_width=base_width,
                           dtype=jnp.bfloat16)
@@ -611,7 +621,8 @@ def resnet_teacher(depth=50, num_classes=1000, image_size=224,
         feed_specs={"image": ([image_size, image_size, 3], "<f4")},
         fetch_specs={"logits": ([num_classes], "<f4"),
                      "probs": ([num_classes], "<f4")},
-        max_batch=max_batch, host=host, port=port)
+        max_batch=max_batch, host=host, port=port,
+        device=device_identity())
 
 
 def gpt_teacher(num_layers=2, d_model=64, num_heads=4, mlp_dim=128,
@@ -636,6 +647,7 @@ def gpt_teacher(num_layers=2, d_model=64, num_heads=4, mlp_dim=128,
     from edl_tpu.models import gpt
     from edl_tpu.ops import quant
 
+    compile_cache.enable()
     model = gpt.Gpt(num_layers=num_layers, d_model=d_model,
                     num_heads=num_heads, mlp_dim=mlp_dim,
                     vocab_size=vocab_size, max_len=max(seq_len, 16),
@@ -662,7 +674,8 @@ def gpt_teacher(num_layers=2, d_model=64, num_heads=4, mlp_dim=128,
         feed_specs={"input_ids": ([seq_len], "<i4")},
         fetch_specs={"logits": ([seq_len, vocab_size], "<f4"),
                      "probs": ([seq_len, vocab_size], "<f4")},
-        max_batch=max_batch, host=host, port=port, **kwargs)
+        max_batch=max_batch, host=host, port=port,
+        device=device_identity(), **kwargs)
 
 
 def lm_teacher(num_layers=2, d_model=64, num_heads=4, mlp_dim=128,
@@ -684,6 +697,7 @@ def lm_teacher(num_layers=2, d_model=64, num_heads=4, mlp_dim=128,
     from edl_tpu.ops import quant
     from edl_tpu.serve.decode_engine import DecodeEngine
 
+    compile_cache.enable()
     # decode path runs f32: greedy sampling is gated token-identical
     # against models.gpt.generate, which bf16 activations would break
     model = gpt.Gpt(num_layers=num_layers, d_model=d_model,
@@ -716,7 +730,7 @@ def lm_teacher(num_layers=2, d_model=64, num_heads=4, mlp_dim=128,
         fetch_specs={"logits": ([seq_len, vocab_size], "<f4"),
                      "probs": ([seq_len, vocab_size], "<f4")},
         max_batch=max_batch, host=host, port=port,
-        decode_engine=engine, **kwargs)
+        decode_engine=engine, device=device_identity(), **kwargs)
 
 
 def main():

@@ -22,7 +22,8 @@ costs to GET there?* Three parts:
 
 Everything here is pure numpy over plain tuples/dicts — PartitionSpecs
 are accepted anywhere a spec is (they iterate as tuples), but jax is
-never imported, so the controller can plan meshes on machines with no
+never imported by the planner (only :func:`device_identity` touches it,
+lazily), so the controller can plan meshes on machines with no
 accelerator runtime.
 
 Mesh convention: axes are an ordered {name: size} dict; devices are
@@ -36,21 +37,54 @@ import os
 
 import numpy as np
 
-# same chip as perf_accounting.py / roofline_resnet.py (single source
-# for the compute/HBM numbers; do not fork the constants)
-V5E_BF16_TFLOPS = 197.0
-V5E_HBM_GBPS = 819.0
-# v5e ICI: 1.6 Tb/s aggregate per chip; ring collectives see roughly
-# the aggregate figure (all links busy), so use it as the collective
-# bandwidth term
-V5E_ICI_GBPS = 200.0
-
-CHIP_V5E = {
-    "name": "v5e",
-    "bf16_tflops": V5E_BF16_TFLOPS,
-    "hbm_gbps": V5E_HBM_GBPS,
-    "ici_gbps": V5E_ICI_GBPS,
+# THE table of published per-chip peaks, keyed by the ``device_kind``
+# JAX reports (libtpu 0.0.34 calls a v5e "TPU v5 lite"). Every other
+# module reads its peaks from here; a device that is not in the table
+# is an error (:func:`chip_peaks`), never a default.
+#   v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#   819 GB/s of HBM bandwidth, 16 GB of HBM, 1,600 Gbit/s (= 200 GB/s)
+#   of chip-to-chip interconnect. Ring collectives keep all links busy,
+#   so the aggregate ICI figure is the collective bandwidth term.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "name": "v5e",
+        "bf16_tflops": 197.0,
+        "hbm_gbps": 819.0,
+        "hbm_gb": 16.0,
+        "ici_gbps": 200.0,
+    },
 }
+
+# the planner's named target chip: it scores hypothetical worlds on
+# machines with no accelerator, so it plans for a chip by name rather
+# than for whatever happens to be attached
+CHIP_V5E = CHIP_PEAKS["TPU v5 lite"]
+
+
+def chip_peaks(device_kind):
+    """Published peaks of the chip JAX calls ``device_kind``. Raises
+    KeyError for a kind the table does not hold: code that turns a
+    MEASURED time into a utilization or a "suspect" verdict must not
+    assume a chip it is not running on."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            "no published peaks for device_kind %r (known: %s); add it "
+            "to costmodel.CHIP_PEAKS with its source"
+            % (device_kind, ", ".join(sorted(CHIP_PEAKS)))) from None
+
+
+def device_identity():
+    """{"platform", "device_kind", "device_count"} of the devices this
+    process runs on, as JAX reports them — stamped on every result line
+    so a number can never be read without the device it came from.
+    Imports jax (and so initialises the backend) on first use."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
 
 # -- measured calibration (tools/roofline_gap.py) --------------------------
 #

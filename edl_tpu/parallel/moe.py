@@ -16,10 +16,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from edl_tpu.parallel.shard_map_compat import shard_map
 from edl_tpu.runtime.mesh import EXPERT_AXIS
 
 

@@ -45,6 +45,11 @@ def make_mesh(dp=None, tp=1, sp=1, pp=1, ep=1, devices=None):
     try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     except (ValueError, AssertionError):
+        # a device set that is not a whole topology has no topology-
+        # aware order, only the plain one. Established on a v5e 2x2
+        # host (PR 21): create_device_mesh orders 4 chips ([0,1,3,2],
+        # the ring), 2x2, and the first 2 or 1 — the live-resize
+        # sub-meshes — itself; it asserts only on 3 of the 4.
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array,
                 (PIPE_AXIS, DATA_AXIS, EXPERT_AXIS, SEQ_AXIS, MODEL_AXIS))

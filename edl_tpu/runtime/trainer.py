@@ -39,6 +39,7 @@ from edl_tpu.runtime import checkpoint as checkpoint_mod
 from edl_tpu.runtime import state as state_mod
 from edl_tpu.runtime.checkpoint import CheckpointManager, MissingKeysError
 from edl_tpu.runtime.mesh import DATA_AXIS, data_sharding, make_mesh
+from edl_tpu.utils import compile_cache
 from edl_tpu.utils.logger import logger
 
 _STEP_MS = obs_metrics.histogram(
@@ -141,9 +142,8 @@ def make_multi_step(loss_fn, tx, steps_per_call, has_aux=False,
     and losses is [steps_per_call].
 
     Amortizes per-step host dispatch latency — the lever when the host
-    is remote or slow relative to the device (dev tunnels, small step
-    times). The rng is folded with the in-scan step counter so each
-    scanned step sees a distinct stream, exactly as if single steps were
+    is slow relative to the device (small step times). The rng is
+    folded with the in-scan step counter so each scanned step sees a distinct stream, exactly as if single steps were
     dispatched with rng = fold_in(rng, state["step"])."""
     if steps_per_call < 1:
         raise ValueError("steps_per_call must be >= 1")
@@ -312,14 +312,12 @@ def _make_overlap_accum_step(loss_fn, tx, accum_steps, _maybe_remat,
             "extra": train_state["extra"],
         }, loss
 
-    from jax.sharding import PartitionSpec
-    from edl_tpu.parallel.shard_map_compat import shard_map
-    state_spec = PartitionSpec()
-    batch_spec = PartitionSpec(None, axes)
-    return shard_map(step, mesh=mesh,
-                     in_specs=(state_spec, batch_spec, state_spec),
-                     out_specs=(state_spec, state_spec),
-                     check_rep=False)
+    state_spec = P()
+    batch_spec = P(None, axes)
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(state_spec, batch_spec, state_spec),
+                         out_specs=(state_spec, state_spec),
+                         check_vma=False)
 
 
 def auto_grad_accum(per_device_batch, max_per_device_batch):
@@ -341,31 +339,21 @@ def auto_grad_accum(per_device_batch, max_per_device_batch):
     raise AssertionError("unreachable: k == per_device_batch always fits")
 
 
-def enable_compilation_cache():
-    """Persistent XLA compilation cache, keyed by program (incl. mesh
-    shape). Cuts stop-resume resize recovery to O(restart) when the new
-    world size was seen before (SURVEY.md §7 'resize vs XLA reality') —
-    set EDL_TPU_COMPILE_CACHE to a shared directory to activate."""
-    cache_dir = os.environ.get("EDL_TPU_COMPILE_CACHE")
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        logger.info("compilation cache at %s", cache_dir)
-
-
 def maybe_init_distributed(env=None):
     """Initialize jax.distributed from the launcher env contract (no-op for
     single-process runs)."""
     global _distributed_initialized
     env = env or TrainerEnv()
-    enable_compilation_cache()
+    # the persistent XLA cache (keyed by program incl. mesh shape) cuts
+    # stop-resume recovery to O(restart) when the new world size was
+    # seen before (SURVEY.md §7 'resize vs XLA reality'); before
+    # jax.distributed.initialize, so it must not touch a backend
+    compile_cache.enable()
     if _distributed_initialized or env.world_size <= 1:
         return env
     # idempotent with external bootstrap (a test rig or launcher that
     # already called jax.distributed.initialize)
-    state = getattr(getattr(jax, "_src", None), "distributed", None)
-    if state is not None and getattr(getattr(state, "global_state", None),
-                                     "client", None) is not None:
+    if jax.distributed.is_initialized():
         _distributed_initialized = True
         return env
     jax.distributed.initialize(
@@ -757,10 +745,6 @@ class ElasticTrainer(object):
     # the lowered computation + shapes + jaxlib version, recomputed by
     # the restarted process — a code or config change simply misses.
 
-    def _aot_dir(self):
-        base = os.environ.get("EDL_TPU_COMPILE_CACHE")
-        return os.path.join(base, "aot_steps") if base else None
-
     def _step_lowered(self, world_n=None):
         """Lower the train step for ``world_n`` devices (None = the
         current mesh), returning (lowered, fingerprint)."""
@@ -817,7 +801,7 @@ class ElasticTrainer(object):
 
     def prewarm_resize_compiles(self, world_sizes, block=True):
         """Compile the train step for OTHER world sizes and serialize
-        the executables under EDL_TPU_COMPILE_CACHE/aot_steps, so the
+        the executables under compile_cache.aot_dir(), so the
         next resize restart LOADS its step instead of compiling it
         (picked up automatically at the restarted trainer's first
         train_step). Scope: single-process trainers on any
@@ -834,11 +818,7 @@ class ElasticTrainer(object):
         if why is not None:
             logger.info("prewarm: %s — skipped", why)
             return []
-        out_dir = self._aot_dir()
-        if out_dir is None:
-            logger.info("prewarm: EDL_TPU_COMPILE_CACHE unset — "
-                        "nowhere to persist, skipped")
-            return []
+        out_dir = compile_cache.aot_dir()
         devices = jax.devices()  # targets may exceed the CURRENT mesh
         current = len(list(self.mesh.devices.flat))
         # the DATA-SHARDED axis of the example batch (under grad
@@ -921,15 +901,11 @@ class ElasticTrainer(object):
 
         if self._prewarm_in_scope() is not None:
             return None
-        aot = self._aot_dir()
-        if aot is None:
-            return None
-        # from here the cache is CONFIGURED: every early-out is a real
-        # miss (full compile paid) and counts toward the doctor's
-        # compile-cache-cold finding
+        aot = compile_cache.aot_dir()
         if not os.path.isdir(aot):
-            _PREWARM_MISSES.inc()
-            return None
+            return None  # nothing was ever prewarmed into this cache
+        # every early-out from here is a real miss (full compile paid)
+        # and counts toward the doctor's compile-cache-cold finding
         n = len(list(self.mesh.devices.flat))
         # any candidate for this world at all? — checked BEFORE paying
         # a trace+lower just to compute the fingerprint (a miss here is
@@ -948,49 +924,51 @@ class ElasticTrainer(object):
         if not os.path.exists(path):
             _PREWARM_MISSES.inc()
             return None
+        from jax.experimental import serialize_executable as se
+        t0 = time.perf_counter()
         try:
-            from jax.experimental import serialize_executable as se
-            t0 = time.perf_counter()
             with open(path, "rb") as f:
                 blob = pickle.load(f)
-            loaded = se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"])
-            repl = self._repl
-            jit_fallback = self._jit_step
-
-            def step(state, batch, rng):
-                # loaded executables take committed inputs with the
-                # EXACT compiled signature; jax.jit would transparently
-                # recompile on a changed rng type or a ragged tail
-                # batch — mirror that by reverting to the jit path on
-                # an input mismatch (argument validation rejects before
-                # any buffer is donated, so the retry is safe)
-                try:
-                    return loaded(state, batch,
-                                  jax.device_put(rng, repl))
-                except (TypeError, ValueError) as e:
-                    # ONLY argument-validation failures are safe to
-                    # retry: they reject before dispatch, so no buffer
-                    # has been donated yet. A post-dispatch failure
-                    # (XlaRuntimeError etc.) leaves state's donated
-                    # buffers deleted — retrying would mask the real
-                    # error with a use-after-donate; let it propagate.
-                    logger.warning(
-                        "AOT step input mismatch (%r); reverting to "
-                        "the jit path for this and later steps", e)
-                    self._jit_step = jit_fallback
-                    return jit_fallback(state, batch, rng)
-
-            logger.info("resize prewarm HIT: world-%d step loaded from "
-                        "%s in %.2fs (compile skipped)", n, path,
-                        time.perf_counter() - t0)
-            _PREWARM_HITS.inc()
-            return step
-        except Exception:
-            logger.exception("prewarm load failed (falling back to "
-                             "the normal compile)")
+        except (OSError, EOFError, pickle.UnpicklingError):
+            logger.exception("prewarm load: unreadable artifact %s "
+                             "(falling back to the normal compile)", path)
             _PREWARM_MISSES.inc()
             return None
+        # NOT guarded: the fingerprint matched (same jax, same lowered
+        # step), so an executable that will not deserialize is a bug to
+        # surface, not a cache miss to count. A sub-mesh executable must
+        # be told its devices, or it expects one shard per process device.
+        loaded = se.deserialize_and_load(
+            blob["payload"], blob["in_tree"], blob["out_tree"],
+            execution_devices=list(self.mesh.devices.flat))
+        repl = self._repl
+        jit_fallback = self._jit_step
+
+        def step(state, batch, rng):
+            # loaded executables take committed inputs with the EXACT
+            # compiled signature; jax.jit would transparently recompile
+            # on a changed rng type or a ragged tail batch — mirror that
+            # by reverting to the jit path on an input mismatch
+            try:
+                return loaded(state, batch, jax.device_put(rng, repl))
+            except (TypeError, ValueError) as e:
+                # ONLY argument-validation failures are safe to retry:
+                # they reject before dispatch, so no buffer has been
+                # donated yet. A post-dispatch failure (XlaRuntimeError
+                # etc.) leaves state's donated buffers deleted —
+                # retrying would mask the real error with a
+                # use-after-donate; let it propagate.
+                logger.warning(
+                    "AOT step input mismatch (%r); reverting to the jit "
+                    "path for this and later steps", e)
+                self._jit_step = jit_fallback
+                return jit_fallback(state, batch, rng)
+
+        logger.info("resize prewarm HIT: world-%d step loaded from %s in "
+                    "%.2fs (compile skipped)", n, path,
+                    time.perf_counter() - t0)
+        _PREWARM_HITS.inc()
+        return step
 
     def local_batch_slice(self, full_batch):
         """Slice a FULL global batch down to the rows this process must
@@ -1010,6 +988,24 @@ class ElasticTrainer(object):
                     self._batch_sharding, x), host_batch)
         return jax.device_put(host_batch, self._batch_sharding)
 
+    def place_batch(self, host_batch):
+        """Per-host rows -> the device batch exactly as train_step feeds
+        it to the step (microbatch-major under gradient accumulation).
+        Public so an entry point can report which devices really hold
+        its batch."""
+        if self._grad_accum > 1:
+            k = self._grad_accum
+            host_batch = jax.tree_util.tree_map(
+                lambda x: x.reshape((k, x.shape[0] // k) + x.shape[1:]),
+                host_batch)
+        return self.shard_batch(host_batch)
+
+    @property
+    def resize_timing(self):
+        """This incarnation's per-stage timing record (compile_s,
+        first_step_s, restore_s, ...; docs/elastic_resize.md) — a copy."""
+        return dict(self._resize_timing)
+
     _STEP_WINDOW = 8  # intervals kept for the cadence estimate
 
     def train_step(self, host_batch, rng=None):
@@ -1027,12 +1023,7 @@ class ElasticTrainer(object):
         self._last_step_start = t0
         if rng is None:
             rng = jax.random.PRNGKey(self._host_step)
-        if self._grad_accum > 1:
-            k = self._grad_accum
-            host_batch = jax.tree_util.tree_map(
-                lambda x: x.reshape((k, x.shape[0] // k) + x.shape[1:]),
-                host_batch)
-        batch = self.shard_batch(host_batch)
+        batch = self.place_batch(host_batch)
         first_step = self._example_batch_sds is None
         if first_step:
             self._example_batch_sds = jax.tree_util.tree_map(
@@ -1325,8 +1316,7 @@ class ElasticTrainer(object):
             self._state_shardings = new_shardings
             self._jit_step = self._build_step()
             prewarm = "n/a"
-            if self._example_batch_sds is not None \
-                    and self._aot_dir() is not None:
+            if self._example_batch_sds is not None:
                 loaded = self._try_load_prewarmed_step()
                 if loaded is not None:
                     self._jit_step = loaded

@@ -379,7 +379,7 @@ def _live_resize_findings(obs, timeline):
             "severity": "warn",
             "summary": ("compile cache cold: %d prewarm-scope first "
                         "step(s) paid a full compile and none loaded "
-                        "an AOT artifact — check EDL_TPU_COMPILE_CACHE "
+                        "an AOT artifact — check JAX_COMPILATION_CACHE_DIR "
                         "and the prewarm_resize_compiles schedule"
                         % int(misses)),
             "metric": "edl_resize_prewarm_misses_total",

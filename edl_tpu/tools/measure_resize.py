@@ -11,17 +11,20 @@ pre-kill step (i.e. the trainer re-initialized, re-compiled — or
 cache-hit / AOT-loaded — restored, and committed new progress).
 
 Arcs:
-- cold / warm: SAME-world restart, without / with the XLA persistent
-  compile cache. (warm = cache hit; the classic restart.)
+- cold / warm: SAME-world restart against an empty / the first
+  incarnation's XLA persistent compile cache. (warm = cache hit; the
+  classic restart.) Every arc hands its children
+  JAX_COMPILATION_CACHE_DIR inside the arc's own work directory, so a
+  run neither reads nor fills the program's default cache.
 - resize_prewarm_on / resize_prewarm_off: WORLD-CHANGING restart
   (n devices -> n//2), the arc the AOT resize prewarm exists for: the
   persistent cache can never carry a compile across world sizes (its
   key includes the platform topology), so without prewarm the shrunken
   world pays a full compile, and with --prewarm_worlds the first
   incarnation serialized the smaller world's step executable ahead of
-  time and the restart just loads it. Runs on a virtual CPU world by
-  default (--platform cpu, 2 -> 1 devices); the 8 -> 4 TPU run uses
-  the same arcs on a multi-chip host (tools/measure_resize_tpu.sh).
+  time and the restart just loads it. Runs on a virtual CPU world
+  with --platform cpu (2 -> 1 devices); --platform tpu runs the same
+  arcs on a multi-chip host.
 
 - live / stop_resume: the zero-downtime comparison. The ``live`` arc
   drives the in-place reshard through the live-resize two-phase commit
@@ -58,6 +61,29 @@ def _spawn_store():
     return StoreServer(host="127.0.0.1", port=0).start()
 
 
+def _tpu_visibility_env(n_chips):
+    """libtpu variables that confine ONE process to the first
+    ``n_chips`` chips of this host. Established on a v5litepod-4 (2x2)
+    host with libtpu 0.0.34 (PR 21): TPU_VISIBLE_DEVICES alone works
+    for one chip but is refused for two ("expected 4, actual 2") — the
+    process must also be told its own chip grid, x-major like the chip
+    ids (chips 0,1 are the x row, so two chips are "2,1,1", not
+    "1,2,1")."""
+    x = int(os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS",
+                           "2,2,1").split(",")[0])
+    if n_chips <= x:
+        grid = (n_chips, 1, 1)
+    elif n_chips % x == 0:
+        grid = (x, n_chips // x, 1)
+    else:
+        raise ValueError("%d chips do not tile a host grid %d wide"
+                         % (n_chips, x))
+    return {"TPU_VISIBLE_DEVICES": ",".join(str(i)
+                                            for i in range(n_chips)),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "%d,%d,%d" % grid,
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 def _spawn_pod(store_endpoint, job_id, log_dir, ckpt_dir, cache_dir,
                args, n_devices=None, prewarm_worlds="", extra_env=None):
     env = dict(os.environ)  # TPU env inherited
@@ -65,20 +91,18 @@ def _spawn_pod(store_endpoint, job_id, log_dir, ckpt_dir, cache_dir,
         from edl_tpu.utils.cpu_mesh import force_cpu_env
         force_cpu_env(env, n_devices)
     elif n_devices is not None:
-        # real TPU VM: libtpu honours TPU_VISIBLE_DEVICES, so the
-        # shrunken incarnation actually sees fewer chips (without this
-        # the "resize" arcs restart into the same full world and the
-        # prewarm comparison is meaningless)
-        env["TPU_VISIBLE_DEVICES"] = ",".join(
-            str(i) for i in range(n_devices))
+        # TPU host: confine the incarnation to its chips, so the
+        # shrunken one actually sees fewer (without this the "resize"
+        # arcs restart into the same full world and the prewarm
+        # comparison is meaningless)
+        env.update(_tpu_visibility_env(n_devices))
     env.update({
         "PYTHONPATH": REPO,
         "EDL_TPU_POD_IP": "127.0.0.1",
         "EDL_TPU_TTL": "3",
         "EDL_TPU_CHECKPOINT_PATH": ckpt_dir,
+        "JAX_COMPILATION_CACHE_DIR": cache_dir,
     })
-    if cache_dir:
-        env["EDL_TPU_COMPILE_CACHE"] = cache_dir
     if extra_env:
         env.update(extra_env)
     os.makedirs(log_dir, exist_ok=True)
@@ -133,10 +157,11 @@ def _wait_step(coord, pred, timeout, proc=None):
     raise TimeoutError("step predicate not reached in %.0fs" % timeout)
 
 
-def run_arc(tag, cache_dir, args):
+def run_arc(tag, args):
     from edl_tpu.coordination.client import CoordClient
 
     tmp = tempfile.mkdtemp(prefix="measure_resize_%s_" % tag)
+    cache_dir = os.path.join(tmp, "cache")
     store = _spawn_store()
     job_id = "rz_%s_%d" % (tag, os.getpid())
     coord = CoordClient([store.endpoint], root=job_id)
@@ -154,9 +179,13 @@ def run_arc(tag, cache_dir, args):
         # survives the kill; steps kept committing after s0 was read)
         base = _store_step(coord)
         base = s0 if base is None else max(base, s0)
+        # warm = the restart finds the first incarnation's cache; cold
+        # = it gets an empty one
         pod = _spawn_pod(store.endpoint, job_id,
                          os.path.join(tmp, "logs2"),
-                         os.path.join(tmp, "ckpt"), cache_dir, args)
+                         os.path.join(tmp, "ckpt"),
+                         cache_dir if tag == "warm" else cache_dir + "_cold",
+                         args)
         s1, _ = _wait_step(coord, lambda s: s > base, args.timeout, pod)
         recovery = time.monotonic() - t0
         return {
@@ -368,8 +397,11 @@ def run_peer_arc(peer, args):
     coord = CoordClient([store.endpoint], root=job_id)
     pod = holdout = None
     try:
+        # both restarts of this arc compile cold (each incarnation gets
+        # its own empty cache): the on/off pair differs in restore only
         pod = _spawn_pod(store.endpoint, job_id,
-                         os.path.join(tmp, "logs"), ckpt_dir, None,
+                         os.path.join(tmp, "logs"), ckpt_dir,
+                         os.path.join(tmp, "cache"),
                          args, extra_env=extra_env)
         s0, t_first = _wait_step(coord,
                                  lambda s: s >= args.steps_per_epoch,
@@ -388,7 +420,8 @@ def run_peer_arc(peer, args):
         base = s0 if base is None else max(base, s0)
         t_spawn = time.time()
         pod = _spawn_pod(store.endpoint, job_id,
-                         os.path.join(tmp, "logs2"), ckpt_dir, None,
+                         os.path.join(tmp, "logs2"), ckpt_dir,
+                         os.path.join(tmp, "cache_cold"),
                          args, extra_env=extra_env)
         s1, _ = _wait_step(coord, lambda s: s > base, args.timeout, pod)
         rec = _read_resize_timing(coord, after_ts=t_kill, timeout=30.0)
@@ -767,7 +800,7 @@ def run_kill_pod_arc_micro(args):
 
 
 def _spawn_worker(store_endpoint, job_id, log_dir, args, n_devices,
-                  cache_dir=None, prewarm_worlds="", ckpt="",
+                  cache_dir, prewarm_worlds="", ckpt="",
                   who="bench_worker"):
     env = dict(os.environ)
     if args.platform == "cpu":
@@ -777,9 +810,8 @@ def _spawn_worker(store_endpoint, job_id, log_dir, args, n_devices,
         # stop-resume respawn run in identical device environments
         force_cpu_env(env, max(n_devices, args.from_devices))
     env.update({"PYTHONPATH": REPO, "EDL_TPU_POD_IP": "127.0.0.1",
-                "EDL_TPU_TTL": "3"})
-    if cache_dir:
-        env["EDL_TPU_COMPILE_CACHE"] = cache_dir
+                "EDL_TPU_TTL": "3",
+                "JAX_COMPILATION_CACHE_DIR": cache_dir})
     os.makedirs(log_dir, exist_ok=True)
     log = open(os.path.join(log_dir, "worker.log"), "ab")
     cmd = [sys.executable, "-u", "-m", "edl_tpu.tools.resize_worker",
@@ -1034,34 +1066,28 @@ def main(argv=None):
         # the micro arcs run jax IN this process; the pod arcs only
         # inherit — either way a CPU run must never grab the TPU
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    cache_dir = tempfile.mkdtemp(prefix="measure_resize_cache_")
     rc = 0
-    try:
-        for tag in args.arcs.split(","):
-            tag = tag.strip()
-            try:
-                if tag in ("peer_restore_on", "peer_restore_off"):
-                    out = (run_peer_arc_micro if args.micro
-                           else run_peer_arc)(tag.endswith("_on"), args)
-                elif tag == "kill_pod":
-                    out = run_kill_pod_arc_micro(args)
-                elif tag == "live":
-                    out = run_live_arc(args)
-                elif tag == "stop_resume":
-                    out = run_stop_resume_arc(args)
-                elif tag in ("resize_prewarm_on", "resize_prewarm_off"):
-                    out = run_resize_arc(tag.endswith("_on"), args)
-                else:
-                    out = run_arc(tag,
-                                  cache_dir if tag == "warm" else None,
-                                  args)
-                print(json.dumps(out), flush=True)
-            except Exception as e:  # noqa: BLE001
-                print(json.dumps({"metric": "resize_recovery_%s" % tag,
-                                  "error": repr(e)}), flush=True)
-                rc = 1
-    finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
+    for tag in args.arcs.split(","):
+        tag = tag.strip()
+        try:
+            if tag in ("peer_restore_on", "peer_restore_off"):
+                out = (run_peer_arc_micro if args.micro
+                       else run_peer_arc)(tag.endswith("_on"), args)
+            elif tag == "kill_pod":
+                out = run_kill_pod_arc_micro(args)
+            elif tag == "live":
+                out = run_live_arc(args)
+            elif tag == "stop_resume":
+                out = run_stop_resume_arc(args)
+            elif tag in ("resize_prewarm_on", "resize_prewarm_off"):
+                out = run_resize_arc(tag.endswith("_on"), args)
+            else:
+                out = run_arc(tag, args)
+            print(json.dumps(out), flush=True)
+        except Exception as e:  # noqa: BLE001
+            print(json.dumps({"metric": "resize_recovery_%s" % tag,
+                              "error": repr(e)}), flush=True)
+            rc = 1
     return rc
 
 

@@ -43,8 +43,10 @@ Usage:
 
 Emits ONE JSON line (schema "roofline_gap/v1"):
     mode            micro | full
-    platform        jax.default_backend() the step ran on
-    chip_builtin    the datasheet constants predictions used
+    platform, device_kind, device_count
+                    the devices the step ran on, as JAX reports them
+    chip_builtin    the datasheet constants predictions used (the
+                    running chip's; --micro uses the named v5e target)
     configs         per-(model, mesh) records: mesh factors, world,
                     measured {total_s, floor_s, dp_s, tp_s},
                     predicted (the step_time_s breakdown),
@@ -174,14 +176,12 @@ def _time_step(step, state, batch, rng, state_sh, batch_sh, repl,
 def _time_allreduce(mesh, axes, tree, iters):
     """Wall seconds of ONE all-reduce of ``tree`` over ``axes`` on
     ``mesh`` — the standalone measurement of a collective term."""
-    from edl_tpu.parallel.shard_map_compat import shard_map
-
     def f(t):
         return jax.tree_util.tree_map(
             lambda g: lax.pmean(g, axes), t)
 
-    jf = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                           check_rep=False))
+    jf = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                               check_vma=False))
     out = jf(tree)  # compile + warm
     jax.block_until_ready(out)
     t0 = time.perf_counter()
@@ -191,7 +191,7 @@ def _time_allreduce(mesh, axes, tree, iters):
     return (time.perf_counter() - t0) / iters
 
 
-def run_config(cfg, iters, warmup, remat_policy, dtype):
+def run_config(cfg, iters, warmup, remat_policy, dtype, chip):
     factors = {"dp": 1, "tp": 1, "pp": 1, "ep": 1}
     factors.update(cfg["mesh"])
     if factors["dp"] == 0:  # 0 = all devices on the dp axis
@@ -258,7 +258,7 @@ def run_config(cfg, iters, warmup, remat_policy, dtype):
         measured_tp_s = 4.0 * profile["n_layers"] * one
 
     pred = costmodel.step_time_s(factors, profile, cfg["total_batch"],
-                                 chip=costmodel.CHIP_V5E)
+                                 chip=chip)
     pred_floor = max(pred["compute_s"], pred["hbm_s"]) * pred["bubble"]
     measured_floor = max(total_s - measured_dp_s - measured_tp_s,
                          _MIN_MEASURED_S)
@@ -362,7 +362,17 @@ def main(argv=None):
                         "(point EDL_TPU_ROOFLINE_CALIB at it)")
     args = p.parse_args(argv)
 
-    platform = jax.default_backend()
+    device = costmodel.device_identity()
+    platform = device["platform"]
+    if args.micro:
+        # the schema guard runs on any backend: its ratios are against
+        # the planner's named target chip, and the record says which
+        # platform produced the "measured" side
+        chip = costmodel.CHIP_V5E
+    else:
+        # a real measured-vs-predicted gap is against the RUNNING
+        # chip's published peaks; an unknown device_kind raises
+        chip = costmodel.chip_peaks(device["device_kind"])
     configs = MICRO_CONFIGS if args.micro else FULL_CONFIGS
     if args.configs:
         want = {n.strip() for n in args.configs.split(",") if n.strip()}
@@ -375,7 +385,7 @@ def main(argv=None):
     for cfg in configs:
         try:
             rec, fit = run_config(cfg, iters, args.warmup, args.remat,
-                                  dtype)
+                                  dtype, chip)
             records.append(rec)
             fits.append(fit)
         except Exception as e:  # noqa: BLE001
@@ -408,7 +418,9 @@ def main(argv=None):
         "schema": "roofline_gap/v1",
         "mode": "micro" if args.micro else "full",
         "platform": platform,
-        "chip_builtin": dict(costmodel.CHIP_V5E),
+        "device_kind": device["device_kind"],
+        "device_count": device["device_count"],
+        "chip_builtin": dict(chip),
         "configs": records,
         "calibration": calibration,
         "gpt_arc": gpt_arc,

@@ -11,10 +11,10 @@ budget (v3: 123 bf16 TFLOP/s vs 900 GB/s = 7.3 B/TF; v5e: 197 vs 819
 = 4.2 B/TF).
 
 Inputs:
-  * the round-5 measured xplane profile of the default bench step
-    (BENCH_SWEEP_r5b.txt stage 2; 50.03 ms device-op time per step,
-    s2d + bn_stats_every 1, batch 128), hardcoded below with
-    provenance, and
+  * the measured xplane profile of the default bench step from the
+    pre-PR-1 chip sweep, 2026-07-31 (git history; 50.03 ms device-op
+    time per step, s2d + bn_stats_every 1, batch 128), hardcoded below
+    with provenance, and
   * an analytic activation-byte account computed here from the
     resnet50_vd block structure (no JAX needed; stride placement
     matches edl_tpu/models/resnet.py — stride-2 on the 3x3, so the
@@ -26,13 +26,15 @@ Run: python -m edl_tpu.tools.roofline_resnet
 
 import json
 
-# v5e datasheet numbers (same constants as perf_accounting.py).
-V5E_BF16_TFLOPS = 197.0
-V5E_HBM_GBPS = 819.0
+from edl_tpu.parallel.costmodel import CHIP_V5E
 
-# Round-5 measured profile, device XLA-op time per step
-# (tools/profile_bench.py on the real chip, 2026-07-31, s2d bn1 b128;
-# BENCH_SWEEP_r5b.txt stage 2).
+# the account is FOR a named chip (the profile below was taken on one)
+V5E_BF16_TFLOPS = CHIP_V5E["bf16_tflops"]
+V5E_HBM_GBPS = CHIP_V5E["hbm_gbps"]
+
+# Measured profile, device XLA-op time per step (tools/profile_bench.py
+# on a v5e chip, 2026-07-31, s2d bn1 b128; pre-PR-1 chip sweep, git
+# history).
 MEASURED_MS = {
     "conv (%fusion)": 19.057,
     "bn stats+grad reduces (%convert_reduce_fusion)": 15.778,

@@ -69,7 +69,8 @@ def main(argv=None):
                    help="comma list of chip counts to AOT-compile the "
                         "step for (background, after epoch 0) so a "
                         "resize restart loads its step instead of "
-                        "compiling; needs EDL_TPU_COMPILE_CACHE")
+                        "compiling (kept beside the compile cache, "
+                        "edl_tpu/utils/compile_cache.py)")
     args = p.parse_args(argv)
 
     if args.seed is not None:
@@ -176,7 +177,7 @@ def main(argv=None):
 
     from edl_tpu.utils.errors import PreemptedError
 
-    loss = None
+    loss = loss_arr = host_batch = None
     accs = None
     imgs_seen = 0
     t_start = time.perf_counter()
@@ -189,7 +190,8 @@ def main(argv=None):
                 trainer.report_status(ts.TrainStatus.NEARTHEEND)
             t_epoch = time.perf_counter()
             for step, host_batch in enumerate(host_batches(epoch)):
-                loss = float(trainer.train_step(host_batch))
+                loss_arr = trainer.train_step(host_batch)
+                loss = float(loss_arr)
                 imgs_seen += args.total_batch_size
                 if (step + 1) % args.fetch_steps == 0:
                     dt = time.perf_counter() - t_epoch
@@ -228,7 +230,31 @@ def main(argv=None):
         "steps": trainer.global_step,
         "world": trainer.world_size,
         "imgs_per_sec": round(imgs_seen / wall, 1),
+        "resumed": resumed,
     }
+    # where the run really happened: the device identity as JAX reports
+    # it, this incarnation's compile/first-step stamps, and — from the
+    # same placement train_step uses — which devices held the batch and
+    # the loss, so "everything on the first chip" cannot read as dp=N
+    import jax
+
+    from edl_tpu.parallel.costmodel import device_identity
+    result.update(device_identity())
+    timing = trainer.resize_timing
+    result.update({k: round(timing[k], 3)
+                   for k in ("compile_s", "first_step_s", "restore_s")
+                   if k in timing})
+    if loss_arr is not None:
+        shards = trainer.place_batch(
+            {"label": host_batch["label"]})["label"].addressable_shards
+        result.update({
+            "batch_devices": len({s.device.id for s in shards}),
+            "per_device_batch": sorted({s.data.size for s in shards}),
+            "loss_devices": len(loss_arr.sharding.device_set),
+            "device_bytes_in_use": [
+                (d.memory_stats() or {}).get("bytes_in_use")
+                for d in jax.local_devices()],
+        })
     if accs:
         result.update({"eval_" + k: v for k, v in accs.items()})
     print(json.dumps(result), flush=True)

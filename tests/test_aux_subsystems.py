@@ -115,21 +115,20 @@ def test_profile_bench_breakdown_parser(tmp_path):
 
 
 @pytest.mark.integration
-def test_bench_gpt_mode_oneshot(tmp_path):
-    """bench.py --model gpt (tiny, CPU): the LM benchmark surface emits
-    a parseable tok/s JSON line through the oneshot path."""
-    import json
+@pytest.mark.parametrize("argv", [[], ["--_oneshot", "--model", "gpt"]])
+def test_bench_refuses_to_run_without_a_tpu(argv):
+    """bench.py has no CPU rung: with no TPU both the parent (whose
+    child is forced onto JAX_PLATFORMS=tpu) and a measurement child
+    started on the CPU backend exit non-zero and print nothing that
+    could be read as a device number."""
     import subprocess
     import sys
 
     from conftest import REPO as repo, cpu_subprocess_env
-    env = cpu_subprocess_env(8)
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--_oneshot",
-         "--model", "gpt", "--gpt_tiny", "--batch_per_chip", "2",
-         "--seq_len", "32", "--iters", "2"],
-        env=env, capture_output=True, text=True, timeout=240)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    out = json.loads([l for l in proc.stdout.splitlines()
-                      if l.startswith("{")][-1])
-    assert out["unit"] == "tok/s/chip" and out["value"] > 0
+        [sys.executable, os.path.join(repo, "bench.py")] + argv,
+        env=cpu_subprocess_env(1), capture_output=True, text=True,
+        timeout=120)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == "", proc.stdout
+    assert "TPU" in proc.stderr or "tpu" in proc.stderr

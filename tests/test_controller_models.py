@@ -39,6 +39,29 @@ def test_pod_multi_proc_device_split():
     assert [t.devices for t in pod.trainers] == [[0, 1], [2, 3]]
 
 
+def test_two_trainers_on_one_host_refused_unless_cpu_pinned():
+    """One process per chip: a pod whose trainers would each claim every
+    local chip is refused with a typed error; the CPU harness
+    (JAX_PLATFORMS=cpu) keeps its multi-process pods."""
+    import pytest
+
+    from edl_tpu.controller.train_process import check_chip_ownership
+    from edl_tpu.utils.errors import TrainProcessError
+
+    os.environ["EDL_TPU_DEVICES"] = "0,1,2,3"
+    try:
+        two = Pod.from_env(_job_env(nproc_per_node=2))
+        one = Pod.from_env(_job_env())
+    finally:
+        del os.environ["EDL_TPU_DEVICES"]
+    check_chip_ownership(two, {"JAX_PLATFORMS": "cpu"})
+    for environ in ({}, {"JAX_PLATFORMS": "tpu"},
+                    {"JAX_PLATFORMS": "tpu,cpu"}):
+        check_chip_ownership(one, environ)
+        with pytest.raises(TrainProcessError):
+            check_chip_ownership(two, environ)
+
+
 def test_cluster_ranks_and_roundtrip():
     cluster = Cluster()
     for _ in range(3):

@@ -183,3 +183,16 @@ def test_step_time_breakdown_fields():
     assert t["total_s"] > 0
     assert t["bubble"] == pytest.approx(
         1.0 + 1.0 / costmodel.PIPELINE_MICROBATCHES)
+
+
+def test_peaks_table_is_keyed_by_device_kind_and_unknown_kinds_raise():
+    """One table of published peaks, keyed by what JAX calls the chip;
+    a device that is not in it is an error, never a default."""
+    v5e = costmodel.chip_peaks("TPU v5 lite")
+    assert v5e is costmodel.CHIP_V5E
+    # Google Cloud "TPU v5e": TFLOP/s bf16, GB/s and GB of HBM
+    assert (v5e["bf16_tflops"], v5e["hbm_gbps"], v5e["hbm_gb"]) == (
+        197, 819, 16)
+    for kind in ("cpu", "TPU v4", ""):
+        with pytest.raises(KeyError):
+            costmodel.chip_peaks(kind)

@@ -165,7 +165,7 @@ def test_live_resize_prewarm_hit(tmp_path, monkeypatch):
     swap loads the AOT executable instead of recompiling — the record
     says so, and that is what the doctor's prewarm_miss detector keys
     off."""
-    monkeypatch.setenv("EDL_TPU_COMPILE_CACHE", str(tmp_path / "cache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     tr = _trainer(8)
     _steps(tr, BATCHES[:1])  # the prewarm needs the batch structure
     assert tr.prewarm_resize_compiles([4], block=True) == [4]
@@ -560,7 +560,7 @@ def test_job_doctor_live_resize_findings():
     assert any("resize.live.fallback" in step for step in fall["chain"])
     miss = report["findings"][1]
     assert miss["metric"] == "edl_resize_prewarm_misses_total"
-    assert "EDL_TPU_COMPILE_CACHE" in miss["summary"]
+    assert "JAX_COMPILATION_CACHE_DIR" in miss["summary"]
     assert "doctor-local" in report["summary"]
     json.dumps(report)
     job_doctor.render(report)  # the human surface renders the chains

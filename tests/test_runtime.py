@@ -1260,7 +1260,7 @@ print(json.dumps({"hit": bool(hits and hits[0]), "loss": loss,
 
     def run(n_devices, *args):
         env = cpu_subprocess_env(n_devices,
-                                 EDL_TPU_COMPILE_CACHE=str(cache))
+                                 JAX_COMPILATION_CACHE_DIR=str(cache))
         r = subprocess.run([sys.executable, "-c", script] + list(args),
                            env=env, capture_output=True, text=True,
                            timeout=240)
@@ -1290,24 +1290,14 @@ def test_prewarm_targets_respect_grad_accum_batch_axis(tmp_path,
     2 % 4 != 0."""
     from edl_tpu.models import linear
 
-    # the trainer's cache enablement mutates PROCESS-GLOBAL jax config
-    # that monkeypatch cannot undo — snapshot and restore it, or later
-    # in-process tests inherit a dead per-test cache dir
-    prior_dir = jax.config.jax_compilation_cache_dir
-    prior_floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    monkeypatch.setenv("EDL_TPU_COMPILE_CACHE", str(tmp_path / "cache"))
-    try:
-        trainer = ElasticTrainer(linear.loss_fn, linear.init_params(),
-                                 optax.sgd(0.01), total_batch_size=32,
-                                 grad_accum=2)
-        batch = {"x": np.ones((32, 13), np.float32),
-                 "y": np.ones((32,), np.float32)}
-        trainer.train_step(batch)
-        done = trainer.prewarm_resize_compiles([4])
-        assert done == [4], done
-        aot = tmp_path / "cache" / "aot_steps"
-        assert list(aot.glob("step_w4_*.pkl"))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prior_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prior_floor)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    trainer = ElasticTrainer(linear.loss_fn, linear.init_params(),
+                             optax.sgd(0.01), total_batch_size=32,
+                             grad_accum=2)
+    batch = {"x": np.ones((32, 13), np.float32),
+             "y": np.ones((32,), np.float32)}
+    trainer.train_step(batch)
+    done = trainer.prewarm_resize_compiles([4])
+    assert done == [4], done
+    aot = tmp_path / "cache" / "aot_steps"
+    assert list(aot.glob("step_w4_*.pkl"))
