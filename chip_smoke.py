@@ -25,10 +25,12 @@ each child that needs the chip has exited before the next starts. Every
 chip-side child runs with JAX_PLATFORMS=tpu, so JAX raises instead of
 dropping to the CPU when the chip is missing or held.
 
-Exit 0 and a last stdout line ``{"ok": true, "device": {...}, ...}`` only
-if every phase passed; anything else is a non-zero exit that names the
-phase, and no result line. What the phases print (seconds, compile
-seconds, losses) are observations of a smoke run, not metrics.
+Exit 0 and a last stdout line ``{"ok": true, "device": {"platform": ...,
+"kind": ..., "count": ...}}`` — exactly those keys — only if every phase
+passed; anything else is a non-zero exit that names the phase, and no
+result line. What the phases observed (seconds, compile seconds, losses)
+goes to stderr and ``chiprun_out/chip_smoke/result.json``: observations
+of a smoke run, not metrics.
 
 ``--cpu_tiny`` runs the same phases at toy sizes on the CPU backend (the
 kernel in the Pallas interpreter) so the script itself can be debugged
@@ -684,13 +686,15 @@ def main():
     finally:
         for proc in list(_LIVE):
             _stop(proc)
+    # The last stdout line is the contract's object and nothing more: the
+    # driver refuses any other key. What the phases observed was said on
+    # stderr as each passed and is kept in result.json.
     result = {"ok": True,
-              "device": {"platform": device["platform"],
-                         "kind": device["device_kind"],
-                         "count": device["device_count"]},
-              "phases": phases}
+              "device": {"platform": str(device["platform"]),
+                         "kind": str(device["device_kind"]),
+                         "count": int(device["device_count"])}}
     with open(os.path.join(OUT, "result.json"), "w") as f:
-        json.dump(result, f, indent=1)
+        json.dump(dict(result, phases=phases), f, indent=1)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -45,11 +45,12 @@ def test_parent_imports_no_jax():
                           timeout=60).returncode == 0
 
 
-def _run_smoke(script, cwd, *args):
+def _run_smoke(script, cwd, *args, env=None, timeout=120):
     # the harness env pins JAX_PLATFORMS=cpu; the smoke's chip-side
     # children override it with tpu, which this sandbox cannot satisfy
     return subprocess.run([sys.executable, script] + list(args), cwd=cwd,
-                          capture_output=True, text=True, timeout=120)
+                          env=env, capture_output=True, text=True,
+                          timeout=timeout)
 
 
 def test_no_tpu_fails_fast_and_names_the_device():
@@ -68,9 +69,21 @@ def test_alone_in_a_directory_fails(tmp_path):
 
 
 @pytest.mark.slow
-def test_phases_end_to_end_at_the_cpu_debug_size():
-    r = _run_smoke(SMOKE, REPO, "--cpu_tiny")
+def test_phases_end_to_end_at_the_cpu_debug_size(tmp_path):
+    # the smoke checks that its restart hits the compile cache, so the
+    # cache the harness switches off (conftest) is on here, placed from
+    # outside and out of the checkout
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    r = _run_smoke(SMOKE, REPO, "--cpu_tiny", env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["ok"] and out["device"]["platform"] == "cpu"
-    assert set(out["phases"]) == {"train", "kernel", "serve"}
+    # exactly the contract's keys: the driver refuses anything more
+    assert set(out) == {"ok", "device"} and out["ok"] is True
+    assert set(out["device"]) == {"platform", "kind", "count"}
+    assert out["device"]["platform"] == "cpu"
+    assert isinstance(out["device"]["kind"], str)
+    assert isinstance(out["device"]["count"], int)
+    with open(os.path.join(REPO, "chiprun_out", "chip_smoke",
+                           "result.json")) as f:
+        assert set(json.load(f)["phases"]) == {"train", "kernel", "serve"}
