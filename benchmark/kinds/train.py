@@ -1,0 +1,320 @@
+"""The training kind: an `ElasticTrainer` job that follows the traffic
+file's schedule of phases, counted in STEPS — `{"steps": n}`,
+`{"save": true}` (asynchronous checkpoint), `{"resize": k}` (live resize
+to k chips) — period after period until `--seconds` have passed. A plain
+training cell is a schedule of steps alone; an elastic one saves and
+resizes. Nothing is scheduled by the clock, so a seed does the same work
+in every run.
+
+`correct` (before the window): the program's first steps on the staged
+batch against the plain float32 reference on the same batch — the
+gradient of step 1, read from the optimizer's first moment, as a relative
+L2 error over all parameters, and the loss of each of the first steps;
+in a schedule with resizes, also that the loss after each resize
+continues the loss before it (a state lost at a resize sends the loss
+back to where the seed's weights had it).
+"""
+
+import collections
+import shutil
+import time
+
+from benchmark.lib import optim
+from benchmark.lib.harness import BenchError, key_from_seed, log
+from benchmark.lib.stats import median
+
+
+def _tree_rel_err(a, b):
+    """||a - b|| / ||b|| over two trees of one structure, float32."""
+    import jax
+    import jax.numpy as jnp
+    num = sum(jnp.sum(jnp.square(x.astype(jnp.float32) - y))
+              for x, y in zip(jax.tree_util.tree_leaves(a),
+                              jax.tree_util.tree_leaves(b)))
+    den = sum(jnp.sum(jnp.square(y)) for y in jax.tree_util.tree_leaves(b))
+    return jnp.sqrt(num / den)
+
+
+def make_job(run):
+    """Seeded weights (reference layout and program layout) and the
+    staged batch for this cell, made on the device in one jitted call."""
+    import jax
+    cfg, job = run.config, run.traffic
+    fam, ref = run.program(), run.reference()
+    key = key_from_seed(run.seed)
+    rows = job["batch_per_chip"] * run.cell["chips"]
+
+    @jax.jit
+    def seeded(key):
+        w = ref.init_weights(cfg, jax.random.fold_in(key, 0))
+        return (w, fam.to_program(w, cfg),
+                fam.make_batch(cfg, job, jax.random.fold_in(key, 1), rows))
+
+    w, (params, extra), batch = seeded(key)
+    return {"cfg": cfg, "job": job, "fam": fam, "ref": ref, "w": w,
+            "params": params, "extra": extra, "batch": batch, "rows": rows,
+            "ref_steps": {}}
+
+
+def make_trainer(run, j):
+    from edl_tpu.runtime.mesh import make_mesh
+    from edl_tpu.runtime.trainer import ElasticTrainer
+    import jax
+    loss_fn, has_aux, shapes = j["fam"].train_parts(j["cfg"], j["job"])
+    got = jax.eval_shape(lambda: (j["params"], j["extra"]))
+    if (jax.tree_util.tree_structure(got)
+            != jax.tree_util.tree_structure(shapes)
+            or [x.shape for x in jax.tree_util.tree_leaves(got)]
+            != [x.shape for x in jax.tree_util.tree_leaves(shapes)]):
+        raise BenchError("the program's parameter tree no longer matches "
+                         "benchmark/program/%s.py" % run.config["family"])
+    saves = any("save" in p for p in j["job"]["schedule"])
+    j["ckpt_dir"] = run.scratch_dir("ckpt") if saves else ""
+    return ElasticTrainer(
+        loss_fn, j["params"], optim.make_tx(j["job"]["optimizer"]),
+        total_batch_size=j["rows"], checkpoint_dir=j["ckpt_dir"],
+        mesh=make_mesh(devices=run.devices), extra_state=j["extra"],
+        has_aux=has_aux, async_save=True)
+
+
+def _reference_step_fn(j, q):
+    """One jitted plain step, traced once per (job, precision)."""
+    import jax
+    if q not in j["ref_steps"]:
+        cfg, ref, spec = j["cfg"], j["ref"], j["job"]["optimizer"]
+
+        @jax.jit
+        def step(w, m, v, t, batch):
+            loss, g = ref.loss_and_grad(w, batch, cfg, q)
+            w2, st = optim.ref_update(spec, w, g, {"m": m, "v": v, "t": t})
+            return loss, j["fam"].to_program(g, cfg)[0], w2, st["m"], st["v"]
+
+        j["ref_steps"][q] = step
+    return j["ref_steps"][q]
+
+
+def reference_steps(j, n_steps, q=None):
+    """Losses of the first `n_steps` plain steps and the gradient of the
+    first, in the program's parameter layout."""
+    step = _reference_step_fn(j, q)
+    w = j["w"]
+    st = optim.ref_init(j["job"]["optimizer"], w)
+    m, v = st["m"], st["v"]
+    losses, g0 = [], None
+    for t in range(n_steps):
+        loss, g, w, m, v = step(w, m, v, t, j["batch"])
+        losses.append(float(loss))
+        g0 = g if t == 0 else g0
+    return losses, g0
+
+
+def check_first_steps(run, j, trainer, staged):
+    """The comparison that decides `correct`; see the module docstring.
+    Returns the numbers compared, for tools/limits.py."""
+    import jax
+    job = j["job"]
+    n = job["check_steps"]
+    scale = optim.moment_scale(job["optimizer"])
+    losses = []
+    for i in range(n):
+        loss = trainer.train_step(staged)
+        jax.block_until_ready(loss)
+        if i == 0:
+            moment = optim.first_moment(trainer.train_state["opt_state"],
+                                        trainer.train_state["params"])
+            moment = jax.tree_util.tree_map(lambda x: x / scale, moment)
+        losses.append(float(loss))
+    with run.span("setup:reference"):
+        ref_losses, g0 = reference_steps(j, n)
+    grad_err = float(jax.jit(_tree_rel_err)(moment, g0))
+    loss_err = max(abs(a - b) / abs(b) for a, b in zip(losses, ref_losses))
+    log("first losses: program %s reference %s" % (losses, ref_losses))
+    return {"grad_rel_err": grad_err, "loss_rel_err": loss_err}
+
+
+#: tools/limits.py: what is put in the program's place — the reference
+#: with both operands of every product rounded to int8, the nearest
+#: precision below the configuration's bf16
+CONTROLS = {"int8": {"q": "int8"}}
+
+
+def compared_numbers(run, names):
+    """tools/limits.py: {name: the numbers `correct` compares} for one
+    seed; the name None is the program, any other one of CONTROLS. A
+    control is the reference alone: it needs one chip, whatever the cell
+    holds."""
+    import jax
+    j = make_job(run)
+    out = {}
+    if None in names:
+        trainer = make_trainer(run, j)
+        try:
+            out[None] = check_first_steps(run, j, trainer,
+                                          trainer.place_batch(j["batch"]))
+        finally:
+            trainer.close()
+    n = j["job"]["check_steps"]
+    sound = None
+    for name in [c for c in names if c is not None]:
+        sound = sound or reference_steps(j, n)
+        losses, g = reference_steps(j, n, **CONTROLS[name])
+        out[name] = {
+            "grad_rel_err": float(jax.jit(_tree_rel_err)(g, sound[1])),
+            "loss_rel_err": max(abs(a - b) / abs(b)
+                                for a, b in zip(losses, sound[0]))}
+    return out
+
+
+class _Driver(object):
+    """Walks the schedule; keeps at most `depth` steps in flight."""
+
+    def __init__(self, run, j, trainer):
+        self.run, self.j, self.trainer = run, j, trainer
+        self.depth = j["job"]["steps_in_flight"]
+        self.world = len(run.devices)
+        self.staged = {}
+        self.samples = 0
+        self.steps = 0
+        self.chip_steps = 0
+        self.pauses = []       # ms, one per live resize
+        self.save_stalls = []  # ms
+        self.resize_records = []
+        self.jumps = []        # |loss after - loss before| / loss before
+        self.last_loss = None
+        self._pause_from = None
+        self._excuse = None
+
+    def batch(self):
+        if self.world not in self.staged:
+            self.staged[self.world] = self.trainer.place_batch(
+                self.j["batch"])
+        return self.staged[self.world]
+
+    def _first_after_resize(self, loss):
+        import jax
+        jax.block_until_ready(loss)
+        self.pauses.append((time.monotonic() - self._pause_from) * 1e3)
+        self._excuse.__exit__(None, None, None)
+        self._pause_from = self._excuse = None
+        rec = self.trainer.resize_timing
+        self.resize_records.append(
+            {k: rec.get(k) for k in ("reshard_s", "compile_s", "drain_s",
+                                     "first_step_s", "prewarm",
+                                     "to_devices")})
+        after = float(loss)
+        self.jumps.append(abs(after - self.last_loss) / abs(self.last_loss))
+
+    def phase(self, p):
+        import jax
+        run, tr = self.run, self.trainer
+        if "steps" in p:
+            batch = self.batch()
+            with run.span("steps"):
+                pending = collections.deque()
+                for _ in range(p["steps"]):
+                    with run.span("train_step" if self._pause_from is None
+                                  else "resize_first_step"):
+                        loss = tr.train_step(batch)
+                    if self._pause_from is not None:
+                        self._first_after_resize(loss)
+                    pending.append(loss)
+                    if len(pending) > self.depth:
+                        jax.block_until_ready(pending.popleft())
+                with run.span("drain_steps"):
+                    jax.block_until_ready(loss)
+            self.last_loss = float(loss)
+            self.steps += p["steps"]
+            self.chip_steps += p["steps"] * self.world
+            self.samples += p["steps"] * self.j["rows"]
+        elif "save" in p:
+            t = time.monotonic()
+            with run.span("save"):
+                tr.save()
+            self.save_stalls.append((time.monotonic() - t) * 1e3)
+        elif "resize" in p:
+            self._excuse = run.compiles.excused()
+            self._excuse.__enter__()
+            self._pause_from = time.monotonic()
+            with run.span("live_resize"):
+                tr.live_resize(p["resize"])
+            self.world = p["resize"]
+        else:
+            raise BenchError("unknown phase %r in the schedule" % (p,))
+
+    def period(self):
+        for p in self.j["job"]["schedule"]:
+            self.phase(p)
+        if self._pause_from is not None or self.world != len(
+                self.run.devices):
+            raise BenchError("a period must end in steps, on the chips "
+                             "it began on")
+
+
+def run(run):
+    run.claim_devices()
+    with run.span("setup:seed"):
+        j = make_job(run)
+        trainer = make_trainer(run, j)
+    job = j["job"]
+    drv = _Driver(run, j, trainer)
+    try:
+        with run.span("setup:check"):
+            got = check_first_steps(run, j, trainer, drv.batch())
+        for name, value in sorted(got.items()):
+            run.check(name, value, job["limits"][name])
+        # the reference's weights and programs are not needed again
+        for k in ("w", "params", "extra"):
+            j.pop(k)
+        j["ref_steps"].clear()
+        smaller = sorted({p["resize"] for p in job["schedule"]
+                          if "resize" in p and p["resize"] < drv.world})
+        if smaller:
+            with run.span("setup:prewarm"):
+                trainer.prewarm_resize_compiles(smaller, block=True)
+        with run.span("setup:warm_period"):
+            drv.period()   # every phase once: its programs and paths
+        warm_jumps, staged = drv.jumps, drv.staged
+        drv = _Driver(run, j, trainer)
+        drv.staged = staged
+        t0 = run.window_open()
+        periods = []
+        traced = [1, 1 + job["trace_periods"]] if run.traced else None
+        while True:
+            if traced and len(periods) == traced[0]:
+                run.trace_start()
+                before = (drv.steps, drv.chip_steps)
+            p0 = time.monotonic()
+            drv.period()
+            periods.append((p0, time.monotonic()))
+            if traced and len(periods) == traced[1]:
+                run.trace_stop()
+                run.count("traced_steps", drv.steps - before[0])
+                run.count("traced_chip_steps", drv.chip_steps - before[1])
+                traced = None
+            if time.monotonic() - t0 >= run.seconds and traced is None:
+                break
+        t1 = run.window_close()
+    finally:
+        trainer.close()
+        if j["ckpt_dir"]:
+            shutil.rmtree(j["ckpt_dir"], ignore_errors=True)
+    if drv.jumps or warm_jumps:
+        run.check("resize_loss_jump", max(drv.jumps + warm_jumps),
+                  job["limits"]["resize_loss_jump"])
+    chips = len(run.devices)
+    flops = j["fam"].train_flops(j["cfg"], job, j["rows"])
+    run.counters.update(
+        steps=drv.steps, periods=len(periods), step_flops=flops,
+        save_stall_ms=drv.save_stalls, resize_records=drv.resize_records,
+        resize_pause_ms=drv.pauses)
+    run.record(periods=[[a - t0, b - t0] for a, b in periods],
+               pauses_ms=drv.pauses, save_stalls_ms=drv.save_stalls,
+               resizes=drv.resize_records, loss_jumps=drv.jumps)
+    # the file names the rate: a schedule that saves and resizes inside
+    # the window measures goodput, another quantity than a plain job's
+    end_to_end = {job["rate_metric"]: drv.samples / (t1 - t0) / chips}
+    if drv.pauses:
+        end_to_end["resize_pause_ms"] = median(drv.pauses)
+    # attempted = optimizer steps asked of the trainer; a step that
+    # raises ends the run, so none fails quietly
+    return run.finish(drv.steps, 0, end_to_end)

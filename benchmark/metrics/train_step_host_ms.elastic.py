@@ -1,0 +1,3 @@
+"""trainer layer: wall time per step that the device did not cover, inside
+the runs of steps (saves and pauses are not steps)."""
+from benchmark.lib.readers import train_step_host_ms as read  # noqa: F401
