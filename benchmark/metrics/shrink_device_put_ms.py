@@ -1,0 +1,7 @@
+"""live resize layer: span `resize.device_put` (the reshard itself: `device_put`
+of the state onto the new mesh, to ready), median over the window's shrinks."""
+from benchmark.lib import progspans
+
+
+def read(view):
+    return progspans.resize_ms(view, "shrink", "device_put")

@@ -38,11 +38,12 @@ import time
 import numpy as np
 
 from edl_tpu.distill.discovery_client import DiscoveryClient, FixedDiscover
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.robustness.policy import CircuitBreaker
 from edl_tpu.rpc import ndarray as nd
 from edl_tpu.rpc.client import RpcClient
 from edl_tpu.rpc.pool import ClientPool
-from edl_tpu.utils import errors, timeline
+from edl_tpu.utils import errors
 from edl_tpu.utils.logger import logger
 
 #: sentinel payload marking a result slot that carries a permanent
@@ -328,7 +329,6 @@ class DistillReader(object):
         depth = self._pipeline_depth if conn.pipelined else 1
         logger.info("distill worker up for teacher %s (depth=%d)",
                     endpoint, depth)
-        tl = timeline.get_timeline()
         pending = collections.deque()  # (task, _PredictFuture) in flight
         ok = True
         while not (stop_ev.is_set() or self._stop.is_set()):
@@ -340,7 +340,7 @@ class DistillReader(object):
             task, fut = pending.popleft()
             epoch, task_id, feed, payload = task
             try:
-                with tl.span("predict@%s" % endpoint):
+                with obs_trace.span("distill.predict", endpoint=endpoint):
                     preds = fut.result()
             except errors.OverloadedError as e:
                 # typed shed from admission control: requeue elsewhere,

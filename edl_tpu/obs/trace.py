@@ -16,26 +16,49 @@ buffer (:data:`TRACER`, default 4096 spans) and are exported on demand
 — :meth:`Tracer.chrome_trace` emits ``chrome://tracing`` /
 Perfetto-loadable JSON. Nothing is written anywhere at runtime.
 
-Cost model: with tracing disabled and no propagated context (the
-default), ``begin_span`` is one attr load + two falsy checks →
-``None``; every downstream call no-ops on ``span is None``. Tracing
-turns on per-process via ``EDL_TPU_TRACE=1`` or ``TRACER.enable()``;
-a propagated remote context is honored even when local sampling is
-off, so one traced client lights up the whole call tree.
+Two classes of span share the API. **Sampled** spans (every RPC) exist
+only while something is tracing: with tracing disabled and no
+propagated context (the default), ``begin_span`` is one attr load + two
+falsy checks → ``None``; every downstream call no-ops on ``span is
+None``. Sampling turns on per-process via ``EDL_TPU_TRACE=1`` or
+``TRACER.enable()``; a propagated remote context is honored even when
+local sampling is off, so one traced client lights up the whole call
+tree. **Stage** spans (``stage=True``: a handful per live resize or
+save, never per step or per request) are always opened and land in the
+ring whatever the sampling switch says — the benchmark's readers and
+the flight recorder need a resize's stages in every run; ``EDL_TPU_OBS=0``
+keeps them out of the ring (the span object is still handed back, so a
+caller that reads its duration keeps working).
+
+One clock with the profiler: a span records its start on
+``time.monotonic()`` (``t0``) beside the unix ``ts``, and a span opened
+through :func:`span` / :func:`server_span` also enters
+``jax.profiler.TraceAnnotation("edl:" + name)`` when ``jax`` is already
+imported in the process — a no-op TraceMe without a profiler session,
+a host-line event beside the device operations with one. This module
+never imports jax itself (``obs`` stays a leaf: a control-plane process
+pays nothing). Bare :func:`begin_span` / :func:`end_span` pairs are not
+annotated: an RPC span may close on another thread than it opened on.
 """
 
 import contextlib
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
+
+from edl_tpu.obs import metrics as _metrics
 
 _tls = threading.local()
 
 #: env switch for root sampling (child spans of a propagated context
 #: are always recorded — the caller already paid for the trace)
 TRACE_ENV = "EDL_TPU_TRACE"
+
+#: what a span is called on the profiler's host line
+ANNOTATION_PREFIX = "edl:"
 
 
 def _new_id():
@@ -44,9 +67,10 @@ def _new_id():
 
 class Span(object):
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "kind",
-                 "ts", "_t0", "dur_ms", "tags", "pid")
+                 "ts", "_t0", "dur_ms", "tags", "pid", "recorded")
 
-    def __init__(self, trace_id, span_id, parent_id, name, kind, tags):
+    def __init__(self, trace_id, span_id, parent_id, name, kind, tags,
+                 recorded=True):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
@@ -57,12 +81,25 @@ class Span(object):
         self.dur_ms = None
         self.tags = tags
         self.pid = os.getpid()
+        # False: timed for its caller, kept out of the ring and off the
+        # profiler's line (a stage span under EDL_TPU_OBS=0)
+        self.recorded = recorded
+
+    @property
+    def seconds(self):
+        """Duration of a closed span, in seconds."""
+        return self.dur_ms / 1e3
+
+    def tag(self, **tags):
+        """Add tags to a span that is still open."""
+        self.tags = dict(self.tags or {}, **tags)
 
     def to_dict(self):
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id, "name": self.name,
-                "kind": self.kind, "ts": self.ts, "dur_ms": self.dur_ms,
-                "tags": self.tags or {}, "pid": self.pid}
+                "kind": self.kind, "ts": self.ts, "t0": self._t0,
+                "dur_ms": self.dur_ms, "tags": self.tags or {},
+                "pid": self.pid}
 
 
 class Tracer(object):
@@ -139,16 +176,21 @@ def inject():
     return [ctx[0], ctx[1]] if ctx is not None else None
 
 
-def begin_span(name, kind="local", parent=None, root=False, tags=None):
+def begin_span(name, kind="local", parent=None, root=False, tags=None,
+               stage=False):
     """Open a span, or return None when nothing is tracing.
 
-    A span is created iff one of: ``parent`` (a remote ``[trace_id,
-    span_id]`` header) is given; this thread has an active context;
-    ``root=True``/sampling is enabled (starts a fresh trace). The
-    caller must pass the result to :func:`end_span` (None is fine).
+    A span is created iff one of: ``parent`` (a ``[trace_id, span_id]``
+    header: a remote caller's, or a span of this process handed to
+    another thread) is given; this thread has an active context;
+    ``root=True``/``stage=True``/sampling is enabled (starts a fresh
+    trace). The caller must pass the result to :func:`end_span` (None
+    is fine). A ``stage`` span is never None (its ``parent``, when
+    given, comes from :func:`current`, not off the wire).
     """
     ctx = getattr(_tls, "ctx", None)
-    if parent is None and ctx is None and not (root or TRACER._enabled):
+    if parent is None and ctx is None \
+            and not (root or stage or TRACER._enabled):
         return None
     if parent is not None:
         try:
@@ -159,7 +201,8 @@ def begin_span(name, kind="local", parent=None, root=False, tags=None):
         trace_id, parent_id = ctx
     else:
         trace_id, parent_id = _new_id() + _new_id(), None
-    return Span(trace_id, _new_id(), parent_id, name, kind, tags)
+    return Span(trace_id, _new_id(), parent_id, name, kind, tags,
+                recorded=_metrics._ENABLED or not stage)
 
 
 def end_span(span, **extra_tags):
@@ -169,18 +212,24 @@ def end_span(span, **extra_tags):
         return
     span.dur_ms = (time.monotonic() - span._t0) * 1e3
     if extra_tags:
-        span.tags = dict(span.tags or {}, **extra_tags)
-    TRACER._record(span)
+        span.tag(**extra_tags)
+    if span.recorded:
+        TRACER._record(span)
 
 
 @contextlib.contextmanager
-def span(name, kind="local", root=False, **tags):
-    """Span context manager; activates the span as this thread's
-    context so nested spans / outbound RPCs chain under it."""
-    sp = begin_span(name, kind=kind, root=root, tags=tags or None)
+def _active(sp):
+    """``sp`` as this thread's context — nested spans / outbound RPCs
+    chain under it — and, where jax is loaded, as an ``edl:<name>``
+    annotation on the profiler's host line."""
     if sp is None:
         yield None
         return
+    ann = None
+    jax = sys.modules.get("jax") if sp.recorded else None
+    if jax is not None:
+        ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + sp.name)
+        ann.__enter__()
     prev = getattr(_tls, "ctx", None)
     _tls.ctx = (sp.trace_id, sp.span_id)
     try:
@@ -188,22 +237,22 @@ def span(name, kind="local", root=False, **tags):
     finally:
         _tls.ctx = prev
         end_span(sp)
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
-@contextlib.contextmanager
+def span(name, kind="local", root=False, parent=None, stage=False, **tags):
+    """Span context manager (see :func:`begin_span` for when a span
+    exists; yields it or None). ``parent`` chains a span opened on
+    another thread under the span it serves: same ``trace_id``."""
+    return _active(begin_span(name, kind=kind, parent=parent, root=root,
+                              tags=tags or None, stage=stage))
+
+
 def server_span(name, header, **tags):
     """Dispatch-side span adopting a remote ``[trace_id, span_id]``
     header as parent (None header → plain :func:`span` semantics, which
     usually means "no span at all"). Activates the context for the
     handler's duration so nested client calls propagate the trace."""
-    sp = begin_span(name, kind="server", parent=header, tags=tags or None)
-    if sp is None:
-        yield None
-        return
-    prev = getattr(_tls, "ctx", None)
-    _tls.ctx = (sp.trace_id, sp.span_id)
-    try:
-        yield sp
-    finally:
-        _tls.ctx = prev
-        end_span(sp)
+    return _active(begin_span(name, kind="server", parent=header,
+                              tags=tags or None))

@@ -63,6 +63,7 @@ import numpy as np
 from edl_tpu.obs import events as obs_events
 from edl_tpu.obs import ledger as obs_ledger
 from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.runtime.fs import get_fs
 from edl_tpu.utils.logger import logger
 
@@ -277,9 +278,10 @@ class _HostBufferPool(object):
 class SaveHandle(object):
     """Completion handle for an async checkpoint save.
 
-    ``blocked_s`` is the training-thread (snapshot) time; ``persist_s``
-    the background write time, set once the persist finishes. wait()
-    blocks without raising; result() re-raises any persist failure."""
+    ``blocked_s`` is the training-thread (snapshot) time, read off the
+    ``save.snapshot`` stage span; ``persist_s`` the background write
+    time, set once the persist finishes. wait() blocks without raising;
+    result() re-raises any persist failure."""
 
     def __init__(self, version):
         self.version = version
@@ -767,26 +769,30 @@ class CheckpointManager(object):
         of an already-durable commit: an on_commit failure is logged,
         never surfaced as a save failure."""
         self.drain()
-        t0 = time.perf_counter()
         # the snapshot is the async save's only training-thread cost
-        with obs_ledger.LEDGER.state("ckpt_block"):
+        with obs_trace.span("save.snapshot", stage=True) as sp, \
+                obs_ledger.LEDGER.state("ckpt_block"):
             entries, dtypes = self._snapshot_dense(tree)
         handle = SaveHandle(version)
-        handle.blocked_s = time.perf_counter() - t0
+        handle.blocked_s = sp.seconds
+        save_span = obs_trace.current()  # the trainer's `save`, or None
 
         def persist():
             p0 = time.perf_counter()
             try:
-                vdir = self._vdir(version)
-                self._fs.delete_tree(vdir)
-                self._fs.makedirs(vdir)
-                table, total = self._write_entries(vdir, "", entries)
-                with self._fs.open(vdir + "/meta.json", "w") as f:
-                    json.dump({"meta": meta or {}, "dtypes": dtypes}, f)
-                # the commit point:
-                with self._fs.open(vdir + "/MANIFEST", "w") as f:
-                    json.dump({"version": version, "format": "stream",
-                               "entries": table, "nbytes": total}, f)
+                with obs_trace.span("save.persist", stage=True,
+                                    parent=save_span, version=version):
+                    vdir = self._vdir(version)
+                    self._fs.delete_tree(vdir)
+                    self._fs.makedirs(vdir)
+                    table, total = self._write_entries(vdir, "", entries)
+                    with self._fs.open(vdir + "/meta.json", "w") as f:
+                        json.dump({"meta": meta or {}, "dtypes": dtypes},
+                                  f)
+                    # the commit point:
+                    with self._fs.open(vdir + "/MANIFEST", "w") as f:
+                        json.dump({"version": version, "format": "stream",
+                                   "entries": table, "nbytes": total}, f)
                 logger.info("checkpoint v%d committed async (%d entries,"
                             " %.1f MB)", version, len(table),
                             total / 1e6)
@@ -1074,10 +1080,11 @@ class CheckpointManager(object):
         shardmeta/MANIFEST carry ``format: "stream"`` with the per-file
         entry tables instead of per-rank npz crcs."""
         self.drain()
-        t0 = time.perf_counter()
-        entries, dtypes = self._snapshot_sharded(tree, rank)
+        with obs_trace.span("save.snapshot", stage=True) as sp:
+            entries, dtypes = self._snapshot_sharded(tree, rank)
         handle = SaveHandle(version)
-        handle.blocked_s = time.perf_counter() - t0
+        handle.blocked_s = sp.seconds
+        save_span = obs_trace.current()  # the trainer's `save`, or None
         vdir = self._vdir(version)
 
         def write_rank_files():
@@ -1110,9 +1117,11 @@ class CheckpointManager(object):
         def persist():
             p0 = time.perf_counter()
             try:
-                out = self._sharded_protocol(version, rank, nranks,
-                                             barrier, timeout,
-                                             write_rank_files, commit)
+                with obs_trace.span("save.persist", stage=True,
+                                    parent=save_span, version=version):
+                    out = self._sharded_protocol(version, rank, nranks,
+                                                 barrier, timeout,
+                                                 write_rank_files, commit)
                 if on_commit is not None:
                     # same contract as save_async: commit observers
                     # are best-effort once the protocol completed

@@ -34,6 +34,7 @@ from edl_tpu.obs import events as obs_events
 from edl_tpu.obs import flight as obs_flight
 from edl_tpu.obs import ledger as obs_ledger
 from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.robustness import faults
 from edl_tpu.runtime import checkpoint as checkpoint_mod
 from edl_tpu.runtime import state as state_mod
@@ -54,6 +55,49 @@ _PREWARM_MISSES = obs_metrics.counter(
     "edl_resize_prewarm_misses_total",
     "first steps in prewarm scope with no usable AOT artifact "
     "(full compile paid)")
+
+#: what JAX did inside ``resize.first_dispatch``: the jax.monitoring
+#: duration events that mean a program was traced, lowered, compiled or
+#: loaded from the persistent cache, each under the tag it feeds
+_JAX_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_s",
+    "/jax/core/compile/backend_compile_duration": "jax_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax_cache_load_s",
+}
+#: ``intervals`` is a list while this thread is inside a first dispatch
+_jax_stages = threading.local()
+
+
+def _on_jax_duration(event, duration, **_):
+    seen = getattr(_jax_stages, "intervals", None)
+    if seen is not None and event in _JAX_STAGE_EVENTS:
+        end = time.monotonic()
+        seen.append((_JAX_STAGE_EVENTS[event], end - duration, end))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _jax_stage_seconds(seen):
+    """{tag: seconds} of the intervals ``_on_jax_duration`` collected,
+    made to add up: a jitted function traced or lowered inside another
+    fires an event of its own within the outer one's, so each tag is the
+    length of its intervals' UNION; and the cache retrieval runs inside
+    the backend-compile event, so ``jax_compile_s`` is that event less
+    the retrieval — on a cache hit next to nothing."""
+    out = {}
+    for tag in _JAX_STAGE_EVENTS.values():
+        total, covered = 0.0, float("-inf")
+        for a, b in sorted((a, b) for t, a, b in seen if t == tag):
+            if b > covered:
+                total += b - max(a, covered)
+                covered = b
+        out[tag] = total
+    out["jax_compile_s"] = max(
+        0.0, out["jax_compile_s"] - out["jax_cache_load_s"])
+    return {tag: round(v, 6) for tag, v in out.items()}
+
 
 _distributed_initialized = False
 
@@ -595,6 +639,10 @@ class ElasticTrainer(object):
         # first step after every live_resize() (same record semantics
         # as a restart, without the restart)
         self._stamp_first_step = True
+        # [trace_id, span_id] of the last live resize's root span, for
+        # the first step after it to join; None for a fresh incarnation
+        self._resize_trace = None
+        self._prewarm_s = 0.0  # see _try_load_prewarmed_step
         # live-resize protocol state (enable_live_resize)
         self._live_watcher = None
         self._live_register = None
@@ -894,11 +942,16 @@ class ElasticTrainer(object):
         return targets
 
     def _try_load_prewarmed_step(self):
-        """At the first train_step: if a prior incarnation serialized
-        THIS world size's step executable, load it and skip the
-        compile. Returns a jit_step-compatible callable or None."""
+        """At the first train_step of an incarnation and inside a live
+        resize: if an earlier prewarm serialized THIS world size's step
+        executable, load it and skip the compile. Returns a
+        jit_step-compatible callable or None, and leaves in
+        ``self._prewarm_s`` the seconds its two stage spans took:
+        ``resize.prewarm_fingerprint``, a trace and lowering of the step
+        that only names the artifact, and ``resize.prewarm_load``."""
         import pickle
 
+        self._prewarm_s = 0.0
         if self._prewarm_in_scope() is not None:
             return None
         aot = compile_cache.aot_dir()
@@ -914,33 +967,42 @@ class ElasticTrainer(object):
         if not glob_mod.glob(os.path.join(aot, "step_w%d_*.pkl" % n)):
             _PREWARM_MISSES.inc()
             return None
-        try:
-            _, fp = self._step_lowered()
-        except Exception:
-            logger.exception("prewarm load: lowering failed")
-            _PREWARM_MISSES.inc()
-            return None
+        fp = None
+        with obs_trace.span("resize.prewarm_fingerprint", stage=True,
+                            world=n) as sp_fp:
+            try:
+                _, fp = self._step_lowered()
+            except Exception:
+                logger.exception("prewarm load: lowering failed")
+        self._prewarm_s = sp_fp.seconds
         path = os.path.join(aot, "step_w%d_%s.pkl" % (n, fp))
-        if not os.path.exists(path):
+        if fp is None or not os.path.exists(path):
             _PREWARM_MISSES.inc()
             return None
         from jax.experimental import serialize_executable as se
-        t0 = time.perf_counter()
-        try:
-            with open(path, "rb") as f:
-                blob = pickle.load(f)
-        except (OSError, EOFError, pickle.UnpicklingError):
-            logger.exception("prewarm load: unreadable artifact %s "
-                             "(falling back to the normal compile)", path)
+        loaded = None
+        with obs_trace.span("resize.prewarm_load", stage=True,
+                            world=n) as sp_load:
+            try:
+                with open(path, "rb") as f:
+                    blob = pickle.load(f)
+            except (OSError, EOFError, pickle.UnpicklingError):
+                logger.exception(
+                    "prewarm load: unreadable artifact %s (falling back "
+                    "to the normal compile)", path)
+            else:
+                # NOT guarded: the fingerprint matched (same jax, same
+                # lowered step), so an executable that will not
+                # deserialize is a bug to surface, not a cache miss to
+                # count. A sub-mesh executable must be told its devices,
+                # or it expects one shard per process device.
+                loaded = se.deserialize_and_load(
+                    blob["payload"], blob["in_tree"], blob["out_tree"],
+                    execution_devices=list(self.mesh.devices.flat))
+        self._prewarm_s += sp_load.seconds
+        if loaded is None:
             _PREWARM_MISSES.inc()
             return None
-        # NOT guarded: the fingerprint matched (same jax, same lowered
-        # step), so an executable that will not deserialize is a bug to
-        # surface, not a cache miss to count. A sub-mesh executable must
-        # be told its devices, or it expects one shard per process device.
-        loaded = se.deserialize_and_load(
-            blob["payload"], blob["in_tree"], blob["out_tree"],
-            execution_devices=list(self.mesh.devices.flat))
         repl = self._repl
         jit_fallback = self._jit_step
 
@@ -965,8 +1027,7 @@ class ElasticTrainer(object):
                 return jit_fallback(state, batch, rng)
 
         logger.info("resize prewarm HIT: world-%d step loaded from %s in "
-                    "%.2fs (compile skipped)", n, path,
-                    time.perf_counter() - t0)
+                    "%.2fs (compile skipped)", n, path, sp_load.seconds)
         _PREWARM_HITS.inc()
         return step
 
@@ -1006,6 +1067,52 @@ class ElasticTrainer(object):
         first_step_s, restore_s, ...; docs/elastic_resize.md) — a copy."""
         return dict(self._resize_timing)
 
+    def _first_step(self, batch, rng):
+        """The first step of an incarnation, and the first after every
+        live_resize() (which re-arms ``_stamp_first_step``): the resize
+        downtime's last stages, as stage spans in the trace of the
+        resize they end. ``resize.first_dispatch`` is the call of the
+        step (trace, lowering, compile or cache load, enqueue: its tags
+        say how much of each), ``resize.first_result`` the wait for the
+        first real step — a block_until_ready that costs nothing the
+        caller would not pay anyway, once per resize."""
+        prewarm_s = 0.0
+        if self._example_batch_sds is None:
+            self._example_batch_sds = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
+            loaded = self._try_load_prewarmed_step()
+            prewarm_s = self._prewarm_s
+            if loaded is not None:
+                self._jit_step = loaded
+        with obs_trace.span("resize.first_step", stage=True,
+                            parent=self._resize_trace):
+            with obs_trace.span("resize.first_dispatch",
+                                stage=True) as sp_dispatch:
+                _jax_stages.intervals = seen = []
+                try:
+                    self.train_state, loss = self._jit_step(
+                        self.train_state, batch, rng)
+                finally:
+                    _jax_stages.intervals = None
+                sp_dispatch.tag(**_jax_stage_seconds(seen))
+            with obs_trace.span("resize.first_result",
+                                stage=True) as sp_result:
+                jax.block_until_ready(loss)
+        self._stamp_first_step = False
+        self._resize_trace = None
+        self._resize_timing["compile_s"] = prewarm_s + sp_dispatch.seconds
+        self._resize_timing["first_step_s"] = sp_result.seconds
+        self._resize_timing["t_first_step"] = time.time()
+        # close the pause HERE so the published ledger snapshot
+        # already carries the full resize_pause for this arc
+        obs_ledger.LEDGER.transition("compute")
+        self._publish_resize_timing()
+        obs_events.emit("resize.first_step",
+                        rank=self.env.global_rank,
+                        compile_s=self._resize_timing["compile_s"],
+                        first_step_s=self._resize_timing["first_step_s"])
+        return loss
+
     _STEP_WINDOW = 8  # intervals kept for the cadence estimate
 
     def train_step(self, host_batch, rng=None):
@@ -1014,8 +1121,9 @@ class ElasticTrainer(object):
             # steady state: the step boundary re-claims the clock for
             # compute. After a resize the clock stays on resize_pause /
             # restore until the first step's result is READY (stamped
-            # below) — the ledger's pause must agree with measure_resize,
-            # which measures to first-step completion, not dispatch.
+            # in _first_step) — the ledger's pause must agree with
+            # measure_resize, which measures to first-step completion,
+            # not dispatch.
             obs_ledger.LEDGER.transition("compute")
         if self._last_step_start is not None:
             self._step_intervals.append(t0 - self._last_step_start)
@@ -1024,36 +1132,11 @@ class ElasticTrainer(object):
         if rng is None:
             rng = jax.random.PRNGKey(self._host_step)
         batch = self.place_batch(host_batch)
-        first_step = self._example_batch_sds is None
-        if first_step:
-            self._example_batch_sds = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
-            loaded = self._try_load_prewarmed_step()
-            if loaded is not None:
-                self._jit_step = loaded
-        self.train_state, loss = self._jit_step(self.train_state, batch, rng)
         if self._stamp_first_step:
-            self._stamp_first_step = False
-            # resize downtime breakdown: the first dispatch wall is
-            # (almost entirely) trace+compile; the extra wait to result
-            # availability is the first real step. Once per incarnation
-            # AND once per live_resize (which re-arms the flag), so the
-            # block_until_ready costs nothing the caller would not pay
-            # anyway.
-            c1 = time.perf_counter()
-            self._resize_timing["compile_s"] = c1 - t0
-            jax.block_until_ready(loss)
-            self._resize_timing["first_step_s"] = time.perf_counter() - c1
-            self._resize_timing["t_first_step"] = time.time()
-            # close the pause HERE so the published ledger snapshot
-            # already carries the full resize_pause for this arc
-            obs_ledger.LEDGER.transition("compute")
-            self._publish_resize_timing()
-            obs_events.emit("resize.first_step",
-                            rank=self.env.global_rank,
-                            compile_s=self._resize_timing["compile_s"],
-                            first_step_s=self._resize_timing
-                            ["first_step_s"])
+            loss = self._first_step(batch, rng)
+        else:
+            self.train_state, loss = self._jit_step(self.train_state,
+                                                    batch, rng)
         self._host_step += 1
         step_s = time.perf_counter() - t0
         self._step_times.append(step_s)
@@ -1288,93 +1371,109 @@ class ElasticTrainer(object):
         # step result (train_step closes the pause when it stamps);
         # the drain below nests ckpt_block over this and returns here
         obs_ledger.LEDGER.transition("resize_pause")
-        try:
-            t0 = time.perf_counter()
-            if faults.PLANE is not None:
-                faults.PLANE.fire("resize.live.drain",
-                                  from_devices=str(old_n),
-                                  to_devices=str(n_devices))
-            # drain: the in-flight async persist commits (and its peer
-            # publish runs) BEFORE the reshape — peers keep a stable
-            # version to read across our reshard
-            self.wait_for_save()
-            drain_s = time.perf_counter() - t0
-            t1 = time.perf_counter()
-            new_mesh = self._target_mesh(n_devices, mesh_shape)
-            if faults.PLANE is not None:
-                faults.PLANE.fire("resize.live.reshard",
-                                  from_devices=str(old_n),
-                                  to_devices=str(n_devices))
-            new_shardings, why_t = self._transplant_shardings(
-                new_mesh, saved["_state_shardings"])
-            if new_shardings is None:
+        with obs_trace.span("resize.live", stage=True,
+                            from_devices=old_n,
+                            to_devices=n_devices) as sp_live:
+            try:
+                with obs_trace.span("resize.drain", stage=True) as sp_drain:
+                    if faults.PLANE is not None:
+                        faults.PLANE.fire("resize.live.drain",
+                                          from_devices=str(old_n),
+                                          to_devices=str(n_devices))
+                    # drain: the in-flight async persist commits (and its
+                    # peer publish runs) BEFORE the reshape — peers keep a
+                    # stable version to read across our reshard
+                    self.wait_for_save()
+                with obs_trace.span("resize.mesh", stage=True) as sp_mesh:
+                    new_mesh = self._target_mesh(n_devices, mesh_shape)
+                    if faults.PLANE is not None:
+                        faults.PLANE.fire("resize.live.reshard",
+                                          from_devices=str(old_n),
+                                          to_devices=str(n_devices))
+                    new_shardings, why_t = self._transplant_shardings(
+                        new_mesh, saved["_state_shardings"])
+                    if new_shardings is None:
+                        raise LiveResizeError(
+                            "uncomputable target spans: %s" % why_t)
+                    self._bind_mesh(new_mesh)
+                with obs_trace.span("resize.device_put", stage=True) as sp_put:
+                    self.train_state, reshard_stats = self._reshard_tree(
+                        self.train_state, new_shardings)
+                    sp_put.tag(source=reshard_stats["source"],
+                               bytes=(reshard_stats["local_bytes"]
+                                      + reshard_stats["peer_bytes"]))
+                self._state_shardings = new_shardings
+                with obs_trace.span("resize.build_step",
+                                    stage=True) as sp_build:
+                    self._jit_step = self._build_step()
+                prewarm, prewarm_s = "n/a", 0.0
+                if self._example_batch_sds is not None:
+                    loaded = self._try_load_prewarmed_step()
+                    prewarm_s = self._prewarm_s
+                    if loaded is not None:
+                        self._jit_step = loaded
+                        prewarm = "hit"
+                    else:
+                        prewarm = "miss"
+                sp_live.tag(prewarm=prewarm)
+            except Exception as e:  # noqa: BLE001 — ANY failure rolls back
+                self._restore_bindings(saved)
+                # black-box the rollback: the evidence (drain/reshard spans,
+                # fault firings) lives in rings this incarnation may not
+                # survive once the stop-resume ladder takes over
+                obs_flight.dump("live_resize_rollback", e)
+                reason = "%s: %s" % (type(e).__name__, e)
+                obs_events.emit("resize.live.fallback", cause=start_id,
+                                rank=self.env.global_rank, reason=reason,
+                                from_devices=old_n, to_devices=n_devices)
+                logger.exception("live resize %d -> %d failed; rolled back "
+                                 "to the old mesh (stop-resume takes over)",
+                                 old_n, n_devices)
+                if isinstance(e, LiveResizeError):
+                    raise
                 raise LiveResizeError(
-                    "uncomputable target spans: %s" % why_t)
-            self._bind_mesh(new_mesh)
-            self.train_state, reshard_stats = self._reshard_tree(
-                self.train_state, new_shardings)
-            self._state_shardings = new_shardings
-            self._jit_step = self._build_step()
-            prewarm = "n/a"
-            if self._example_batch_sds is not None:
-                loaded = self._try_load_prewarmed_step()
-                if loaded is not None:
-                    self._jit_step = loaded
-                    prewarm = "hit"
-                else:
-                    prewarm = "miss"
-            reshard_s = time.perf_counter() - t1
-        except Exception as e:  # noqa: BLE001 — ANY failure rolls back
-            self._restore_bindings(saved)
-            # black-box the rollback: the evidence (drain/reshard spans,
-            # fault firings) lives in rings this incarnation may not
-            # survive once the stop-resume ladder takes over
-            obs_flight.dump("live_resize_rollback", e)
-            reason = "%s: %s" % (type(e).__name__, e)
-            obs_events.emit("resize.live.fallback", cause=start_id,
-                            rank=self.env.global_rank, reason=reason,
-                            from_devices=old_n, to_devices=n_devices)
-            logger.exception("live resize %d -> %d failed; rolled back "
-                             "to the old mesh (stop-resume takes over)",
-                             old_n, n_devices)
-            if isinstance(e, LiveResizeError):
-                raise
-            raise LiveResizeError(
-                "live resize %d -> %d failed (%s); rolled back"
-                % (old_n, n_devices, reason)) from e
-        # a live resize begins a new timing "incarnation": the record
-        # carries the same stages measure_resize reads, with
-        # t_construct = the moment training paused, so the driver's
-        # after_ts filter works unchanged
-        self._resize_timing = {
-            "t_construct": t_start, "mode": "live",
-            "t_resume_start": t_start,
-            "drain_s": round(drain_s, 6),
-            "reshard_s": round(reshard_s, 6),
-            "from_devices": old_n, "to_devices": n_devices,
-            "from_mesh": {str(a): int(s) for a, s in
-                          zip(saved["mesh"].axis_names,
-                              saved["mesh"].devices.shape)},
-            "prewarm": prewarm,
-            "restore_source": reshard_stats["source"],
-            "restore_bytes": (reshard_stats["local_bytes"]
-                              + reshard_stats["peer_bytes"]),
-            "restore_peers": reshard_stats["peers"],
-        }
-        if self._state_server is not None \
-                and self._state_server.version is not None:
-            self._resize_timing["version"] = self._state_server.version
-        self._stamp_first_step = True
-        obs_events.emit("resize.live.done", cause=start_id,
-                        rank=self.env.global_rank,
-                        from_devices=old_n, to_devices=n_devices,
-                        reshard_s=reshard_s, prewarm=prewarm,
-                        source=reshard_stats["source"])
-        logger.info("live resize %d -> %d: drain %.3fs reshard %.3fs "
-                    "(%s, prewarm %s) — process stayed alive", old_n,
-                    n_devices, drain_s, reshard_s,
-                    reshard_stats["source"], prewarm)
-        return dict(self._resize_timing)
+                    "live resize %d -> %d failed (%s); rolled back"
+                    % (old_n, n_devices, reason)) from e
+            drain_s = sp_drain.seconds
+            # everything between the drain and the first step: the sum of
+            # its stage spans (what no span covers is a glob and two
+            # attribute stores)
+            reshard_s = (sp_mesh.seconds + sp_put.seconds + sp_build.seconds
+                         + prewarm_s)
+            # a live resize begins a new timing "incarnation": the record
+            # carries the same stages measure_resize reads, with
+            # t_construct = the moment training paused, so the driver's
+            # after_ts filter works unchanged
+            self._resize_timing = {
+                "t_construct": t_start, "mode": "live",
+                "t_resume_start": t_start,
+                "drain_s": round(drain_s, 6),
+                "reshard_s": round(reshard_s, 6),
+                "from_devices": old_n, "to_devices": n_devices,
+                "from_mesh": {str(a): int(s) for a, s in
+                              zip(saved["mesh"].axis_names,
+                                  saved["mesh"].devices.shape)},
+                "prewarm": prewarm,
+                "restore_source": reshard_stats["source"],
+                "restore_bytes": (reshard_stats["local_bytes"]
+                                  + reshard_stats["peer_bytes"]),
+                "restore_peers": reshard_stats["peers"],
+            }
+            if self._state_server is not None \
+                    and self._state_server.version is not None:
+                self._resize_timing["version"] = self._state_server.version
+            self._stamp_first_step = True
+            self._resize_trace = [sp_live.trace_id, sp_live.span_id]
+            obs_events.emit("resize.live.done", cause=start_id,
+                            rank=self.env.global_rank,
+                            from_devices=old_n, to_devices=n_devices,
+                            reshard_s=reshard_s, prewarm=prewarm,
+                            source=reshard_stats["source"])
+            logger.info("live resize %d -> %d: drain %.3fs reshard %.3fs "
+                        "(%s, prewarm %s) — process stayed alive", old_n,
+                        n_devices, drain_s, reshard_s,
+                        reshard_stats["source"], prewarm)
+            return dict(self._resize_timing)
 
     def enable_live_resize(self, who=None):
         """Join the live-resize protocol: advertise the TTL-leased
@@ -1876,20 +1975,31 @@ class ElasticTrainer(object):
         previous save first."""
         if self._ckpt is None:
             return
-        version = self.global_step
-        # deep-snapshot the control-plane state NOW — the background writer
-        # must not see the live State's nested dicts mutating under it
-        import json
-        state_snapshot = json.loads(self.state.to_json())
-        # the sharding record (PartitionSpec tree + mesh axes) rides
-        # meta.json through every save path — restore never needs it
-        # (span intersection works blind) but the resize planner reads
-        # it to cost a target mesh before touching any data
-        meta = {"state": state_snapshot,
-                "sharding": checkpoint_mod.sharding_record(
-                    self._state_shardings)}
+        # the stages a save blocks training for, as stage spans under
+        # one root; the background write joins the trace from its own
+        # thread (save.snapshot and save.persist: runtime/checkpoint.py)
+        with obs_trace.span("save", stage=True,
+                            version=self.global_step):
+            self._save()
 
-        self.wait_for_save()
+    def _save(self):
+        version = self.global_step
+        with obs_trace.span("save.state_json", stage=True):
+            # deep-snapshot the control-plane state NOW — the background
+            # writer must not see the live State's nested dicts mutating
+            # under it
+            import json
+            state_snapshot = json.loads(self.state.to_json())
+            # the sharding record (PartitionSpec tree + mesh axes) rides
+            # meta.json through every save path — restore never needs it
+            # (span intersection works blind) but the resize planner
+            # reads it to cost a target mesh before touching any data
+            meta = {"state": state_snapshot,
+                    "sharding": checkpoint_mod.sharding_record(
+                        self._state_shardings)}
+
+        with obs_trace.span("save.drain_prev", stage=True):
+            self.wait_for_save()
         # peer restore plane: capture SEPARATE host copies of this
         # process's shards NOW (the training thread — later steps may
         # donate the originals, and the engine's pooled staging buffers

@@ -22,6 +22,13 @@ at observation time. This bench quantifies both halves:
   iteration, the exact shape of the instrumented trainer loop) with
   the kill switch on vs off; ``overhead_pct`` against the <1%
   acceptance criterion for the goodput ledger.
+- ``span`` — what one stage span (``obs.trace.span(..., stage=True)``:
+  a handful per live resize or save) costs open to close: with the
+  kill switch on (the object is timed, nothing recorded), recorded
+  with no profiler session (a ring append plus, jax being loaded, a
+  no-op ``edl:`` TraceMe), and recorded under a ``jax.profiler``
+  session (the annotation is written into the capture).
+  ``--span-only`` prints this section alone.
 - ``detectors`` — the ACTIVE layer's cost and latency: one
   HealthMonitor.evaluate() tick over a synthetic fleet of ``pods``
   snapshot docs, timed per window (``overhead_pct_of_interval`` is the
@@ -145,6 +152,37 @@ def bench_ledger(iters=20_000, work_us=1000.0, repeats=3):
                          if off_s > 0 else None),
         "criterion_pct": 1.0,
     }
+
+
+def bench_span(n=20_000, session_n=2_000):
+    """Stage-span cost arc (see module docstring): ns per span, open to
+    close, in the three states a span can be in. The profiler session
+    is a real ``jax.profiler`` capture into a throwaway directory."""
+    import jax
+
+    def one():
+        with obs_trace.span("obs_bench.stage", stage=True):
+            pass
+
+    out = {"n": n, "session_n": session_n}
+    prev = obs_metrics.set_enabled(False)
+    try:
+        out["silenced_ns"] = round(_ns_per_op(one, n), 1)
+    finally:
+        obs_metrics.set_enabled(prev)
+    one()  # warm: the first annotation loads the profiler's module
+    out["recorded_ns"] = round(_ns_per_op(one, n), 1)
+    logdir = tempfile.mkdtemp(prefix="obs_bench_span_")
+    try:
+        jax.profiler.start_trace(logdir)
+        try:
+            out["recorded_in_session_ns"] = round(
+                _ns_per_op(one, session_n), 1)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        shutil.rmtree(logdir, ignore_errors=True)
+    return out
 
 
 def _synth_fleet_docs(pods, window, step_ms_by_pod, state, base_ts,
@@ -383,6 +421,8 @@ def run(mode="micro", **cfg):
         "primitives": bench_primitives(),
         "ledger": (bench_ledger(iters=1_000, work_us=100.0)
                    if mode == "micro" else bench_ledger()),
+        "span": (bench_span(n=2_000, session_n=200)
+                 if mode == "micro" else bench_span()),
         "detectors": bench_detectors(),
         "autopilot": bench_autopilot(),
     }
@@ -392,6 +432,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--micro", action="store_true",
                     help="hermetic CI-sized run (the tier-1 smoke)")
+    ap.add_argument("--span-only", action="store_true",
+                    help="print the stage-span cost section alone")
     ap.add_argument("--files", type=int, default=None)
     ap.add_argument("--rows", type=int, default=None)
     ap.add_argument("--dim", type=int, default=None)
@@ -399,6 +441,11 @@ def main(argv=None):
     ap.add_argument("--step-ms", type=float, default=None)
     ap.add_argument("--fetch-ahead", type=int, default=None)
     args = ap.parse_args(argv)
+    if args.span_only:
+        json.dump({"schema": "obs_bench/v1", "span": bench_span()},
+                  sys.stdout, indent=2)
+        sys.stdout.write("\n")
+        return 0
     out = run(mode="micro" if args.micro else "full",
               files=args.files, rows=args.rows, dim=args.dim,
               batch_size=args.batch_size, step_ms=args.step_ms,

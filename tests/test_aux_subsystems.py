@@ -1,6 +1,5 @@
-"""Aux subsystem tests: timeline profiler, input pipeline, liveft layer."""
+"""Aux subsystem tests: input pipeline, liveft layer."""
 
-import io
 import os
 import time
 
@@ -9,21 +8,6 @@ import pytest
 from PIL import Image
 
 from edl_tpu.liveft import elastic
-from edl_tpu.utils import timeline
-
-
-def test_timeline_nop_vs_real(monkeypatch):
-    monkeypatch.delenv("EDL_TPU_PROFILE", raising=False)
-    assert isinstance(timeline.get_timeline(), timeline._NopTimeLine)
-    monkeypatch.setenv("EDL_TPU_PROFILE", "1")
-    buf = io.StringIO()
-    tl = timeline.get_timeline(out=buf)
-    with tl.span("predict"):
-        time.sleep(0.01)
-    tl.record("fetch")
-    out = buf.getvalue()
-    assert "op=predict" in out and "op=fetch" in out
-    assert "ms=" in out
 
 
 def _make_image_tree(tmp_path, classes=2, per_class=3, size=40):

@@ -1,0 +1,257 @@
+"""The benchmark's readers of the program's stage spans
+(benchmark/lib/progspans.py and the thirteen files under
+benchmark/metrics/ that use it), each on a hand-made ring and `view`:
+medians by direction, None without the span, roots outside the window
+left out, the arithmetic of `resize_other_ms`. The cell's own end-to-end
+test runs with the benchmark's tests (benchmark/tests/); these are in
+tier-1 so that every PR counts them.
+"""
+
+import json
+import os
+
+import pytest
+
+from benchmark.lib import harness, progspans
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL = "resnet50vd-dp4-elastic"
+NEW = ["shrink_pause_ms", "grow_pause_ms", "shrink_device_put_ms",
+       "grow_device_put_ms", "shrink_fingerprint_ms", "shrink_step_load_ms",
+       "grow_first_trace_ms", "grow_first_load_ms", "shrink_first_run_ms",
+       "grow_first_run_ms", "resize_other_ms", "save_drain_prev_ms",
+       "save_snapshot_ms"]
+
+
+def _span(trace, name, t0, ms, parent="root", **tags):
+    return {"trace_id": trace, "span_id": "%s/%s" % (trace, name),
+            "parent_id": None if parent is None else "%s/%s" % (trace, parent),
+            "name": name, "kind": "local", "ts": 0.0, "t0": t0,
+            "dur_ms": ms, "tags": tags, "pid": 1}
+
+
+def _shrink(trace, t0, put, fp, load, run, gap=10.0):
+    """A 4 -> 2 resize starting at `t0` s: drain 5, mesh 1, device_put,
+    build_step 2, fingerprint, load, then `gap` ms outside any span, a
+    dispatch of 3 ms that built nothing, and the first result."""
+    at, out = t0, []
+    for name, ms in [("resize.drain", 5.0), ("resize.mesh", 1.0),
+                     ("resize.device_put", put), ("resize.build_step", 2.0),
+                     ("resize.prewarm_fingerprint", fp),
+                     ("resize.prewarm_load", load)]:
+        out.append(_span(trace, name, at, ms, parent="resize.live"))
+        at += ms / 1e3
+    live_ms = (at - t0) * 1e3
+    out.append(_span(trace, "resize.live", t0, live_ms, parent=None,
+                     from_devices=4, to_devices=2, prewarm="hit"))
+    at += gap / 1e3
+    out.append(_span(trace, "resize.first_step", at, 3.0 + run,
+                     parent="resize.live"))
+    out.append(_span(trace, "resize.first_dispatch", at, 3.0,
+                     parent="resize.first_step", jax_trace_s=0.0,
+                     jax_lower_s=0.0, jax_compile_s=0.0,
+                     jax_cache_load_s=0.0))
+    out.append(_span(trace, "resize.first_result", at + 3e-3, run,
+                     parent="resize.first_step"))
+    return out, live_ms + gap + 3.0 + run
+
+
+def _grow(trace, t0, put, trace_ms, lower_ms, load_ms, run):
+    """A 2 -> 4 resize: no prewarm spans; the first dispatch traces,
+    lowers and loads, and takes 4 ms more than those."""
+    at, out = t0, []
+    for name, ms in [("resize.drain", 5.0), ("resize.mesh", 1.0),
+                     ("resize.device_put", put), ("resize.build_step", 2.0)]:
+        out.append(_span(trace, name, at, ms, parent="resize.live"))
+        at += ms / 1e3
+    live_ms = (at - t0) * 1e3
+    out.append(_span(trace, "resize.live", t0, live_ms, parent=None,
+                     from_devices=2, to_devices=4, prewarm="miss"))
+    dispatch = trace_ms + lower_ms + load_ms + 4.0
+    out.append(_span(trace, "resize.first_step", at, dispatch + run,
+                     parent="resize.live"))
+    out.append(_span(trace, "resize.first_dispatch", at, dispatch,
+                     parent="resize.first_step",
+                     jax_trace_s=trace_ms / 1e3, jax_lower_s=lower_ms / 1e3,
+                     jax_compile_s=0.001, jax_cache_load_s=load_ms / 1e3
+                     - 0.001))
+    out.append(_span(trace, "resize.first_result", at + dispatch / 1e3, run,
+                     parent="resize.first_step"))
+    return out, live_ms + dispatch + run
+
+
+def _save(trace, t0, drain, snap):
+    return [_span(trace, "save", t0, 1.0 + drain + snap, parent=None,
+                  version=7),
+            _span(trace, "save.state_json", t0, 1.0, parent="save"),
+            _span(trace, "save.drain_prev", t0 + 1e-3, drain, parent="save"),
+            _span(trace, "save.snapshot", t0 + (1 + drain) / 1e3, snap,
+                  parent="save"),
+            _span(trace, "save.persist", t0 + 0.5, 400.0, parent="save",
+                  version=7)]
+
+
+@pytest.fixture()
+def world(monkeypatch):
+    """A window [100, 200] holding three shrinks, two grows and three
+    saves; a warm-up period before it and a resize after it."""
+    ring, pauses = [], {}
+    for key, (t0, put, fp, load, run) in {
+            "s1": (110.0, 200.0, 3000.0, 30.0, 60.0),
+            "s2": (140.0, 240.0, 2800.0, 34.0, 50.0),
+            "s3": (170.0, 220.0, 3400.0, 20.0, 70.0),
+            "warm": (50.0, 900.0, 9000.0, 90.0, 900.0),
+            "late": (205.0, 900.0, 9000.0, 90.0, 900.0)}.items():
+        spans, pauses[key] = _shrink(key, t0, put, fp, load, run)
+        ring += spans
+    for key, (t0, put, tr, lo, ld, run) in {
+            "g1": (120.0, 180.0, 2000.0, 1000.0, 500.0, 80.0),
+            "g2": (150.0, 160.0, 2200.0, 1200.0, 300.0, 100.0)}.items():
+        spans, pauses[key] = _grow(key, t0, put, tr, lo, ld, run)
+        ring += spans
+    for key, (t0, drain, snap) in {"v1": (105.0, 0.0, 430.0),
+                                   "v2": (135.0, 12.0, 410.0),
+                                   "v3": (165.0, 2.0, 450.0),
+                                   "vwarm": (40.0, 500.0, 5000.0)}.items():
+        ring += _save(key, t0, drain, snap)
+    # a sampled span of the RPC layer shares the ring
+    ring.append(_span("rpc", "rpc.client/put", 130.0, 1.0, parent=None))
+    ring.sort(key=lambda s: s["t0"] + s["dur_ms"] / 1e3)
+    monkeypatch.setattr(progspans, "ring", lambda: list(ring))
+    return {"view": {"window": [100.0, 200.0]}, "pauses": pauses,
+            "ring": ring}
+
+
+def _read(name, view):
+    return harness.load_module("metrics", name).read(view)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("shrink_device_put_ms", 220.0),       # of 200, 240, 220
+    ("grow_device_put_ms", 170.0),         # of 180, 160
+    ("shrink_fingerprint_ms", 3000.0),
+    ("shrink_step_load_ms", 30.0),
+    ("grow_first_trace_ms", 3200.0),       # of 3000, 3400
+    ("grow_first_load_ms", 400.0),         # of 500, 300
+    ("shrink_first_run_ms", 60.0),
+    ("grow_first_run_ms", 90.0),
+    ("save_drain_prev_ms", 2.0),           # of 0, 12, 2
+    ("save_snapshot_ms", 430.0),
+])
+def test_stage_medians_by_direction(world, name, want):
+    assert _read(name, world["view"]) == pytest.approx(want)
+
+
+def test_pauses_run_from_the_root_s_start_to_the_first_step_s_end(world):
+    p = world["pauses"]
+    assert _read("shrink_pause_ms", world["view"]) == pytest.approx(
+        sorted([p["s1"], p["s2"], p["s3"]])[1])
+    assert _read("grow_pause_ms", world["view"]) == pytest.approx(
+        (p["g1"] + p["g2"]) / 2)
+    # s1: drain 5 + mesh 1 + 200 + build 2 + 3000 + 30, 10 outside any
+    # span, dispatch 3, result 60
+    assert p["s1"] == pytest.approx(3311.0)
+
+
+def test_resize_other_is_the_pause_less_the_named_stages(world):
+    # a shrink: drain 5 + mesh 1 + build 2 + the 10 no span covers + the
+    # 3 of a dispatch that built nothing = 21; a grow: 5 + 1 + 2 + the 4
+    # of its dispatch beyond trace, lower and load = 12
+    assert _read("resize_other_ms", world["view"]) == pytest.approx(21.0)
+    recs = progspans.resizes(world["view"])
+    assert sorted(r["direction"] for r in recs) == ["grow"] * 2 \
+        + ["shrink"] * 3
+    others = sorted(r["pause"] - sum(r[k] or 0.0 for k in progspans.NAMED)
+                    for r in recs)
+    assert others == pytest.approx([12.0, 12.0, 21.0, 21.0, 21.0])
+
+
+def test_roots_outside_the_window_are_left_out(world):
+    view = {"window": [0.0, 1000.0]}       # now warm-up and late count
+    assert _read("shrink_device_put_ms", view) == pytest.approx(240.0)
+    assert _read("save_drain_prev_ms", view) == pytest.approx(7.0)
+    assert len(progspans.resizes(view)) == 7
+    assert len(progspans.resizes({"window": [100.0, 125.0]})) == 2
+    assert progspans.resizes({"window": [300.0, 400.0]}) == []
+
+
+def test_traces_under_the_profiler_s_capture_are_left_out(world):
+    """The harness's `trace_window` span covers the traced period: its
+    resizes and saves ran on a host the profiler slowed."""
+    view = {"window": [100.0, 200.0],
+            "spans": [("steps", 100.0, 109.0), ("trace_window", 132.0, 168.0),
+                      ("live_resize", 140.0, 143.3)]}
+    got = progspans.resizes(view)       # s2 and g2 start inside it
+    assert sorted((r["direction"], r["device_put"]) for r in got) == [
+        ("grow", 180.0), ("shrink", 200.0), ("shrink", 220.0)]
+    assert _read("shrink_device_put_ms", view) == pytest.approx(210.0)
+    assert _read("grow_first_load_ms", view) == pytest.approx(500.0)
+    assert _read("save_snapshot_ms", view) == pytest.approx(430.0)  # v1
+    assert _read("save_drain_prev_ms", view) == pytest.approx(0.0)
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_none_without_the_span(monkeypatch, name):
+    """The parent's program records no stage span: an empty ring, or
+    spans without a monotonic start. Nothing is read and nothing raises."""
+    view = {"window": [100.0, 200.0]}
+    monkeypatch.setattr(progspans, "ring", lambda: [])
+    assert _read(name, view) is None
+
+
+def test_a_grow_has_no_fingerprint_and_a_shrink_no_first_trace(
+        world, monkeypatch):
+    ring = [s for s in world["ring"] if s["trace_id"] in ("g1", "g2")]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("shrink_fingerprint_ms", world["view"]) is None
+    assert _read("shrink_pause_ms", world["view"]) is None
+    assert _read("grow_pause_ms", world["view"]) is not None
+    for r in progspans.resizes(world["view"]):
+        assert r["fingerprint"] is None and r["step_load"] is None
+
+
+def test_a_resize_without_its_first_step_is_left_out(world, monkeypatch):
+    ring = [s for s in world["ring"]
+            if not (s["trace_id"] == "s2"
+                    and s["name"].startswith("resize.first"))]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert len(progspans.resizes(world["view"])) == 4
+    assert _read("shrink_device_put_ms", world["view"]) == pytest.approx(
+        210.0)
+
+
+def test_ring_reads_the_program_s_tracer_and_drops_clockless_spans(
+        monkeypatch):
+    from edl_tpu.obs import trace as obs_trace
+    obs_trace.TRACER.clear()
+    try:
+        with obs_trace.span("resize.device_put", stage=True):
+            pass
+        [got] = progspans.ring()
+        assert got["name"] == "resize.device_put" and got["t0"] > 0
+        # an older program's span says only `ts`: no clock to place it by
+        monkeypatch.setattr(obs_trace.Span, "to_dict",
+                            lambda self: {"name": self.name, "ts": self.ts,
+                                          "dur_ms": self.dur_ms})
+        assert progspans.ring() == []
+    finally:
+        obs_trace.TRACER.clear()
+
+
+def test_benchmark_names_every_new_reader_once_for_the_elastic_cell():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW
+    for name in NEW:
+        m = entries[name]
+        assert m["source"] == "program_span" and m["unit"] == "ms"
+        assert m["workloads"] == [CELL] and m["better"] == "lower"
+        if name.startswith("save_"):
+            assert (m["layer"], m["moves"]) == ("checkpoint",
+                                                "elastic_samples_s_chip")
+        else:
+            assert (m["layer"], m["moves"]) == ("live resize",
+                                                "resize_pause_ms")
+        assert os.path.exists(os.path.join(
+            REPO, "benchmark", "metrics", name + ".py"))

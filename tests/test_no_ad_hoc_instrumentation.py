@@ -1,7 +1,8 @@
 """Tier-1 wiring for tools/check_no_ad_hoc_instrumentation.py: a NEW
 stopwatch-plus-print pair in one function fails the build — record a
-registry histogram (edl_tpu.obs.metrics) or a timeline span
-(edl_tpu.utils.timeline) so the sample lands on the fleet snapshot."""
+registry histogram (edl_tpu.obs.metrics) or a span
+(edl_tpu.obs.trace.span) so the sample lands on the fleet snapshot or
+in the span ring."""
 
 import ast
 import os

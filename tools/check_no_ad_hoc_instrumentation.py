@@ -7,7 +7,7 @@ A function that both reads a stopwatch (``time.monotonic()`` /
 exactly what ``edl_tpu.obs`` replaces: the sample never reaches the
 fleet snapshot, can't be aggregated by job_stats, and costs a syscall
 on the hot path. Record it as a registry histogram (pre-bound handle +
-``observe``) or a timeline span (``edl_tpu.utils.timeline``) instead.
+``observe``) or a span (``edl_tpu.obs.trace.span``) instead.
 
 Timing INTO a variable/stat dict is fine (most of the tree does that);
 only the timed-then-printed combination in one function is flagged.
@@ -17,12 +17,14 @@ print reports by design) are out of scope.
 A second, stricter rule applies to ``edl_tpu/runtime/`` and
 ``edl_tpu/serve/`` only: a raw stopwatch PAIR
 (``t0 = time.monotonic()`` … ``<x> - t0``) whose delta goes anywhere
-but a sanctioned sink (``observe`` / ``inc`` / ``set`` / ``time_ms``)
-is wall-clock attribution bypassing the time ledger — the seconds it
+but a sanctioned sink (``observe`` / ``inc`` / ``set`` / ``time_ms`` /
+a span's ``tag``) is wall-clock attribution bypassing the time ledger — the seconds it
 measures are invisible to ``goodput/v1`` (in serve, to the decode
 TTFT/ITL admission estimates). Route the
-interval through :class:`edl_tpu.obs.ledger.TimeLedger` (or a registry
-histogram) instead. Deadline math (``deadline = monotonic() + x`` /
+interval through :class:`edl_tpu.obs.ledger.TimeLedger`, a registry
+histogram or — for a stage of a resize or a save — a stage span of
+``edl_tpu.obs.trace`` (the span is the stopwatch: read ``.seconds`` off
+it) instead. Deadline math (``deadline = monotonic() + x`` /
 ``deadline - monotonic()``) passes automatically: the deadline variable
 is not a bare stopwatch read, so it is never tracked. Remaining
 legitimate sites live in STOPWATCH_ALLOWLIST with a justification.
@@ -44,9 +46,7 @@ EXCLUDE_DIRS = ("edl_tpu/obs", "edl_tpu/tools")
 STOPWATCHES = {"monotonic", "perf_counter"}
 
 # (relpath, enclosing function) -> why the stopwatch+console pair is OK.
-# Empty today: the one legacy site (utils/timeline.py's stderr sink)
-# was rewired onto the registry with an injected output object, which
-# this lint correctly no longer sees as a raw console write.
+# Empty today.
 ALLOWLIST = {}
 
 #: only these subtrees are held to the stopwatch-pair rule — runtime is
@@ -57,7 +57,7 @@ PAIR_SCAN_PREFIX = ("edl_tpu/runtime/", "edl_tpu/serve/")
 
 #: calls whose argument position is a sanctioned destination for a
 #: stopwatch delta (registry handles and the span tracer)
-SINK_METHODS = {"observe", "inc", "set", "time_ms"}
+SINK_METHODS = {"observe", "inc", "set", "time_ms", "span", "tag"}
 
 # (relpath, enclosing function) -> why this raw stopwatch pair may
 # bypass the ledger. Keep justifications specific: the next reader
@@ -66,21 +66,9 @@ STOPWATCH_ALLOWLIST = {
     ("edl_tpu/runtime/trainer.py", "train_step"):
         "step_s feeds _STEP_MS.observe and the cadence estimator; the "
         "interval itself is ledgered as the compute state",
-    ("edl_tpu/runtime/trainer.py", "live_resize"):
-        "drain_s/reshard_s are resize_bench/v1 stage stamps published "
-        "via _resize_timing; the wall clock is ledgered resize_pause",
     ("edl_tpu/runtime/trainer.py", "compile_all"):
         "prewarm compiles run on a background thread (never ledgered "
         "by design); the duration is a log line only",
-    ("edl_tpu/runtime/trainer.py", "_try_load_prewarmed_step"):
-        "AOT-load duration log line inside an interval already "
-        "ledgered resize_pause",
-    ("edl_tpu/runtime/checkpoint.py", "save_async"):
-        "blocked_s stamps the snapshot cost onto the SaveHandle; the "
-        "interval itself is ledgered ckpt_block",
-    ("edl_tpu/runtime/checkpoint.py", "save_sharded_async"):
-        "blocked_s stamps the snapshot cost onto the SaveHandle; the "
-        "interval itself is ledgered ckpt_block",
     ("edl_tpu/runtime/checkpoint.py", "persist"):
         "the async persist driver is a background thread whose "
         "concurrency is deliberately NOT ledgered; persist_s lands on "
@@ -250,7 +238,7 @@ def main():
         for rel, func, line in violations:
             print("  %s:%d in %s()" % (rel, line, func))
         print("record a registry histogram (edl_tpu.obs.metrics) or a "
-              "timeline span (edl_tpu.utils.timeline) instead, or "
+              "span (edl_tpu.obs.trace.span) instead, or "
               "allowlist the site in "
               "tools/check_no_ad_hoc_instrumentation.py with a "
               "justification.")
@@ -260,8 +248,8 @@ def main():
         for rel, func, line in pair_violations:
             print("  %s:%d in %s()" % (rel, line, func))
         print("attribute the interval through edl_tpu.obs.ledger "
-              "(LEDGER.state/transition) or a registry histogram, or "
-              "add the site to STOPWATCH_ALLOWLIST with a "
+              "(LEDGER.state/transition), a registry histogram or a "
+              "stage span (edl_tpu.obs.trace), or add the site to STOPWATCH_ALLOWLIST with a "
               "justification.")
     if violations or pair_violations or stale or stale_pairs:
         return 1
