@@ -16,6 +16,7 @@ Design (TPU-first):
   adjust hooks re-tune hyperparameters for the new world size.
 """
 
+import functools
 import os
 import threading
 import time
@@ -55,6 +56,10 @@ _PREWARM_MISSES = obs_metrics.counter(
     "edl_resize_prewarm_misses_total",
     "first steps in prewarm scope with no usable AOT artifact "
     "(full compile paid)")
+_STEP_REUSES = obs_metrics.counter(
+    "edl_resize_step_reuses_total",
+    "live resizes that took their step executable from the table this "
+    "process already held (no fingerprint, load or compile in the pause)")
 
 #: what JAX did inside ``resize.first_dispatch``: the jax.monitoring
 #: duration events that mean a program was traced, lowered, compiled or
@@ -633,7 +638,15 @@ class ElasticTrainer(object):
                 self._state_server = None
 
         self._jit_step = self._build_step()
-        self._example_batch_sds = None  # captured at the first step
+        # captured at the first step
+        self._example_batch_sds = self._example_rng_sds = None
+        # step executables this process holds ready, by _step_key():
+        # what prewarm_resize_compiles compiled, what was loaded from
+        # its file, and the step of every world live_resize has left.
+        # What a step is built from (_loss_fn, _tx,
+        # _grad_accum, _remat_policy, _step_fn) is set above and never
+        # again, so an entry stays good for the life of the trainer.
+        self._ready_steps = {}
         # the step that next stamps compile_s/first_step_s into
         # _resize_timing: the first step of this incarnation, and the
         # first step after every live_resize() (same record semantics
@@ -793,12 +806,59 @@ class ElasticTrainer(object):
     # the lowered computation + shapes + jaxlib version, recomputed by
     # the restarted process — a code or config change simply misses.
 
+    def _step_key(self, mesh, state_shardings, batch_sharding):
+        """What a step executable was built for, as far as this trainer
+        can observe it: the mesh's devices and axes (another
+        factorisation of the same devices is another key), every state
+        leaf's PartitionSpec, the batch's structure and spec, the rng's
+        aval. Needs the examples the first step captured."""
+        batch, batch_tree = jax.tree_util.tree_flatten(
+            self._example_batch_sds)
+        return (tuple(d.id for d in mesh.devices.flat),
+                tuple(mesh.axis_names), tuple(mesh.devices.shape),
+                tuple(sh.spec for sh in
+                      jax.tree_util.tree_leaves(state_shardings)),
+                batch_sharding.spec, batch_tree,
+                tuple((x.shape, x.dtype)
+                      for x in batch + [self._example_rng_sds]))
+
+    def _aot_step(self, executable, key, repl, jit_fallback):
+        """An AOT executable of the step (kept from a prewarm's compile
+        or loaded from its file) as a ``_jit_step``, entered in the
+        table of ready steps under ``key``."""
+        def step(state, batch, rng):
+            # AOT executables take committed inputs with the EXACT
+            # compiled signature; jax.jit would transparently recompile
+            # on a changed rng type or a ragged tail batch — mirror that
+            # by reverting to the jit path on an input mismatch
+            try:
+                return executable(state, batch, jax.device_put(rng, repl))
+            except (TypeError, ValueError) as e:
+                # ONLY argument-validation failures are safe to retry:
+                # they reject before dispatch, so no buffer has been
+                # donated yet. A post-dispatch failure (XlaRuntimeError
+                # etc.) leaves state's donated buffers deleted —
+                # retrying would mask the real error with a
+                # use-after-donate; let it propagate.
+                logger.warning(
+                    "AOT step input mismatch (%r); reverting to the jit "
+                    "path for this and later steps", e)
+                self._ready_steps.pop(key, None)
+                self._jit_step = jit_fallback
+                return jit_fallback(state, batch, rng)
+
+        self._ready_steps[key] = step
+        return step
+
     def _step_lowered(self, world_n=None):
         """Lower the train step for ``world_n`` devices (None = the
-        current mesh), returning (lowered, fingerprint)."""
+        current mesh), returning (lowered, fingerprint, adopt):
+        ``adopt(executable)`` is ``_aot_step`` for that world, the jit
+        of this lowering as its fallback."""
         import hashlib
 
         if world_n is None:
+            mesh_n = self.mesh
             state_sh = self._state_shardings
             data_sh = self._batch_sharding
             repl = self._repl
@@ -816,20 +876,23 @@ class ElasticTrainer(object):
             if state_sh is None:
                 raise ValueError("world %d: uncomputable target "
                                  "spans: %s" % (world_n, why))
-        lowered = jax.jit(
+        jitted = jax.jit(
             self._raw_step(),
             in_shardings=(state_sh, data_sh, repl),
             out_shardings=(state_sh, repl),
-            donate_argnums=(0,)).lower(
-                jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                    self.train_state),
-                self._example_batch_sds,
-                jax.ShapeDtypeStruct((2,), np.uint32))
+            donate_argnums=(0,))
+        lowered = jitted.lower(
+            jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                self.train_state),
+            self._example_batch_sds, self._example_rng_sds)
         h = hashlib.sha256()
         h.update(jax.version.__version__.encode())
         h.update(lowered.as_text().encode())
-        return lowered, h.hexdigest()[:24]
+        adopt = functools.partial(
+            self._aot_step, key=self._step_key(mesh_n, state_sh, data_sh),
+            repl=repl, jit_fallback=jitted)
+        return lowered, h.hexdigest()[:24], adopt
 
     def _prewarm_in_scope(self):
         """Same family as _live_scope_check: prewarm covers any mesh
@@ -914,9 +977,9 @@ class ElasticTrainer(object):
             for n in targets:
                 try:
                     t0 = time.perf_counter()
-                    lowered, fp = self._step_lowered(n)
-                    payload, in_tree, out_tree = se.serialize(
-                        lowered.compile())
+                    lowered, fp, adopt = self._step_lowered(n)
+                    compiled = lowered.compile()
+                    payload, in_tree, out_tree = se.serialize(compiled)
                     path = os.path.join(out_dir,
                                         "step_w%d_%s.pkl" % (n, fp))
                     tmp = path + ".tmp.%d" % os.getpid()
@@ -925,6 +988,9 @@ class ElasticTrainer(object):
                                      "in_tree": in_tree,
                                      "out_tree": out_tree}, f)
                     os.replace(tmp, path)
+                    # the file is for a restart; this process keeps
+                    # the executable itself for its own live resizes
+                    adopt(compiled)
                     done.append(n)
                     logger.info(
                         "prewarm: world-%d step compiled + serialized "
@@ -942,8 +1008,9 @@ class ElasticTrainer(object):
         return targets
 
     def _try_load_prewarmed_step(self):
-        """At the first train_step of an incarnation and inside a live
-        resize: if an earlier prewarm serialized THIS world size's step
+        """At the first train_step of an incarnation, and inside a live
+        resize to a world whose step this process does not hold ready:
+        if an earlier prewarm serialized THIS world size's step
         executable, load it and skip the compile. Returns a
         jit_step-compatible callable or None, and leaves in
         ``self._prewarm_s`` the seconds its two stage spans took:
@@ -971,7 +1038,7 @@ class ElasticTrainer(object):
         with obs_trace.span("resize.prewarm_fingerprint", stage=True,
                             world=n) as sp_fp:
             try:
-                _, fp = self._step_lowered()
+                _, fp, adopt = self._step_lowered()
             except Exception:
                 logger.exception("prewarm load: lowering failed")
         self._prewarm_s = sp_fp.seconds
@@ -1003,33 +1070,10 @@ class ElasticTrainer(object):
         if loaded is None:
             _PREWARM_MISSES.inc()
             return None
-        repl = self._repl
-        jit_fallback = self._jit_step
-
-        def step(state, batch, rng):
-            # loaded executables take committed inputs with the EXACT
-            # compiled signature; jax.jit would transparently recompile
-            # on a changed rng type or a ragged tail batch — mirror that
-            # by reverting to the jit path on an input mismatch
-            try:
-                return loaded(state, batch, jax.device_put(rng, repl))
-            except (TypeError, ValueError) as e:
-                # ONLY argument-validation failures are safe to retry:
-                # they reject before dispatch, so no buffer has been
-                # donated yet. A post-dispatch failure (XlaRuntimeError
-                # etc.) leaves state's donated buffers deleted —
-                # retrying would mask the real error with a
-                # use-after-donate; let it propagate.
-                logger.warning(
-                    "AOT step input mismatch (%r); reverting to the jit "
-                    "path for this and later steps", e)
-                self._jit_step = jit_fallback
-                return jit_fallback(state, batch, rng)
-
         logger.info("resize prewarm HIT: world-%d step loaded from %s in "
                     "%.2fs (compile skipped)", n, path, sp_load.seconds)
         _PREWARM_HITS.inc()
-        return step
+        return adopt(loaded)
 
     def local_batch_slice(self, full_batch):
         """Slice a FULL global batch down to the rows this process must
@@ -1078,8 +1122,10 @@ class ElasticTrainer(object):
         caller would not pay anyway, once per resize."""
         prewarm_s = 0.0
         if self._example_batch_sds is None:
-            self._example_batch_sds = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
+            self._example_batch_sds, self._example_rng_sds = \
+                jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    (batch, rng))
             loaded = self._try_load_prewarmed_step()
             prewarm_s = self._prewarm_s
             if loaded is not None:
@@ -1321,8 +1367,10 @@ class ElasticTrainer(object):
         picks a (dp, tp, pp, ep) factorization — e.g. the cluster
         generator's roofline choice — default: keep the current model
         axes and rescale dp), transplant every state PartitionSpec onto
-        it, reshard params + optimizer state, rebuild the step (loading
-        the prewarmed AOT executable when one exists), and resume — the
+        it, reshard params + optimizer state, take the new world's step
+        (the executable this process already holds for it — prewarmed
+        here, or left behind when the job last ran there — else the
+        prewarmed artifact on disk, else a fresh jit), and resume — the
         process never exits, so the kill/barrier/restore stages of the
         stop-resume budget are eliminated. Stamps a fresh
         ``_resize_timing`` record (mode "live", with the new
@@ -1367,6 +1415,10 @@ class ElasticTrainer(object):
             return {"mode": "live", "noop": True,
                     "from_devices": old_n, "to_devices": n_devices}
         saved = self._snapshot_bindings()
+        left_key = None
+        if self._example_batch_sds is not None:
+            left_key = self._step_key(self.mesh, self._state_shardings,
+                                      self._batch_sharding)
         # training is paused from here until the first post-reshard
         # step result (train_step closes the pause when it stamps);
         # the drain below nests ckpt_block over this and returns here
@@ -1403,19 +1455,34 @@ class ElasticTrainer(object):
                                bytes=(reshard_stats["local_bytes"]
                                       + reshard_stats["peer_bytes"]))
                 self._state_shardings = new_shardings
+                # where the new world's step comes from: the table
+                # ("memory": nothing to build, name, load or trace), a
+                # prewarmed artifact ("disk"), or a fresh jit that the
+                # first dispatch compiles ("compile")
+                prewarm, prewarm_s, step_source = "n/a", 0.0, "compile"
                 with obs_trace.span("resize.build_step",
                                     stage=True) as sp_build:
-                    self._jit_step = self._build_step()
-                prewarm, prewarm_s = "n/a", 0.0
-                if self._example_batch_sds is not None:
+                    ready = None
+                    if left_key is not None:
+                        ready = self._ready_steps.get(self._step_key(
+                            new_mesh, new_shardings, self._batch_sharding))
+                    self._jit_step = ready or self._build_step()
+                if ready is not None:
+                    prewarm, step_source = "hit", "memory"
+                    _STEP_REUSES.inc()
+                elif left_key is not None:
                     loaded = self._try_load_prewarmed_step()
                     prewarm_s = self._prewarm_s
                     if loaded is not None:
                         self._jit_step = loaded
-                        prewarm = "hit"
+                        prewarm, step_source = "hit", "disk"
                     else:
                         prewarm = "miss"
-                sp_live.tag(prewarm=prewarm)
+                if left_key is not None and not self._stamp_first_step:
+                    # the step that ran on the world being left stays
+                    # ready (a jit wrapper keeps its compiled program)
+                    self._ready_steps[left_key] = saved["_jit_step"]
+                sp_live.tag(prewarm=prewarm, step_source=step_source)
             except Exception as e:  # noqa: BLE001 — ANY failure rolls back
                 self._restore_bindings(saved)
                 # black-box the rollback: the evidence (drain/reshard spans,
@@ -1453,7 +1520,7 @@ class ElasticTrainer(object):
                 "from_mesh": {str(a): int(s) for a, s in
                               zip(saved["mesh"].axis_names,
                                   saved["mesh"].devices.shape)},
-                "prewarm": prewarm,
+                "prewarm": prewarm, "step_source": step_source,
                 "restore_source": reshard_stats["source"],
                 "restore_bytes": (reshard_stats["local_bytes"]
                                   + reshard_stats["peer_bytes"]),
@@ -1468,11 +1535,12 @@ class ElasticTrainer(object):
                             rank=self.env.global_rank,
                             from_devices=old_n, to_devices=n_devices,
                             reshard_s=reshard_s, prewarm=prewarm,
+                            step_source=step_source,
                             source=reshard_stats["source"])
             logger.info("live resize %d -> %d: drain %.3fs reshard %.3fs "
-                        "(%s, prewarm %s) — process stayed alive", old_n,
-                        n_devices, drain_s, reshard_s,
-                        reshard_stats["source"], prewarm)
+                        "(%s, prewarm %s, step from %s) — process stayed "
+                        "alive", old_n, n_devices, drain_s, reshard_s,
+                        reshard_stats["source"], prewarm, step_source)
             return dict(self._resize_timing)
 
     def enable_live_resize(self, who=None):

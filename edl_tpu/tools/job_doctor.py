@@ -304,8 +304,9 @@ def _live_resize_findings(obs, timeline):
       quotes the recorded reason (stale_version / insufficient_partners
       / fault / error).
     - prewarm_miss: prewarm-scope first steps paid a full compile and
-      none ever loaded an AOT artifact — the compile cache is cold or
-      unconfigured, so every resize (live or not) eats compile_s."""
+      none ever loaded an AOT artifact or took a step executable the
+      process already held — the compile cache is cold or unconfigured,
+      so every resize (live or not) eats compile_s."""
     findings = []
     falls = [e for e in timeline
              if e.get("kind") == "resize.live.fallback"]
@@ -370,7 +371,11 @@ def _live_resize_findings(obs, timeline):
             "event_ids": [last.get("id")]
             if last.get("id") is not None else [],
         })
-    hits = _counter_total(obs, "edl_resize_prewarm_hits_total")
+    # no compile paid: an AOT artifact loaded, or a step executable the
+    # process already held taken at a live resize
+    hits = sum(_counter_total(obs, name) or 0.0
+               for name in ("edl_resize_prewarm_hits_total",
+                            "edl_resize_step_reuses_total"))
     misses = _counter_total(obs, "edl_resize_prewarm_misses_total")
     if misses and not hits:
         findings.append({

@@ -162,18 +162,194 @@ def test_live_resize_noop_and_scope_rejections():
 
 def test_live_resize_prewarm_hit(tmp_path, monkeypatch):
     """With a compile cache and a prewarmed target world, the live
-    swap loads the AOT executable instead of recompiling — the record
+    swap takes the executable the prewarm kept instead of recompiling,
+    and the grow back takes the step the job left there — the record
     says so, and that is what the doctor's prewarm_miss detector keys
-    off."""
+    off. A trainer that does not hold it loads the artifact from disk."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     tr = _trainer(8)
     _steps(tr, BATCHES[:1])  # the prewarm needs the batch structure
     assert tr.prewarm_resize_compiles([4], block=True) == [4]
     rec = tr.live_resize(4)
-    assert rec["prewarm"] == "hit"
+    assert (rec["prewarm"], rec["step_source"]) == ("hit", "memory")
     _steps(tr, BATCHES[1:2])
-    # the un-prewarmed grow leg is an honest miss, not "n/a"
-    assert tr.live_resize(8)["prewarm"] == "miss"
+    # the grow leg goes back to a world the job has run on
+    rec = tr.live_resize(8)
+    assert (rec["prewarm"], rec["step_source"]) == ("hit", "memory")
+    _steps(tr, BATCHES[2:3])
+    # a world never visited nor prewarmed is an honest miss, not "n/a"
+    rec = tr.live_resize(2)
+    assert (rec["prewarm"], rec["step_source"]) == ("miss", "compile")
+    _steps(tr, BATCHES[3:4])
+    # another trainer of this process: empty table, artifact on disk
+    other = _trainer(8)
+    _steps(other, BATCHES[:1])
+    rec = other.live_resize(4)
+    assert (rec["prewarm"], rec["step_source"]) == ("hit", "disk")
+    _steps(other, BATCHES[1:2])
+    # before any step there is no batch structure to key a step by
+    fresh = _trainer(8)
+    rec = fresh.live_resize(4)
+    assert (rec["prewarm"], rec["step_source"]) == ("n/a", "compile")
+    _steps(fresh, BATCHES[:1])
+
+
+# -- the table of ready steps ----------------------------------------------
+
+
+WALK = (2, 4, 2, 4)
+
+
+def _walk(reuse):
+    """4 -> 2 -> 4 -> 2 -> 4, two steps on every world: the losses, and
+    per resize its record and what JAX did in its first dispatch."""
+    from edl_tpu.obs import trace as obs_trace
+    tr = _trainer(4)
+    losses, records, jax_s = [], [], []
+    batches = iter(BATCHES + BATCHES)
+
+    def two_steps():
+        for _ in range(2):
+            b = next(batches)
+            losses.append(np.asarray(
+                tr.train_step(tr.local_batch_slice(b))).tobytes())
+
+    two_steps()
+    for world in WALK:
+        if not reuse:
+            tr._ready_steps.clear()
+        obs_trace.TRACER.clear()
+        records.append(tr.live_resize(world))
+        two_steps()
+        [d] = [s for s in obs_trace.TRACER.spans()
+               if s["name"] == "resize.first_dispatch"]
+        jax_s.append(d["tags"])
+    tr.close()
+    return {"losses": losses, "records": records, "jax_s": jax_s}
+
+
+@pytest.fixture(scope="module")
+def walks():
+    return {reuse: _walk(reuse) for reuse in (True, False)}
+
+
+@pytest.mark.parametrize("pause", [1, 2, 3])
+def test_walk_back_to_a_world_fires_no_jax_event(walks, pause):
+    """From the second pause on every target is a world the job has run
+    on: the step comes from the table and its first dispatch traces,
+    lowers, compiles and loads nothing."""
+    walk = walks[True]
+    assert walk["records"][0]["step_source"] == "compile"
+    rec = walk["records"][pause]
+    assert (rec["prewarm"], rec["step_source"]) == ("hit", "memory")
+    assert set(walk["jax_s"][pause]) == {
+        "jax_trace_s", "jax_lower_s", "jax_compile_s", "jax_cache_load_s"}
+    assert sum(walk["jax_s"][pause].values()) == 0.0
+    # with the table emptied the same pause builds the step again
+    assert walks[False]["records"][pause]["step_source"] == "compile"
+    assert walks[False]["jax_s"][pause]["jax_trace_s"] > 0
+
+
+def test_walk_losses_equal_bit_for_bit_without_the_table(walks):
+    """Reuse changes where the executable comes from, never what it
+    computes: the same program on the same state."""
+    assert len(walks[True]["losses"]) == 2 * (1 + len(WALK))
+    assert walks[True]["losses"] == walks[False]["losses"]
+
+
+def test_reuse_is_counted():
+    from edl_tpu.runtime import trainer as trainer_mod
+    tr = _trainer(4)
+    _steps(tr, BATCHES[:1])
+    before = trainer_mod._STEP_REUSES.value
+    tr.live_resize(2)
+    _steps(tr, BATCHES[1:2])
+    assert trainer_mod._STEP_REUSES.value == before
+    tr.live_resize(4)
+    assert trainer_mod._STEP_REUSES.value == before + 1
+
+
+def test_another_factorisation_of_the_same_devices_is_another_key():
+    batches = _tp_batches()
+    tr = _tp_trainer(4)
+    _steps(tr, batches[:1])
+    rec = tr.live_resize(4, mesh_shape={"dp": 2, "tp": 2})
+    assert rec["step_source"] == "compile"
+    _steps(tr, batches[1:2])
+    # the same four devices as dp=1 x tp=4: never run, so not held
+    rec = tr.live_resize(4, mesh_shape={"dp": 1, "tp": 4})
+    assert rec["step_source"] == "compile"
+    _steps(tr, batches[2:3])
+    assert len(tr._ready_steps) == 2
+    # each factorisation finds its own step again
+    for shape in ({"dp": 2, "tp": 2}, {"dp": 4}, {"dp": 1, "tp": 4}):
+        rec = tr.live_resize(4, mesh_shape=shape)
+        assert rec["step_source"] == "memory", shape
+        for a, n in shape.items():
+            assert tr.mesh.shape[a] == n
+        _steps(tr, batches[3:4])
+    assert len(tr._ready_steps) == 3
+
+
+def test_failing_ready_step_is_evicted_and_training_goes_on(
+        tmp_path, monkeypatch):
+    """An AOT entry takes exactly the inputs it was compiled for. One
+    that refuses its first call (here: half the rows) does so before
+    dispatch: it leaves the table, the jit path takes the step, and the
+    jit step is what the world keeps from then on."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    tr = _trainer(8)
+    _steps(tr, BATCHES[:1])
+    assert tr.prewarm_resize_compiles([4], block=True) == [4]
+    [key] = tr._ready_steps
+    assert tr.live_resize(4)["step_source"] == "memory"
+    aot = tr._jit_step
+    assert tr._ready_steps[key] is aot
+    half = linear.synthetic_batch(TOTAL_BATCH // 2, seed=9)
+    loss = tr.train_step(half)
+    assert np.isfinite(float(loss))
+    assert key not in tr._ready_steps
+    assert tr._jit_step is not aot
+    _steps(tr, BATCHES[1:2])
+    tr.live_resize(8)
+    _steps(tr, BATCHES[2:3])
+    # world 4 now keeps the jit step that ran there
+    assert tr.live_resize(4)["step_source"] == "memory"
+    assert tr._jit_step is not aot
+    _steps(tr, BATCHES[3:4])
+
+
+def test_prewarm_thread_racing_a_resize_takes_the_old_path(
+        tmp_path, monkeypatch):
+    """block=False: the entry appears when the thread finishes. A resize
+    that comes first finds neither entry nor artifact, compiles as it
+    always did, and does not wait for the thread."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    tr = _trainer(8)
+    _steps(tr, BATCHES[:1])
+    gate = threading.Event()
+    lower = tr._step_lowered
+
+    def held(world_n=None):
+        if threading.current_thread().name == "resize-prewarm":
+            assert gate.wait(60)
+        return lower(world_n)
+
+    monkeypatch.setattr(tr, "_step_lowered", held)
+    assert tr.prewarm_resize_compiles([4], block=False) == [4]
+    rec = tr.live_resize(4)
+    assert (rec["prewarm"], rec["step_source"]) == ("miss", "compile")
+    _steps(tr, BATCHES[1:2])
+    assert tr._prewarm_thread.is_alive()
+    assert len(tr._ready_steps) == 1    # the step left on world 8
+    gate.set()
+    tr._prewarm_thread.join(60)
+    assert not tr._prewarm_thread.is_alive()
+    assert len(tr._ready_steps) == 2    # and the thread's, for world 4
+    assert tr.live_resize(8)["step_source"] == "memory"
+    _steps(tr, BATCHES[2:3])
+    assert tr.live_resize(4)["step_source"] == "memory"
+    _steps(tr, BATCHES[3:4])
 
 
 # -- the store protocol ----------------------------------------------------
@@ -564,6 +740,30 @@ def test_job_doctor_live_resize_findings():
     assert "doctor-local" in report["summary"]
     json.dumps(report)
     job_doctor.render(report)  # the human surface renders the chains
+
+
+@pytest.mark.parametrize("counters,found", [
+    ({"edl_resize_prewarm_misses_total": 2.0}, True),
+    ({"edl_resize_prewarm_misses_total": 2.0,
+      "edl_resize_prewarm_hits_total": 1.0}, False),
+    # a step taken from the process's own table paid no compile either
+    ({"edl_resize_prewarm_misses_total": 2.0,
+      "edl_resize_step_reuses_total": 3.0}, False),
+    ({"edl_resize_step_reuses_total": 3.0}, False),
+])
+def test_job_doctor_prewarm_miss_counts_memory_reuse_as_hit(counters,
+                                                            found):
+    from edl_tpu.tools import job_doctor
+    obs_doc = {
+        "schema": "obs_pub/v1", "events": [],
+        "metrics": {"metrics": {name: {"series": [{"value": v}]}
+                                for name, v in counters.items()}},
+    }
+    report = job_doctor.diagnose({"job_id": "j", "job_status": None,
+                                  "health": None,
+                                  "obs": {"pod-00": obs_doc}})
+    assert [f["detector"] for f in report["findings"]] == (
+        ["prewarm_miss"] if found else [])
 
 
 # -- cross-mesh (model-parallel) transitions -------------------------------

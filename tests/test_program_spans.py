@@ -67,41 +67,71 @@ def clean_ring():
     obs_trace.TRACER.clear()
 
 
+#: the resizes of the `arc` fixture, in the order it makes them: where
+#: each takes its step from, and what its root says
+CASES = {
+    "memory_shrink": {"from_devices": 4, "to_devices": 2,
+                      "prewarm": "hit", "step_source": "memory"},
+    "memory_grow": {"from_devices": 2, "to_devices": 4,
+                    "prewarm": "hit", "step_source": "memory"},
+    "disk": {"from_devices": 4, "to_devices": 2,
+             "prewarm": "hit", "step_source": "disk"},
+    "compile": {"from_devices": 2, "to_devices": 1,
+                "prewarm": "miss", "step_source": "compile"},
+}
+ALL_CASES = pytest.mark.parametrize("case", list(CASES))
+
+
 @pytest.fixture(scope="module")
 def arc(tmp_path_factory):
     """One 4 -> 2 -> 4 arc with an async save before each resize and the
-    2-chip step prewarmed, as the benchmark's elastic cell runs it: the
-    ring's spans, the timing record after each first step, and what the
-    ledger charged to `resize_pause` over each resize."""
+    2-chip step prewarmed in this process, as the benchmark's elastic
+    cell runs it: both resizes take a step the trainer holds ready. Then
+    a second trainer, whose table is empty: its 4 -> 2 finds the first
+    one's artifact on disk, its 2 -> 1 goes to a world nobody compiled
+    for. The ring's spans, the timing record after each first step, and
+    what the ledger charged to `resize_pause` over each resize."""
     tmp = tmp_path_factory.mktemp("arc")
     mp = pytest.MonkeyPatch()
     mp.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp / "cache"))
+    records, pauses, cases = {}, {}, iter(CASES)
+
+    def resize(tr, world, batch_no):
+        before = obs_ledger.LEDGER.totals()["resize_pause"]
+        tr.live_resize(world)
+        _step(tr, batch_no)
+        case = next(cases)
+        pauses[case] = obs_ledger.LEDGER.totals()["resize_pause"] - before
+        records[case] = tr.resize_timing
+
     tr = _trainer(4, ckpt=str(tmp / "ckpt"), async_save=True)
+    other = _trainer(4)
     try:
         _step(tr, 0)
         assert tr.prewarm_resize_compiles([2], block=True) == [2]
+        _step(other, 0)
         obs_trace.TRACER.clear()
-        records, pauses = {}, {}
         for i, world in enumerate((2, 4)):
             tr.save()
-            before = obs_ledger.LEDGER.totals()["resize_pause"]
-            tr.live_resize(world)
-            _step(tr, 1 + i)
-            pauses[world] = (obs_ledger.LEDGER.totals()["resize_pause"]
-                             - before)
-            records[world] = tr.resize_timing
+            resize(tr, world, 1 + i)
         tr.wait_for_save()
+        for i, world in enumerate((2, 1)):
+            resize(other, world, 1 + i)
         spans = obs_trace.TRACER.spans()
     finally:
         tr.close()
+        other.close()
         mp.undo()
         obs_trace.TRACER.clear()
     return {"spans": spans, "records": records, "pauses": pauses}
 
 
-def _resize(arc, world):
-    """(root, the spans of its trace) of the resize to `world` chips."""
-    [root] = _named(arc["spans"], "resize.live", to_devices=world)
+def _resize(arc, case):
+    """(root, the spans of its trace) of the resize called `case`."""
+    roots = sorted(_named(arc["spans"], "resize.live"),
+                   key=lambda s: s["t0"])
+    assert len(roots) == len(CASES)
+    root = roots[list(CASES).index(case)]
     return root, [s for s in arc["spans"]
                   if s["trace_id"] == root["trace_id"]]
 
@@ -109,26 +139,25 @@ def _resize(arc, world):
 # -- a live resize ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("world,prewarm", [(2, "hit"), (4, "miss")])
-def test_resize_leaves_one_root_with_the_table_s_children(arc, world,
-                                                          prewarm):
-    root, trace = _resize(arc, world)
+@ALL_CASES
+def test_resize_leaves_one_root_with_the_table_s_children(arc, case):
+    root, trace = _resize(arc, case)
     assert root["parent_id"] is None
-    assert root["tags"] == {"from_devices": 6 - world, "to_devices": world,
-                            "prewarm": prewarm}
+    assert root["tags"] == CASES[case]
     children = [s for s in trace if s["parent_id"] == root["span_id"]
                 and s["name"] != "resize.first_step"]
-    # a grow finds no artifact for its world: the glob misses before the
-    # fingerprint, so neither prewarm span exists there
-    want = RESIZE_STAGES + (PREWARM_STAGES if prewarm == "hit" else [])
+    # a step the trainer holds ready needs no name and no load, so
+    # neither prewarm span is opened; nor is it when the glob finds no
+    # artifact for the world. Only the trainer with an empty table and
+    # an artifact on disk fingerprints and loads
+    want = RESIZE_STAGES + (PREWARM_STAGES if case == "disk" else [])
     assert [s["name"] for s in sorted(children, key=lambda s: s["t0"])] \
         == want
-    assert len(_named(arc["spans"], "resize.live")) == 2
 
 
-@pytest.mark.parametrize("world", [2, 4])
-def test_resize_children_lie_inside_the_root_and_do_not_overlap(arc, world):
-    root, trace = _resize(arc, world)
+@ALL_CASES
+def test_resize_children_lie_inside_the_root_and_do_not_overlap(arc, case):
+    root, trace = _resize(arc, case)
     children = sorted((s for s in trace
                        if s["parent_id"] == root["span_id"]
                        and s["name"] != "resize.first_step"),
@@ -140,9 +169,9 @@ def test_resize_children_lie_inside_the_root_and_do_not_overlap(arc, world):
         assert _end(a) <= b["t0"] + eps, (a["name"], b["name"])
 
 
-@pytest.mark.parametrize("world", [2, 4])
-def test_first_step_joins_the_trace_of_its_resize(arc, world):
-    root, trace = _resize(arc, world)
+@ALL_CASES
+def test_first_step_joins_the_trace_of_its_resize(arc, case):
+    root, trace = _resize(arc, case)
     [first] = [s for s in trace if s["name"] == "resize.first_step"]
     # it follows the resize it ends: linked to the root, begun after it
     assert first["parent_id"] == root["span_id"]
@@ -159,28 +188,28 @@ def test_first_step_joins_the_trace_of_its_resize(arc, world):
          "resize.first_result"] + RESIZE_STAGES + PREWARM_STAGES)
 
 
-@pytest.mark.parametrize("world", [2, 4])
-def test_first_dispatch_says_what_jax_did(arc, world):
-    _, trace = _resize(arc, world)
+@ALL_CASES
+def test_first_dispatch_says_what_jax_did(arc, case):
+    _, trace = _resize(arc, case)
     [d] = [s for s in trace if s["name"] == "resize.first_dispatch"]
     assert set(d["tags"]) == {"jax_trace_s", "jax_lower_s", "jax_compile_s",
                               "jax_cache_load_s"}
     total = sum(d["tags"].values())
     assert total <= d["dur_ms"] / 1e3 + 1e-3
-    if world == 2:
-        # the prewarmed executable was loaded: nothing to trace or build
+    if case != "compile":
+        # an executable held ready or loaded: nothing to trace or build
         assert total == 0.0
     else:
-        # no artifact for 4 chips: the step is traced and built again
+        # a world nobody compiled for: the step is traced and built
         assert d["tags"]["jax_trace_s"] > 0
         assert d["tags"]["jax_lower_s"] > 0
         assert d["tags"]["jax_compile_s"] > 0
 
 
-@pytest.mark.parametrize("world", [2, 4])
-def test_timing_record_is_read_off_the_spans(arc, world):
-    _, trace = _resize(arc, world)
-    rec = arc["records"][world]
+@ALL_CASES
+def test_timing_record_is_read_off_the_spans(arc, case):
+    _, trace = _resize(arc, case)
+    rec = arc["records"][case]
 
     def total(names):
         return sum(s["dur_ms"] for s in trace if s["name"] in names) / 1e3
@@ -193,20 +222,21 @@ def test_timing_record_is_read_off_the_spans(arc, world):
         total(["resize.first_dispatch"]), abs=1e-9)
     assert rec["first_step_s"] == pytest.approx(
         total(["resize.first_result"]), abs=1e-9)
-    assert rec["mode"] == "live" and rec["to_devices"] == world
+    assert rec["mode"] == "live"
+    assert {k: rec[k] for k in CASES[case]} == CASES[case]
 
 
-@pytest.mark.parametrize("world", [2, 4])
-def test_ledger_pause_is_bounded_by_the_spans(arc, world):
+@ALL_CASES
+def test_ledger_pause_is_bounded_by_the_spans(arc, case):
     """The ledger's `resize_pause` runs from the transition before
     `resize.live` opens to the one after `resize.first_step` closes: the
     two spans, plus what the caller did between them (here: nothing but
     placing the next batch)."""
-    root, trace = _resize(arc, world)
+    root, trace = _resize(arc, case)
     [first] = [s for s in trace if s["name"] == "resize.first_step"]
     spans_s = (root["dur_ms"] + first["dur_ms"]) / 1e3
     wall_s = _end(first) - root["t0"]
-    pause = arc["pauses"][world]
+    pause = arc["pauses"][case]
     # the drain nests ckpt_block over the pause and takes its seconds
     [drain] = [s for s in trace if s["name"] == "resize.drain"]
     assert spans_s - drain["dur_ms"] / 1e3 - 5e-3 <= pause <= wall_s + 5e-3
