@@ -1,0 +1,216 @@
+"""Sparse decoder family: a causal LM whose every layer is grouped-query
+attention followed by a top-k mixture of ReGLU experts, with the router
+tapped BEFORE attention (it reads the attention's normed input), RMSNorm,
+no biases, an untied output head, and a per-layer choice of rotary
+positions or none and of a causal window or the full prefix.
+
+One chip's share of an expert-parallel, head-parallel, vocabulary-parallel
+deployment: a layer is told how many query and key-value heads, which
+experts (``first_expert``, ``experts_held``) and how many rows of the
+vocabulary it holds. The router scores ALL ``num_experts``; rows routed to
+experts held elsewhere are left out of the result, which goes on to the
+next layer as it is (``parallel/moe.py:held_experts_ffn``). Nothing stands
+in for the absent chips.
+
+bf16 activations and products; float32 parameters, router scores,
+attention softmax and logits. Attention goes through the one dispatch
+(``ops/attention.py:attention_context``: dense or the Pallas flash
+kernel).
+"""
+
+from typing import Any, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import optax
+
+from edl_tpu.ops.attention import attention_context
+from edl_tpu.parallel import moe
+
+#: what a layer counts about its routing each step (float32 scalars);
+#: `load_max` is kept as a running maximum, the others as running sums
+COUNTERS = ("rows_held", "load_max", "load_mean", "tokens_unserved",
+            "rows_dropped")
+
+
+def _init(std=0.02):
+    return nn.initializers.normal(stddev=std)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
+                                + self.eps)
+        return (y * scale).astype(x.dtype)
+
+
+def rope(x, theta):
+    """Rotary positions, half-split convention: x [b, s, h, d]; pair i is
+    (x[i], x[i + d/2]), turned by position * theta ** (-2 i / d)."""
+    d = x.shape[-1]
+    half = d // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq[None]
+    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class SparseDecoderLayer(nn.Module):
+    """h = norm(x); route on h; x' = x + attention(h); out = x' +
+    held experts(norm(x')) by h's routing. Returns (out, counters)."""
+    heads: int                 # query heads held here
+    kv_heads: int              # key-value heads held here
+    head_dim: int
+    num_experts: int           # the router's width: all the layer's experts
+    experts_held: int
+    first_expert: int
+    experts_per_token: int
+    expert_width: int
+    use_rope: bool
+    rope_theta: float
+    window: Optional[int]      # None: the whole causal prefix
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    use_flash: Optional[bool] = None
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        dt = self.dtype
+        proj = lambda name, shape: self.param(name, _init(), shape,
+                                              jnp.float32).astype(dt)
+        h = RMSNorm(self.eps, name="norm_attn")(x)
+        with jax.named_scope("moe.route"):
+            router = self.param("router", _init(), (d, self.num_experts),
+                                jnp.float32)
+            idx, p = moe.route_top_k(h.reshape(b * s, d), router,
+                                     self.experts_per_token)
+        with jax.named_scope("attn.window" if self.window else "attn.full"):
+            q = jnp.einsum("bsd,dhk->bshk", h,
+                           proj("query", (d, self.heads, self.head_dim)))
+            k = jnp.einsum("bsd,dhk->bshk", h,
+                           proj("key", (d, self.kv_heads, self.head_dim)))
+            v = jnp.einsum("bsd,dhk->bshk", h,
+                           proj("value", (d, self.kv_heads, self.head_dim)))
+            if self.use_rope:
+                q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+            a = attention_context(q, k, v, causal=True, mask=None, dtype=dt,
+                                  use_flash=self.use_flash,
+                                  window=self.window)
+            x = x + jnp.einsum("bshk,hkd->bsd", a,
+                               proj("out", (self.heads, self.head_dim, d)))
+        u = RMSNorm(self.eps, name="norm_moe")(x)
+        f = self.expert_width
+        gate_up = self.param("experts_gate_up", _init(),
+                             (self.experts_held, d, 2 * f), jnp.float32)
+        down = self.param("experts_down", _init(),
+                          (self.experts_held, f, d), jnp.float32)
+        m, counters = moe.held_experts_ffn(
+            u.reshape(b * s, d), idx, p, gate_up, down, self.first_expert)
+        return x + m.reshape(b, s, d), counters
+
+
+class SparseDecoder(nn.Module):
+    """ids [b, s] -> (float32 logits [b, s, vocab], counters {name: [L]})."""
+    vocab_size: int            # rows of the vocabulary held here
+    d_model: int
+    num_layers: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    num_experts: int
+    experts_held: int
+    first_expert: int
+    experts_per_token: int
+    expert_width: int
+    rope_layout: Sequence[int]      # per layer: 1 = rotary, 0 = none
+    window_layout: Sequence[int]    # per layer: 1 = window, 0 = full
+    window: int
+    rope_theta: float
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    remat: bool = False
+    use_flash: Optional[bool] = None
+
+    @nn.compact
+    def __call__(self, ids):
+        embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
+                           jnp.float32)
+        x = jnp.take(embed, ids, axis=0).astype(self.dtype)
+        # remat by layer, keeping the chosen experts and the two grouped
+        # products' results: the experts' forward is the one part whose
+        # cost follows the routing
+        layer_cls = (nn.remat(
+            SparseDecoderLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *moe.SAVED_UNDER_REMAT)) if self.remat else SparseDecoderLayer)
+        per_layer = []
+        for i in range(self.num_layers):
+            x, counters = layer_cls(
+                heads=self.heads, kv_heads=self.kv_heads,
+                head_dim=self.head_dim, num_experts=self.num_experts,
+                experts_held=self.experts_held,
+                first_expert=self.first_expert,
+                experts_per_token=self.experts_per_token,
+                expert_width=self.expert_width,
+                use_rope=bool(self.rope_layout[i]),
+                rope_theta=self.rope_theta,
+                window=self.window if self.window_layout[i] else None,
+                eps=self.eps, dtype=self.dtype, use_flash=self.use_flash,
+                name="layer_%d" % i)(x)
+            per_layer.append(counters)
+        x = RMSNorm(self.eps, name="norm_final")(x)
+        with jax.named_scope("lm_head"):
+            head = self.param("lm_head", _init(),
+                              (self.d_model, self.vocab_size), jnp.float32)
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
+                                preferred_element_type=jnp.float32)
+        return logits, {n: jnp.stack([c[n] for c in per_layer])
+                        for n in COUNTERS}
+
+
+def init_counters(num_layers):
+    """The routing counters a trainer carries in its extra state:
+    ``{"counters": {name: [L] float32, "steps": scalar}}``."""
+    # one buffer each: the trainer donates its state to the step
+    return {"counters": dict(
+        {n: jnp.zeros((num_layers,), jnp.float32) for n in COUNTERS},
+        steps=jnp.zeros((), jnp.float32))}
+
+
+def accumulate_counters(extra, step_counters):
+    old = extra["counters"]
+    new = {n: (jnp.maximum(old[n], step_counters[n]) if n == "load_max"
+               else old[n] + step_counters[n]) for n in COUNTERS}
+    new["steps"] = old["steps"] + 1.0
+    return dict(extra, counters=new)
+
+
+def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
+    """(model, params, extra_state, loss_fn) for ElasticTrainer with
+    ``has_aux=True``: next-token cross-entropy over batch["input_ids"]
+    (shift inside); the extra state carries the routing counters on the
+    device (``trainer.extra_state["counters"]``), which the trainer
+    mirrors into obs.metrics where it synchronises anyway."""
+    dummy = jnp.zeros((dummy_batch, dummy_seq), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), dummy)["params"]
+
+    def loss_fn(params, extra, batch, rng):
+        ids = batch["input_ids"]
+        logits, counters = model.apply({"params": params}, ids)
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1], ids[:, 1:]).mean()
+        return loss, accumulate_counters(
+            extra, jax.lax.stop_gradient(counters))
+
+    return model, params, init_counters(model.num_layers), loss_fn

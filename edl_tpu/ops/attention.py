@@ -1,8 +1,10 @@
-"""Shared attention-impl dispatch for the model families (BERT, GPT):
-in-shard ring / ring over the sp mesh axis / Pallas flash kernel / dense
-— one copy of the -1e30 mask convention, sm_scale, and the interpret
-mode CPU tests use. Lives in ops/ (neutral layer) so model modules don't import
-each other for infrastructure.
+"""Shared attention-impl dispatch for the model families (BERT, GPT, the
+sparse decoder): in-shard ring / ring over the sp mesh axis / Pallas flash
+kernel / dense — one copy of the -1e30 mask convention, sm_scale, and the
+interpret mode CPU tests use. The flash and dense paths take a causal
+``window`` and fewer key-value heads than query heads (grouped-query
+attention); K and V are never repeated in memory. Lives in ops/ (neutral
+layer) so model modules don't import each other for infrastructure.
 
 ``use_flash=None`` (the default) auto-dispatches: on TPU, shapes the
 Pallas kernel handles exactly take the flash path; everything else stays
@@ -24,6 +26,12 @@ _FLASH_HEAD_MULT = 8
 def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
                           seq_kv=None, offset=None):
     """Why auto-dispatch would (not) pick flash for this shape.
+
+    The kernel takes any number of query heads per key-value head and,
+    under ``causal``, a ``window`` (a query sees its own position and the
+    ``window - 1`` before it); neither changes the answer below, so
+    neither is asked for. (A window without ``causal`` is refused by
+    :func:`attention_context` itself, whatever the path.)
 
     Returns ``None`` when the flash path is legal and profitable, else a
     human-readable reason string (the dense path is taken). Pure shape
@@ -72,12 +80,29 @@ def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
     return None
 
 
+def _causal_band(q_pos, k_pos, window):
+    """[q, k] bool: which keys a query may read: none ahead of it and,
+    with a window, its own position and the ``window - 1`` before."""
+    keep = q_pos[:, None] >= k_pos[None, :]
+    if window is not None:
+        keep = jnp.logical_and(keep, q_pos[:, None] - k_pos[None, :] < window)
+    return keep
+
+
 def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
-                      use_ring=False, use_flash=None, mesh=None):
-    """The shared attention-impl dispatch for BERT and GPT: in-shard ring
-    (already inside a shard_map over ``ring_axis``) / ring over the sp
-    mesh axis / Pallas flash kernel / dense — one copy of the -1e30 mask
-    convention, sm_scale, and the interpret mode CPU tests use.
+                      use_ring=False, use_flash=None, mesh=None,
+                      window=None):
+    """The shared attention-impl dispatch for BERT, GPT and the sparse
+    decoder: in-shard ring (already inside a shard_map over
+    ``ring_axis``) / ring over the sp mesh axis / Pallas flash kernel /
+    dense — one copy of the -1e30 mask convention, sm_scale, and the
+    interpret mode CPU tests use.
+
+    q is [batch, seq, heads, dim]; k and v are [batch, seq_kv, kv_heads,
+    dim] with ``heads`` a multiple of ``kv_heads`` (query head i reads kv
+    head ``i // (heads // kv_heads)``). ``window`` (flash and dense paths,
+    needs ``causal``): a query reads its own position and the
+    ``window - 1`` before it; ``None`` reads the whole causal prefix.
 
     ``use_flash``: ``True`` forces the Pallas flash kernel, ``False``
     forces dense, ``None`` (default) auto-dispatches by
@@ -87,6 +112,17 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
     """
     head_dim = q.shape[-1]
     scale = head_dim ** -0.5
+    heads, kv_heads = q.shape[2], k.shape[2]
+    group = heads // kv_heads
+    if group * kv_heads != heads:
+        raise ValueError("%d query heads do not divide over %d kv heads"
+                         % (heads, kv_heads))
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    if ring_axis or use_ring:
+        if window is not None or group > 1:
+            raise ValueError("ring attention takes no window and equal "
+                             "head counts")
     if ring_axis:
         from edl_tpu.parallel.ring_attention import _ring_attention_shard
         return _ring_attention_shard(q, k, v, axis_name=ring_axis,
@@ -116,17 +152,27 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
         # which JAX raises at start-up rather than handing out a CPU
         # backend — so a process that lost its chip cannot reach this
         # line and quietly run the kernel in the interpreter.
-        return mha(q, k, v, causal=causal,
+        return mha(q, k, v, causal=causal, window=window,
                    interpret=jax.default_backend() == "cpu")
+    b, s = q.shape[:2]
+    if group > 1:
+        # the query heads of one kv head, one run of the sequence after
+        # another: [b, group * s, kv_heads, d] against k as it is
+        q = q.reshape(b, s, kv_heads, group, head_dim).transpose(
+            0, 3, 1, 2, 4).reshape(b, group * s, kv_heads, head_dim)
     scores = jnp.einsum("bqhd,bkhd->bhqk",
                         (q * scale).astype(jnp.float32),
                         k.astype(jnp.float32))
     if causal:
-        s = q.shape[1]
-        tri = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(tri[None, None], scores, -1e30)
+        keep = _causal_band(jnp.tile(jnp.arange(s), group),
+                            jnp.arange(k.shape[1]), window)
+        scores = jnp.where(keep[None, None], scores, -1e30)
     if mask is not None:
         scores = jnp.where(mask[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs,
-                      v.astype(jnp.float32)).astype(dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs,
+                     v.astype(jnp.float32)).astype(dtype)
+    if group > 1:
+        out = out.reshape(b, group, s, kv_heads, head_dim).transpose(
+            0, 2, 3, 1, 4).reshape(b, s, heads, head_dim)
+    return out

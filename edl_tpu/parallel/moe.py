@@ -200,3 +200,156 @@ def moe_ffn(params, x, mesh, capacity_factor=2.0, k=1,
     if return_aux:
         return y, metrics["aux_loss"]
     return y
+
+
+# -- dropless held experts -------------------------------------------------
+#
+# One chip's share of an expert-parallel layer: the router scores ALL the
+# layer's experts, this program holds ``experts_held`` of them starting at
+# ``first_expert`` and computes their part of the result for every row
+# routed to them — no capacity, no dropped row, and no row multiplied by an
+# expert it did not choose (ops/grouped_matmul.py). Rows routed to experts
+# held elsewhere are left out; on one chip the layer runs without its
+# exchange, and nothing stands in for the absent chips.
+
+#: rows of a grouped-product tile; each held expert's rows are padded to
+#: whole tiles (at least one), so a tile belongs to one expert
+ROW_TILE = 512
+
+#: `jax.ad_checkpoint.checkpoint_name`s of the chosen experts and of the two
+#: grouped products' results: a layer under remat that saves these
+#: (`checkpoint_policies.save_only_these_names`) does not run the experts'
+#: forward products a second time — the part of a step whose cost follows
+#: the routing — and recomputes the gathers and the gate around them. The
+#: choice is saved WITH the products: a recomputed top-k may break a near
+#: tie the other way, and rows laid out by one choice must not meet
+#: products saved under another.
+SAVED_UNDER_REMAT = ("moe.choice", "moe.gate_up", "moe.down")
+
+
+def route_top_k(h, router, k):
+    """Router scores in float32 over ALL experts, the k largest per row,
+    and their softmax over the chosen k (equal to a softmax over all,
+    renormalised over the chosen). Returns (idx [T, k] int32, p [T, k])."""
+    from jax.ad_checkpoint import checkpoint_name
+    scores = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    idx = checkpoint_name(lax.top_k(lax.stop_gradient(scores), k)[1].astype(
+        jnp.int32), SAVED_UNDER_REMAT[0])
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, jax.nn.softmax(top, axis=-1)
+
+
+def plan_held_rows(idx, first_expert, experts_held, tm=ROW_TILE):
+    """Where every (token, choice) that picked a held expert goes among
+    the rows sorted by expert. Static shapes: ``rows = T * k +
+    experts_held * tm`` slots, the worst case, of which the used tiles
+    come first. Choices are numbered choice-major (``j * T + t``) and
+    their arrays are [k, T], so that what is gathered by them is k whole
+    [T, D] slabs and no short axis sits next to the lanes.
+
+    Returns a dict: ``slot`` [k, T] (the row of each choice; ``rows`` =
+    out of range where the expert is held elsewhere), ``slot_token``
+    [rows] (the token in each row; T = empty), ``slot_choice`` [rows]
+    (j * T + t of the choice in each row; T * k = empty), ``tile_group``
+    [rows // tm], ``n_used`` [1], ``counts`` [experts_held]."""
+    t, k = idx.shape
+    rows = t * k + experts_held * tm
+    local = idx.T.reshape(-1) - first_expert
+    held = jnp.logical_and(local >= 0, local < experts_held)
+    key = jnp.where(held, local, experts_held)
+    # a counting sort: the rank of a choice among those of its expert
+    onehot = (key[:, None] == jnp.arange(experts_held)[None, :]).astype(
+        jnp.int32)
+    before = jnp.cumsum(onehot, axis=0) - onehot
+    counts = onehot.sum(axis=0)
+    rank = jnp.sum(before * onehot, axis=1)
+    padded = jnp.maximum((counts + tm - 1) // tm, 1) * tm
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    slot = jnp.where(held, starts[jnp.minimum(key, experts_held - 1)] + rank,
+                     rows)
+    choice = jnp.arange(t * k, dtype=jnp.int32)
+    slot_choice = jnp.full((rows,), t * k, jnp.int32).at[slot].set(
+        choice, mode="drop")
+    slot_token = jnp.where(slot_choice < t * k, slot_choice % t, t)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(rows // tm) * tm, side="right"),
+        experts_held - 1).astype(jnp.int32)
+    return {"slot": slot.reshape(k, t).astype(jnp.int32),
+            "slot_token": slot_token.astype(jnp.int32),
+            "slot_choice": slot_choice,
+            "tile_group": tile_group,
+            "n_used": (ends[-1:] // tm).astype(jnp.int32),
+            "counts": counts}
+
+
+@jax.custom_vjp
+def _take_rows(src, idx, inverse):
+    """src[idx] with zeros where idx is out of range. A permutation with
+    holes: ``inverse`` [r, len(src)] lists, for each row of src, the r
+    places of the (flattened) result that may read it (out of range =
+    none), so the backward pass is a gather too and never a
+    scatter-add."""
+    del inverse
+    return jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(src, idx, inverse):
+    return _take_rows(src, idx, inverse), inverse
+
+
+def _take_rows_bwd(inverse, g):
+    g = g.reshape((-1, g.shape[-1]))
+    back = jnp.take(g, inverse, axis=0, mode="fill", fill_value=0)
+    return back.sum(axis=0), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
+                     tm=ROW_TILE, interpret=None):
+    """The held experts' part of a top-k ReGLU expert layer.
+
+    u [T, D] (the layer's normed input), idx/p [T, k] from
+    :func:`route_top_k`, w_gate_up [held, D, 2 * F] (gate then up),
+    w_down [held, F, D]. Returns (m [T, D] in u's dtype:
+    sum over the chosen held experts of p * down(relu(gate u) * (up u)),
+    counters: float32 scalars ``rows_held``, ``load_max``, ``load_mean``,
+    ``tokens_unserved``, ``rows_dropped``). ``interpret`` (default: on
+    the CPU backend, as the attention dispatch does) runs the Pallas
+    kernels in the interpreter, for tests."""
+    from jax.ad_checkpoint import checkpoint_name
+    from edl_tpu.ops.grouped_matmul import grouped_matmul
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    held, d, f2 = w_gate_up.shape
+    t, k = idx.shape
+    with jax.named_scope("moe.dispatch"):
+        plan = plan_held_rows(idx, first_expert, held, tm)
+        rows = _take_rows(u, plan["slot_token"], plan["slot"])
+    with jax.named_scope("moe.experts"):
+        gm = functools.partial(grouped_matmul, tile_group=plan["tile_group"],
+                               n_used=plan["n_used"], tm=tm,
+                               interpret=interpret)
+        gu = checkpoint_name(gm(rows, w_gate_up), SAVED_UNDER_REMAT[1])
+        hid = jax.nn.relu(gu[:, :f2 // 2]) * gu[:, f2 // 2:]
+        y = checkpoint_name(gm(hid, w_down), SAVED_UNDER_REMAT[2])
+    with jax.named_scope("moe.combine"):
+        picked = _take_rows(y, plan["slot"], plan["slot_choice"][None, :])
+        m = jnp.sum(picked.astype(jnp.float32) * p.T[..., None], axis=0)
+    served = plan["slot"] < rows.shape[0]
+    counts = plan["counts"].astype(jnp.float32)
+    counters = {
+        "rows_held": counts.sum(),
+        "load_max": counts.max(),
+        "load_mean": counts.mean(),
+        "tokens_unserved": jnp.sum(
+            jnp.logical_not(served.any(axis=0))).astype(jnp.float32),
+        # every choice of a held expert has a row: the rows placed equal
+        # the choices counted, whatever the routing
+        "rows_dropped": counts.sum() - jnp.sum(
+            plan["slot_choice"] < t * k).astype(jnp.float32),
+    }
+    return m.astype(u.dtype), counters

@@ -61,6 +61,13 @@ _STEP_REUSES = obs_metrics.counter(
     "live resizes that took their step executable from the table this "
     "process already held (no fingerprint, load or compile in the pause)")
 
+_MODEL_COUNTER = obs_metrics.gauge(
+    "edl_train_model_counter",
+    "what the model counts on the device in extra_state['counters'] (e.g. "
+    "an expert layer's routing), as last mirrored at a save, a live "
+    "resize or close(); index = position in the counter's vector",
+    labels=("name", "index"))
+
 #: what JAX did inside ``resize.first_dispatch``: the jax.monitoring
 #: duration events that mean a program was traced, lowered, compiled or
 #: loaded from the persistent cache, each under the tag it feeds
@@ -1436,6 +1443,7 @@ class ElasticTrainer(object):
                     # peer publish runs) BEFORE the reshape — peers keep a
                     # stable version to read across our reshard
                     self.wait_for_save()
+                    self.mirror_model_counters()
                 with obs_trace.span("resize.mesh", stage=True) as sp_mesh:
                     new_mesh = self._target_mesh(n_devices, mesh_shape)
                     if faults.PLANE is not None:
@@ -2016,6 +2024,25 @@ class ElasticTrainer(object):
     def extra_state(self):
         return self.train_state["extra"]
 
+    def mirror_model_counters(self):
+        """Copy ``extra_state["counters"]`` — numbers a model keeps ON the
+        device in the step's extra state, so that counting costs a step no
+        host read — into the metrics registry
+        (``edl_train_model_counter{name, index}``). Called where the
+        trainer synchronises with the device anyway: a save, a live
+        resize, ``close``. Returns what it read ({} where the model keeps
+        none)."""
+        extra = self.train_state["extra"]
+        counters = extra.get("counters") if isinstance(extra, dict) else None
+        if not counters:
+            return {}
+        host = {name: np.atleast_1d(np.asarray(value, np.float64))
+                for name, value in jax.device_get(counters).items()}
+        for name, values in host.items():
+            for i, v in enumerate(values):
+                _MODEL_COUNTER.labels(name, i).set(float(v))
+        return host
+
     def _state_fully_addressable(self):
         return all(getattr(x, "is_fully_addressable", True)
                    for x in jax.tree_util.tree_leaves(self.train_state))
@@ -2052,6 +2079,7 @@ class ElasticTrainer(object):
 
     def _save(self):
         version = self.global_step
+        self.mirror_model_counters()
         with obs_trace.span("save.state_json", stage=True):
             # deep-snapshot the control-plane state NOW — the background
             # writer must not see the live State's nested dicts mutating
@@ -2156,6 +2184,10 @@ class ElasticTrainer(object):
         constructing several trainers should close the ones they
         drop)."""
         self.wait_for_save()
+        try:
+            self.mirror_model_counters()
+        except Exception:  # a state lost to a failed step must not
+            logger.exception("model counters not mirrored")  # block close
         if self._live_register is not None:
             try:
                 self._live_register.stop()

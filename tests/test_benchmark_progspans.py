@@ -242,7 +242,10 @@ def test_benchmark_names_every_new_reader_once_for_the_elastic_cell():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW
+    # appended together, in this order (later PRs append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
     for name in NEW:
         m = entries[name]
         assert m["source"] == "program_span" and m["unit"] == "ms"

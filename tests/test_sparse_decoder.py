@@ -1,0 +1,389 @@
+"""The sparse decoder (`models/sparse_decoder.py`), its dropless
+held-experts layer (`parallel/moe.py:held_experts_ffn`,
+`ops/grouped_matmul.py`) and the benchmark's readers of what they count:
+
+- the model against the plain reference (`benchmark/reference/
+  smallthinker-21b-a3b.py`) on seeded weights at the `tiny` size: loss and
+  every gradient leaf, in float32 and in bfloat16;
+- the share test: the 8 expert shares and the 4 head shares of one layer
+  add up to what the uncut reference gives for the whole layer;
+- dropless under adversarial routing: every token picks held experts only;
+  none picks any;
+- the routing counters from the device, through `ElasticTrainer`, into the
+  metrics registry, and the benchmark's readers on a recorded view.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import harness, kernel_readers
+from edl_tpu.models import sparse_decoder
+from edl_tpu.ops import grouped_matmul as gm
+from edl_tpu.parallel import moe
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG = "smallthinker-21b-a3b"
+HI = jax.lax.Precision.HIGHEST
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         CONFIG + ".json"))
+    cfg = dict(cfg, **cfg["tiny"])
+    ref = harness.load_module("reference", CONFIG)
+    fam = harness.load_module("program", cfg["family"])
+    w = ref.init_weights(cfg, jax.random.PRNGKey(3))
+    ids = jax.random.randint(jax.random.PRNGKey(4), (2, 32), 0,
+                             cfg["vocab_size"], jnp.int32)
+    return cfg, ref, fam, w, {"input_ids": ids}
+
+
+def _program_loss_and_grad(cfg, fam, w, batch, dtype):
+    model = fam.build_model(cfg, {"remat": True}).clone(dtype=dtype)
+    _, _, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
+    params, _ = fam.to_program(w, cfg)
+    (loss, extra), grads = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, extra, batch, None), has_aux=True))(params)
+    return loss, grads, extra
+
+
+def _leaves(tree):
+    return {jax.tree_util.keystr(k): v for k, v in
+            jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def _grad_leaf_names():
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         CONFIG + ".json"))
+    cfg = dict(cfg, **cfg["tiny"])
+    fam = harness.load_module("program", cfg["family"])
+    return sorted(_leaves(fam.train_parts(cfg, {"remat": True})[2][0]))
+
+
+@pytest.fixture(scope="module")
+def both_float32(tiny):
+    cfg, ref, fam, w, batch = tiny
+    want_loss, g = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg))(w)
+    loss, grads, extra = _program_loss_and_grad(cfg, fam, w, batch,
+                                                jnp.float32)
+    return (loss, _leaves(grads), extra, want_loss,
+            _leaves(fam.to_program(g, cfg)[0]))
+
+
+def test_model_loss_matches_reference_float32(both_float32):
+    loss, _, extra, want_loss, _ = both_float32
+    assert abs(float(loss) - float(want_loss)) <= 1e-4 * float(want_loss)
+    assert float(extra["counters"]["rows_dropped"].sum()) == 0.0
+
+
+@pytest.mark.parametrize("leaf", _grad_leaf_names())
+def test_model_gradient_leaf_matches_reference_float32(both_float32, leaf):
+    _, got, _, _, want = both_float32
+    scale = float(jnp.abs(want[leaf]).max())
+    assert scale > 0, "a leaf the loss does not reach"
+    np.testing.assert_allclose(got[leaf], want[leaf], atol=1e-4 * scale,
+                               rtol=1e-4)
+
+
+def test_model_matches_reference_bfloat16(tiny):
+    """bf16 activations and products against the float32 reference: the
+    comparison `correct` makes on the chip (a relative L2 error over all
+    parameters), at the tiny size."""
+    cfg, ref, fam, w, batch = tiny
+    want_loss, g = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg))(w)
+    loss, grads, _ = _program_loss_and_grad(cfg, fam, w, batch,
+                                            jnp.bfloat16)
+    assert abs(float(loss) - float(want_loss)) <= 2e-3 * float(want_loss)
+    got, want = _leaves(grads), _leaves(fam.to_program(g, cfg)[0])
+    num = sum(float(jnp.sum(jnp.square(got[k] - want[k]))) for k in want)
+    den = sum(float(jnp.sum(jnp.square(want[k]))) for k in want)
+    assert (num / den) ** 0.5 < 0.05
+
+
+def test_remat_keeps_the_choice_with_the_saved_products(tiny):
+    """Under remat the layer saves the chosen experts WITH the two grouped
+    products (`moe.SAVED_UNDER_REMAT`): in bf16, where a recomputed top-k
+    can break a near tie the other way, every gradient leaf — the routers'
+    above all — matches the layer that recomputes nothing."""
+    cfg, _, fam, w, batch = tiny
+
+    def grads(remat):
+        model = fam.build_model(cfg, {"remat": remat})
+        _, _, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
+        params, _ = fam.to_program(w, cfg)
+        return _leaves(jax.jit(jax.grad(
+            lambda p: loss_fn(p, extra, batch, None)[0]))(params))
+
+    plain, rematted = grads(False), grads(True)
+    for leaf, want in plain.items():
+        err = float(jnp.linalg.norm(rematted[leaf] - want)
+                    / jnp.linalg.norm(want))
+        assert err < 0.02, (leaf, err)
+
+
+def test_int8_control_is_far_from_the_reference(tiny):
+    cfg, ref, _, w, batch = tiny
+    loss, g = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg))(w)
+    loss8, g8 = jax.jit(
+        lambda w: ref.loss_and_grad(w, batch, cfg, "int8"))(w)
+    num = sum(float(jnp.sum(jnp.square(g8[k] - g[k]))) for k in g)
+    den = sum(float(jnp.sum(jnp.square(g[k]))) for k in g)
+    assert (num / den) ** 0.5 > 0.2
+    with pytest.raises(ValueError):
+        ref.loss_and_grad(w, batch, cfg, "int4")
+
+
+# -- the share test ----------------------------------------------------------
+
+def _uncut_layer(tiny):
+    """One layer at the tiny widths, UNCUT: all 8 experts, 8 query heads on
+    4 kv heads; and its input."""
+    cfg, ref, _, _, _ = tiny
+    whole = dict(cfg, moe_num_primary_experts=cfg["moe_router_outputs"],
+                 num_attention_heads=8, num_key_value_heads=4,
+                 num_hidden_layers=1, first_expert=0)
+    w = ref.init_weights(whole, jax.random.PRNGKey(7))
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 32, cfg["hidden_size"]))
+    return whole, ref.layer_weights(w, 0), x
+
+
+@pytest.mark.parametrize("use_window", [0, 1])
+def test_head_shares_add_up_to_the_uncut_layer(tiny, use_window):
+    _, ref, _, _, _ = tiny
+    whole, lw, h = _uncut_layer(tiny)
+    hd = whole["head_dim"]
+    want = ref.attention_part(h, lw, use_window, use_window, whole)
+    total = jnp.zeros_like(want)
+    for share in range(4):          # 2 query heads and their kv head each
+        part = dict(whole, num_attention_heads=2, num_key_value_heads=1)
+        qs = slice(share * 2 * hd, (share + 1) * 2 * hd)
+        ks = slice(share * hd, (share + 1) * hd)
+        cut = dict(lw, w_q=lw["w_q"][:, qs], w_k=lw["w_k"][:, ks],
+                   w_v=lw["w_v"][:, ks], w_o=lw["w_o"][qs])
+        total += ref.attention_part(h, cut, use_window, use_window, part)
+    np.testing.assert_allclose(total, want, atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("who", ["reference", "program"])
+def test_expert_shares_add_up_to_the_uncut_layer(tiny, who):
+    """8 shares of 1 expert each (`first_expert` 0..7), the router whole in
+    every one: reference shares against the uncut reference, and the
+    program's grouped-product layer, share by share, against it too."""
+    _, ref, _, _, _ = tiny
+    whole, lw, x = _uncut_layer(tiny)
+    u = x.reshape(-1, x.shape[-1])
+    idx, p = ref.route(u, lw["w_r"], whole)
+    want = ref.experts_part(u, idx, p, lw, whole)
+    total = jnp.zeros_like(want)
+    for first in range(8):
+        cut = dict(lw, w_gate_up=lw["w_gate_up"][first:first + 1],
+                   w_down=lw["w_down"][first:first + 1])
+        if who == "reference":
+            part = dict(whole, moe_num_primary_experts=1, first_expert=first)
+            total += ref.experts_part(u, idx, p, cut, part)
+        else:
+            m, counters = moe.held_experts_ffn(
+                u, idx, p, cut["w_gate_up"], cut["w_down"], first, tm=16)
+            assert float(counters["rows_dropped"]) == 0.0
+            total += m
+    np.testing.assert_allclose(total, want, atol=2e-5, rtol=1e-4)
+
+
+# -- dropless, whatever the routing -----------------------------------------
+
+def _dense_held(u, idx, p, w_gate_up, w_down, first):
+    f = w_down.shape[1]
+    out = jnp.zeros_like(u)
+    for e in range(w_down.shape[0]):
+        weight = jnp.where(idx == first + e, p, 0.0).sum(1)
+        gu = jnp.dot(u, w_gate_up[e], precision=HI)
+        out += weight[:, None] * jnp.dot(
+            jax.nn.relu(gu[:, :f]) * gu[:, f:], w_down[e], precision=HI)
+    return out
+
+
+def _layer_inputs(t=96, d=128, f=128, held=4, k=3):
+    key = jax.random.PRNGKey(1)
+    u = jax.random.normal(key, (t, d))
+    w_gate_up = 0.1 * jax.random.normal(jax.random.fold_in(key, 2),
+                                        (held, d, 2 * f))
+    w_down = 0.1 * jax.random.normal(jax.random.fold_in(key, 3),
+                                     (held, f, d))
+    p = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 4),
+                                         (t, k)), -1)
+    return u, w_gate_up, w_down, p
+
+
+@pytest.mark.parametrize("routing,rows,unserved", [
+    ("all_to_one_held_expert", 96 * 3, 0),   # every choice is held: 3T rows
+    ("none_held", 0, 96),
+    ("mixed", None, None)])
+def test_dropless_under_adversarial_routing(routing, rows, unserved):
+    u, w_gate_up, w_down, p = _layer_inputs()
+    t, first = u.shape[0], 4
+    if routing == "all_to_one_held_expert":
+        # the worst case for a capacity layer: one expert takes every
+        # token's first choice, the other choices are held too
+        idx = jnp.tile(jnp.array([[5, 6, 7]], jnp.int32), (t, 1))
+    elif routing == "none_held":
+        idx = jnp.tile(jnp.array([[0, 1, 2]], jnp.int32), (t, 1))
+    else:
+        idx = jax.random.randint(jax.random.PRNGKey(9), (t, 3), 0, 16,
+                                 jnp.int32)
+    m, c = moe.held_experts_ffn(u, idx, p, w_gate_up, w_down, first, tm=16)
+    np.testing.assert_allclose(
+        m, _dense_held(u, idx, p, w_gate_up, w_down, first), atol=1e-5,
+        rtol=1e-5)
+    assert float(c["rows_dropped"]) == 0.0
+    held = np.logical_and(np.asarray(idx) >= first, np.asarray(idx) < 8)
+    assert float(c["rows_held"]) == held.sum()
+    assert float(c["tokens_unserved"]) == (~held.any(1)).sum()
+    if rows is not None:
+        assert (float(c["rows_held"]), float(c["tokens_unserved"])) == (
+            rows, unserved)
+
+
+def test_held_experts_gradients_match_dense():
+    u, w_gate_up, w_down, p = _layer_inputs()
+    idx = jax.random.randint(jax.random.PRNGKey(5), (u.shape[0], 3), 0, 16,
+                             jnp.int32)
+    fast = lambda *a: jnp.sum(jnp.sin(moe.held_experts_ffn(  # noqa: E731
+        a[0], idx, a[3], a[1], a[2], 4, tm=16)[0]))
+    slow = lambda *a: jnp.sum(jnp.sin(_dense_held(  # noqa: E731
+        a[0], idx, a[3], a[1], a[2], 4)))
+    got = jax.grad(fast, (0, 1, 2, 3))(u, w_gate_up, w_down, p)
+    want = jax.grad(slow, (0, 1, 2, 3))(u, w_gate_up, w_down, p)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.abs(b).max()),
+                                   rtol=1e-4)
+
+
+@pytest.mark.parametrize("n_used", [1, 3, 6])
+def test_grouped_matmul_kernels_match_plain_products(n_used):
+    tm, k, n = 16, 256, 128
+    key = jax.random.PRNGKey(n_used)
+    x = jax.random.normal(key, (6 * tm, k))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (3, k, n))
+    tile_group = jnp.array([0, 0, 1, 2, 2, 2], jnp.int32)
+    used = jnp.array([n_used], jnp.int32)
+    args = (tile_group, used, tm)
+    got = gm.grouped_matmul(x, w, tile_group, used, tm, True)
+    want = gm.grouped_matmul_reference(x, w, *args)
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    fast = jax.grad(lambda x, w: jnp.sum(jnp.cos(gm.grouped_matmul(
+        x, w, tile_group, used, tm, True))), (0, 1))(x, w)
+    slow = jax.grad(lambda x, w: jnp.sum(jnp.cos(
+        gm.grouped_matmul_reference(x, w, *args))), (0, 1))(x, w)
+    if n_used == 6:   # every group owns a used tile: dw is whole
+        for a, b in zip(fast, slow):
+            np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
+    else:
+        np.testing.assert_allclose(fast[0], slow[0], atol=1e-3, rtol=1e-3)
+
+
+def test_row_plan_is_sorted_by_expert_in_whole_tiles():
+    idx = jnp.array([[4, 9], [5, 4], [0, 5], [4, 6]], jnp.int32)
+    plan = moe.plan_held_rows(idx, 4, 3, tm=2)
+    assert plan["counts"].tolist() == [3, 2, 1]
+    # expert 4: 2 tiles (3 rows), expert 5: 1, expert 6: 1
+    assert plan["tile_group"].tolist()[:4] == [0, 0, 1, 2]
+    assert int(plan["n_used"][0]) == 4
+    # choices are numbered choice-major: all first choices, then all
+    # second ones; `slot` is [k, T]
+    assert plan["slot"].tolist() == [[0, 4, 14, 1], [14, 2, 5, 6]]
+    assert plan["slot_token"].tolist()[:7] == [0, 3, 1, 4, 1, 2, 3]
+
+
+# -- counters: device -> trainer -> registry -> readers ---------------------
+
+def test_trainer_mirrors_routing_counters_into_the_registry(tiny):
+    import optax
+    from edl_tpu.obs import metrics
+    from edl_tpu.runtime.trainer import ElasticTrainer
+    cfg, _, fam, w, batch = tiny
+    loss_fn, has_aux, _ = fam.train_parts(cfg, {"remat": False})
+    params, extra = fam.to_program(w, cfg)
+    from edl_tpu.runtime.mesh import make_mesh
+    # one device, as the cell: the interpreted Pallas kernels are not
+    # partitioned over the test harness's eight virtual ones
+    trainer = ElasticTrainer(loss_fn, params, optax.sgd(1e-3),
+                             total_batch_size=2, extra_state=extra,
+                             has_aux=has_aux,
+                             mesh=make_mesh(devices=jax.devices()[:1]))
+    ids = batch["input_ids"]
+    try:
+        for _ in range(2):
+            trainer.train_step(trainer.place_batch({"input_ids": ids}))
+        assert "steps" not in kernel_readers.model_counters() or (
+            kernel_readers.model_counters()["steps"] != [2.0])
+    finally:
+        trainer.close()
+    got = kernel_readers.model_counters()
+    n = cfg["num_hidden_layers"]
+    assert got["steps"] == [2.0]
+    assert len(got["rows_held"]) == n and sum(got["rows_held"]) > 0
+    assert got["rows_dropped"] == [0.0] * n
+    assert kernel_readers.load_max_over_mean(got) >= 1.0
+    assert kernel_readers.expert_rows_per_step(got) == pytest.approx(
+        sum(got["rows_held"]) / n / 2.0)
+    snap = metrics.REGISTRY.snapshot()["metrics"]["edl_train_model_counter"]
+    assert snap["labelnames"] == ["name", "index"]
+
+
+def _view(ops, steps=30):
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         CONFIG + ".json"))
+    job = harness.load_json(os.path.join(REPO, "benchmark", "traffic",
+                                         "tokens-8192.json"))
+    return {"trace": {"ops": ops}, "counters": {"traced_steps": steps},
+            "config": cfg, "traffic": job,
+            "peaks": {"bf16_flops": 197e12, "hbm_bytes_s": 819e9}}
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd_resident", "moe_gmm",
+                                    "moe_tgmm"])
+def test_kernel_readers_on_a_recorded_view(kernel):
+    """A view as lib/xplane.py reduces a trace: op classes and seconds
+    over 30 traced steps. The kernel is found under its name however
+    autodiff wrapped it; the share is its least time over the measured."""
+    ops = [["fusion", 3.0], [kernel, 0.6], ["jvp_%s_" % kernel, 0.3],
+           ["while", 0.5]]
+    device_ms = harness.load_module("metrics", kernel + "_device_ms")
+    roofline = harness.load_module("metrics", kernel + "_roofline_pct")
+    view = _view(ops)
+    assert device_ms.read(view) == pytest.approx(30.0)
+    share = roofline.read(view)
+    assert 0.0 < share < 100.0
+    fam = harness.load_module("program", "sparse_decoder")
+    flops, nbytes = fam.kernel_costs(view["config"], view["traffic"], 2,
+                                     kernel_readers.expert_rows_per_step(
+                                         kernel_readers.model_counters()))[
+                                             kernel]
+    assert share == pytest.approx(
+        100.0 * max(flops / 197e12, nbytes / 819e9) / 0.030)
+    # a program without the kernel: nothing to read, nothing raised
+    empty = _view([["fusion", 3.0]])
+    assert device_ms.read(empty) is None and roofline.read(empty) is None
+
+
+def test_counter_readers_return_none_without_counters(monkeypatch):
+    monkeypatch.setattr(kernel_readers, "model_counters", lambda: {})
+    for name in ("moe_rows_dropped", "moe_expert_load_max_over_mean"):
+        mod = harness.load_module("metrics", name)
+        monkeypatch.setattr(mod, "model_counters", lambda: {})
+        assert mod.read({}) is None
+
+
+def test_train_flops_counts_the_band_and_the_expected_rows():
+    fam = harness.load_module("program", "sparse_decoder")
+    view = _view([])
+    total = fam.train_flops(view["config"], view["traffic"], 2)
+    assert total == pytest.approx(10.99e12, rel=0.01)   # ISSUE 27: 10.9
+    assert fam.band_pairs(8192) == 8192 * 8193 / 2
+    assert fam.band_pairs(8192, 4096) == 4096 * 4097 / 2 + 4096 * 4096
+    assert fam.expected_expert_rows(view["config"], 16384) == 12288
