@@ -1,31 +1,41 @@
-"""Pallas flash attention for TPU: blockwise online-softmax forward kernel
-with a memory-efficient blockwise-recompute backward.
+"""Pallas flash attention for TPU: blockwise online-softmax forward
+kernels and FlashAttention-2's backward as two more kernels.
 
-The hot op of the transformer models (edl_tpu/models/bert.py) and of the
-teacher inference servers. Never materializes the [seq, seq] score matrix:
+The hot op of the decoder models (edl_tpu/models/gpt.py,
+edl_tpu/models/sparse_decoder.py; models/bert.py runs it without a causal
+mask) on the training path. Never materializes the [seq, seq] score
+matrix, forward or backward:
 
 - forward: a Pallas kernel gridded over (batch*heads, q_blocks); each
   program streams kv blocks from VMEM with fp32 online-softmax
-  accumulation on the MXU (q/k/v blocks sized to the 128-lane tiling);
-- backward: custom_vjp that recomputes per-block attention under
-  `lax.scan` (flash-style recompute — O(seq) memory, XLA-fused), so the
-  kernel composes with jit/grad and with the ring-attention sp layer
-  (edl_tpu/parallel/ring_attention.py) which shards the sequence BEFORE
-  attention is applied per shard.
+  accumulation on the MXU (q/k/v blocks sized to the 128-lane tiling),
+  and writes, beside the result, the row statistic lse = m + log(l),
+  float32, four bytes a query row;
+- backward: a custom_vjp whose residuals are (q, k, v, out, lse). Two
+  Pallas kernels rebuild p = exp(scores - lse) tile by tile in VMEM: one
+  gridded over q blocks accumulates dq over the band's kv blocks, one
+  gridded over kv blocks accumulates dk and dv over the q blocks (of every
+  query head of the kv head) whose band reaches it. Products take their
+  operands in the inputs' dtype with float32 accumulation; scores, p and
+  ds are float32. So the op composes with jit/grad/remat and with the
+  ring-attention sp layer (edl_tpu/parallel/ring_attention.py), which
+  shards the sequence BEFORE attention is applied per shard.
 
 Layout: q, k, v are [batch, heads, seq, head_dim]. With grouped-query
 attention k and v have fewer heads and q is [batch, kv_heads, group * seq,
 head_dim] (the query heads of a kv head one after another: K/V are never
 repeated); a causal ``window`` keeps a query's own position and the
-``window - 1`` before it, and blocks outside the band are skipped, in the
-forward kernels and in the backward's scans (``mha`` takes the model's
-[batch, seq, heads, dim] layout and does the regrouping).
+``window - 1`` before it, and blocks outside the band are neither loaded
+nor computed, in the forward and in the backward kernels (``mha`` takes
+the model's [batch, seq, heads, dim] layout, does the regrouping, and
+keeps its residuals in that layout).
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -42,8 +52,24 @@ def _q_block_index(n_q_seq):
     return qi if n_q_seq is None else lax.rem(qi, n_q_seq)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                block_k, seq_len, causal, sm_scale, q_block, window=None,
+def _flip(x):
+    """A row statistic moved between the sublanes and the lanes: [n, 1] ->
+    [1, n] or back. Whole 128s go through a [128, n] transpose; any other
+    n through an identity mask and a sum. Both are exact."""
+    col = x.shape[1] == 1
+    n = x.shape[0] if col else x.shape[1]
+    if n % 128 == 0:
+        if col:
+            return jnp.broadcast_to(x, (n, 128)).T[:1]
+        return jnp.broadcast_to(x, (128, n)).T[:, :1]
+    eye = (lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, x, 0.0), axis=0 if col else 1,
+                   keepdims=True)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, block_k, seq_len, causal, sm_scale, q_block, window=None,
                 n_q_seq=None):
     """One (bh, q_block, k_block) grid step. kv blocks stream through VMEM
     via the third grid dimension (fastest-varying, revisiting the same out
@@ -97,12 +123,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[:]
-                    / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        lse_ref[0] = _flip(m_ref[:] + jnp.log(l))
 
 
-def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, *, block_k, seq_len,
-                         causal, sm_scale, q_block, window=None,
+def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
+                         seq_len, causal, sm_scale, q_block, window=None,
                          n_q_seq=None):
     """Fast path for kv that fits VMEM: fori_loop over kv blocks so causal
     masking skips the loads AND compute right of the diagonal, and a
@@ -150,7 +177,9 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, *, block_k, seq_len,
     if window is not None:
         first = lax.div(jnp.maximum(qi * q_block - window + 1, 0), block_k)
     acc, m, l = lax.fori_loop(first, last, body, (acc, m, l))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    l = jnp.maximum(l, 1e-30)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    lse_ref[0] = _flip(m + jnp.log(l))
 
 
 # kv (k + v) resident in VMEM up to this many bytes; beyond it, stream
@@ -161,8 +190,9 @@ FWD_RESIDENT_NAME = "flash_fwd_resident"
 FWD_STREAM_NAME = "flash_fwd_stream"
 
 
+@functools.partial(jax.jit, static_argnums=tuple(range(3, 11)))
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               window=None, group=1):
+               window=None, group=1, resident_bytes=_RESIDENT_KV_BYTES):
     """q is [b, h, group * s, d]: the ``group`` query heads that share kv
     head h, one run of the sequence after another; k, v are [b, h, s, d]
     and are never repeated in memory."""
@@ -188,9 +218,13 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                          "query's own: it needs causal=True")
     band = dict(window=window, n_q_seq=n_q_seq)
 
+    # the row statistic lse = m + log(l), float32, rows along the lanes:
+    # 4 bytes a row, the residual the backward kernels rebuild p from
+    out_shape = (jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+                 jax.ShapeDtypeStruct((bh, 1, rows), jnp.float32))
     kv_bytes = 2 * sk * d * k.dtype.itemsize
-    if kv_bytes <= _RESIDENT_KV_BYTES and sk % block_k == 0:
-        out = pl.pallas_call(
+    if kv_bytes <= resident_bytes and sk % block_k == 0:
+        out, lse = pl.pallas_call(
             functools.partial(_fwd_kernel_resident, block_k=block_k,
                               seq_len=sk, causal=causal, sm_scale=sm_scale,
                               q_block=block_q, **band),
@@ -200,15 +234,16 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                 pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
                 pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, d),
-                                   lambda i, j: (i, j, 0)),
-            out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+            out_specs=(pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+                       pl.BlockSpec((1, 1, block_q),
+                                    lambda i, j: (i, 0, j))),
+            out_shape=out_shape,
             interpret=interpret,
             name=FWD_RESIDENT_NAME,
         )(qf, kf, vf)
-        return out.reshape(b, h, rows, d)
+        return out.reshape(b, h, rows, d), lse
 
-    out = pl.pallas_call(
+    out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, seq_len=sk,
                           causal=causal, sm_scale=sm_scale,
                           q_block=block_q, **band),
@@ -218,8 +253,11 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
             pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+        out_specs=(pl.BlockSpec((1, block_q, d),
+                                lambda i, j, kb: (i, j, 0)),
+                   pl.BlockSpec((1, 1, block_q),
+                                lambda i, j, kb: (i, 0, j))),
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -228,14 +266,13 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         interpret=interpret,
         name=FWD_STREAM_NAME,
     )(qf, kf, vf)
-    return out.reshape(b, h, rows, d)
+    return out.reshape(b, h, rows, d), lse
 
 
 def _block_layout(k, v, block_k):
     """Pad kv to a whole number of blocks and reshape for scanning:
-    (kb, vb) are [n_blocks, b, h, block_k, d] f32. ONE copy of the
-    layout shared by the blockwise forward and the recompute backward
-    so the two can never disagree on padding."""
+    (kb, vb) are [n_blocks, b, h, block_k, d] f32 (the blockwise
+    reference's layout)."""
     b, h, sk, d = k.shape
     n_blocks = (sk + block_k - 1) // block_k
     pad = n_blocks * block_k - sk
@@ -248,14 +285,12 @@ def _block_layout(k, v, block_k):
     return kb, vb, n_blocks
 
 
-def _block_mask(ki, block_k, s, sk, causal, window=None, q_pos=None):
-    """[rows, block_k] validity mask for kv block ``ki``: ragged tail rows
+def _block_mask(ki, block_k, s, sk, causal, window=None):
+    """[s, block_k] validity mask for kv block ``ki``: ragged tail rows
     beyond sk are invalid; under causal q may not attend ahead, and with
     a window not further back than its own position and the ``window - 1``
-    before it. ``q_pos`` gives the rows' positions where they are not
-    0..s-1 (a slice of the sequence, query heads stacked). The one copy
-    of the mask convention for forward AND backward."""
-    q_pos = (jnp.arange(s) if q_pos is None else q_pos)[:, None]
+    before it. The mask convention of the kernels, in plain jnp."""
+    q_pos = jnp.arange(s)[:, None]
     k_pos = ki * block_k + jnp.arange(block_k)[None, :]
     mask = k_pos < sk
     if causal:
@@ -297,8 +332,421 @@ def _blockwise_reference(q, k, v, causal, sm_scale, block_k=512,
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+#: the backward kernels' names in a device trace: they hold `flash_bwd`
+#: and not the forward's names, so each reader sums its own kernels
+BWD_NAME = "flash_bwd"
+BWD_DQ_NAME = "flash_bwd_dq"
+BWD_DKV_NAME = "flash_bwd_dkv"
+
+# the backward's tiles: [block, block] float32 scores, p, dp and ds live in
+# VMEM only. While k and v fit VMEM whole (_RESIDENT_KV_BYTES, as in the
+# forward) one kernel computes each tile once and accumulates dk and dv
+# there too; beyond that two kernels stream the other side through the grid
+_BWD_BLOCK = 512
+_BWD_VMEM_LIMIT = 64 << 20
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    """Operands as they arrive (bfloat16 in training, float32 in the tight
+    tests), float32 accumulation."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _kv_band(xp, q_lo, block_q, block_k, n_k, causal, window, ragged):
+    """For the q block that starts at position ``q_lo``: the kv blocks its
+    band reaches are [first, last); those wholly inside the band, which
+    need no mask, are [ufirst, ulast). ``xp`` is jnp inside a kernel or an
+    index map and numpy where the wrapper sizes the grid."""
+    q_hi = q_lo + block_q - 1
+    first = ufirst = 0 * q_lo
+    last = ulast = n_k + 0 * q_lo
+    if ragged:                      # the zero-padded tail of k and v
+        ulast = ulast - 1
+    if causal:
+        last = xp.minimum(q_hi // block_k + 1, n_k)
+        ulast = (q_lo + 1) // block_k
+    if window is not None:
+        first = xp.maximum(q_lo - window + 1, 0) // block_k
+        ufirst = (xp.maximum(q_hi - window + 1, 0) + block_k - 1) // block_k
+    ufirst = xp.clip(ufirst, first, last)
+    return first, ufirst, xp.clip(ulast, ufirst, last), last
+
+
+def _q_band(xp, k_lo, block_q, block_k, n_q, n_k, causal, window, ragged):
+    """The same for the kv block that starts at ``k_lo``: the q blocks (of
+    one query head's run of the sequence) whose band reaches it."""
+    k_hi = k_lo + block_k - 1
+    first = ufirst = 0 * k_lo
+    last = ulast = n_q + 0 * k_lo
+    if causal:
+        first = k_lo // block_q
+        ufirst = (k_hi + block_q - 1) // block_q
+    if window is not None:
+        last = xp.minimum((k_hi + window - 1) // block_q + 1, n_q)
+        ulast = (k_lo + window) // block_q
+    if ragged:                      # every tile of the padded tail is masked
+        ulast = xp.where(k_lo == (n_k - 1) * block_k, 0, ulast)
+    ufirst = xp.clip(ufirst, first, last)
+    return first, ufirst, xp.clip(ulast, ufirst, last), last
+
+
+def _p_and_ds(a, b, da, db, lse, delta, q_pos, k_pos, *, sm_scale, masked,
+              causal, window, kv_len):
+    """One tile of the recomputed softmax and of its gradient, float32:
+    p = exp(scale * a.b^T - lse), ds = p * (da.db^T - delta). (a, b) is
+    (q, k) with lse, delta and q_pos columns, or (k, q) with them rows: the
+    tile is then the transpose, with no transposing done. The scale
+    goes to the float32 scores, so q is not rounded again. ``masked`` is
+    static: only a tile that straddles the diagonal, the band's far edge
+    or the padded tail builds a mask."""
+    p = jnp.exp(_dot(a, b, _NT) * sm_scale - lse)
+    if masked:
+        keep = None
+        if causal:
+            keep = q_pos >= k_pos
+            if window is not None:
+                keep = jnp.logical_and(keep, q_pos - k_pos < window)
+        if kv_len is not None:
+            tail = k_pos < kv_len
+            keep = tail if keep is None else jnp.logical_and(keep, tail)
+        if keep is not None:        # else a loop of no trips, traced
+            p = jnp.where(keep, p, 0.0)
+    return p, p * (_dot(da, db, _NT) - delta)
+
+
+def _edge_and_inner(idx, first, ufirst, ulast, last):
+    """(tile is visited and needs a mask, tile is visited and needs none)"""
+    inner = jnp.logical_and(idx >= ufirst, idx < ulast)
+    return (jnp.logical_and(idx < last, jnp.logical_not(inner)),
+            jnp.logical_and(idx < last, inner))
+
+
+def _bwd_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
+                         block_k, n_q_seq, n_k, band, tile):
+    """k and v whole in VMEM: one program a q block loops over the band's
+    kv blocks only, computes each tile once (five products), sums its dq in
+    registers and adds its share of dk and dv to float32 accumulators that
+    stay in VMEM over all q blocks, of every query head, of the kv head."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q_lo = _q_block_index(n_q_seq) * block_q
+    q, do = q_ref[0], do_ref[0]
+    lse, delta = _flip(lse_ref[0]), _flip(delta_ref[0])       # [TQ, 1]
+    q_pos = q_lo + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+
+    def tiles(masked):
+        def body(ki, dq):
+            k_lo = pl.multiple_of(ki * block_k, block_k)
+            k = k_ref[0, pl.ds(k_lo, block_k), :]
+            v = v_ref[0, pl.ds(k_lo, block_k), :]
+            k_pos = k_lo + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+            p, ds = _p_and_ds(q, k, do, v, lse, delta, q_pos, k_pos,
+                              masked=masked, **tile)
+            ds = ds.astype(k.dtype)
+            dk_acc[pl.ds(k_lo, block_k), :] += _dot(ds, q, _TN)
+            dv_acc[pl.ds(k_lo, block_k), :] += _dot(p.astype(do.dtype), do,
+                                                    _TN)
+            return dq + _dot(ds, k, _NN)
+        return body
+
+    first, ufirst, ulast, last = _kv_band(jnp, q_lo, block_q, block_k, n_k,
+                                          **band)
+    dq = jnp.zeros(q.shape, jnp.float32)
+    dq = lax.fori_loop(first, ufirst, tiles(True), dq)
+    dq = lax.fori_loop(ufirst, ulast, tiles(False), dq)
+    dq = lax.fori_loop(ulast, last, tiles(True), dq)
+    dq_ref[0] = (dq * tile["sm_scale"]).astype(dq_ref.dtype)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * tile["sm_scale"]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   acc_ref, *, block_q, block_k, n_q_seq, n_k, band, tile):
+    """dq of one q block with kv streamed: grid step t brings the t-th kv
+    block of its band (the index map clamps, so steps past the band load
+    nothing new and compute nothing)."""
+    t = pl.program_id(2)
+    q_lo = _q_block_index(n_q_seq) * block_q
+    first, ufirst, ulast, last = _kv_band(jnp, q_lo, block_q, block_k, n_k,
+                                          **band)
+    ki = first + t
+
+    @pl.when(t == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def compute(masked):
+        q_pos = q_lo + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
+                                                    (1, block_k), 1)
+        k = k_ref[0]
+        _, ds = _p_and_ds(q_ref[0], k, do_ref[0], v_ref[0],
+                          _flip(lse_ref[0]), _flip(delta_ref[0]), q_pos,
+                          k_pos, masked=masked, **tile)
+        acc_ref[:] += _dot(ds.astype(k.dtype), k, _NN)
+
+    edge, inner = _edge_and_inner(ki, first, ufirst, ulast, last)
+    pl.when(edge)(functools.partial(compute, True))
+    pl.when(inner)(functools.partial(compute, False))
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = (acc_ref[:] * tile["sm_scale"]).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, block_q, block_k, n_q_seq,
+                    n_k, n_steps, band, tile):
+    """dk and dv of one kv block with q, dO, lse and delta streamed: grid
+    step t brings, for query head t // n_steps, the (t % n_steps)-th q
+    block whose band reaches it. Tiles are [TK, TQ]: lse and delta are
+    read as they are stored, rows along the lanes."""
+    t = pl.program_id(2)
+    k_lo = pl.program_id(1) * block_k
+    first, ufirst, ulast, last = _q_band(jnp, k_lo, block_q, block_k,
+                                         n_q_seq, n_k, **band)
+    qi = first + lax.rem(t, n_steps)
+
+    @pl.when(t == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def compute(masked):
+        k_pos = k_lo + lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32,
+                                                    (1, block_q), 1)
+        q, do = q_ref[0], do_ref[0]
+        p, ds = _p_and_ds(k_ref[0], q, v_ref[0], do, lse_ref[0],
+                          delta_ref[0], q_pos, k_pos, masked=masked, **tile)
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, _NN)
+        dv_acc[:] += _dot(p.astype(do.dtype), do, _NN)
+
+    edge, inner = _edge_and_inner(qi, first, ufirst, ulast, last)
+    pl.when(edge)(functools.partial(compute, True))
+    pl.when(inner)(functools.partial(compute, False))
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * tile["sm_scale"]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_block(s, widest):
+    """The backward's tile edge for a sequence of ``s``: ``widest`` (512),
+    its half or its quarter, the first that divides it (wide tiles
+    amortise the loop and the grid step; the band's edge wastes at most a
+    tile's width), else one tile for a short sequence, else ``widest`` and
+    a zero-padded tail."""
+    for block in (widest, widest // 2, widest // 4):
+        if s % block == 0:
+            return block
+    return min(widest, s)
+
+
+def _pad_runs(x, axis, group, s, to):
+    """Zero-pad each of the ``group`` runs of length ``s`` along ``axis``
+    to length ``to`` (a whole number of blocks)."""
+    if to == s:
+        return x
+    shape = x.shape
+    x = x.reshape(shape[:axis] + (group, s) + shape[axis + 1:])
+    pad = [(0, 0)] * x.ndim
+    pad[axis + 1] = (0, to - s)
+    return jnp.pad(x, pad).reshape(shape[:axis] + (group * to,)
+                                   + shape[axis + 1:])
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 13)))
+def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
+               window=None, group=1, block=_BWD_BLOCK,
+               resident_bytes=_RESIDENT_KV_BYTES):
+    """FlashAttention-2's backward on the chip, visiting only the tiles of
+    the causal band. p is rebuilt from the forward's ``lse`` [bh, 1, rows],
+    ds from it and ``delta`` [b, h, rows] = rowsum(dO * out); score tiles
+    never leave VMEM; every product takes its operands in the inputs' dtype
+    and accumulates in float32. Rows are the ``group`` query heads of a kv
+    head, each a run of the sequence: dk and dv sum over all of them, K and
+    V are never repeated. While k and v fit VMEM whole, one kernel; beyond
+    that, one gridded over q blocks for dq and one over kv blocks for dk
+    and dv, the other side streamed. A ragged sequence is zero-padded to
+    whole tiles here (padded rows carry dO = 0 and add nothing). Under jit
+    with ``block`` and ``resident_bytes`` static (the caller passes the
+    module's), so the layers of a model share one trace and one lowering."""
+    b, h, rows, d = q.shape
+    s, sk, bh = rows // group, k.shape[2], b * h
+    block_q, block_k = _bwd_block(s, block), _bwd_block(sk, block)
+    s_pad = pl.cdiv(s, block_q) * block_q
+    sk_pad = pl.cdiv(sk, block_k) * block_k
+    n_q_seq, n_k = s_pad // block_q, sk_pad // block_k
+    n_q = group * n_q_seq
+    # without a causal mask the padded keys need one of their own
+    ragged = not causal and sk_pad != sk
+
+    qf = _pad_runs(q.reshape(bh, rows, d), 1, group, s, s_pad)
+    dof = _pad_runs(g.astype(q.dtype).reshape(bh, rows, d), 1, group, s,
+                    s_pad)
+    lse = _pad_runs(lse, 2, group, s, s_pad)
+    delta = _pad_runs(delta.reshape(bh, 1, rows), 2, group, s, s_pad)
+    kf = _pad_runs(k.reshape(bh, sk, d), 1, 1, sk, sk_pad)
+    vf = _pad_runs(v.reshape(bh, sk, d), 1, 1, sk, sk_pad)
+
+    band = dict(causal=causal, window=window, ragged=ragged)
+    tile = dict(sm_scale=sm_scale, causal=causal, window=window,
+                kv_len=sk if ragged else None)
+    shape = dict(block_q=block_q, block_k=block_k, n_q_seq=n_q_seq, n_k=n_k,
+                 band=band, tile=tile)
+    call = functools.partial(pl.pallas_call, interpret=interpret)
+    params = lambda *semantics: pltpu.CompilerParams(  # noqa: E731
+        dimension_semantics=semantics, vmem_limit_bytes=_BWD_VMEM_LIMIT)
+    q_block = pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0))
+    q_stats = pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j))
+    kv_block = pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0))
+    dq_shape = jax.ShapeDtypeStruct(qf.shape, q.dtype)
+    dkv_shape = (jax.ShapeDtypeStruct(kf.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vf.shape, v.dtype))
+
+    if 2 * sk_pad * d * k.dtype.itemsize <= resident_bytes:
+        kv_whole = pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0))
+        dq, dk, dv = call(
+            functools.partial(_bwd_kernel_resident, **shape),
+            grid=(bh, n_q),
+            in_specs=[q_block, kv_whole, kv_whole, q_block, q_stats,
+                      q_stats],
+            out_specs=(q_block, kv_whole, kv_whole),
+            out_shape=(dq_shape,) + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((sk_pad, d), jnp.float32),
+                            pltpu.VMEM((sk_pad, d), jnp.float32)],
+            compiler_params=params("parallel", "arbitrary"),
+            name=BWD_NAME)(qf, kf, vf, dof, lse, delta)
+    else:
+        # grid steps a q block needs: the widest band, in kv blocks
+        first, _, _, last = _kv_band(np, np.arange(n_q_seq) * block_q,
+                                     block_q, block_k, n_k, **band)
+
+        def kv_of(i, j, t):
+            first, _, _, last = _kv_band(
+                jnp, lax.rem(j, n_q_seq) * block_q, block_q, block_k, n_k,
+                **band)
+            return i, jnp.minimum(first + t, last - 1), 0
+
+        kv_step = pl.BlockSpec((1, block_k, d), kv_of)
+        dq = call(
+            functools.partial(_bwd_dq_kernel, **shape),
+            grid=(bh, n_q, int(np.max(last - first))),
+            in_specs=[q_block, kv_step, kv_step, q_block, q_stats, q_stats],
+            out_specs=q_block, out_shape=dq_shape,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=params("parallel", "parallel", "arbitrary"),
+            name=BWD_DQ_NAME)(qf, kf, vf, dof, lse, delta)
+
+        # ... and a kv block, in q blocks, for each query head in turn
+        first, _, _, last = _q_band(np, np.arange(n_k) * block_k, block_q,
+                                    block_k, n_q_seq, n_k, **band)
+        n_steps = int(np.max(last - first))
+
+        def q_of(j, t):
+            first, _, _, last = _q_band(jnp, j * block_k, block_q, block_k,
+                                        n_q_seq, n_k, **band)
+            return (lax.div(t, n_steps) * n_q_seq
+                    + jnp.minimum(first + lax.rem(t, n_steps), last - 1))
+
+        q_step = pl.BlockSpec((1, block_q, d),
+                              lambda i, j, t: (i, q_of(j, t), 0))
+        stats_step = pl.BlockSpec((1, 1, block_q),
+                                  lambda i, j, t: (i, 0, q_of(j, t)))
+        dk, dv = call(
+            functools.partial(_bwd_dkv_kernel, n_steps=n_steps, **shape),
+            grid=(bh, n_k, group * n_steps),
+            in_specs=[kv_block, kv_block, q_step, q_step, stats_step,
+                      stats_step],
+            out_specs=(kv_block, kv_block), out_shape=dkv_shape,
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            compiler_params=params("parallel", "parallel", "arbitrary"),
+            name=BWD_DKV_NAME)(kf, vf, qf, dof, lse, delta)
+
+    if s_pad != s:
+        dq = dq.reshape(bh, group, s_pad, d)[:, :, :s]
+    return (dq.reshape(b, h, rows, d), dk[:, :sk].reshape(b, h, sk, d),
+            dv[:, :sk].reshape(b, h, sk, d))
+
+
+def _kernel_layout(x, group=1):
+    """The models' [batch, seq, heads, dim] -> the kernels' [batch,
+    heads // group, group * seq, dim]: the ``group`` query heads of a kv
+    head one run of the sequence after another."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, h // group, group * s, d)
+
+
+def _model_layout(x, group=1):
+    b, h, rows, d = x.shape
+    return x.reshape(b, h * group, rows // group, d).transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
+def _attend(q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
+            group, seq_major):
+    return _attend_fwd(q, k, v, causal, sm_scale, block_q, block_k,
+                       interpret, window, group, seq_major)[0]
+
+
+def _attend_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                window, group, seq_major):
+    """With ``seq_major`` the arguments, the result and so the residuals
+    are in the models' layout, which the projections around the attention
+    hold anyway: the kernels' [batch, heads, seq, dim] copies live for the
+    length of a kernel and are remade in the backward, not kept from the
+    forward (three arrays the size of q a layer, otherwise)."""
+    qt, kt, vt = q, k, v
+    if seq_major:
+        qt, kt, vt = _kernel_layout(q, group), _kernel_layout(k), \
+            _kernel_layout(v)
+    out, lse = _flash_fwd(qt, kt, vt, causal, sm_scale, block_q, block_k,
+                          interpret, window, group, _RESIDENT_KV_BYTES)
+    if seq_major:
+        out = _model_layout(out, group)
+    return out, (q, k, v, out, lse)
+
+
+def _attend_bwd(causal, sm_scale, block_q, block_k, interpret, window, group,
+                seq_major, res, g):
+    q, k, v, out, lse = res
+    # delta_i = sum_d dO_i * out_i, the softmax jacobian's row term
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    if seq_major:
+        # the barrier keeps XLA from merging these transposes with the
+        # forward's, which would keep the forward's copies alive instead
+        q, k, v = lax.optimization_barrier((q, k, v))
+        q, g = _kernel_layout(q, group), _kernel_layout(g, group)
+        k, v = _kernel_layout(k), _kernel_layout(v)
+        delta = _kernel_layout(delta[..., None], group)[..., 0]
+    dq, dk, dv = _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale,
+                            interpret, window, group, _BWD_BLOCK,
+                            _RESIDENT_KV_BYTES)
+    if seq_major:
+        dq, dk, dv = _model_layout(dq, group), _model_layout(dk), \
+            _model_layout(dv)
+    return dq, dk, dv
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
                     block_k=128, interpret=False, window=None, group=1):
     """Blockwise exact attention; k/v are [batch, kv_heads, seq, dim] and
@@ -308,161 +756,22 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     for each query, its own position and the ``window - 1`` before it."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                      interpret, window, group)
+    return _attend(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window, group, False)
 
 
-def _flash_bwd(q, k, v, out, g, causal, sm_scale, block_k=512,
-               window=None, group=1):
-    """The FA2-style memory-efficient backward: recompute per-block
-    attention from saved (out) plus a cheap O(seq)-carry statistics
-    pass, then accumulate dq and emit per-block dk/dv under lax.scan.
-    Live memory is O(seq*(dim + block_k)) — LINEAR in sequence length.
-    (The previous implementation took jax.vjp of the blockwise forward,
-    whose scan residuals stash every block's scores: O(seq^2) — the
-    static account showed its temp memory EXCEEDING dense attention at
-    8k, PERF_ACCOUNTING.json r5.)
-
-    With a ``window`` both scans visit, for each kv block, only the
-    ``span`` query positions whose band can reach it (a slice of the
-    sequence that starts at the block), not the whole sequence; without
-    one the slice is the sequence and nothing is cut. Rows are the
-    ``group`` query heads of a kv head, each a run of the sequence."""
-    b, h, rows, d = q.shape
-    s = rows // group
-    sk = k.shape[2]
-    span = s
-    if window is not None and block_k + window < s:
-        span = block_k + window
-    q32 = q.astype(jnp.float32) * sm_scale
-    g32 = g.astype(jnp.float32)
-    kb, vb, n_blocks = _block_layout(k, v, block_k)
-
-    def start_of(ki):
-        # first query position a kv block's scans read; the last blocks'
-        # slices are pushed back so that they end with the sequence
-        return jnp.minimum(ki * block_k, s - span)
-
-    def cut(x, ki):
-        """x [b, h, group * s, ...] -> its rows at positions
-        [start, start + span) of every query head."""
-        if span == s:
-            return x
-        x = x.reshape((b, h, group, s) + x.shape[3:])
-        x = lax.dynamic_slice_in_dim(x, start_of(ki), span, axis=3)
-        return x.reshape((b, h, group * span) + x.shape[4:])
-
-    def put(x, part, ki, combine):
-        """Write ``combine(old rows, part)`` back where ``cut`` read."""
-        if span == s:
-            return combine(x, part)
-        shape = x.shape
-        x = x.reshape((b, h, group, s) + shape[3:])
-        part = part.reshape((b, h, group, span) + shape[3:])
-        old = lax.dynamic_slice_in_dim(x, start_of(ki), span, axis=3)
-        x = lax.dynamic_update_slice_in_dim(x, combine(old, part),
-                                            start_of(ki), axis=3)
-        return x.reshape(shape)
-
-    def mask_of(ki):
-        q_pos = None
-        if span != s or group > 1:
-            q_pos = jnp.tile((0 if span == s else start_of(ki))
-                             + jnp.arange(span), group)
-        return _block_mask(ki, block_k, s, sk, causal, window, q_pos)
-
-    # pass 1: row statistics (m, l) only — O(seq) carry, no O(s^2) stash
-    def stats_body(carry, blk):
-        m_all, l_all = carry
-        k_blk, ki = blk
-        m, l = cut(m_all, ki), cut(l_all, ki)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", cut(q32, ki), k_blk)
-        mask = mask_of(ki)
-        scores = jnp.where(mask[None, None], scores, _NEG_INF)
-        m_new = jnp.maximum(m, scores.max(-1))
-        l = l * jnp.exp(m - m_new) + jnp.where(
-            mask[None, None],
-            jnp.exp(scores - m_new[..., None]), 0.0).sum(-1)
-        keep = lambda old, new: new
-        return (put(m_all, m_new, ki, keep), put(l_all, l, ki, keep)), None
-
-    m0 = jnp.full((b, h, rows), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, h, rows), jnp.float32)
-    (m, l), _ = lax.scan(stats_body, (m0, l0),
-                         (kb, jnp.arange(n_blocks)))
-    l = jnp.maximum(l, 1e-30)
-    # delta_i = sum_d g_i * out_i  (the softmax-jacobian row term)
-    delta = jnp.sum(g32 * out.astype(jnp.float32), axis=-1)  # [b,h,rows]
-
-    # pass 2: dq accumulates in the carry; dk/dv emit per block (the
-    # stacked outputs reassemble to full dk/dv — O(seq*dim) total)
-    def grad_body(dq, blk):
-        k_blk, v_blk, ki = blk
-        mask = mask_of(ki)
-        q_c, g_c = cut(q32, ki), cut(g32, ki)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q_c, k_blk)
-        scores = jnp.where(mask[None, None], scores, _NEG_INF)
-        p = jnp.exp(scores - cut(m, ki)[..., None]) / cut(l, ki)[..., None]
-        p = jnp.where(mask[None, None], p, 0.0)
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, g_c)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", g_c, v_blk)
-        ds = p * (dp - cut(delta, ki)[..., None])
-        dq = put(dq, sm_scale * jnp.einsum("bhqk,bhkd->bhqd", ds, k_blk),
-                 ki, jnp.add)
-        # q32 already carries one sm_scale factor, which is exactly
-        # dk_j = sm_scale * sum_i ds_ij q_i
-        dk_blk = jnp.einsum("bhqk,bhqd->bhkd", ds, q_c)
-        return dq, (dk_blk, dv_blk)
-
-    dq0 = jnp.zeros((b, h, rows, d), jnp.float32)
-    dq, (dk_blocks, dv_blocks) = lax.scan(
-        grad_body, dq0, (kb, vb, jnp.arange(n_blocks)))
-    dk = dk_blocks.transpose(1, 2, 0, 3, 4).reshape(b, h,
-                                                    n_blocks * block_k, d)
-    dv = dv_blocks.transpose(1, 2, 0, 3, 4).reshape(b, h,
-                                                    n_blocks * block_k, d)
-    return (dq.astype(q.dtype), dk[:, :, :sk].astype(k.dtype),
-            dv[:, :, :sk].astype(v.dtype))
-
-
-def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-             window, group):
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    out = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                     window, group)
-    return out, (q, k, v, out)
-
-
-def _vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window, group,
-             res, g):
-    q, k, v, out = res
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    return _flash_bwd(q, k, v, out, g, causal, sm_scale, window=window,
-                      group=group)
-
-
-flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
-
-
-def mha(q, k, v, causal=False, sm_scale=None, window=None, **kw):
-    """Convenience wrapper for [batch, seq, heads, dim] layouts (the model
-    code's layout): transposes in/out around flash_attention. k and v may
-    have fewer heads than q (grouped-query attention: query head i reads
-    kv head i // group); they are not repeated in memory."""
-    b, s, hq, d = q.shape
-    hkv = k.shape[2]
+def mha(q, k, v, causal=False, sm_scale=None, window=None, block_q=128,
+        block_k=128, interpret=False):
+    """The same for [batch, seq, heads, dim] arrays (the model code's
+    layout). k and v may have fewer heads than q (grouped-query attention:
+    query head i reads kv head i // group); they are not repeated in
+    memory."""
+    hq, hkv = q.shape[2], k.shape[2]
     group = hq // hkv
     if group * hkv != hq:
         raise ValueError("%d query heads do not divide over %d kv heads"
                          % (hq, hkv))
-    qt = q.transpose(0, 2, 1, 3)
-    if group > 1:
-        qt = qt.reshape(b, hkv, group * s, d)
-    out = flash_attention(qt, k.transpose(0, 2, 1, 3),
-                          v.transpose(0, 2, 1, 3), causal, sm_scale,
-                          window=window, group=group, **kw)
-    if group > 1:
-        out = out.reshape(b, hq, s, d)
-    return out.transpose(0, 2, 1, 3)
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    return _attend(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window, group, True)

@@ -273,8 +273,11 @@ def plan_held_rows(idx, first_expert, experts_held, tm=ROW_TILE):
     slot_choice = jnp.full((rows,), t * k, jnp.int32).at[slot].set(
         choice, mode="drop")
     slot_token = jnp.where(slot_choice < t * k, slot_choice % t, t)
+    # a tile's expert: how many held experts end at or before its first
+    # row (compared against all of them: no loop on the device)
     tile_group = jnp.minimum(
-        jnp.searchsorted(ends, jnp.arange(rows // tm) * tm, side="right"),
+        jnp.searchsorted(ends, jnp.arange(rows // tm) * tm, side="right",
+                         method="compare_all"),
         experts_held - 1).astype(jnp.int32)
     return {"slot": slot.reshape(k, t).astype(jnp.int32),
             "slot_token": slot_token.astype(jnp.int32),

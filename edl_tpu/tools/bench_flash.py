@@ -57,7 +57,8 @@ def bench_one(impl, batch, heads, seq, dim, causal, iters, warmup,
 
     if grad:
         # the TRAINING path: fwd + the attention backward (for flash,
-        # the FA2-style _flash_bwd via the custom vjp)
+        # the custom vjp's two Pallas kernels, flash_bwd_dq and
+        # flash_bwd_dkv)
         fn = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
             fwd(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2)))
     else:
@@ -76,7 +77,9 @@ def bench_one(impl, batch, heads, seq, dim, causal, iters, warmup,
     # 4*b*h*s^2*d multiply-adds fwd (qk + av), causal halves it. The
     # backward: dense keeps the probs as residuals (no recompute) —
     # ~2x fwd of grad matmuls, 3x total; flash recomputes per block —
-    # ~2.5x fwd, 3.5x total.
+    # ~2.5x fwd, 3.5x total (the model's count: the two backward kernels
+    # each rebuild the scores, seven products in all where five are
+    # required).
     ms /= inner  # per-application, comparable across --inner settings
     flops = 4.0 * batch * heads * seq * seq * dim * (0.5 if causal
                                                      else 1.0)

@@ -315,9 +315,9 @@ def attention_account(devices, seq, impl, batch=1, heads=12, dim=64,
     """Forward(+backward) attention at GPT-2s head shape. ``impl``:
     dense (materializes the s x s scores), flash (the Pallas kernel —
     Mosaic compiles it AOT like any other op; ``interpret=True`` for
-    CPU, where the custom-vjp backward still exercises the real
-    O(seq)-memory _flash_bwd), block (the lax.scan blockwise
-    reference, the kernel's semantic twin)."""
+    CPU, where the custom-vjp backward runs the same two Pallas
+    kernels of _flash_bwd in the interpreter), block (the lax.scan
+    blockwise reference, the kernel's semantic twin)."""
     from edl_tpu.ops.attention import attention_context
     from edl_tpu.ops.flash_attention import _blockwise_reference, mha
 

@@ -3,7 +3,8 @@ Pallas flash kernel (interpreter) and a naive repeat-the-heads softmax
 agree, forward and gradients, for a window shorter than, equal to and
 longer than the sequence, with and without rotary positions, on both
 forward kernels (kv resident in VMEM, kv streamed) and on the backward's
-sliced scans (window + block < sequence)."""
+Pallas kernels (one while k and v stay in VMEM, one for dq and one for
+dk/dv beyond; tiles inside the band, on its edges and outside it)."""
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +51,8 @@ CASES = [
     (256, 6, 2, 256),     # window = seq
     (256, 4, 4, 300),     # window > seq, equal head counts
     (256, 4, 2, None),    # grouped heads, whole causal prefix
-    (1280, 2, 1, 300),    # the backward's scans cut to a slice of the
-    (1024, 2, 1, 200),    # sequence (window + 512 < seq)
+    (1280, 2, 1, 300),    # a sequence of several blocks: tiles wholly
+    (1024, 2, 1, 200),    # inside the band, on both edges, and skipped
 ]
 
 
@@ -92,18 +93,124 @@ def test_blockwise_reference_takes_a_window():
                                _naive(q, k, v, 50), atol=2e-5, rtol=2e-5)
 
 
-def test_backward_without_window_is_the_unsliced_scan():
-    """`window=None`, one query head per kv head: the backward reads the
-    whole sequence for every kv block, as before windows existed — no
-    dynamic slice in its jaxpr; with a short window there is."""
-    q, k, v, t = [x.transpose(0, 2, 1, 3) for x in _inputs(1024, 2, 2, 0)]
+def _kernel_variant(monkeypatch, variant, block=128):
+    """128-wide backward tiles, so that these sequences have several; and
+    the operand a kernel revisits held whole in VMEM or streamed through
+    the grid (the same switch moves the forward between its kernels)."""
+    monkeypatch.setattr(fa, "_BWD_BLOCK", block)
+    if variant == "streamed":
+        monkeypatch.setattr(fa, "_RESIDENT_KV_BYTES", 0)
 
-    def bwd(window):
-        return str(jax.make_jaxpr(lambda q, k, v, t: fa._flash_bwd(
-            q, k, v, t, t, True, 0.2, window=window))(q, k, v, t))
 
-    assert "dynamic_slice" not in bwd(None)
-    assert "dynamic_slice" in bwd(128)
+@pytest.mark.parametrize("s,hq,hkv,window", CASES)
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("variant", ["resident", "streamed"])
+def test_flash_gradient_matches_dense(monkeypatch, variant, dtype, tol, s,
+                                      hq, hkv, window):
+    """The Pallas backward against the dense path's gradient: float32
+    inputs element by element at the forward's tolerance; bfloat16 inputs
+    (products in bfloat16, float32 accumulation, p and ds rounded where
+    they enter a product) against the float32 gradient at the same
+    values, in relative L2 norm."""
+    _kernel_variant(monkeypatch, variant)
+    q, k, v, t = [x.astype(dtype) for x in _inputs(s, hq, hkv, 1)]
+
+    def grads(use_flash, q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(attention_context(
+            q, k, v, causal=True, mask=None, dtype=q.dtype,
+            use_flash=use_flash, window=window).astype(jnp.float32)
+            * t.astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+    got = grads(True, q, k, v)
+    want = grads(False, *[x.astype(jnp.float32) for x in (q, k, v)])
+    for a, b in zip(got, want):
+        assert a.dtype == q.dtype
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, atol=tol, rtol=tol)
+        else:
+            a = np.asarray(a, np.float32)
+            assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("variant", ["resident", "streamed"])
+def test_flash_gradient_pads_a_ragged_sequence(monkeypatch, variant, causal):
+    """160 positions and backward tiles of 96, 48 or 24: none divides, so
+    the wrapper pads q, dO, k and v to 192; without a causal mask the
+    padded keys are masked in the kernel. (The forward runs in tiles of
+    32, which divide: it takes no ragged sequence beyond one tile.)"""
+    _kernel_variant(monkeypatch, variant, block=96)
+    q, k, v, t = _inputs(160, 4, 4, 0)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * t),
+                        (0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: fa.mha(q, k, v, causal=causal, block_q=32,
+                                       block_k=32, interpret=True))
+    want = grads(lambda q, k, v: attention_context(
+        q, k, v, causal=causal, mask=None, dtype=jnp.float32,
+        use_flash=False))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("s,hq,hkv,window", [(256, 7, 1, 64),
+                                             (384, 2, 2, None)])
+@pytest.mark.parametrize("variant", ["resident", "streamed"])
+def test_forward_kernels_write_the_row_statistic(monkeypatch, variant, s, hq,
+                                                 hkv, window):
+    """lse = m + log(l) of every query row, from both forward kernels,
+    against logsumexp of the dense scores; [batch * kv heads, 1, rows]:
+    four bytes a row, the rows of a kv head's query heads in turn."""
+    _kernel_variant(monkeypatch, variant)
+    q, k, v, _ = _inputs(s, hq, hkv, 1)
+    g = hq // hkv
+    qt, kt, vt = fa._kernel_layout(q, g), fa._kernel_layout(k), \
+        fa._kernel_layout(v)
+    out, lse = fa._flash_fwd(qt, kt, vt, True, 32 ** -0.5, 128, 128, True,
+                             window, g, fa._RESIDENT_KV_BYTES)
+    sc = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * 32 ** -0.5
+    pos = jnp.tile(jnp.arange(s), g)[:, None], jnp.arange(s)[None]
+    keep = pos[0] >= pos[1]
+    if window is not None:
+        keep &= pos[0] - pos[1] < window
+    want = jax.nn.logsumexp(jnp.where(keep, sc, -jnp.inf), axis=-1)
+    assert lse.shape == (2 * hkv, 1, g * s) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse.reshape(want.shape), want, atol=2e-5,
+                               rtol=2e-5)
+    np.testing.assert_allclose(fa._model_layout(out, g),
+                               _naive(q, k, v, window), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 128])
+@pytest.mark.parametrize("variant", ["resident", "streamed"])
+def test_backward_is_pallas_kernels_and_no_loop(monkeypatch, variant, window):
+    """The gradient's jaxpr holds no `while` and no `scan`, with and
+    without a window: the backward is the Pallas kernels named
+    `flash_bwd*` (the names the benchmark's reader finds in a trace): one
+    while k and v stay in VMEM, one for dq and one for dk/dv beyond."""
+    _kernel_variant(monkeypatch, variant)
+    q, k, v, t = _inputs(1024, 2, 2, 0)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(fa.mha(
+        q, k, v, causal=True, window=window, interpret=True) * t),
+        (0, 1, 2)))(q, k, v)
+
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            if eqn.primitive.name != "pallas_call":   # not a kernel's body
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from primitives(sub)
+
+    eqns = list(primitives(jaxpr.jaxpr))
+    assert not [e for e in eqns if e.primitive.name in ("while", "scan")]
+    names = sorted(str(e.params["name"]) for e in eqns
+                   if e.primitive.name == "pallas_call")
+    want = {"resident": [fa.BWD_NAME, fa.FWD_RESIDENT_NAME],
+            "streamed": [fa.BWD_DKV_NAME, fa.BWD_DQ_NAME,
+                         fa.FWD_STREAM_NAME]}[variant]
+    assert names == want
 
 
 @pytest.mark.parametrize("seq,head_dim,fragment", [

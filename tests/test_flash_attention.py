@@ -44,15 +44,16 @@ def test_blockwise_reference_matches_dense():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_flash_gradients_match_dense():
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_gradients_match_dense(causal):
     q, k, v = _qkv(s=48, d=8)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, True, None, 16, 16, True)
+        return (flash_attention(q, k, v, causal, None, 16, 16, True)
                 ** 2).sum()
 
     def loss_dense(q, k, v):
-        return (_dense_bhsd(q, k, v, True) ** 2).sum()
+        return (_dense_bhsd(q, k, v, causal) ** 2).sum()
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)

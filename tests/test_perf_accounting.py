@@ -8,7 +8,7 @@ silently regress between chip runs:
   traced loss must actually subsample the stats reads.
 - dense-vs-blockwise attention: pinned on compiled memory growth —
   dense temp memory is quadratic in sequence length, blockwise (the
-  flash kernel's semantic twin) is linear.
+  flash kernel's semantic twin) and the flash backward are linear.
 - fused multi-step: pinned on compiled memory — scanning K train steps
   into one executable must not inflate live memory.
 - the TPU compiler itself: `tools/perf_accounting.py` AOT-compiles the
@@ -86,20 +86,23 @@ def test_dense_attention_temp_is_quadratic_blockwise_linear():
 
 
 def test_flash_backward_memory_is_linear():
-    """The FA2-style _flash_bwd (r5) must stay O(seq) in live memory —
-    the previous backward (vjp of the blockwise forward) was O(seq^2)
-    and at 8k cost MORE temp than dense. 4x the sequence must cost
-    ~4x the temp (quadratic would be 16x)."""
+    """The attention backward (the Pallas kernels of _flash_bwd, here in
+    the interpreter) must stay O(seq) in live memory: score tiles never
+    reach HBM, so nothing is quadratic. 4x the sequence must cost ~4x
+    the temp (quadratic would be 16x)."""
     import jax.numpy as jnp
 
     from edl_tpu.ops import flash_attention as fa
 
     def temp_at(seq):
         s = jax.ShapeDtypeStruct((1, 12, seq, 64), jnp.bfloat16)
+        lse = jax.ShapeDtypeStruct((12, 1, seq), jnp.float32)
+        delta = jax.ShapeDtypeStruct((1, 12, seq), jnp.float32)
 
-        def bwd(q, k, v, out, g):
-            return fa._flash_bwd(q, k, v, out, g, True, 64 ** -0.5)
-        comp = jax.jit(bwd).lower(s, s, s, s, s).compile()
+        def bwd(q, k, v, lse, delta, g):
+            return fa._flash_bwd(q, k, v, lse, delta, g, True, 64 ** -0.5,
+                                 True)
+        comp = jax.jit(bwd).lower(s, s, s, lse, delta, s).compile()
         return comp.memory_analysis().temp_size_in_bytes
 
     t2k, t8k = temp_at(2048), temp_at(8192)
@@ -162,6 +165,43 @@ def test_tpu_compiler_accounts_bn_tradeoff():
     assert bn4["flops"] < bn1["flops"] * 1.02, (bn1, bn4)
     ratio = bn4["bytes_accessed"] / bn1["bytes_accessed"]
     assert 0.3 < ratio < 2.2, (bn1, bn4)
+
+
+@pytest.mark.integration
+@pytest.mark.parametrize("batch,kv_heads,seq,dim,group,window,streamed", [
+    (12, 12, 1024, 64, 1, None, False),     # gpt2s-train
+    (2, 1, 8192, 128, 7, None, False),      # the sparse decoder's full
+    (2, 1, 8192, 128, 7, 4096, False),      # layer, and its window layers
+    (1, 2, 32768, 64, 2, 8192, True),       # k and v beyond VMEM: streamed
+])
+def test_tpu_compiler_takes_the_flash_kernels_at_the_cells_shapes(
+        batch, kv_heads, seq, dim, group, window, streamed):
+    """Mosaic compiles the flash forward and backward kernels for a v5e
+    at the widths the benchmark's LM cells run, and the streamed ones at a
+    length where k and v no longer fit VMEM (what interpret mode cannot
+    show: tiling, VMEM, transposed products), and the gradient holds no
+    loop outside the kernels. Nothing runs: not a chip run."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from edl_tpu.ops import flash_attention as fa
+    chip = SingleDeviceSharding(_tpu_topology_or_skip()[0])
+    q = jax.ShapeDtypeStruct((batch, kv_heads, group * seq, dim),
+                             jnp.bfloat16, sharding=chip)
+    k = jax.ShapeDtypeStruct((batch, kv_heads, seq, dim), jnp.bfloat16,
+                             sharding=chip)
+
+    def grads(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window, group=group), q, k, v)
+        return out, vjp(g)
+
+    text = jax.jit(grads).lower(q, k, k, q).compile().as_text()
+    names = ((fa.FWD_STREAM_NAME, fa.BWD_DQ_NAME, fa.BWD_DKV_NAME)
+             if streamed else (fa.FWD_RESIDENT_NAME, fa.BWD_NAME))
+    for name in names:
+        assert name in text, name
+    assert " while(" not in text
 
 
 # -- bandwidth roofline (pure arithmetic, r5 measured profile) ------------

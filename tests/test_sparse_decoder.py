@@ -371,6 +371,28 @@ def test_kernel_readers_on_a_recorded_view(kernel):
     assert device_ms.read(empty) is None and roofline.read(empty) is None
 
 
+@pytest.mark.parametrize("ops,want", [
+    # every backward kernel, however autodiff and remat wrapped its name,
+    # and not the forward's: 0.9 s over 30 steps
+    ([["flash_bwd", 0.3], ["flash_bwd_dq", 0.2], ["flash_bwd_dkv", 0.2],
+      ["transpose_jvp_flash_bwd_", 0.2], ["flash_fwd_resident", 0.7],
+      ["while", 0.5]], 30.0),
+    # the parent's program: scans and no kernel: nothing read, none raised
+    ([["fusion", 3.0], ["while", 0.5], ["flash_fwd_resident", 0.7]], None),
+])
+def test_flash_bwd_reader_sums_the_backward_kernels_only(ops, want):
+    from edl_tpu.ops import flash_attention as fa
+    for bwd in (fa.BWD_NAME, fa.BWD_DQ_NAME, fa.BWD_DKV_NAME):
+        assert "flash_bwd" in bwd
+        for fwd in (fa.FWD_RESIDENT_NAME, fa.FWD_STREAM_NAME):
+            assert fwd not in bwd and "flash_bwd" not in fwd
+    got = harness.load_module("metrics", "flash_bwd_device_ms").read(
+        _view(ops))
+    assert got == (None if want is None else pytest.approx(want))
+    fwd_ms = harness.load_module("metrics", "flash_fwd_resident_device_ms")
+    assert fwd_ms.read(_view(ops)) == pytest.approx(0.7 / 30 * 1e3)
+
+
 def test_counter_readers_return_none_without_counters(monkeypatch):
     monkeypatch.setattr(kernel_readers, "model_counters", lambda: {})
     for name in ("moe_rows_dropped", "moe_expert_load_max_over_mean"):
