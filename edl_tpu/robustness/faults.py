@@ -51,9 +51,12 @@ store.repl.snapshot      before a follower installs a leader snapshot
                          (ctx: term, index)
 store.repl.apply         before a committed entry is applied (ctx:
                          index, kind)
-resize.live.drain        in live_resize before the save-engine drain
-                         (ctx: from_devices, to_devices) — a failure
-                         here rolls back before anything moved
+resize.live.drain        in live_resize inside the span resize.drain,
+                         before any wait for the save in flight (one
+                         is taken only where the reshard reads the
+                         committed version; ctx: from_devices,
+                         to_devices) — a failure here rolls back
+                         before anything moved
 resize.live.reshard      in live_resize after the new mesh is built,
                          before any state is resharded (ctx:
                          from_devices, to_devices) — the mid-reshard

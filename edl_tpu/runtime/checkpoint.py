@@ -487,6 +487,12 @@ class CheckpointManager(object):
                              h.version, h.exception())
         return h
 
+    def persisting(self):
+        """True while an async save's persist is still running. Takes
+        nothing: the handle stays for the next drain() to collect."""
+        h = self._inflight
+        return h is not None and not h.done()
+
     def close(self):
         """Drain the in-flight save and shut the writer pool down."""
         self.drain()
@@ -735,20 +741,30 @@ class CheckpointManager(object):
     def _write_entries(self, vdir, prefix, entries):
         """Fan the entry files out across the writer pool; returns the
         manifest entry table {span_key: {file, dtype, shape, crc,
-        nbytes, chunk, chunk_crcs}} and the total byte count."""
+        nbytes, chunk, chunk_crcs}} and the total byte count.
+
+        Files are named by the entries' sorted keys and SUBMITTED
+        largest first. A large entry's write and checksum release the
+        interpreter lock; a small one costs mostly interpreter time. The
+        training thread is busiest on the interpreter right after the
+        save returns (the next dispatches; a live resize's reshard), so
+        it meets the writers while they need the lock least — and the
+        longest writes start first, which keeps the pool's tail short."""
         pool = self._io_pool()
-        futs = []
-        for i, skey in enumerate(sorted(entries)):
-            fname = "%sa%04d.bin" % (prefix, i)
-            arr = entries[skey]
-            futs.append((skey, fname, arr,
-                         pool.submit(self._write_entry_file,
-                                     "%s/%s" % (vdir, fname), arr)))
+        order = sorted(entries)
+        fname = {skey: "%sa%04d.bin" % (prefix, i)
+                 for i, skey in enumerate(order)}
+        futs = {skey: pool.submit(self._write_entry_file,
+                                  "%s/%s" % (vdir, fname[skey]),
+                                  entries[skey])
+                for skey in sorted(order,
+                                   key=lambda k: -entries[k].nbytes)}
         table = {}
         total = 0
-        for skey, fname, arr, fut in futs:
-            nbytes, crc, chunk_crcs = fut.result()
-            table[skey] = {"file": fname, "dtype": arr.dtype.str,
+        for skey in order:
+            arr = entries[skey]
+            nbytes, crc, chunk_crcs = futs[skey].result()
+            table[skey] = {"file": fname[skey], "dtype": arr.dtype.str,
                            "shape": list(arr.shape), "crc": crc,
                            "nbytes": nbytes, "chunk": _CHUNK,
                            "chunk_crcs": chunk_crcs}

@@ -60,6 +60,10 @@ _STEP_REUSES = obs_metrics.counter(
     "edl_resize_step_reuses_total",
     "live resizes that took their step executable from the table this "
     "process already held (no fingerprint, load or compile in the pause)")
+_DRAIN_DEFERRED = obs_metrics.counter(
+    "edl_resize_drain_deferred_total",
+    "live resizes that met an async save's persist in flight and left it "
+    "running (fully addressable state: the reshard reads no version)")
 
 _MODEL_COUNTER = obs_metrics.gauge(
     "edl_train_model_counter",
@@ -1352,11 +1356,11 @@ class ElasticTrainer(object):
         local-span paste, peer range-reads at the committed version,
         per-span FS fill (live_resize.reshard_placed). Returns
         (new_tree, stats)."""
-        leaves = jax.tree_util.tree_leaves(tree)
-        if all(getattr(x, "is_fully_addressable", True) for x in leaves):
+        if self._fully_addressable(tree):
             out = jax.device_put(tree, shardings)
             jax.block_until_ready(out)
-            nbytes = sum(int(getattr(x, "nbytes", 0)) for x in leaves)
+            nbytes = sum(int(getattr(x, "nbytes", 0))
+                         for x in jax.tree_util.tree_leaves(tree))
             return out, {"source": "local", "local_bytes": nbytes,
                          "peer_bytes": 0, "peers": 0, "fs_keys": []}
         from edl_tpu.runtime import live_resize as live_mod
@@ -1369,8 +1373,9 @@ class ElasticTrainer(object):
                            if self._state_server is not None else None))
 
     def live_resize(self, n_devices, mesh_shape=None):
-        """Reshape the mesh to ``n_devices`` IN PLACE: drain the save
-        engine to a clean boundary, rebuild the mesh (``mesh_shape``
+        """Reshape the mesh to ``n_devices`` IN PLACE: wait for the
+        in-flight async save where the reshard reads its result (below),
+        rebuild the mesh (``mesh_shape``
         picks a (dp, tp, pp, ep) factorization — e.g. the cluster
         generator's roofline choice — default: keep the current model
         axes and rescale dp), transplant every state PartitionSpec onto
@@ -1384,11 +1389,27 @@ class ElasticTrainer(object):
         ``reshard_s`` stage); the next train_step stamps
         compile/first-step and republishes it.
 
+        The wait for an async save's persist is taken only when some
+        state leaf is not fully addressable: that reshard (the placed
+        ladder) range-reads peers and the file system at the committed
+        version. Fully addressable state reshards from the live device
+        arrays, so a persist in flight is left running: it commits and
+        publishes on its own thread as after any save, and the next
+        ``save()``, ``close()``, the preemption guard or ``atexit``
+        collects its handle (and logs its failure). The span
+        ``resize.drain`` is then short; the root span's tag ``drain``
+        and ``_resize_timing["drain"]`` say which it was — "deferred",
+        "waited", or "idle" (nothing in flight) — and
+        ``edl_resize_drain_deferred_total`` counts the first.
+        ``_resize_timing["version"]`` names the version the state server
+        serves at that moment: the last COMMIT, which after a deferred
+        drain may be the save before the one still being written.
+
         On ANY failure the trainer is rolled back to the old mesh —
         numerically untouched, still training — and LiveResizeError is
         raised; the caller (the intent ack path, or an operator) lets
         the stop-resume ladder handle the membership change instead.
-        Chaos fault points: ``resize.live.drain`` (before the drain)
+        Chaos fault points: ``resize.live.drain`` (before any wait)
         and ``resize.live.reshard`` (after the new mesh is built,
         before any state moves)."""
         from edl_tpu.utils.errors import LiveResizeError
@@ -1439,10 +1460,22 @@ class ElasticTrainer(object):
                         faults.PLANE.fire("resize.live.drain",
                                           from_devices=str(old_n),
                                           to_devices=str(n_devices))
-                    # drain: the in-flight async persist commits (and its
-                    # peer publish runs) BEFORE the reshape — peers keep a
-                    # stable version to read across our reshard
-                    self.wait_for_save()
+                    # the wait is taken only where its result is read:
+                    # the placed ladder reads peers and the file system
+                    # at the committed version, which must not move
+                    # across the reshard. Fully addressable state
+                    # reshards from the live device arrays and the
+                    # persist reads only its own host copies: it is left
+                    # running, for the next drain to collect
+                    local = self._fully_addressable(self.train_state)
+                    drain = "idle"
+                    if self._ckpt is not None and self._ckpt.persisting():
+                        drain = "deferred" if local else "waited"
+                    sp_live.tag(drain=drain)
+                    if not local:
+                        self.wait_for_save()
+                    elif drain == "deferred":
+                        _DRAIN_DEFERRED.inc()
                     self.mirror_model_counters()
                 with obs_trace.span("resize.mesh", stage=True) as sp_mesh:
                     new_mesh = self._target_mesh(n_devices, mesh_shape)
@@ -1522,7 +1555,7 @@ class ElasticTrainer(object):
             self._resize_timing = {
                 "t_construct": t_start, "mode": "live",
                 "t_resume_start": t_start,
-                "drain_s": round(drain_s, 6),
+                "drain_s": round(drain_s, 6), "drain": drain,
                 "reshard_s": round(reshard_s, 6),
                 "from_devices": old_n, "to_devices": n_devices,
                 "from_mesh": {str(a): int(s) for a, s in
@@ -1545,10 +1578,11 @@ class ElasticTrainer(object):
                             reshard_s=reshard_s, prewarm=prewarm,
                             step_source=step_source,
                             source=reshard_stats["source"])
-            logger.info("live resize %d -> %d: drain %.3fs reshard %.3fs "
-                        "(%s, prewarm %s, step from %s) — process stayed "
-                        "alive", old_n, n_devices, drain_s, reshard_s,
-                        reshard_stats["source"], prewarm, step_source)
+            logger.info("live resize %d -> %d: drain %.3fs (%s) reshard "
+                        "%.3fs (%s, prewarm %s, step from %s) — process "
+                        "stayed alive", old_n, n_devices, drain_s, drain,
+                        reshard_s, reshard_stats["source"], prewarm,
+                        step_source)
             return dict(self._resize_timing)
 
     def enable_live_resize(self, who=None):
@@ -2043,9 +2077,10 @@ class ElasticTrainer(object):
                 _MODEL_COUNTER.labels(name, i).set(float(v))
         return host
 
-    def _state_fully_addressable(self):
+    @staticmethod
+    def _fully_addressable(tree):
         return all(getattr(x, "is_fully_addressable", True)
-                   for x in jax.tree_util.tree_leaves(self.train_state))
+                   for x in jax.tree_util.tree_leaves(tree))
 
     def save(self):
         """Write the versioned checkpoint + State (reference: rank0
@@ -2130,7 +2165,7 @@ class ElasticTrainer(object):
                             "redundancy shard push for v%d failed; "
                             "this version has no parity cover", version)
 
-        if not self._state_fully_addressable():
+        if not self._fully_addressable(self.train_state):
             # per-host sharded write; every rank participates
             rank = jax.process_index()
             nranks = jax.process_count()

@@ -1,5 +1,5 @@
 """The benchmark's readers of the program's stage spans
-(benchmark/lib/progspans.py and the thirteen files under
+(benchmark/lib/progspans.py and the fifteen files under
 benchmark/metrics/ that use it), each on a hand-made ring and `view`:
 medians by direction, None without the span, roots outside the window
 left out, the arithmetic of `resize_other_ms`. The cell's own end-to-end
@@ -21,6 +21,8 @@ NEW = ["shrink_pause_ms", "grow_pause_ms", "shrink_device_put_ms",
        "grow_first_trace_ms", "grow_first_load_ms", "shrink_first_run_ms",
        "grow_first_run_ms", "resize_other_ms", "save_drain_prev_ms",
        "save_snapshot_ms"]
+#: the wait a resize takes for the save in flight, and that save's write
+LATER = ["resize_drain_ms", "save_persist_ms"]
 
 
 def _span(trace, name, t0, ms, parent="root", **tags):
@@ -137,6 +139,8 @@ def _read(name, view):
     ("grow_first_run_ms", 90.0),
     ("save_drain_prev_ms", 2.0),           # of 0, 12, 2
     ("save_snapshot_ms", 430.0),
+    ("resize_drain_ms", 5.0),              # every resize of `world`
+    ("save_persist_ms", 400.0),            # every save of `world`
 ])
 def test_stage_medians_by_direction(world, name, want):
     assert _read(name, world["view"]) == pytest.approx(want)
@@ -190,7 +194,59 @@ def test_traces_under_the_profiler_s_capture_are_left_out(world):
     assert _read("save_drain_prev_ms", view) == pytest.approx(0.0)
 
 
-@pytest.mark.parametrize("name", NEW)
+def _with_durations(ring, name, by_trace):
+    return [dict(s, dur_ms=by_trace[s["trace_id"]]) if s["name"] == name
+            else s for s in ring]
+
+
+@pytest.mark.parametrize("drains,want", [
+    # every resize left the write running: what is left is the fault
+    # point and the counters' mirror
+    ({"s1": 0.4, "s2": 0.7, "s3": 0.6, "g1": 0.5, "g2": 0.3}, 0.5),
+    # every resize waited for the disk, the grows longer
+    ({"s1": 171.0, "s2": 269.0, "s3": 240.0, "g1": 396.0, "g2": 243.0},
+     243.0),
+    # shrinks and grows are ONE median: two that waited among five
+    ({"s1": 0.4, "s2": 250.0, "s3": 0.6, "g1": 0.5, "g2": 300.0}, 0.6),
+])
+def test_drain_is_one_median_over_shrinks_and_grows(world, monkeypatch,
+                                                    drains, want):
+    drains = dict(drains, warm=900.0, late=900.0)
+    ring = _with_durations(world["ring"], "resize.drain", drains)
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("resize_drain_ms", world["view"]) == pytest.approx(want)
+
+
+def test_drain_counts_a_resize_whose_first_step_the_ring_lost(
+        world, monkeypatch):
+    ring = [s for s in _with_durations(
+                world["ring"], "resize.drain",
+                {"s1": 1.0, "s2": 200.0, "s3": 3.0, "g1": 2.0, "g2": 4.0,
+                 "warm": 900.0, "late": 900.0})
+            if not (s["trace_id"] == "s2"
+                    and s["name"].startswith("resize.first"))]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert len(progspans.resizes(world["view"])) == 4
+    assert _read("resize_drain_ms", world["view"]) == pytest.approx(3.0)
+
+
+def test_persist_is_the_writer_s_span_and_skips_a_write_still_running(
+        world, monkeypatch):
+    ring = _with_durations(world["ring"], "save.persist",
+                           {"v1": 200.0, "v2": 640.0, "v3": 380.0,
+                            "vwarm": 5000.0})
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("save_persist_ms", world["view"]) == pytest.approx(380.0)
+    # a span is recorded when it ends: the last save's write, still
+    # running when the ring is read, is not there yet
+    ring = [s for s in ring if not (s["trace_id"] == "v3"
+                                    and s["name"] == "save.persist")]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("save_persist_ms", world["view"]) == pytest.approx(420.0)
+    assert _read("save_snapshot_ms", world["view"]) == pytest.approx(430.0)
+
+
+@pytest.mark.parametrize("name", NEW + LATER)
 def test_none_without_the_span(monkeypatch, name):
     """The parent's program records no stage span: an empty ring, or
     spans without a monotonic start. Nothing is read and nothing raises."""
@@ -256,5 +312,15 @@ def test_benchmark_names_every_new_reader_once_for_the_elastic_cell():
         else:
             assert (m["layer"], m["moves"]) == ("live resize",
                                                 "resize_pause_ms")
+        assert os.path.exists(os.path.join(
+            REPO, "benchmark", "metrics", name + ".py"))
+    assert names[-len(LATER):] == LATER
+    for name, layer, moves in [
+            ("resize_drain_ms", "live resize", "resize_pause_ms"),
+            ("save_persist_ms", "checkpoint", "elastic_samples_s_chip")]:
+        assert entries[name] == {
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "program_span", "layer": layer, "moves": moves,
+            "workloads": [CELL]}
         assert os.path.exists(os.path.join(
             REPO, "benchmark", "metrics", name + ".py"))

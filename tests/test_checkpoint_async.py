@@ -107,6 +107,45 @@ def test_async_save_restore_roundtrip(ckpt_fs):
     cm.close()
 
 
+def test_entries_are_written_largest_first_under_the_sorted_keys_names(
+        tmp_path):
+    """The order of the writes is the writer pool's business (the large
+    ones, which leave the interpreter to the training thread, go first);
+    the files' names, the manifest and what a restore reads are those of
+    the keys in sorted order, as they always were."""
+    written = []
+
+    class _Recording(_WrapFS):
+        def write_chunks(self, path, chunks):
+            written.append(path.rsplit("/", 1)[1])
+            return self._inner.write_chunks(path, chunks)
+
+    cm = CheckpointManager(str(tmp_path), fs=_Recording(LocalFS()),
+                           workers=1)
+    tree = {"a_small": np.arange(3, dtype=np.float32),
+            "b_large": np.arange(4096, dtype=np.float32),
+            "c_mid": np.arange(64, dtype=np.float32),
+            "d_large": np.arange(4096, dtype=np.float32) + 1.0,
+            "e_none": np.zeros((0,), np.float32)}
+    vdir = cm.save_async(3, tree).result(30)
+    with open(vdir + "/MANIFEST") as f:
+        manifest = json.load(f)
+    keys = sorted(manifest["entries"])
+    assert list(manifest["entries"]) == keys
+    assert [manifest["entries"][k]["file"] for k in keys] == [
+        "a%04d.bin" % i for i in range(5)]
+    by_file = {e["file"]: e["nbytes"] for e in manifest["entries"].values()}
+    # b before d: entries of one size keep the order of their keys
+    assert written == ["a0001.bin", "a0003.bin", "a0002.bin", "a0000.bin",
+                       "a0004.bin"]
+    assert [by_file[f] for f in written] == sorted(by_file.values(),
+                                                   reverse=True)
+    _, restored, _ = cm.restore(3, target=tree)
+    for k, v in tree.items():
+        np.testing.assert_array_equal(restored[k], v)
+    cm.close()
+
+
 def test_async_backpressure_drains_previous(tmp_path):
     """max_inflight=1: a second save_async must BLOCK until the first
     persist lands (which is what makes host-buffer reuse safe)."""

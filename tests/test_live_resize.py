@@ -28,8 +28,10 @@ from edl_tpu.controller import cluster as cluster_mod
 from edl_tpu.controller import constants
 from edl_tpu.models import linear
 from edl_tpu.obs import events as obs_events
+from edl_tpu.obs import trace as obs_trace
 from edl_tpu.robustness import faults
 from edl_tpu.runtime import live_resize as live_mod
+from edl_tpu.runtime import trainer as trainer_mod
 from edl_tpu.runtime.mesh import make_mesh
 from edl_tpu.runtime.trainer import ElasticTrainer
 from edl_tpu.utils.errors import LiveResizeError
@@ -350,6 +352,212 @@ def test_prewarm_thread_racing_a_resize_takes_the_old_path(
     _steps(tr, BATCHES[2:3])
     assert tr.live_resize(4)["step_source"] == "memory"
     _steps(tr, BATCHES[3:4])
+
+
+# -- the save in flight: waited for only where the reshard reads it ---------
+
+
+class _Held(object):
+    """A trainer two steps in, on 4 devices, whose asynchronous save is
+    held in flight by a gate on the entry writes; `fail` makes every
+    write raise once the gate opens."""
+
+    def __init__(self, tmp_path, fail=None):
+        self.tr = _trainer(4, ckpt=str(tmp_path / "ckpt"), async_save=True)
+        _steps(self.tr, BATCHES[:2])
+        self.gate = threading.Event()
+        write = self.tr._ckpt._write_entry_file
+
+        def held(path, arr):
+            assert self.gate.wait(60)
+            if fail is not None:
+                raise fail
+            return write(path, arr)
+
+        self.tr._ckpt._write_entry_file = held
+        self.tr.save()
+        self.version = self.tr.global_step
+        self.handle = self.tr._ckpt._inflight
+        self.at_save = _state_bytes(self.tr)
+
+    def in_flight(self):
+        return (not self.handle.done()
+                and self.tr._ckpt._inflight is self.handle
+                and self.tr._ckpt.versions() == [])
+
+    def restored(self):
+        _, tree, _ = self.tr._ckpt.restore(
+            self.version, target=dict(self.tr.train_state))
+        return [np.asarray(x).tobytes()
+                for x in jax.tree_util.tree_leaves(tree)]
+
+    def blocks_until_the_gate_opens(self, fn, meanwhile=lambda: None):
+        """Run `fn` on a thread: it must still be running after a while
+        with the write held (`meanwhile` looks at what it has done by
+        then), and end once the gate opens."""
+        out = []
+        t = threading.Thread(target=lambda: out.append(fn()))
+        t.start()
+        t.join(0.4)
+        # (a drain that is waiting has already taken the handle)
+        assert t.is_alive() and not self.handle.done()
+        assert self.tr._ckpt.versions() == []
+        meanwhile()
+        self.gate.set()
+        t.join(60)
+        assert not t.is_alive() and self.handle.done()
+        return out[0]
+
+
+@pytest.fixture()
+def held(tmp_path):
+    h = _Held(tmp_path)
+    yield h
+    h.gate.set()
+    h.tr.close()
+
+
+def _root_drain_tags():
+    return [s["tags"].get("drain")
+            for s in obs_trace.TRACER.find(name="resize.live")]
+
+
+def test_resize_leaves_the_persist_in_flight_running(held):
+    """Fully addressable state: the resize returns and the first step on
+    the new mesh completes while the save is still being written."""
+    obs_trace.TRACER.clear()
+    before = trainer_mod._DRAIN_DEFERRED.value
+    rec = held.tr.live_resize(2)
+    loss = held.tr.train_step(held.tr.local_batch_slice(BATCHES[2]))
+    jax.block_until_ready(loss)
+    assert held.in_flight()
+    assert _world(held.tr) == 2
+    assert rec["drain"] == held.tr.resize_timing["drain"] == "deferred"
+    assert _root_drain_tags() == ["deferred"]
+    assert trainer_mod._DRAIN_DEFERRED.value == before + 1
+    # the drain span is there, and short: nothing was waited for
+    [drain] = obs_trace.TRACER.find(name="resize.drain")
+    assert drain["dur_ms"] == pytest.approx(rec["drain_s"] * 1e3, abs=2e-3)
+
+
+def test_deferred_save_commits_the_state_at_the_save(held):
+    held.tr.live_resize(2)
+    _steps(held.tr, BATCHES[2:4])
+    held.gate.set()
+    held.tr.wait_for_save()
+    assert held.tr._ckpt.versions() == [held.version]
+    assert held.tr._ckpt._inflight is None
+    assert held.restored() == held.at_save
+    assert _state_bytes(held.tr) != held.at_save
+
+
+def test_reshard_fault_with_the_persist_held_rolls_back_and_commits(held):
+    plane = faults.FaultPlane(seed=7)
+    plane.inject("resize.live.reshard", "error", error="RpcError")
+    plane.install()
+    try:
+        with pytest.raises(LiveResizeError):
+            held.tr.live_resize(2)
+    finally:
+        plane.uninstall()
+    assert ("resize.live.reshard", "error") in plane.log
+    assert _world(held.tr) == 4
+    assert _state_bytes(held.tr) == held.at_save
+    assert held.in_flight()       # the failed resize took no handle
+    held.gate.set()
+    held.tr.wait_for_save()
+    assert held.tr._ckpt.versions() == [held.version]
+    assert held.restored() == held.at_save
+    _steps(held.tr, [BATCHES[2]])
+
+
+@pytest.mark.parametrize("waiter", ["close", "save"])
+def test_the_next_drain_waits_for_the_deferred_persist(held, waiter):
+    """Whoever drains next collects the handle the resize left: the next
+    save (max_inflight stays 1) and close()."""
+    held.tr.live_resize(2)
+    _steps(held.tr, BATCHES[2:3])
+    held.blocks_until_the_gate_opens(getattr(held.tr, waiter))
+    held.tr.wait_for_save()
+    want = [held.version] + ([held.tr.global_step] if waiter == "save"
+                             else [])
+    assert held.tr._ckpt.versions() == want
+
+
+def test_placed_reshard_waits_for_the_persist_before_it_moves(
+        held, monkeypatch):
+    """A leaf that is not fully addressable sends the reshard down the
+    placed ladder, which reads the committed version: the wait is taken
+    before the mesh is touched, as it always was."""
+    monkeypatch.setattr(ElasticTrainer, "_fully_addressable",
+                        staticmethod(lambda tree: False))
+    obs_trace.TRACER.clear()
+    before = trainer_mod._DRAIN_DEFERRED.value
+    committed = []
+
+    def resize():
+        rec = held.tr.live_resize(2)
+        committed.append(held.tr._ckpt.versions())
+        return rec
+
+    def nothing_has_moved():
+        names = {s["name"] for s in obs_trace.TRACER.spans()}
+        assert not names & {"resize.drain", "resize.mesh", "resize.live"}
+        assert _world(held.tr) == 4
+
+    rec = held.blocks_until_the_gate_opens(resize, nothing_has_moved)
+    assert rec["drain"] == "waited" and _root_drain_tags() == ["waited"]
+    assert committed == [[held.version]]
+    assert trainer_mod._DRAIN_DEFERRED.value == before
+    assert held.tr._ckpt._inflight is None
+    assert _world(held.tr) == 2
+    assert _state_bytes(held.tr) == held.at_save
+
+
+def test_a_persist_that_fails_while_deferred_is_logged_by_the_next_drain(
+        tmp_path, monkeypatch):
+    from edl_tpu.runtime import checkpoint as checkpoint_mod
+    h = _Held(tmp_path, fail=IOError("injected: disk full"))
+    errors = []
+    monkeypatch.setattr(checkpoint_mod.logger, "error",
+                        lambda fmt, *a: errors.append(fmt % a))
+    try:
+        assert h.tr.live_resize(2)["drain"] == "deferred"
+        _steps(h.tr, BATCHES[2:3])
+        h.gate.set()
+        assert h.handle.wait(60)
+        # the resize did not swallow the handle, and nobody has read it
+        assert h.tr._ckpt._inflight is h.handle and errors == []
+        h.tr.wait_for_save()
+        assert len(errors) == 1
+        assert "v%d failed" % h.version in errors[0]
+        assert "disk full" in errors[0]
+        assert h.tr._ckpt._inflight is None
+        assert h.tr._ckpt.versions() == []
+    finally:
+        h.gate.set()
+        h.tr.close()
+
+
+@pytest.mark.parametrize("saved", [False, True])
+def test_resize_with_nothing_in_flight_reads_idle(tmp_path, saved):
+    """No checkpoint directory, or a save whose write has finished: there
+    is nothing to wait for and nothing deferred."""
+    tr = _trainer(4, ckpt=str(tmp_path / "ckpt") if saved else None,
+                  async_save=True)
+    try:
+        _steps(tr, BATCHES[:1])
+        if saved:
+            tr.save()
+            assert tr._ckpt._inflight.wait(60)
+        obs_trace.TRACER.clear()
+        before = trainer_mod._DRAIN_DEFERRED.value
+        assert tr.live_resize(2)["drain"] == "idle"
+        assert _root_drain_tags() == ["idle"]
+        assert trainer_mod._DRAIN_DEFERRED.value == before
+        _steps(tr, BATCHES[1:2])
+    finally:
+        tr.close()
 
 
 # -- the store protocol ----------------------------------------------------

@@ -143,7 +143,13 @@ def _resize(arc, case):
 def test_resize_leaves_one_root_with_the_table_s_children(arc, case):
     root, trace = _resize(arc, case)
     assert root["parent_id"] is None
-    assert root["tags"] == CASES[case]
+    tags = dict(root["tags"])
+    # the second trainer saves nothing; the first one's few bytes may be
+    # written before its resize looks (tests/test_live_resize.py holds
+    # the write open to tell the cases apart)
+    assert tags.pop("drain") in (("deferred", "idle")
+                                 if case.startswith("memory") else ("idle",))
+    assert tags == CASES[case]
     children = [s for s in trace if s["parent_id"] == root["span_id"]
                 and s["name"] != "resize.first_step"]
     # a step the trainer holds ready needs no name and no load, so
