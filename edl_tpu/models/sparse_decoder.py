@@ -1,8 +1,15 @@
 """Sparse decoder family: a causal LM whose every layer is grouped-query
-attention followed by a top-k mixture of ReGLU experts, with the router
-tapped BEFORE attention (it reads the attention's normed input), RMSNorm,
-no biases, an untied output head, and a per-layer choice of rotary
-positions or none and of a causal window or the full prefix.
+attention followed by a top-k mixture of gated-linear-unit experts, with
+RMSNorm, no biases and an untied output head. What differs between the
+models of the family is said by attributes: per layer, rotary positions or
+none, and which keys a query reads — the full causal prefix, a causal
+window, or the ``select_topk`` keys a learned indexer chose
+(DeepSeek-Sparse-Attention: ``ops/sparse_attention.py``; the indexer
+learns from a loss term of its own and from nothing else); the router's
+input (``router_input``: the attention's normed input, i.e. tapped BEFORE
+attention, or the expert layer's own normed input); the experts' gate
+(``expert_activation``: ReLU or SiLU); RMSNorm over the head width on
+queries and keys (``qk_norm``). The defaults are SmallThinker's.
 
 One chip's share of an expert-parallel, head-parallel, vocabulary-parallel
 deployment: a layer is told how many query and key-value heads, which
@@ -15,7 +22,7 @@ in for the absent chips.
 bf16 activations and products; float32 parameters, router scores,
 attention softmax and logits. Attention goes through the one dispatch
 (``ops/attention.py:attention_context``: dense or the Pallas flash
-kernel).
+kernels).
 """
 
 from typing import Any, Optional, Sequence
@@ -25,13 +32,19 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from edl_tpu.ops.attention import attention_context
+from edl_tpu.ops import sparse_attention
+from edl_tpu.ops.attention import attention_context, selected_attention
 from edl_tpu.parallel import moe
 
 #: what a layer counts about its routing each step (float32 scalars);
 #: `load_max` is kept as a running maximum, the others as running sums
 COUNTERS = ("rows_held", "load_max", "load_mean", "tokens_unserved",
             "rows_dropped")
+#: and, in a model with a selecting layer, about its selection: the keys
+#: kept summed over the rows, the rows that kept another number than
+#: min(position + 1, select_topk) (exact ties at the threshold only), and
+#: the layer's index loss (mean over its tokens); running sums
+SELECT_COUNTERS = ("pairs_kept", "rows_off_count", "index_loss")
 
 
 def _init(std=0.02):
@@ -66,8 +79,10 @@ def rope(x, theta):
 
 
 class SparseDecoderLayer(nn.Module):
-    """h = norm(x); route on h; x' = x + attention(h); out = x' +
-    held experts(norm(x')) by h's routing. Returns (out, counters)."""
+    """h = norm(x); x' = x + attention(h); u = norm(x'); out = x' + held
+    experts(u), routed on h or on u (``router_input``). Returns (out,
+    counters); a selecting layer's counters hold its index loss, which is
+    differentiable (towards the indexer alone)."""
     heads: int                 # query heads held here
     kv_heads: int              # key-value heads held here
     head_dim: int
@@ -82,6 +97,48 @@ class SparseDecoderLayer(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     use_flash: Optional[bool] = None
+    select_topk: Optional[int] = None   # keys a query keeps; None: no indexer
+    index_heads: int = 0
+    index_dim: int = 0
+    router_input: str = "attn_norm"     # or "moe_norm"
+    expert_activation: str = "relu"     # or "silu"
+    qk_norm: bool = False
+
+    def _route(self, x):
+        b, s, d = x.shape
+        with jax.named_scope("moe.route"):
+            router = self.param("router", _init(), (d, self.num_experts),
+                                jnp.float32)
+            return moe.route_top_k(x.reshape(b * s, d), router,
+                                   self.experts_per_token)
+
+    def _index(self, h, proj):
+        """The indexer on stop_gradient(h): (qi, ki, wi, tau). Its four
+        tensors learn from the index loss alone, and nothing upstream of
+        them learns from it."""
+        from jax.ad_checkpoint import checkpoint_name
+        d = h.shape[-1]
+        h = jax.lax.stop_gradient(h)
+        with jax.named_scope("attn.index"):
+            qi = jnp.einsum("bsd,dhk->bshk", h, proj(
+                "index_query", (d, self.index_heads, self.index_dim)))
+            ki = jnp.einsum("bsd,dk->bsk", h, proj("index_key",
+                                                   (d, self.index_dim)))
+            wi = jnp.einsum("bsd,dh->bsh", h, proj(
+                "index_weight", (d, self.index_heads)),
+                preferred_element_type=jnp.float32)
+            if self.use_rope:
+                qi = rope(qi, self.rope_theta)
+                ki = rope(ki[:, :, None], self.rope_theta)[:, :, 0]
+            qi, ki, wi = (checkpoint_name(x, n) for x, n in zip(
+                (qi, ki, wi), sparse_attention.SAVED_UNDER_REMAT))
+            tau = checkpoint_name(sparse_attention.index_thresholds(
+                qi, ki, wi, self.select_topk),
+                sparse_attention.SAVED_UNDER_REMAT[3])
+        # for tools that compare the choice (a no-op unless a caller makes
+        # the collection mutable)
+        self.sow("intermediates", "select", (qi, ki, wi, tau))
+        return qi, ki, wi, tau
 
     @nn.compact
     def __call__(self, x):
@@ -89,34 +146,53 @@ class SparseDecoderLayer(nn.Module):
         dt = self.dtype
         proj = lambda name, shape: self.param(name, _init(), shape,
                                               jnp.float32).astype(dt)
+        if self.router_input not in ("attn_norm", "moe_norm"):
+            raise ValueError("router_input %r" % (self.router_input,))
         h = RMSNorm(self.eps, name="norm_attn")(x)
-        with jax.named_scope("moe.route"):
-            router = self.param("router", _init(), (d, self.num_experts),
-                                jnp.float32)
-            idx, p = moe.route_top_k(h.reshape(b * s, d), router,
-                                     self.experts_per_token)
-        with jax.named_scope("attn.window" if self.window else "attn.full"):
+        if self.router_input == "attn_norm":
+            idx, p = self._route(h)
+        select = self._index(h, proj) if self.select_topk else None
+        with jax.named_scope("attn.select" if select else "attn.window"
+                             if self.window else "attn.full"):
             q = jnp.einsum("bsd,dhk->bshk", h,
                            proj("query", (d, self.heads, self.head_dim)))
             k = jnp.einsum("bsd,dhk->bshk", h,
                            proj("key", (d, self.kv_heads, self.head_dim)))
             v = jnp.einsum("bsd,dhk->bshk", h,
                            proj("value", (d, self.kv_heads, self.head_dim)))
+            if self.qk_norm:
+                q = RMSNorm(self.eps, name="norm_query")(q)
+                k = RMSNorm(self.eps, name="norm_key")(k)
             if self.use_rope:
                 q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
-            a = attention_context(q, k, v, causal=True, mask=None, dtype=dt,
-                                  use_flash=self.use_flash,
-                                  window=self.window)
+            if select:
+                a, kl, kept = selected_attention(
+                    q, k, v, select, dtype=dt, use_flash=self.use_flash)
+            else:
+                a = attention_context(q, k, v, causal=True, mask=None,
+                                      dtype=dt, use_flash=self.use_flash,
+                                      window=self.window)
             x = x + jnp.einsum("bshk,hkd->bsd", a,
                                proj("out", (self.heads, self.head_dim, d)))
         u = RMSNorm(self.eps, name="norm_moe")(x)
+        if self.router_input == "moe_norm":
+            idx, p = self._route(u)
         f = self.expert_width
         gate_up = self.param("experts_gate_up", _init(),
                              (self.experts_held, d, 2 * f), jnp.float32)
         down = self.param("experts_down", _init(),
                           (self.experts_held, f, d), jnp.float32)
         m, counters = moe.held_experts_ffn(
-            u.reshape(b * s, d), idx, p, gate_up, down, self.first_expert)
+            u.reshape(b * s, d), idx, p, gate_up, down, self.first_expert,
+            activation=self.expert_activation)
+        if select:
+            with jax.named_scope("attn.index_loss"):
+                want = jnp.minimum(jnp.arange(s) + 1, self.select_topk)
+                counters = dict(
+                    counters, pairs_kept=kept.sum(),
+                    rows_off_count=jnp.sum(kept != want[None]).astype(
+                        jnp.float32),
+                    index_loss=kl.mean())
         return x + m.reshape(b, s, d), counters
 
 
@@ -141,6 +217,22 @@ class SparseDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
     remat: bool = False
     use_flash: Optional[bool] = None
+    select_layout: Sequence[int] = ()   # per layer: 1 = learned selection
+    select_topk: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    router_input: str = "attn_norm"
+    expert_activation: str = "relu"
+    qk_norm: bool = False
+    index_loss_weight: float = 1.0
+
+    def select_layers(self):
+        """Per layer: whether it reads a learned selection."""
+        layout = tuple(self.select_layout) + (0,) * self.num_layers
+        return tuple(bool(flag) for flag in layout[:self.num_layers])
+
+    def selects(self):
+        return any(self.select_layers())
 
     @nn.compact
     def __call__(self, ids):
@@ -150,12 +242,14 @@ class SparseDecoder(nn.Module):
         # remat by layer, keeping the chosen experts and the two grouped
         # products' results: the experts' forward is the one part whose
         # cost follows the routing
+        saved = moe.SAVED_UNDER_REMAT + (
+            sparse_attention.SAVED_UNDER_REMAT if self.selects() else ())
         layer_cls = (nn.remat(
             SparseDecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *moe.SAVED_UNDER_REMAT)) if self.remat else SparseDecoderLayer)
+            policy=jax.checkpoint_policies.save_only_these_names(*saved))
+            if self.remat else SparseDecoderLayer)
         per_layer = []
-        for i in range(self.num_layers):
+        for i, select in enumerate(self.select_layers()):
             x, counters = layer_cls(
                 heads=self.heads, kv_heads=self.kv_heads,
                 head_dim=self.head_dim, num_experts=self.num_experts,
@@ -167,7 +261,14 @@ class SparseDecoder(nn.Module):
                 rope_theta=self.rope_theta,
                 window=self.window if self.window_layout[i] else None,
                 eps=self.eps, dtype=self.dtype, use_flash=self.use_flash,
-                name="layer_%d" % i)(x)
+                select_topk=self.select_topk if select else None,
+                index_heads=self.index_heads, index_dim=self.index_dim,
+                router_input=self.router_input,
+                expert_activation=self.expert_activation,
+                qk_norm=self.qk_norm, name="layer_%d" % i)(x)
+            if self.selects() and not select:
+                counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
+                                             for n in SELECT_COUNTERS})
             per_layer.append(counters)
         x = RMSNorm(self.eps, name="norm_final")(x)
         with jax.named_scope("lm_head"):
@@ -176,22 +277,28 @@ class SparseDecoder(nn.Module):
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
                                 preferred_element_type=jnp.float32)
         return logits, {n: jnp.stack([c[n] for c in per_layer])
-                        for n in COUNTERS}
+                        for n in counter_names(self.selects())}
 
 
-def init_counters(num_layers):
-    """The routing counters a trainer carries in its extra state:
-    ``{"counters": {name: [L] float32, "steps": scalar}}``."""
+def counter_names(selects=False):
+    return COUNTERS + (SELECT_COUNTERS if selects else ())
+
+
+def init_counters(num_layers, selects=False):
+    """The counters a trainer carries in its extra state: ``{"counters":
+    {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
+    model with a selecting layer, the selection's."""
     # one buffer each: the trainer donates its state to the step
     return {"counters": dict(
-        {n: jnp.zeros((num_layers,), jnp.float32) for n in COUNTERS},
+        {n: jnp.zeros((num_layers,), jnp.float32)
+         for n in counter_names(selects)},
         steps=jnp.zeros((), jnp.float32))}
 
 
 def accumulate_counters(extra, step_counters):
     old = extra["counters"]
     new = {n: (jnp.maximum(old[n], step_counters[n]) if n == "load_max"
-               else old[n] + step_counters[n]) for n in COUNTERS}
+               else old[n] + step_counters[n]) for n in step_counters}
     new["steps"] = old["steps"] + 1.0
     return dict(extra, counters=new)
 
@@ -199,9 +306,11 @@ def accumulate_counters(extra, step_counters):
 def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
     """(model, params, extra_state, loss_fn) for ElasticTrainer with
     ``has_aux=True``: next-token cross-entropy over batch["input_ids"]
-    (shift inside); the extra state carries the routing counters on the
-    device (``trainer.extra_state["counters"]``), which the trainer
-    mirrors into obs.metrics where it synchronises anyway."""
+    (shift inside) and, where a layer selects its keys, ``index_loss_weight``
+    times the mean over those layers of their index loss; the extra state
+    carries the routing and selection counters on the device
+    (``trainer.extra_state["counters"]``), which the trainer mirrors into
+    obs.metrics where it synchronises anyway."""
     dummy = jnp.zeros((dummy_batch, dummy_seq), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), dummy)["params"]
 
@@ -210,7 +319,11 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
         logits, counters = model.apply({"params": params}, ids)
         loss = optax.softmax_cross_entropy_with_integer_labels(
             logits[:, :-1], ids[:, 1:]).mean()
+        if model.selects():
+            loss = loss + model.index_loss_weight * (
+                counters["index_loss"].sum() / sum(model.select_layers()))
         return loss, accumulate_counters(
             extra, jax.lax.stop_gradient(counters))
 
-    return model, params, init_counters(model.num_layers), loss_fn
+    return (model, params, init_counters(model.num_layers, model.selects()),
+            loss_fn)

@@ -2,8 +2,9 @@
 sparse decoder): in-shard ring / ring over the sp mesh axis / Pallas flash
 kernel / dense — one copy of the -1e30 mask convention, sm_scale, and the
 interpret mode CPU tests use. The flash and dense paths take a causal
-``window`` and fewer key-value heads than query heads (grouped-query
-attention); K and V are never repeated in memory. Lives in ops/ (neutral
+``window`` or a learned selection of keys (``select``) and fewer key-value
+heads than query heads (grouped-query attention); K and V are never
+repeated in memory. Lives in ops/ (neutral
 layer) so model modules don't import each other for infrastructure.
 
 ``use_flash=None`` (the default) auto-dispatches: on TPU, shapes the
@@ -89,9 +90,36 @@ def _causal_band(q_pos, k_pos, window):
     return keep
 
 
+def selected_attention(q, k, v, select, *, dtype, use_flash=None):
+    """Causal attention over a LEARNED selection of keys
+    (ops/sparse_attention.py): ``select`` = (qi [b, s, hi, di], ki [b, s,
+    di], wi [b, s, hi], tau [b, s]) — the indexer's queries, keys and head
+    weights and each query's threshold (``index_thresholds``); query t
+    reads the keys s <= t whose score I[t, s] reaches tau[t]. Returns
+    (context [b, s, heads, dim], kl [b, s], kept [b, s]): the context, the
+    indexer's loss per row (its only source of gradient) and the number of
+    keys each query read. The same dispatch as :func:`attention_context`:
+    the Pallas kernels on a TPU for shapes they take (``use_flash=True``
+    forces them, in the interpreter on the CPU), else dense."""
+    from edl_tpu.ops import sparse_attention
+    if use_flash is None:
+        use_flash = (
+            flash_dispatch_reason(q.shape[1], q.shape[-1],
+                                  seq_kv=k.shape[1]) is None
+            and sparse_attention.kernel_reason(
+                q.shape[1], k.shape[2], q.shape[-1], select[0].shape[-1],
+                k.dtype.itemsize) is None)
+    if use_flash:
+        out, kl, kept = sparse_attention.select_attend(
+            q, k, v, select, interpret=jax.default_backend() == "cpu")
+    else:
+        out, kl, kept = sparse_attention.dense_select_attend(q, k, v, select)
+    return out.astype(dtype), kl, kept
+
+
 def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
                       use_ring=False, use_flash=None, mesh=None,
-                      window=None):
+                      window=None, select=None):
     """The shared attention-impl dispatch for BERT, GPT and the sparse
     decoder: in-shard ring (already inside a shard_map over
     ``ring_axis``) / ring over the sp mesh axis / Pallas flash kernel /
@@ -103,6 +131,11 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
     head ``i // (heads // kv_heads)``). ``window`` (flash and dense paths,
     needs ``causal``): a query reads its own position and the
     ``window - 1`` before it; ``None`` reads the whole causal prefix.
+    ``select`` (flash and dense paths, needs ``causal``, excludes
+    ``window``, a padding mask and the ring): (qi, ki, wi, tau) of a learned
+    indexer — a query reads the keys of its causal prefix whose index score
+    reaches its threshold (:func:`selected_attention`, which also returns
+    the indexer's loss and the keys kept).
 
     ``use_flash``: ``True`` forces the Pallas flash kernel, ``False``
     forces dense, ``None`` (default) auto-dispatches by
@@ -119,6 +152,19 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
                          % (heads, kv_heads))
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
+    if select is not None:
+        if not causal:
+            raise ValueError("a selection needs causal=True")
+        if window is not None:
+            raise ValueError("a selection excludes a window")
+        if ring_axis or use_ring:
+            raise ValueError("ring attention takes no selection")
+        if mask is not None:
+            raise ValueError("a selection takes no padding mask")
+        if q.shape[1] != k.shape[1]:
+            raise ValueError("a selection needs square q/kv")
+        return selected_attention(q, k, v, select, dtype=dtype,
+                                  use_flash=use_flash)[0]
     if ring_axis or use_ring:
         if window is not None or group > 1:
             raise ValueError("ring attention takes no window and equal "

@@ -28,7 +28,10 @@ repeated); a causal ``window`` keeps a query's own position and the
 ``window - 1`` before it, and blocks outside the band are neither loaded
 nor computed, in the forward and in the backward kernels (``mha`` takes
 the model's [batch, seq, heads, dim] layout, does the regrouping, and
-keeps its residuals in that layout).
+keeps its residuals in that layout). A learned SELECTION of single keys
+(``select=``) is no band: ``mha`` and ``flash_attention`` hand it to the
+masked kernels of ops/sparse_attention.py, which share this file's
+conventions and helpers; without one, nothing here changes.
 """
 
 import functools
@@ -747,25 +750,47 @@ def _attend_bwd(causal, sm_scale, block_q, block_k, interpret, window, group,
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+def _selected(q, k, v, causal, sm_scale, window, interpret, select):
+    """The kernels of a learned selection live in ops/sparse_attention.py
+    under names of their own (`dsa_*`): one rebuilt tile of the index
+    scores serves every query head, which the band kernels' one-head
+    programs cannot share. Model layout in and out."""
+    from edl_tpu.ops import sparse_attention
+    if not causal or window is not None:
+        raise ValueError("a selection needs causal=True and no window")
+    return sparse_attention.select_attend(q, k, v, select, sm_scale=sm_scale,
+                                          interpret=interpret)[0]
+
+
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
-                    block_k=128, interpret=False, window=None, group=1):
+                    block_k=128, interpret=False, window=None, group=1,
+                    select=None):
     """Blockwise exact attention; k/v are [batch, kv_heads, seq, dim] and
     q/out [batch, kv_heads, group * seq, dim]: the ``group`` query heads
     of a kv head one run of the sequence after another (``group=1``: the
     usual [batch, heads, seq, dim]). ``window`` (needs ``causal``) keeps,
-    for each query, its own position and the ``window - 1`` before it."""
+    for each query, its own position and the ``window - 1`` before it;
+    ``select`` = (qi, ki, wi, tau) in the models' layout keeps the keys a
+    learned indexer chose (ops/sparse_attention.py)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if select is not None:
+        return _kernel_layout(_selected(
+            _model_layout(q, group), _model_layout(k), _model_layout(v),
+            causal, sm_scale, window, interpret, select), group)
     return _attend(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    window, group, False)
 
 
 def mha(q, k, v, causal=False, sm_scale=None, window=None, block_q=128,
-        block_k=128, interpret=False):
+        block_k=128, interpret=False, select=None):
     """The same for [batch, seq, heads, dim] arrays (the model code's
     layout). k and v may have fewer heads than q (grouped-query attention:
     query head i reads kv head i // group); they are not repeated in
     memory."""
+    if select is not None:
+        return _selected(q, k, v, causal, sm_scale, window, interpret,
+                         select)
     hq, hkv = q.shape[2], k.shape[2]
     group = hq // hkv
     if group * hkv != hq:

@@ -1,0 +1,603 @@
+"""Attention over a LEARNED selection of keys (DeepSeek-Sparse-Attention,
+arXiv:2512.02556): an indexer scores every causal (query, key) pair,
+
+    I[t, s] = sum_j wi[t, j] * relu(qi[t, j] . ki[s]) * (heads_i * dim_i) ** -0.5
+
+each query keeps the ``k`` keys with the largest scores (all of them while
+it has at most ``k``), attention runs over the kept keys only, and the
+indexer learns from KL(mean over heads of the attention's probabilities ||
+softmax of I over the kept keys). The kept set is not a band, so no block
+of the causal triangle can be skipped: the kernels below are MASKED
+kernels — they visit every causal tile, rebuild the tile of I from (qi,
+ki, wi) in VMEM, compare it with the row's threshold ``tau`` and go on as
+the flash kernels do. No [seq, seq] array crosses HBM, for the mask, the
+scores or the probabilities.
+
+Four Pallas kernels, each gridded over (batch, query blocks) with k, v and
+ki of the sequence whole in VMEM and every held query head of the block
+served by one rebuilt tile of I:
+
+- ``dsa_index_tau``: the thresholds, EXACT — the row's scores go to VMEM as
+  order-preserving integers and the k-th largest is found bit by bit, 32
+  counting passes, no sort and no approximation;
+- ``dsa_fwd``: online-softmax attention over the kept keys; beside the
+  result the row statistics lse (per head), the indexer's own lse over the
+  kept keys, and the number of keys kept (what the step really ran);
+- ``dsa_index_kl``: the KL term per row (needs the final lse, so a second
+  pass over the tiles);
+- ``dsa_bwd``: one pass for dq, dk, dv AND the indexer's dqi, dki, dwi
+  (pi - mean p through relu and wi): each tile's p is computed once.
+
+``select_attend`` ties them into one ``custom_vjp``; ``index_thresholds``
+and ``dense_select_attend`` are the plain ``jax.numpy`` twins the dense
+dispatch and the tests use. Products take their operands in the dtype they
+arrive in with float32 accumulation; scores, thresholds, probabilities and
+statistics are float32.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from edl_tpu.ops.flash_attention import _NEG_INF, _NN, _NT, _TN, _dot, _flip
+
+#: the kernels' names in a device trace
+TAU_NAME = "dsa_index_tau"
+FWD_NAME = "dsa_fwd"
+KL_NAME = "dsa_index_kl"
+BWD_NAME = "dsa_bwd"
+
+#: `checkpoint_name`s of the indexer's queries, keys and head weights and of
+#: the thresholds: a layer under remat keeps the choice WITH what it was
+#: made from (35 MiB a layer at 16384 positions). A recomputed score that
+#: differs in its last bit from the one its threshold was found among moves
+#: a key across it, as a recomputed top-k of the router breaks a near tie
+#: the other way.
+SAVED_UNDER_REMAT = ("attn.index_q", "attn.index_k", "attn.index_w",
+                     "attn.tau")
+
+_BLOCK = 512
+_TAU_BLOCK_Q = 128
+_VMEM_LIMIT = 100 << 20
+#: k, v, ki and the float32 accumulators of dk, dv, dki stay in VMEM for
+#: the whole sequence: what that may cost (bytes, the backward's count)
+_RESIDENT_LIMIT = 72 << 20
+_INT_MIN = np.int32(-2 ** 31)
+
+
+def index_scale(heads_i, dim_i):
+    return float(heads_i * dim_i) ** -0.5
+
+
+def index_scores(qi, ki, wi):
+    """I [b, t, s] float32 from qi [b, s, hi, di], ki [b, s, di], wi
+    [b, s, hi]: products in the operands' dtype, float32 accumulation, the
+    heads summed in order (the order the kernels sum them in)."""
+    hi, di = qi.shape[2], qi.shape[3]
+    w = wi.astype(jnp.float32) * index_scale(hi, di)
+    acc = jnp.zeros((qi.shape[0], qi.shape[1], ki.shape[1]), jnp.float32)
+    for j in range(hi):
+        x = jnp.einsum("btd,bsd->bts", qi[:, :, j], ki,
+                       preferred_element_type=jnp.float32)
+        acc = acc + jnp.maximum(x, 0.0) * w[:, :, j, None]
+    return acc
+
+
+def _causal(s):
+    pos = jnp.arange(s)
+    return pos[:, None] >= pos[None, :]
+
+
+def _kept(scores, tau):
+    """[b, t, s] bool: key s of the causal prefix reaches query t's
+    threshold."""
+    return jnp.logical_and(_causal(scores.shape[1])[None],
+                           lax.stop_gradient(scores)
+                           >= lax.stop_gradient(tau)[..., None])
+
+
+def selection_mask(qi, ki, wi, tau):
+    """[b, t, s] bool: key s is read by query t."""
+    return _kept(index_scores(qi, ki, wi), tau)
+
+
+def _block_for(s, block):
+    for b in (block, block // 2):
+        if b and s % b == 0:
+            return b
+    return s
+
+
+def thresholds_kernel_reason(seq_len, index_dim, itemsize=2):
+    """None where the thresholds' kernel takes the shape (ki and a query
+    block's keys of the whole sequence in VMEM), else why not."""
+    if seq_len % 8:
+        return "seq_len %d not a multiple of 8" % seq_len
+    need = seq_len * (max(index_dim, 128) * 2 * itemsize + _TAU_BLOCK_Q * 4)
+    if need > _RESIDENT_LIMIT:
+        return "ki and a block's scores of %d positions do not fit VMEM" \
+            % seq_len
+    return None
+
+
+def kernel_reason(seq_len, kv_heads, head_dim, index_dim, itemsize=2):
+    """None where the attention kernels take the shape, else why not: k, v
+    and ki twice over (double-buffered) and the backward's float32 dk, dv
+    and dki, a VMEM lane tile wide at least."""
+    if seq_len % 8:
+        return "seq_len %d not a multiple of 8" % seq_len
+    need = seq_len * (kv_heads * head_dim * (4 * itemsize + 8)
+                      + max(index_dim, 128) * (4 * itemsize + 4))
+    if need > _RESIDENT_LIMIT:
+        return ("k, v and ki of %d positions do not fit VMEM whole (%d "
+                "bytes with the backward's accumulators)" % (seq_len, need))
+    return None
+
+
+# -- inside the kernels ----------------------------------------------------
+
+def _columns(ref, n):
+    """The n columns [rows, 1] of a [1, rows, n] block."""
+    x = ref[0]
+    return [x[:, j:j + 1] for j in range(n)]
+
+
+def _row_columns(ref, n):
+    """The n rows of a [1, n, rows] block, each as a column [rows, 1]."""
+    return [_flip(ref[0, j:j + 1, :]) for j in range(n)]
+
+
+def _index_tile(qi_ref, ki_blk, wi_cols):
+    """One [TQ, TK] tile of I: the same sums in the same order in every
+    kernel, so that `I >= tau` decides alike in all of them."""
+    acc = jnp.zeros((qi_ref.shape[2], ki_blk.shape[0]), jnp.float32)
+    for j, w in enumerate(wi_cols):
+        acc = acc + jnp.maximum(_dot(qi_ref[0, j], ki_blk, _NT), 0.0) * w
+    return acc
+
+
+def _ordered(x):
+    """float32 <-> int32 whose signed order is the floats' order (its own
+    inverse)."""
+    return x ^ ((x >> 31) & np.int32(0x7FFFFFFF))
+
+
+def _positions(q_lo, block_q, k_lo, block_k):
+    return (q_lo + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0),
+            k_lo + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
+
+
+def _n_blocks(q_lo, block_q, block_k):
+    """kv blocks up to and with the one that holds the block's last row."""
+    return lax.div(q_lo + block_q - 1, block_k) + 1
+
+
+def _tau_kernel(qi_ref, ki_ref, wi_ref, tau_ref, keys_ref, *, block_q,
+                block_k, topk, hi):
+    q_lo = pl.program_id(1) * block_q
+    wi_cols = _columns(wi_ref, hi)
+    last = _n_blocks(q_lo, block_q, block_k)
+    lanes = min(128, block_k)
+
+    def fill(kb, carry):
+        k_lo = pl.multiple_of(kb * block_k, block_k)
+        q_pos, k_pos = _positions(q_lo, block_q, k_lo, block_k)
+        tile = _index_tile(qi_ref, ki_ref[0, pl.ds(k_lo, block_k), :],
+                           wi_cols)
+        key = _ordered(lax.bitcast_convert_type(tile, jnp.int32))
+        keys_ref[:, pl.ds(k_lo, block_k)] = jnp.where(q_pos >= k_pos, key,
+                                                      _INT_MIN)
+        return carry
+
+    lax.fori_loop(0, last, fill, 0)
+
+    def count(cand):
+        """[TQ, 1] float32: keys of the row at or above its candidate."""
+        def body(kb, c):
+            k_lo = pl.multiple_of(kb * block_k, block_k)
+            hit = jnp.where(keys_ref[:, pl.ds(k_lo, block_k)] >= cand, 1.0,
+                            0.0)
+            for u in range(block_k // lanes):
+                c = c + hit[:, u * lanes:(u + 1) * lanes]
+            return c
+        c = lax.fori_loop(0, last, body,
+                          jnp.zeros((block_q, lanes), jnp.float32))
+        return c.sum(axis=1, keepdims=True)
+
+    # the k-th largest key, from the sign bit down: the largest value that
+    # at least k keys of the row reach
+    want = float(topk)
+    zero = jnp.zeros((block_q, 1), jnp.int32)
+    prefix = jnp.where(count(zero) >= want, zero, _INT_MIN)
+
+    def bit(n, prefix):
+        cand = prefix | lax.shift_left(jnp.int32(1), 30 - n)
+        return jnp.where(count(cand) >= want, cand, prefix)
+
+    prefix = lax.fori_loop(0, 31, bit, prefix)
+    tau = lax.bitcast_convert_type(_ordered(prefix), jnp.float32)
+    q_pos = q_lo + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    tau_ref[0] = _flip(jnp.where(q_pos < topk, -jnp.inf, tau))
+
+
+def _keep_tile(qi_ref, ki_ref, wi_cols, tau, q_lo, k_lo, block_q, block_k):
+    q_pos, k_pos = _positions(q_lo, block_q, k_lo, block_k)
+    tile = _index_tile(qi_ref, ki_ref[0, pl.ds(k_lo, block_k), :], wi_cols)
+    return tile, jnp.logical_and(q_pos >= k_pos, tile >= tau)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, wi_ref, tau_ref, o_ref,
+                lse_ref, lsei_ref, kept_ref, acc_ref, m_ref, l_ref, *,
+                block_q, block_k, sm_scale, heads, group, hi):
+    q_lo = pl.program_id(1) * block_q
+    wi_cols = _columns(wi_ref, hi)
+    tau = _flip(tau_ref[0])
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    def body(kb, carry):
+        mi, li, cnt = carry
+        k_lo = pl.multiple_of(kb * block_k, block_k)
+        tile, keep = _keep_tile(qi_ref, ki_ref, wi_cols, tau, q_lo, k_lo,
+                                block_q, block_k)
+        # the indexer's own softmax statistic over the kept keys
+        mi_new = jnp.maximum(mi, jnp.where(keep, tile, _NEG_INF).max(
+            axis=1, keepdims=True))
+        li = li * jnp.exp(mi - mi_new) + jnp.where(
+            keep, jnp.exp(tile - mi_new), 0.0).sum(axis=1, keepdims=True)
+        cnt = cnt + jnp.where(keep, 1.0, 0.0).sum(axis=1, keepdims=True)
+        for h in range(heads):
+            k_blk = k_ref[0, h // group, pl.ds(k_lo, block_k), :]
+            v_blk = v_ref[0, h // group, pl.ds(k_lo, block_k), :]
+            s = jnp.where(keep, _dot(q_ref[0, h], k_blk, _NT) * sm_scale,
+                          _NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + _dot(p.astype(v_blk.dtype),
+                                                  v_blk, _NN)
+        return mi_new, li, cnt
+
+    col = lambda x: jnp.full((block_q, 1), x, jnp.float32)  # noqa: E731
+    mi, li, cnt = lax.fori_loop(0, _n_blocks(q_lo, block_q, block_k), body,
+                                (col(_NEG_INF), col(0.0), col(0.0)))
+    for h in range(heads):
+        l = jnp.maximum(l_ref[h], 1e-30)
+        o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
+        lse_ref[0, h:h + 1, :] = _flip(m_ref[h] + jnp.log(l))
+    lsei_ref[0] = _flip(mi + jnp.log(jnp.maximum(li, 1e-30)))
+    kept_ref[0] = _flip(cnt)
+
+
+def _kl_kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, wi_ref, tau_ref,
+               lsei_ref, kl_ref, *, block_q, block_k, sm_scale, heads, group,
+               hi):
+    q_lo = pl.program_id(1) * block_q
+    wi_cols = _columns(wi_ref, hi)
+    tau = _flip(tau_ref[0])
+    lse_cols = _row_columns(lse_ref, heads)
+
+    def body(kb, carry):
+        a, b, total = carry
+        k_lo = pl.multiple_of(kb * block_k, block_k)
+        tile, keep = _keep_tile(qi_ref, ki_ref, wi_cols, tau, q_lo, k_lo,
+                                block_q, block_k)
+        pbar = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(heads):
+            k_blk = k_ref[0, h // group, pl.ds(k_lo, block_k), :]
+            pbar = pbar + jnp.where(keep, jnp.exp(
+                _dot(q_ref[0, h], k_blk, _NT) * sm_scale - lse_cols[h]), 0.0)
+        pbar = pbar * (1.0 / heads)
+        a = a + (pbar * jnp.log(jnp.maximum(pbar, 1e-37))).sum(
+            axis=1, keepdims=True)
+        b = b + (pbar * tile).sum(axis=1, keepdims=True)
+        return a, b, total + pbar.sum(axis=1, keepdims=True)
+
+    zero = jnp.zeros((block_q, 1), jnp.float32)
+    a, b, total = lax.fori_loop(0, _n_blocks(q_lo, block_q, block_k), body,
+                                (zero, zero, zero))
+    # sum pbar (log pbar - (I - lse_I)) over the kept keys
+    kl_ref[0] = _flip(a - b + _flip(lsei_ref[0]) * total)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
+                ki_ref, wi_ref, tau_ref, lsei_ref, gkl_ref, dq_ref, dk_ref,
+                dv_ref, dqi_ref, dki_ref, dwi_ref, dq_acc, dk_acc, dv_acc,
+                dqi_acc, dki_acc, *, block_q, block_k, sm_scale, heads,
+                group, hi):
+    i = pl.program_id(1)
+    q_lo = i * block_q
+
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dki_acc[:] = jnp.zeros_like(dki_acc)
+
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+    dqi_acc[:] = jnp.zeros_like(dqi_acc)
+    wi_cols = _columns(wi_ref, hi)
+    tau = _flip(tau_ref[0])
+    lsei, gkl = _flip(lsei_ref[0]), _flip(gkl_ref[0])
+    lse_cols = _row_columns(lse_ref, heads)
+    delta_cols = _row_columns(delta_ref, heads)
+    head_lane = lax.broadcasted_iota(jnp.int32, (1, hi), 1)
+
+    def body(kb, dwi):
+        k_lo = pl.multiple_of(kb * block_k, block_k)
+        tile, keep = _keep_tile(qi_ref, ki_ref, wi_cols, tau, q_lo, k_lo,
+                                block_q, block_k)
+        rows = pl.ds(k_lo, block_k)
+        pbar = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(heads):
+            kv = h // group
+            k_blk, v_blk = k_ref[0, kv, rows, :], v_ref[0, kv, rows, :]
+            q, do = q_ref[0, h], do_ref[0, h]
+            p = jnp.where(keep, jnp.exp(_dot(q, k_blk, _NT) * sm_scale
+                                        - lse_cols[h]), 0.0)
+            ds = (p * (_dot(do, v_blk, _NT) - delta_cols[h])).astype(
+                k_blk.dtype)
+            dq_acc[h] += _dot(ds, k_blk, _NN)
+            dk_acc[kv, rows, :] += _dot(ds, q, _TN)
+            dv_acc[kv, rows, :] += _dot(p.astype(do.dtype), do, _TN)
+            pbar = pbar + p
+        # d KL / d I = softmax of I over the kept keys - mean p, there
+        di = gkl * (jnp.where(keep, jnp.exp(tile - lsei), 0.0)
+                    - pbar * (1.0 / heads))
+        ki_blk = ki_ref[0, rows, :]
+        for j in range(hi):
+            qi = qi_ref[0, j]
+            x = _dot(qi, ki_blk, _NT)
+            dwi = dwi + jnp.where(head_lane == j, (
+                di * jnp.maximum(x, 0.0)).sum(axis=1, keepdims=True), 0.0)
+            dx = jnp.where(x > 0.0, di * wi_cols[j], 0.0).astype(qi.dtype)
+            dqi_acc[j] += _dot(dx, ki_blk, _NN)
+            dki_acc[rows, :] += _dot(dx, qi, _TN)
+        return dwi
+
+    dwi = lax.fori_loop(0, _n_blocks(q_lo, block_q, block_k), body,
+                        jnp.zeros((block_q, hi), jnp.float32))
+    dq_ref[0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+    dqi_ref[0] = dqi_acc[:].astype(dqi_ref.dtype)
+    dwi_ref[0] = dwi
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dki_ref[0] = dki_acc[:].astype(dki_ref.dtype)
+
+
+# -- the calls -------------------------------------------------------------
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _heads_first(x):
+    """[b, s, h, d] -> [b, h, s, d]"""
+    return x.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _thresholds(qi, ki, wi, topk, block_q, block_k, interpret):
+    """qi [b, hi, s, di], ki [b, s, di], wi [b, s, hi] (scaled, float32)
+    -> tau [b, 1, s]."""
+    b, hi, s, di = qi.shape
+    return pl.pallas_call(
+        functools.partial(_tau_kernel, block_q=block_q, block_k=block_k,
+                          topk=topk, hi=hi),
+        grid=(b, s // block_q),
+        in_specs=[pl.BlockSpec((1, hi, block_q, di),
+                               lambda i, j: (i, 0, j, 0)),
+                  pl.BlockSpec((1, s, di), lambda i, j: (i, 0, 0)),
+                  pl.BlockSpec((1, block_q, hi), lambda i, j: (i, j, 0))],
+        out_specs=pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, s), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, s), jnp.int32)],
+        compiler_params=_params("parallel", "parallel"),
+        interpret=interpret, name=TAU_NAME)(qi, ki, wi)
+
+
+def _scaled(wi, qi):
+    return wi.astype(jnp.float32) * index_scale(qi.shape[2], qi.shape[3])
+
+
+def index_thresholds(qi, ki, wi, topk, use_kernel=None, interpret=False,
+                     block=None):
+    """tau [b, s] float32: for each query the ``topk``-th largest of its
+    causal scores I[t, s <= t], -inf while it has at most ``topk`` keys —
+    so that {s <= t : I[t, s] >= tau[t]} are the keys it keeps, exact
+    ties at tau all kept. Exact on either path (``use_kernel``: the
+    counting kernel; default on a TPU for shapes it takes — else
+    ``lax.top_k`` over the materialised scores). No gradient."""
+    qi, ki, wi = lax.stop_gradient((qi, ki, wi))
+    b, s, hi, di = qi.shape
+    if use_kernel is None:
+        use_kernel = (jax.default_backend() == "tpu"
+                      and thresholds_kernel_reason(
+                          s, di, ki.dtype.itemsize) is None)
+    if use_kernel:
+        block_q = _block_for(s, block or _TAU_BLOCK_Q)
+        block_k = _block_for(s, block or _BLOCK)
+        return _thresholds(_heads_first(qi), ki, _scaled(wi, qi), int(topk),
+                           block_q, block_k, interpret)[:, 0]
+    if s <= topk:
+        return jnp.full((b, s), -jnp.inf, jnp.float32)
+    scores = jnp.where(_causal(s)[None], index_scores(qi, ki, wi), -jnp.inf)
+    kth = lax.top_k(scores, topk)[0][..., -1]
+    return jnp.where(jnp.arange(s)[None] < topk, -jnp.inf, kth)
+
+
+def _specs(b, s, heads, kv_heads, d, hi, di, block_q):
+    """The block specs the three attention kernels share."""
+    at_q = lambda i, j: (i, 0, j, 0)       # noqa: E731
+    whole = lambda i, j: (i, 0, 0, 0)      # noqa: E731
+    return {
+        "q": pl.BlockSpec((1, heads, block_q, d), at_q),
+        "kv": pl.BlockSpec((1, kv_heads, s, d), whole),
+        "qi": pl.BlockSpec((1, hi, block_q, di), at_q),
+        "ki": pl.BlockSpec((1, s, di), lambda i, j: (i, 0, 0)),
+        "wi": pl.BlockSpec((1, block_q, hi), lambda i, j: (i, j, 0)),
+        "row": pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+        "rows": pl.BlockSpec((1, heads, block_q), lambda i, j: (i, 0, j)),
+    }
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _forward(q, k, v, qi, ki, wi, tau, sm_scale, block, interpret):
+    """Kernel layouts: q [b, h, s, d], k, v [b, hkv, s, d], qi [b, hi, s,
+    di], ki [b, s, di], wi [b, s, hi], tau [b, 1, s]. Returns (out, lse
+    [b, h, s], lse_i, kept, kl: each [b, 1, s])."""
+    b, heads, s, d = q.shape
+    kv_heads, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    sp = _specs(b, s, heads, kv_heads, d, hi, di, block)
+    shape = dict(block_q=block, block_k=block, sm_scale=sm_scale,
+                 heads=heads, group=heads // kv_heads, hi=hi)
+    row = jax.ShapeDtypeStruct((b, 1, s), jnp.float32)
+    rows = jax.ShapeDtypeStruct((b, heads, s), jnp.float32)
+    call = functools.partial(pl.pallas_call, grid=(b, s // block),
+                             compiler_params=_params("parallel", "parallel"),
+                             interpret=interpret)
+    out, lse, lse_i, kept = call(
+        functools.partial(_fwd_kernel, **shape),
+        in_specs=[sp["q"], sp["kv"], sp["kv"], sp["qi"], sp["ki"], sp["wi"],
+                  sp["row"]],
+        out_specs=(sp["q"], sp["rows"], sp["row"], sp["row"]),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), rows, row, row),
+        scratch_shapes=[pltpu.VMEM((heads, block, d), jnp.float32),
+                        pltpu.VMEM((heads, block, 1), jnp.float32),
+                        pltpu.VMEM((heads, block, 1), jnp.float32)],
+        name=FWD_NAME)(q, k, v, qi, ki, wi, tau)
+    kl = call(
+        functools.partial(_kl_kernel, **shape),
+        in_specs=[sp["q"], sp["kv"], sp["rows"], sp["qi"], sp["ki"],
+                  sp["wi"], sp["row"], sp["row"]],
+        out_specs=sp["row"], out_shape=row,
+        name=KL_NAME)(q, k, lse, qi, ki, wi, tau, lse_i)
+    return out, lse, lse_i, kept, kl
+
+
+@functools.partial(jax.jit, static_argnums=(12, 13, 14))
+def _backward(q, k, v, do, lse, delta, qi, ki, wi, tau, lse_i, gkl,
+              sm_scale, block, interpret):
+    b, heads, s, d = q.shape
+    kv_heads, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    sp = _specs(b, s, heads, kv_heads, d, hi, di, block)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block, block_k=block,
+                          sm_scale=sm_scale, heads=heads,
+                          group=heads // kv_heads, hi=hi),
+        grid=(b, s // block),
+        in_specs=[sp["q"], sp["kv"], sp["kv"], sp["q"], sp["rows"],
+                  sp["rows"], sp["qi"], sp["ki"], sp["wi"], sp["row"],
+                  sp["row"], sp["row"]],
+        out_specs=(sp["q"], sp["kv"], sp["kv"], sp["qi"], sp["ki"],
+                   sp["wi"]),
+        out_shape=(like(q), like(k), like(v), like(qi), like(ki), like(wi)),
+        scratch_shapes=[pltpu.VMEM((heads, block, d), jnp.float32),
+                        pltpu.VMEM((kv_heads, s, d), jnp.float32),
+                        pltpu.VMEM((kv_heads, s, d), jnp.float32),
+                        pltpu.VMEM((hi, block, di), jnp.float32),
+                        pltpu.VMEM((s, di), jnp.float32)],
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=interpret, name=BWD_NAME)(
+            q, k, v, do, lse, delta, qi, ki, wi, tau, lse_i, gkl)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _select_attend(q, k, v, qi, ki, wi, tau, sm_scale, block, interpret):
+    return _select_attend_fwd(q, k, v, qi, ki, wi, tau, sm_scale, block,
+                              interpret)[0]
+
+
+def _select_attend_fwd(q, k, v, qi, ki, wi, tau, sm_scale, block, interpret):
+    """Arguments, results and residuals in the models' layout (the kernels'
+    copies live for the length of a kernel, as in ops/flash_attention)."""
+    out, lse, lse_i, kept, kl = _forward(
+        _heads_first(q), _heads_first(k), _heads_first(v), _heads_first(qi),
+        ki, _scaled(wi, qi), tau[:, None], sm_scale, block, interpret)
+    out = _heads_first(out)
+    return ((out, kl[:, 0], kept[:, 0]),
+            (q, k, v, qi, ki, wi, tau, out, lse, lse_i))
+
+
+def _select_attend_bwd(sm_scale, block, interpret, res, g):
+    q, k, v, qi, ki, wi, tau, out, lse, lse_i = res
+    g_out, g_kl, _ = g
+    delta = jnp.sum(g_out.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1)
+    q, k, v, qi = lax.optimization_barrier((q, k, v, qi))
+    dq, dk, dv, dqi, dki, dwi = _backward(
+        _heads_first(q), _heads_first(k), _heads_first(v),
+        _heads_first(g_out.astype(q.dtype)), lse, delta, _heads_first(qi),
+        ki, _scaled(wi, qi), tau[:, None], lse_i,
+        g_kl.astype(jnp.float32)[:, None], sm_scale, block, interpret)
+    scale = index_scale(qi.shape[2], qi.shape[3])
+    return (_heads_first(dq), _heads_first(dk), _heads_first(dv),
+            _heads_first(dqi), dki, (dwi * scale).astype(wi.dtype),
+            jnp.zeros_like(tau))
+
+
+_select_attend.defvjp(_select_attend_fwd, _select_attend_bwd)
+
+
+def select_attend(q, k, v, select, sm_scale=None, interpret=False,
+                  block=None):
+    """q [b, s, h, d]; k, v [b, s, hkv, d] (query head i reads kv head
+    ``i // (h // hkv)``); ``select`` = (qi [b, s, hi, di], ki [b, s, di],
+    wi [b, s, hi], tau [b, s]). Returns (out [b, s, h, d], kl [b, s]:
+    KL(stop_gradient(mean over heads of p) || softmax of I over the kept
+    keys), kept [b, s]: keys each query read). Gradients: out -> q, k, v
+    only (the choice is discrete); kl -> qi, ki, wi only."""
+    qi, ki, wi, tau = select
+    b, s, heads, d = q.shape
+    why = kernel_reason(s, k.shape[2], d, qi.shape[3], k.dtype.itemsize)
+    if why:
+        raise ValueError("no selection kernel for this shape: " + why)
+    if heads % k.shape[2]:
+        raise ValueError("%d query heads do not divide over %d kv heads"
+                         % (heads, k.shape[2]))
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    return _select_attend(q, k, v, qi, ki, wi,
+                          lax.stop_gradient(tau).astype(jnp.float32),
+                          sm_scale, _block_for(s, block or _BLOCK),
+                          interpret)
+
+
+def dense_select_attend(q, k, v, select, sm_scale=None):
+    """The same in plain ``jax.numpy`` with [seq, seq] arrays (the dense
+    dispatch; what the kernels are tested against)."""
+    qi, ki, wi, tau = select
+    b, s, heads, d = q.shape
+    kv_heads = k.shape[2]
+    group = heads // kv_heads
+    if sm_scale is None:
+        sm_scale = d ** -0.5
+    scores_i = index_scores(qi, ki, wi)
+    keep = _kept(scores_i, tau)
+    qg = q.reshape(b, s, kv_heads, group, d)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+                        k.astype(jnp.float32)) * sm_scale
+    probs = jax.nn.softmax(jnp.where(keep[:, None, None], scores, _NEG_INF),
+                           axis=-1)
+    probs = jnp.where(keep[:, None, None], probs, 0.0)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
+    pbar = lax.stop_gradient(probs.mean(axis=(1, 2)))
+    log_pi = jax.nn.log_softmax(jnp.where(keep, scores_i, _NEG_INF), axis=-1)
+    kl = jnp.sum(jnp.where(keep, pbar * (jnp.log(jnp.maximum(pbar, 1e-37))
+                                         - log_pi), 0.0), axis=-1)
+    return (out.reshape(b, s, heads, d).astype(q.dtype), kl,
+            keep.sum(axis=-1).astype(jnp.float32))

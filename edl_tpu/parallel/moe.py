@@ -311,14 +311,19 @@ def _take_rows_bwd(inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
+#: the gate's activation in a gated-linear-unit expert, by name
+EXPERT_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
 def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
-                     tm=ROW_TILE, interpret=None):
-    """The held experts' part of a top-k ReGLU expert layer.
+                     tm=ROW_TILE, interpret=None, activation="relu"):
+    """The held experts' part of a top-k gated-linear-unit expert layer:
+    ReGLU (``activation="relu"``, the default) or SwiGLU (``"silu"``).
 
     u [T, D] (the layer's normed input), idx/p [T, k] from
     :func:`route_top_k`, w_gate_up [held, D, 2 * F] (gate then up),
     w_down [held, F, D]. Returns (m [T, D] in u's dtype:
-    sum over the chosen held experts of p * down(relu(gate u) * (up u)),
+    sum over the chosen held experts of p * down(act(gate u) * (up u)),
     counters: float32 scalars ``rows_held``, ``load_max``, ``load_mean``,
     ``tokens_unserved``, ``rows_dropped``). ``interpret`` (default: on
     the CPU backend, as the attention dispatch does) runs the Pallas
@@ -337,7 +342,8 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
                                n_used=plan["n_used"], tm=tm,
                                interpret=interpret)
         gu = checkpoint_name(gm(rows, w_gate_up), SAVED_UNDER_REMAT[1])
-        hid = jax.nn.relu(gu[:, :f2 // 2]) * gu[:, f2 // 2:]
+        act = EXPERT_ACTIVATIONS[activation]
+        hid = act(gu[:, :f2 // 2]) * gu[:, f2 // 2:]
         y = checkpoint_name(gm(hid, w_down), SAVED_UNDER_REMAT[2])
     with jax.named_scope("moe.combine"):
         picked = _take_rows(y, plan["slot"], plan["slot_choice"][None, :])

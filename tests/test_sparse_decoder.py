@@ -409,3 +409,341 @@ def test_train_flops_counts_the_band_and_the_expected_rows():
     assert fam.band_pairs(8192) == 8192 * 8193 / 2
     assert fam.band_pairs(8192, 4096) == 4096 * 4097 / 2 + 4096 * 4096
     assert fam.expected_expert_rows(view["config"], 16384) == 12288
+
+
+# == keye-vl2-30b-a3b: learned selection, post-attention router, SiLU =======
+
+KEYE = "keye-vl2-30b-a3b"
+
+
+def _keye_cfg():
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         KEYE + ".json"))
+    return dict(cfg, **cfg["tiny"])
+
+
+@pytest.fixture(scope="module")
+def keye():
+    cfg = _keye_cfg()
+    ref = harness.load_module("reference", KEYE)
+    fam = harness.load_module("program", cfg["family"])
+    w = ref.init_weights(cfg, jax.random.PRNGKey(3))
+    ids = jax.random.randint(jax.random.PRNGKey(4), (2, 32), 0,
+                             cfg["vocab_size"], jnp.int32)
+    return cfg, ref, fam, w, {"input_ids": ids}
+
+
+def _keye_loss_and_grad(cfg, fam, w, batch, dtype, remat=True,
+                        use_flash=None):
+    model = fam.build_model(cfg, {"remat": remat}).clone(
+        dtype=dtype, use_flash=use_flash)
+    _, _, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
+    params, _ = fam.to_program(w, cfg)
+    (loss, extra), grads = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, extra, batch, None), has_aux=True))(params)
+    return loss, grads, extra
+
+
+def _keye_leaf_names():
+    cfg = _keye_cfg()
+    fam = harness.load_module("program", cfg["family"])
+    return sorted(_leaves(fam.train_parts(cfg, {"remat": True})[2][0]))
+
+
+INDEXER_LEAVES = ("index_query", "index_key", "index_weight")
+
+
+@pytest.fixture(scope="module", params=["dense", "kernels"])
+def keye_float32(request, keye):
+    cfg, ref, fam, w, batch = keye
+    want_loss, g = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg))(w)
+    loss, grads, extra = _keye_loss_and_grad(
+        cfg, fam, w, batch, jnp.float32,
+        use_flash=request.param == "kernels")
+    return (loss, _leaves(grads), extra, want_loss,
+            _leaves(fam.to_program(g, cfg)[0]))
+
+
+def test_keye_loss_and_counters_match_the_reference_float32(keye,
+                                                            keye_float32):
+    cfg = keye[0]
+    loss, _, extra, want_loss, _ = keye_float32
+    assert abs(float(loss) - float(want_loss)) <= 1e-4 * float(want_loss)
+    c = extra["counters"]
+    n, topk = cfg["num_hidden_layers"], cfg["sa_config"]["topk"]
+    want = 2 * sum(min(t + 1, topk) for t in range(32))
+    # at least top-k keys a row; more only at an exact tie at a threshold
+    # (the tiny indexer's scores are often exactly 0): the rows that are
+    # off are counted, and are few
+    for kept, off in zip(c["pairs_kept"].tolist(),
+                         c["rows_off_count"].tolist()):
+        assert 0 <= kept - want <= 8 * off and off <= 4
+    assert c["rows_dropped"].tolist() == [0.0] * n
+    assert all(x > 1e-4 for x in c["index_loss"].tolist())
+
+
+@pytest.mark.parametrize("leaf", _keye_leaf_names())
+def test_keye_gradient_leaf_matches_reference_float32(keye_float32, leaf):
+    """Every leaf ON ITS OWN: the indexer's three tensors a layer are 5%
+    of the parameters and would hide in one norm over all of them."""
+    _, got, _, _, want = keye_float32
+    scale = float(jnp.abs(want[leaf]).max())
+    assert scale > 0, "a leaf the loss does not reach"
+    if "layer_0" in leaf or not any(i in leaf for i in INDEXER_LEAVES):
+        np.testing.assert_allclose(got[leaf], want[leaf], atol=2e-4 * scale,
+                                   rtol=2e-4)
+    else:
+        # a deeper indexer's input carries float32's last bits of layer 0,
+        # and one key that crosses a threshold moves its gradient by a
+        # percent: the leaf's own norm, not its elements
+        err = float(jnp.linalg.norm(got[leaf] - want[leaf])
+                    / jnp.linalg.norm(want[leaf]))
+        assert err < 0.05, err
+
+
+def test_keye_leaves_hold_the_indexer_the_norms_and_no_more():
+    names = _keye_leaf_names()
+    layer0 = {n.split("']['")[1].rstrip("']") for n in names
+              if "layer_0" in n}
+    assert layer0 == {"norm_attn", "norm_query", "norm_key", "norm_moe",
+                      "query", "key", "value", "out", "router",
+                      "experts_gate_up", "experts_down", *INDEXER_LEAVES}
+    assert sum(any(i in n for i in INDEXER_LEAVES) for n in names) == 6
+
+
+def test_keye_matches_reference_bfloat16(keye):
+    """What `correct` compares on the chip, at the tiny size — and the
+    indexer's group on its own, which one norm over all leaves would
+    hide."""
+    cfg, ref, fam, w, batch = keye
+    want_loss, g = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg))(w)
+    loss, grads, _ = _keye_loss_and_grad(cfg, fam, w, batch, jnp.bfloat16)
+    assert abs(float(loss) - float(want_loss)) <= 5e-3 * float(want_loss)
+    got, want = _leaves(grads), _leaves(fam.to_program(g, cfg)[0])
+
+    def err(keys):
+        num = sum(float(jnp.sum(jnp.square(got[k] - want[k]))) for k in keys)
+        den = sum(float(jnp.sum(jnp.square(want[k]))) for k in keys)
+        return (num / den) ** 0.5
+
+    assert err(list(want)) < 0.3
+    assert err([k for k in want if any(i in k for i in INDEXER_LEAVES)]
+               ) < 0.3
+
+
+def test_keye_remat_keeps_the_thresholds_with_what_depends_on_them(keye):
+    """Under remat the layer saves the thresholds WITH the indexer's
+    operands they were found from, beside the chosen experts: in bf16
+    every gradient leaf matches the layer that recomputes nothing."""
+    cfg, _, fam, w, batch = keye
+    plain = _leaves(_keye_loss_and_grad(cfg, fam, w, batch, jnp.bfloat16,
+                                        remat=False)[1])
+    rematted = _leaves(_keye_loss_and_grad(cfg, fam, w, batch,
+                                           jnp.bfloat16, remat=True)[1])
+    for leaf, want in plain.items():
+        err = float(jnp.linalg.norm(rematted[leaf] - want)
+                    / jnp.linalg.norm(want))
+        assert err < 0.02, (leaf, err)
+    from edl_tpu.ops import sparse_attention
+    assert sparse_attention.SAVED_UNDER_REMAT[3] == "attn.tau"
+
+
+def test_keye_int8_control_is_far_from_the_reference(keye):
+    cfg, ref, _, w, batch = keye
+    _, g = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg))(w)
+    _, g8 = jax.jit(lambda w: ref.loss_and_grad(w, batch, cfg, "int8"))(w)
+    num = sum(float(jnp.sum(jnp.square(g8[k] - g[k]))) for k in g)
+    den = sum(float(jnp.sum(jnp.square(g[k]))) for k in g)
+    assert (num / den) ** 0.5 > 0.2
+
+
+def _keye_uncut_layer(keye):
+    """One layer at the tiny widths, UNCUT: all 16 experts of a 16-wide
+    router, 8 query heads on 4 kv heads, the indexer as it is."""
+    cfg, ref, _, _, _ = keye
+    whole = dict(cfg, num_experts=16, num_local_experts=16,
+                 num_attention_heads=8, num_key_value_heads=4,
+                 num_hidden_layers=1, first_expert=0)
+    w = ref.init_weights(whole, jax.random.PRNGKey(7))
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 32, cfg["hidden_size"]))
+    return whole, ref.layer_weights(w, 0), x
+
+
+def test_keye_head_shares_add_up_to_the_uncut_layer(keye):
+    """Four shares of 2 query heads and their kv head, the indexer WHOLE in
+    every one (each chip computes every row's choice alike): their parts
+    of the attention result add up to the uncut layer's; the choice, which
+    every share computes, is counted once — it is the same in all four."""
+    _, ref, _, _, _ = keye
+    whole, lw, h = _keye_uncut_layer(keye)
+    hd = whole["head_dim"]
+    want, _ = ref.attention_part(h, lw, whole)
+    total = jnp.zeros_like(want)
+    for share in range(4):
+        part = dict(whole, num_attention_heads=2, num_key_value_heads=1)
+        qs = slice(share * 2 * hd, (share + 1) * 2 * hd)
+        ks = slice(share * hd, (share + 1) * hd)
+        cut = dict(lw, w_q=lw["w_q"][:, qs], w_k=lw["w_k"][:, ks],
+                   w_v=lw["w_v"][:, ks], w_o=lw["w_o"][qs])
+        total += ref.attention_part(h, cut, part)[0]
+    np.testing.assert_allclose(total, want, atol=1e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("who", ["reference", "program"])
+def test_keye_expert_shares_add_up_to_the_uncut_layer(keye, who):
+    """Sixteen shares of 1 SiLU expert each, the router whole in every
+    one."""
+    _, ref, _, _, _ = keye
+    whole, lw, x = _keye_uncut_layer(keye)
+    u = x.reshape(-1, x.shape[-1])
+    idx, p = ref.route(u, lw["w_r"], whole)
+    want = ref.experts_part(u, idx, p, lw, whole)
+    total = jnp.zeros_like(want)
+    for first in range(16):
+        cut = dict(lw, w_gate_up=lw["w_gate_up"][first:first + 1],
+                   w_down=lw["w_down"][first:first + 1])
+        if who == "reference":
+            part = dict(whole, num_experts=1, first_expert=first)
+            total += ref.experts_part(u, idx, p, cut, part)
+        else:
+            m, counters = moe.held_experts_ffn(
+                u, idx, p, cut["w_gate_up"], cut["w_down"], first, tm=16,
+                activation="silu")
+            assert float(counters["rows_dropped"]) == 0.0
+            total += m
+    np.testing.assert_allclose(total, want, atol=2e-5, rtol=1e-4)
+
+
+def test_expert_activation_is_named_and_relu_by_default():
+    u, w_gate_up, w_down, p = _layer_inputs()
+    idx = jax.random.randint(jax.random.PRNGKey(5), (u.shape[0], 3), 0, 16,
+                             jnp.int32)
+    relu = moe.held_experts_ffn(u, idx, p, w_gate_up, w_down, 4, tm=16)[0]
+    named = moe.held_experts_ffn(u, idx, p, w_gate_up, w_down, 4, tm=16,
+                                 activation="relu")[0]
+    silu = moe.held_experts_ffn(u, idx, p, w_gate_up, w_down, 4, tm=16,
+                                activation="silu")[0]
+    np.testing.assert_array_equal(np.asarray(relu), np.asarray(named))
+    assert float(jnp.abs(silu - relu).max()) > 1e-3
+    with pytest.raises(KeyError):
+        moe.held_experts_ffn(u, idx, p, w_gate_up, w_down, 4, tm=16,
+                             activation="gelu")
+
+
+def test_smallthinker_tree_and_counters_are_what_they_were(tiny):
+    """The new attributes default to what SmallThinker runs: its parameter
+    tree has no indexer or q/k norm, its extra state no selection
+    counter."""
+    cfg, _, fam, _, _ = tiny
+    params, extra = fam.train_parts(cfg, {"remat": True})[2]
+    assert sorted(params["layer_0"]) == [
+        "experts_down", "experts_gate_up", "key", "norm_attn", "norm_moe",
+        "out", "query", "router", "value"]
+    assert sorted(extra["counters"]) == sorted(
+        sparse_decoder.COUNTERS + ("steps",))
+    model = fam.build_model(cfg, {"remat": True})
+    assert (model.router_input, model.expert_activation, model.qk_norm,
+            model.selects()) == ("attn_norm", "relu", False, False)
+
+
+def test_trainer_mirrors_selection_counters_and_the_readers_read_them(keye):
+    import optax
+    from edl_tpu.runtime.mesh import make_mesh
+    from edl_tpu.runtime.trainer import ElasticTrainer
+    cfg, _, fam, w, batch = keye
+    loss_fn, has_aux, _ = fam.train_parts(cfg, {"remat": False})
+    params, extra = fam.to_program(w, cfg)
+    trainer = ElasticTrainer(loss_fn, params, optax.sgd(1e-3),
+                             total_batch_size=2, extra_state=extra,
+                             has_aux=has_aux,
+                             mesh=make_mesh(devices=jax.devices()[:1]))
+    try:
+        for _ in range(2):
+            trainer.train_step(trainer.place_batch(batch))
+    finally:
+        trainer.close()
+    got = kernel_readers.model_counters()
+    n, topk = cfg["num_hidden_layers"], cfg["sa_config"]["topk"]
+    kept = 2 * sum(min(t + 1, topk) for t in range(32))
+    assert got["steps"] == [2.0]
+    assert all(0 <= x - 2.0 * kept <= 64 for x in got["pairs_kept"])
+    assert len(got["rows_off_count"]) == n and len(got["index_loss"]) == n
+    view = {"traffic": {"seq_len": 32, "batch_per_chip": 2},
+            "cell": {"chips": 1}}
+    pct = harness.load_module("metrics", "dsa_pairs_kept_pct").read(view)
+    assert pct == pytest.approx(100.0 * kept / (2 * 32 * 33 / 2), rel=0.05)
+    assert pct == 100.0 * sum(got["pairs_kept"]) / (2 * n * 2 * 32 * 33 / 2)
+    off = harness.load_module("metrics", "dsa_rows_off_count").read(view)
+    assert off == pytest.approx(sum(got["rows_off_count"]) / 2.0)
+
+
+def test_selection_counter_readers_return_none_without_counters(monkeypatch):
+    """On the parent commit there is no such counter: nothing read,
+    nothing raised."""
+    for name in ("dsa_pairs_kept_pct", "dsa_rows_off_count"):
+        mod = harness.load_module("metrics", name)
+        monkeypatch.setattr(mod, "model_counters", lambda: {})
+        assert mod.read({"traffic": {"seq_len": 32, "batch_per_chip": 2},
+                         "cell": {"chips": 1}}) is None
+
+
+def _keye_view(ops, steps=10):
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         KEYE + ".json"))
+    job = harness.load_json(os.path.join(REPO, "benchmark", "traffic",
+                                         "tokens-16384.json"))
+    return {"trace": {"ops": ops}, "counters": {"traced_steps": steps},
+            "config": cfg, "traffic": job,
+            "peaks": {"bf16_flops": 197e12, "hbm_bytes_s": 819e9}}
+
+
+@pytest.mark.parametrize("kernel", ["dsa_index_tau", "dsa_fwd",
+                                    "dsa_index_kl", "dsa_bwd", "moe_gmm",
+                                    "moe_tgmm"])
+def test_keye_kernel_readers_on_a_recorded_view(kernel):
+    ops = [["fusion", 3.0], [kernel, 0.2], ["checkpoint_%s_" % kernel, 0.1],
+           ["while", 0.5]]
+    device_ms = harness.load_module("metrics", kernel + "_device_ms")
+    roofline = harness.load_module("metrics", kernel + "_roofline_pct")
+    view = _keye_view(ops)
+    assert device_ms.read(view) == pytest.approx(30.0)
+    assert 0.0 < roofline.read(view) < 100.0
+    empty = _keye_view([["fusion", 3.0]])
+    assert device_ms.read(empty) is None and roofline.read(empty) is None
+
+
+def test_keye_flops_count_the_kept_pairs_only():
+    fam = harness.load_module("program", "dsa_decoder")
+    view = _keye_view([])
+    cfg, job = view["config"], view["traffic"]
+    assert fam.kept_pairs(16384, 2048) == 2048 * 2049 / 2 + 14336 * 2048
+    assert fam.kept_pairs(16384, 2048) / fam.band_pairs(16384) == \
+        pytest.approx(0.2344, abs=1e-4)
+    assert fam.expected_expert_rows(cfg, 16384) == 8192
+    total = fam.train_flops(cfg, job, 1)
+    assert total == pytest.approx(10.45e12, rel=0.02)
+    # a masked kernel that computes every causal pair cannot read more
+    # than kept / causal of its roofline on operations
+    costs = fam.kernel_costs(cfg, job, 1)
+    assert set(costs) == {"dsa_index_tau", "dsa_fwd", "dsa_index_kl",
+                          "dsa_bwd", "moe_gmm", "moe_tgmm"}
+    executed_fwd = 2 * 4 * fam.band_pairs(16384) * (8 * 4 * 128 + 16 * 128)
+    assert costs["dsa_fwd"][0] / executed_fwd < 0.2344
+
+
+def test_keye_configuration_holds_the_published_widths():
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         KEYE + ".json"))
+    assert (cfg["hidden_size"], cfg["head_dim"], cfg["moe_intermediate_size"],
+            cfg["num_local_experts"], cfg["num_experts_per_tok"],
+            cfg["sa_config"]["topk"], cfg["rope_theta"]) == (
+                2048, 128, 768, 128, 8, 2048, 10000000)
+    assert sorted(cfg["reduced"]) == sorted(cfg["published"]) == sorted(
+        cfg["reduced_why"])
+    ref = harness.load_module("reference", KEYE)
+    n = sum(int(np.prod(shape)) for shape, _ in
+            ref.weight_shapes(cfg).values())
+    assert n == pytest.approx(257.8e6, rel=2e-3)
+    src = open(os.path.join(REPO, "benchmark", "reference",
+                            KEYE + ".py")).read()
+    assert "edl_tpu" not in src.replace("`edl_tpu/`", "")
