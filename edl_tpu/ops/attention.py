@@ -18,8 +18,10 @@ import os
 import jax
 import jax.numpy as jnp
 
-# Pallas kernel defaults (ops/flash_attention.py): blocks are 128x128
-# with block_q clamped to seq. Lane tiling wants head_dim % 8 == 0.
+# What the Pallas kernels (ops/flash_attention.py) take without a ragged
+# tile: their tiles are 512 wide, or 256 or 128 by what divides the
+# sequence, so whole multiples of 128 (or one tile for a shorter
+# sequence). Lane tiling wants head_dim % 8 == 0.
 _FLASH_BLOCK = 128
 _FLASH_HEAD_MULT = 8
 

@@ -1,32 +1,43 @@
 """Pallas flash attention for TPU: blockwise online-softmax forward
-kernels and FlashAttention-2's backward as two more kernels.
+kernels and FlashAttention-2's backward kernels.
 
 The hot op of the decoder models (edl_tpu/models/gpt.py,
 edl_tpu/models/sparse_decoder.py; models/bert.py runs it without a causal
 mask) on the training path. Never materializes the [seq, seq] score
 matrix, forward or backward:
 
-- forward: a Pallas kernel gridded over (batch*heads, q_blocks); each
-  program streams kv blocks from VMEM with fp32 online-softmax
-  accumulation on the MXU (q/k/v blocks sized to the 128-lane tiling),
-  and writes, beside the result, the row statistic lse = m + log(l),
-  float32, four bytes a query row;
+- forward: a Pallas kernel gridded over (batch*heads, q_blocks) that loops
+  over the band's kv blocks with k and v whole in VMEM, or, beyond
+  ``_RESIDENT_KV_BYTES``, streams them through a third grid dimension.
+  Online softmax; the result and, beside it, the row statistic
+  lse = m + log(l), float32, four bytes a query row;
 - backward: a custom_vjp whose residuals are (q, k, v, out, lse). Two
   Pallas kernels rebuild p = exp(scores - lse) tile by tile in VMEM: one
   gridded over q blocks accumulates dq over the band's kv blocks, one
   gridded over kv blocks accumulates dk and dv over the q blocks (of every
-  query head of the kv head) whose band reaches it. Products take their
-  operands in the inputs' dtype with float32 accumulation; scores, p and
-  ds are float32. So the op composes with jit/grad/remat and with the
-  ring-attention sp layer (edl_tpu/parallel/ring_attention.py), which
-  shards the sequence BEFORE attention is applied per shard.
+  query head of the kv head) whose band reaches it.
+
+Forward and backward compute a score tile the same way. Products take their
+operands in the inputs' dtype (bfloat16 in training) with float32
+accumulation (``_dot``); the scale goes to the float32 scores, so the
+forward's lse is built from the very expression the backward rebuilds p
+from; scores, m, l, the accumulators, p and ds are float32, and p and ds
+are rounded only where they enter a product. Tiles are ``_BLOCK`` (512)
+wide, or its half or quarter by what divides the sequence (``_tile_edge``),
+one tile for a short sequence. Of the band's tiles only those that straddle
+the diagonal, the window's far edge or a padded tail build a mask
+(``_kv_band``, ``_q_band``); those wholly inside it build none. The op
+composes with jit/grad/remat and with the ring-attention sp layer
+(edl_tpu/parallel/ring_attention.py), which shards the sequence BEFORE
+attention is applied per shard.
 
 Layout: q, k, v are [batch, heads, seq, head_dim]. With grouped-query
 attention k and v have fewer heads and q is [batch, kv_heads, group * seq,
 head_dim] (the query heads of a kv head one after another: K/V are never
 repeated); a causal ``window`` keeps a query's own position and the
 ``window - 1`` before it, and blocks outside the band are neither loaded
-nor computed, in the forward and in the backward kernels (``mha`` takes
+nor computed, in the forward and in the backward kernels (the streamed
+forward alone loads them and skips their compute; ``mha`` takes
 the model's [batch, seq, heads, dim] layout, does the regrouping, and
 keeps its residuals in that layout). A learned SELECTION of single keys
 (``select=``) is no band: ``mha`` and ``flash_attention`` hand it to the
@@ -71,15 +82,115 @@ def _flip(x):
                    keepdims=True)
 
 
+# the tiles, forward and backward: [block, block] float32 scores and p (the
+# backward's dp and ds too) live in VMEM only. While k and v fit VMEM whole
+# (_RESIDENT_KV_BYTES) one kernel a pass loops over the band's kv blocks;
+# beyond that the other side streams through the grid
+_BLOCK = 512
+_VMEM_LIMIT = 64 << 20
+_RESIDENT_KV_BYTES = 4 << 20
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    """Operands as they arrive (bfloat16 in training, float32 in the tight
+    tests), float32 accumulation."""
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _tile_edge(s, widest):
+    """The tile edge for a sequence of ``s``: ``widest`` (512), its half
+    or its quarter, the first that divides it (wide tiles amortise the
+    loop and the grid step; the band's edge wastes at most a tile's
+    width), else one tile for a short sequence, else ``widest`` and a
+    ragged tail (the backward zero-pads it)."""
+    for block in (widest, widest // 2, widest // 4):
+        if s % block == 0:
+            return block
+    return min(widest, s)
+
+
+def _kv_band(xp, q_lo, block_q, block_k, n_k, causal, window, ragged):
+    """For the q block that starts at position ``q_lo``: the kv blocks its
+    band reaches are [first, last); those wholly inside the band, which
+    need no mask, are [ufirst, ulast). ``xp`` is jnp inside a kernel or an
+    index map and numpy where the wrapper sizes the grid."""
+    q_hi = q_lo + block_q - 1
+    first = ufirst = 0 * q_lo
+    last = ulast = n_k + 0 * q_lo
+    if ragged:                      # the tail of k and v past the sequence
+        ulast = ulast - 1
+    if causal:
+        last = xp.minimum(q_hi // block_k + 1, n_k)
+        ulast = (q_lo + 1) // block_k
+    if window is not None:
+        first = xp.maximum(q_lo - window + 1, 0) // block_k
+        ufirst = (xp.maximum(q_hi - window + 1, 0) + block_k - 1) // block_k
+    ufirst = xp.clip(ufirst, first, last)
+    return first, ufirst, xp.clip(ulast, ufirst, last), last
+
+
+def _edge_and_inner(idx, first, ufirst, ulast, last):
+    """(tile is visited and needs a mask, tile is visited and needs none)"""
+    visited = jnp.logical_and(idx >= first, idx < last)
+    inner = jnp.logical_and(idx >= ufirst, idx < ulast)
+    return (jnp.logical_and(visited, jnp.logical_not(inner)),
+            jnp.logical_and(visited, inner))
+
+
+def _band_mask(q_pos, k_pos, causal, window, kv_len):
+    """Which pairs of an edge tile count, or None where all do: under
+    ``causal`` no key ahead of the query and, with a window, none further
+    back than ``window - 1``; ``kv_len`` set, no key of the tail past it."""
+    keep = None
+    if causal:
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = jnp.logical_and(keep, q_pos - k_pos < window)
+    if kv_len is not None:
+        tail = k_pos < kv_len
+        keep = tail if keep is None else jnp.logical_and(keep, tail)
+    return keep
+
+
+def _softmax_tile(q, k, v, carry, q_lo, k_lo, *, sm_scale, masked, causal,
+                  window, kv_len):
+    """One kv tile of the online softmax: (acc, m, l), float32, moved on
+    by p = exp(scale * q.k^T - m). The scores are the expression
+    ``_p_and_ds`` rebuilds p from; l sums the float32 p, which is rounded
+    only where it enters p.v. ``masked`` is static: a tile wholly inside
+    the band builds no positions, no compare and no select."""
+    acc, m, l = carry
+    scores = _dot(q, k, _NT) * sm_scale                      # [TQ, TK]
+    keep = None
+    if masked:
+        tq, tk = scores.shape
+        keep = _band_mask(
+            q_lo + lax.broadcasted_iota(jnp.int32, (tq, 1), 0),
+            k_lo + lax.broadcasted_iota(jnp.int32, (1, tk), 1),
+            causal, window, kv_len)
+    if keep is not None:
+        scores = jnp.where(keep, scores, _NEG_INF)
+    m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
+    p = jnp.exp(scores - m_new)
+    if keep is not None:            # a row the tile holds no key of
+        p = jnp.where(keep, p, 0.0)
+    correction = jnp.exp(m - m_new)
+    return (acc * correction + _dot(p.astype(v.dtype), v, _NN), m_new,
+            l * correction + p.sum(axis=-1, keepdims=True))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, block_k, seq_len, causal, sm_scale, q_block, window=None,
-                n_q_seq=None):
+                *, block_q, block_k, n_q_seq, n_k, band, tile):
     """One (bh, q_block, k_block) grid step. kv blocks stream through VMEM
     via the third grid dimension (fastest-varying, revisiting the same out
-    block), so VMEM holds only tiles regardless of sequence length."""
-    qi = _q_block_index(n_q_seq)
+    block), so VMEM holds only tiles regardless of sequence length; a
+    block outside the band computes nothing."""
     ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    q_lo = _q_block_index(n_q_seq) * block_q
 
     @pl.when(ki == 0)
     def _init():
@@ -87,42 +198,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: blocks strictly right of the diagonal contribute nothing
-    diag_ok = (ki * block_k <= qi * q_block + q_block - 1) if causal \
-        else True
-    if window is not None:
-        # ... and so do blocks wholly left of the band's oldest key
-        diag_ok = jnp.logical_and(
-            diag_ok, ki * block_k + block_k - 1 >= qi * q_block - window + 1)
+    def compute(masked):
+        acc_ref[:], m_ref[:], l_ref[:] = _softmax_tile(
+            q_ref[0], k_ref[0], v_ref[0], (acc_ref[:], m_ref[:], l_ref[:]),
+            q_lo, ki * block_k, masked=masked, **tile)
 
-    @pl.when(diag_ok)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale      # [TQ, d]
-        tq = q.shape[0]
-        k_blk = k_ref[0].astype(jnp.float32)             # [TK, d]
-        v_blk = v_ref[0].astype(jnp.float32)
-        scores = jax.lax.dot_general(                    # [TQ, TK]
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        q_pos = qi * q_block + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
-        k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
-                                                    (1, block_k), 1)
-        mask = k_pos < seq_len                           # ragged last block
-        if causal:
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        if window is not None:
-            mask = jnp.logical_and(mask, q_pos - k_pos < window)
-        scores = jnp.where(mask, scores, _NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        p = jnp.where(mask, p, 0.0)
-        correction = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * correction + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    edge, inner = _edge_and_inner(
+        ki, *_kv_band(jnp, q_lo, block_q, block_k, n_k, **band))
+    pl.when(edge)(functools.partial(compute, True))
+    pl.when(inner)(functools.partial(compute, False))
 
     @pl.when(ki == n_k - 1)
     def _finalize():
@@ -131,66 +215,79 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = _flip(m_ref[:] + jnp.log(l))
 
 
-def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
-                         seq_len, causal, sm_scale, q_block, window=None,
-                         n_q_seq=None):
-    """Fast path for kv that fits VMEM: fori_loop over kv blocks so causal
-    masking skips the loads AND compute right of the diagonal, and a
-    window those left of the band."""
-    qi = _q_block_index(n_q_seq)
-    q = q_ref[0].astype(jnp.float32) * sm_scale        # [TQ, d]
-    tq, d = q.shape
-    q_pos = qi * q_block + lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+def _diagonal_tiles(block_q, block_k, q_len, kv_len, causal, window):
+    """How many edge tiles end the band of EVERY q block, or None where
+    that differs from one q block to the next: with a causal mask, whole
+    tiles of which one edge divides the other, no q block past the keys
+    and a window (if any) too wide to cut into the diagonal's tiles, they
+    are the max(1, block_q // block_k) kv blocks that hold the diagonal."""
+    if (causal and 0 in (block_q % block_k, block_k % block_q)
+            and q_len % block_q == 0 and q_len <= kv_len
+            and (window is None or window >= block_q + block_k - 1)):
+        return max(1, block_q // block_k)
+    return None
 
-    def body(ki, carry):
-        acc, m, l = carry
-        k_blk = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(
-            jnp.float32)
-        v_blk = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(
-            jnp.float32)
-        scores = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            mask = q_pos >= k_pos
-            if window is not None:
-                mask = jnp.logical_and(mask, q_pos - k_pos < window)
-            scores = jnp.where(mask, scores, _NEG_INF)
-        m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        correction = jnp.exp(m - m_new)
-        l_new = l * correction + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * correction + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
 
-    acc = jnp.zeros((tq, d), jnp.float32)
-    m = jnp.full((tq, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((tq, 1), jnp.float32)
-    if causal:
-        last = lax.div(qi * q_block + (tq - 1), block_k) + 1
-    else:
-        last = seq_len // block_k
-    first = 0
-    if window is not None:
-        first = lax.div(jnp.maximum(qi * q_block - window + 1, 0), block_k)
-    acc, m, l = lax.fori_loop(first, last, body, (acc, m, l))
+def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
+                         block_k, n_q_seq, n_k, band, tile, diagonal):
+    """Fast path for kv that fits VMEM: one program a q block loops over
+    the band's kv blocks only, so a causal mask skips the loads AND the
+    compute right of the diagonal, and a window those left of the band.
+    ``diagonal`` is ``_diagonal_tiles``' count."""
+    q_lo = _q_block_index(n_q_seq) * block_q
+    q = q_ref[0]
+
+    def tiles(masked):
+        def body(ki, carry):
+            k_lo = pl.multiple_of(ki * block_k, block_k)
+            return _softmax_tile(q, k_ref[0, pl.ds(k_lo, block_k), :],
+                                 v_ref[0, pl.ds(k_lo, block_k), :], carry,
+                                 q_lo, k_lo, masked=masked, **tile)
+        return body
+
+    first, ufirst, ulast, last = _kv_band(jnp, q_lo, block_q, block_k, n_k,
+                                          **band)
+    carry = (jnp.zeros(q.shape, jnp.float32),
+             jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    # a program is a few tiles long, so its loops' set-up shows (GPT-2s's
+    # 1.5 tiles: a third of the kernel): an edge the band does not have gets
+    # no loop, and a diagonal of a known number of tiles is not a loop
+    if band["window"] is not None:
+        carry = lax.fori_loop(first, ufirst, tiles(True), carry)
+    carry = lax.fori_loop(ufirst, ulast, tiles(False), carry)
+    if diagonal is not None:
+        for t in range(diagonal):
+            carry = tiles(True)(ulast + t, carry)
+    elif band["causal"]:
+        carry = lax.fori_loop(ulast, last, tiles(True), carry)
+    acc, m, l = carry
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = _flip(m + jnp.log(l))
 
 
-# kv (k + v) resident in VMEM up to this many bytes; beyond it, stream
-_RESIDENT_KV_BYTES = 4 << 20
-
 #: the kernels' names in a device trace (the op class lib/xplane.py shows)
 FWD_RESIDENT_NAME = "flash_fwd_resident"
 FWD_STREAM_NAME = "flash_fwd_stream"
+
+
+def _compiler_params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _statics(block_q, block_k, n_q_seq, n_k, sm_scale, causal, window,
+             kv_len):
+    """What each kernel here is specialised on: its tiles, the band
+    (``_kv_band``, ``_q_band``) and a tile's arithmetic. ``kv_len`` is the
+    sequence's length where the last kv block has a tail past it that only
+    a mask removes, else None."""
+    return dict(block_q=block_q, block_k=block_k, n_q_seq=n_q_seq, n_k=n_k,
+                band=dict(causal=causal, window=window,
+                          ragged=kv_len is not None),
+                tile=dict(sm_scale=sm_scale, causal=causal, window=window,
+                          kv_len=kv_len))
 
 
 @functools.partial(jax.jit, static_argnums=tuple(range(3, 11)))
@@ -198,7 +295,8 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                window=None, group=1, resident_bytes=_RESIDENT_KV_BYTES):
     """q is [b, h, group * s, d]: the ``group`` query heads that share kv
     head h, one run of the sequence after another; k, v are [b, h, s, d]
-    and are never repeated in memory."""
+    and are never repeated in memory. Under jit with the tiles and
+    ``resident_bytes`` static (the caller passes the module's)."""
     b, h, rows, d = q.shape
     s = rows // group
     sk = k.shape[2]
@@ -219,53 +317,47 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     if window is not None and not causal:
         raise ValueError("a window is the last `window` keys up to the "
                          "query's own: it needs causal=True")
-    band = dict(window=window, n_q_seq=n_q_seq)
+    ragged = sk % block_k != 0      # the streamed kernel masks the tail
+    shape = _statics(block_q, block_k, n_q_seq, n_k, sm_scale, causal,
+                     window, sk if ragged else None)
 
     # the row statistic lse = m + log(l), float32, rows along the lanes:
     # 4 bytes a row, the residual the backward kernels rebuild p from
     out_shape = (jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, rows), jnp.float32))
+    q_block = pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0))
+    q_stats = pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j))
     kv_bytes = 2 * sk * d * k.dtype.itemsize
-    if kv_bytes <= resident_bytes and sk % block_k == 0:
+    if kv_bytes <= resident_bytes and not ragged:
+        kv_whole = pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0))
         out, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel_resident, block_k=block_k,
-                              seq_len=sk, causal=causal, sm_scale=sm_scale,
-                              q_block=block_q, **band),
+            functools.partial(
+                _fwd_kernel_resident, **shape, diagonal=_diagonal_tiles(
+                    block_q, block_k, s, sk, causal, window)),
             grid=(bh, n_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0)),
-            ],
-            out_specs=(pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-                       pl.BlockSpec((1, 1, block_q),
-                                    lambda i, j: (i, 0, j))),
+            in_specs=[q_block, kv_whole, kv_whole],
+            out_specs=(q_block, q_stats),
             out_shape=out_shape,
+            compiler_params=_compiler_params("parallel", "parallel"),
             interpret=interpret,
             name=FWD_RESIDENT_NAME,
         )(qf, kf, vf)
         return out.reshape(b, h, rows, d), lse
 
+    kv_block = pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0))
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_k=block_k, seq_len=sk,
-                          causal=causal, sm_scale=sm_scale,
-                          q_block=block_q, **band),
+        functools.partial(_fwd_kernel, **shape),
         grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, block_q, d),
-                                lambda i, j, kb: (i, j, 0)),
-                   pl.BlockSpec((1, 1, block_q),
-                                lambda i, j, kb: (i, 0, j))),
+        in_specs=[q_block, kv_block, kv_block],
+        out_specs=(q_block, q_stats),
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
         interpret=interpret,
         name=FWD_STREAM_NAME,
     )(qf, kf, vf)
@@ -341,43 +433,6 @@ BWD_NAME = "flash_bwd"
 BWD_DQ_NAME = "flash_bwd_dq"
 BWD_DKV_NAME = "flash_bwd_dkv"
 
-# the backward's tiles: [block, block] float32 scores, p, dp and ds live in
-# VMEM only. While k and v fit VMEM whole (_RESIDENT_KV_BYTES, as in the
-# forward) one kernel computes each tile once and accumulates dk and dv
-# there too; beyond that two kernels stream the other side through the grid
-_BWD_BLOCK = 512
-_BWD_VMEM_LIMIT = 64 << 20
-
-_NT = (((1,), (1,)), ((), ()))      # a @ b.T
-_NN = (((1,), (0,)), ((), ()))      # a @ b
-_TN = (((0,), (0,)), ((), ()))      # a.T @ b
-
-
-def _dot(a, b, dims):
-    """Operands as they arrive (bfloat16 in training, float32 in the tight
-    tests), float32 accumulation."""
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
-
-
-def _kv_band(xp, q_lo, block_q, block_k, n_k, causal, window, ragged):
-    """For the q block that starts at position ``q_lo``: the kv blocks its
-    band reaches are [first, last); those wholly inside the band, which
-    need no mask, are [ufirst, ulast). ``xp`` is jnp inside a kernel or an
-    index map and numpy where the wrapper sizes the grid."""
-    q_hi = q_lo + block_q - 1
-    first = ufirst = 0 * q_lo
-    last = ulast = n_k + 0 * q_lo
-    if ragged:                      # the zero-padded tail of k and v
-        ulast = ulast - 1
-    if causal:
-        last = xp.minimum(q_hi // block_k + 1, n_k)
-        ulast = (q_lo + 1) // block_k
-    if window is not None:
-        first = xp.maximum(q_lo - window + 1, 0) // block_k
-        ufirst = (xp.maximum(q_hi - window + 1, 0) + block_k - 1) // block_k
-    ufirst = xp.clip(ufirst, first, last)
-    return first, ufirst, xp.clip(ulast, ufirst, last), last
-
 
 def _q_band(xp, k_lo, block_q, block_k, n_q, n_k, causal, window, ragged):
     """The same for the kv block that starts at ``k_lo``: the q blocks (of
@@ -407,25 +462,11 @@ def _p_and_ds(a, b, da, db, lse, delta, q_pos, k_pos, *, sm_scale, masked,
     static: only a tile that straddles the diagonal, the band's far edge
     or the padded tail builds a mask."""
     p = jnp.exp(_dot(a, b, _NT) * sm_scale - lse)
-    if masked:
-        keep = None
-        if causal:
-            keep = q_pos >= k_pos
-            if window is not None:
-                keep = jnp.logical_and(keep, q_pos - k_pos < window)
-        if kv_len is not None:
-            tail = k_pos < kv_len
-            keep = tail if keep is None else jnp.logical_and(keep, tail)
-        if keep is not None:        # else a loop of no trips, traced
-            p = jnp.where(keep, p, 0.0)
+    keep = _band_mask(q_pos, k_pos, causal, window, kv_len) if masked \
+        else None
+    if keep is not None:            # else a loop of no trips, traced
+        p = jnp.where(keep, p, 0.0)
     return p, p * (_dot(da, db, _NT) - delta)
-
-
-def _edge_and_inner(idx, first, ufirst, ulast, last):
-    """(tile is visited and needs a mask, tile is visited and needs none)"""
-    inner = jnp.logical_and(idx >= ufirst, idx < ulast)
-    return (jnp.logical_and(idx < last, jnp.logical_not(inner)),
-            jnp.logical_and(idx < last, inner))
 
 
 def _bwd_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -548,18 +589,6 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_block(s, widest):
-    """The backward's tile edge for a sequence of ``s``: ``widest`` (512),
-    its half or its quarter, the first that divides it (wide tiles
-    amortise the loop and the grid step; the band's edge wastes at most a
-    tile's width), else one tile for a short sequence, else ``widest`` and
-    a zero-padded tail."""
-    for block in (widest, widest // 2, widest // 4):
-        if s % block == 0:
-            return block
-    return min(widest, s)
-
-
 def _pad_runs(x, axis, group, s, to):
     """Zero-pad each of the ``group`` runs of length ``s`` along ``axis``
     to length ``to`` (a whole number of blocks)."""
@@ -575,7 +604,7 @@ def _pad_runs(x, axis, group, s, to):
 
 @functools.partial(jax.jit, static_argnums=tuple(range(6, 13)))
 def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
-               window=None, group=1, block=_BWD_BLOCK,
+               window=None, group=1, block=_BLOCK,
                resident_bytes=_RESIDENT_KV_BYTES):
     """FlashAttention-2's backward on the chip, visiting only the tiles of
     the causal band. p is rebuilt from the forward's ``lse`` [bh, 1, rows],
@@ -591,7 +620,7 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
     module's), so the layers of a model share one trace and one lowering."""
     b, h, rows, d = q.shape
     s, sk, bh = rows // group, k.shape[2], b * h
-    block_q, block_k = _bwd_block(s, block), _bwd_block(sk, block)
+    block_q, block_k = _tile_edge(s, block), _tile_edge(sk, block)
     s_pad = pl.cdiv(s, block_q) * block_q
     sk_pad = pl.cdiv(sk, block_k) * block_k
     n_q_seq, n_k = s_pad // block_q, sk_pad // block_k
@@ -607,14 +636,10 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
     kf = _pad_runs(k.reshape(bh, sk, d), 1, 1, sk, sk_pad)
     vf = _pad_runs(v.reshape(bh, sk, d), 1, 1, sk, sk_pad)
 
-    band = dict(causal=causal, window=window, ragged=ragged)
-    tile = dict(sm_scale=sm_scale, causal=causal, window=window,
-                kv_len=sk if ragged else None)
-    shape = dict(block_q=block_q, block_k=block_k, n_q_seq=n_q_seq, n_k=n_k,
-                 band=band, tile=tile)
+    shape = _statics(block_q, block_k, n_q_seq, n_k, sm_scale, causal,
+                     window, sk if ragged else None)
+    band = shape["band"]
     call = functools.partial(pl.pallas_call, interpret=interpret)
-    params = lambda *semantics: pltpu.CompilerParams(  # noqa: E731
-        dimension_semantics=semantics, vmem_limit_bytes=_BWD_VMEM_LIMIT)
     q_block = pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0))
     q_stats = pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j))
     kv_block = pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0))
@@ -633,7 +658,7 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
             out_shape=(dq_shape,) + dkv_shape,
             scratch_shapes=[pltpu.VMEM((sk_pad, d), jnp.float32),
                             pltpu.VMEM((sk_pad, d), jnp.float32)],
-            compiler_params=params("parallel", "arbitrary"),
+            compiler_params=_compiler_params("parallel", "arbitrary"),
             name=BWD_NAME)(qf, kf, vf, dof, lse, delta)
     else:
         # grid steps a q block needs: the widest band, in kv blocks
@@ -653,7 +678,7 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
             in_specs=[q_block, kv_step, kv_step, q_block, q_stats, q_stats],
             out_specs=q_block, out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            compiler_params=params("parallel", "parallel", "arbitrary"),
+            compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
             name=BWD_DQ_NAME)(qf, kf, vf, dof, lse, delta)
 
         # ... and a kv block, in q blocks, for each query head in turn
@@ -679,7 +704,7 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
             out_specs=(kv_block, kv_block), out_shape=dkv_shape,
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
-            compiler_params=params("parallel", "parallel", "arbitrary"),
+            compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
             name=BWD_DKV_NAME)(kf, vf, qf, dof, lse, delta)
 
     if s_pad != s:
@@ -714,13 +739,17 @@ def _attend_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     are in the models' layout, which the projections around the attention
     hold anyway: the kernels' [batch, heads, seq, dim] copies live for the
     length of a kernel and are remade in the backward, not kept from the
-    forward (three arrays the size of q a layer, otherwise)."""
+    forward (three arrays the size of q a layer, otherwise). A tile edge
+    the caller left open is chosen from the shape, as the backward's."""
     qt, kt, vt = q, k, v
     if seq_major:
         qt, kt, vt = _kernel_layout(q, group), _kernel_layout(k), \
             _kernel_layout(v)
-    out, lse = _flash_fwd(qt, kt, vt, causal, sm_scale, block_q, block_k,
-                          interpret, window, group, _RESIDENT_KV_BYTES)
+    out, lse = _flash_fwd(
+        qt, kt, vt, causal, sm_scale,
+        block_q or _tile_edge(qt.shape[2] // group, _BLOCK),
+        block_k or _tile_edge(kt.shape[2], _BLOCK), interpret, window, group,
+        _RESIDENT_KV_BYTES)
     if seq_major:
         out = _model_layout(out, group)
     return out, (q, k, v, out, lse)
@@ -739,7 +768,7 @@ def _attend_bwd(causal, sm_scale, block_q, block_k, interpret, window, group,
         k, v = _kernel_layout(k), _kernel_layout(v)
         delta = _kernel_layout(delta[..., None], group)[..., 0]
     dq, dk, dv = _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale,
-                            interpret, window, group, _BWD_BLOCK,
+                            interpret, window, group, _BLOCK,
                             _RESIDENT_KV_BYTES)
     if seq_major:
         dq, dk, dv = _model_layout(dq, group), _model_layout(dk), \
@@ -762,14 +791,16 @@ def _selected(q, k, v, causal, sm_scale, window, interpret, select):
                                           interpret=interpret)[0]
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
-                    block_k=128, interpret=False, window=None, group=1,
+def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
+                    block_k=None, interpret=False, window=None, group=1,
                     select=None):
     """Blockwise exact attention; k/v are [batch, kv_heads, seq, dim] and
     q/out [batch, kv_heads, group * seq, dim]: the ``group`` query heads
     of a kv head one run of the sequence after another (``group=1``: the
     usual [batch, heads, seq, dim]). ``window`` (needs ``causal``) keeps,
     for each query, its own position and the ``window - 1`` before it;
+    ``block_q`` / ``block_k`` fix the forward's tile, which is otherwise
+    chosen from the sequence (``_tile_edge``), as the backward's always is;
     ``select`` = (qi, ki, wi, tau) in the models' layout keeps the keys a
     learned indexer chose (ops/sparse_attention.py)."""
     if sm_scale is None:
@@ -782,8 +813,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
                    window, group, False)
 
 
-def mha(q, k, v, causal=False, sm_scale=None, window=None, block_q=128,
-        block_k=128, interpret=False, select=None):
+def mha(q, k, v, causal=False, sm_scale=None, window=None, block_q=None,
+        block_k=None, interpret=False, select=None):
     """The same for [batch, seq, heads, dim] arrays (the model code's
     layout). k and v may have fewer heads than q (grouped-query attention:
     query head i reads kv head i // group); they are not repeated in

@@ -8,12 +8,90 @@ and prints nothing that could be read as a device number, and a config
 that fails is an error line AND a non-zero exit.
 
     python -m edl_tpu.tools.bench_flash --seqs 1024,2048,8192,32768
+
+``--cells`` times the flash forward alone at the shapes the benchmark's
+language-model cells run it (``CELL_SHAPES``), in bfloat16, at the tile the
+kernel chooses from the shape and at each explicit tile of ``--tiles``: the
+numbers a tile is chosen from before a cell is run.
+
+    python -m edl_tpu.tools.bench_flash --cells --inner 8
 """
 
 import argparse
 import json
 import sys
 import time
+
+
+#: name, batch, kv heads, query heads a kv head, sequence, head width,
+#: window: gpt2s-train's 12 x 12 heads at 1024; smallthinker-moe-train-8k's
+#: full and windowed layers (7 query heads on 1 kv head, k + v 4 MiB)
+CELL_SHAPES = [
+    ("gpt2s-train", 12, 12, 1, 1024, 64, None),
+    ("smallthinker-moe-train-8k.full", 2, 1, 7, 8192, 128, None),
+    ("smallthinker-moe-train-8k.window", 2, 1, 7, 8192, 128, 4096),
+]
+
+
+def _ms_a_call(fn, args, iters, warmup):
+    """Host clock around ``iters`` calls that end in block_until_ready,
+    after ``warmup`` calls (the first compiles)."""
+    import jax
+    out = None
+    for _ in range(warmup):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3
+
+
+def band_pairs(seq, window=None):
+    """(query, key) pairs of the causal band: a query reads its own
+    position and, with a window, the ``window - 1`` before it."""
+    w = seq if window is None else min(window, seq)
+    return w * (w + 1) // 2 + (seq - w) * w
+
+
+def bench_cell_forward(name, batch, kv_heads, group, seq, dim, window, tile,
+                       iters, warmup, inner, peak_tflops):
+    """The flash forward alone, bfloat16, ``inner`` applications chained in
+    one executable (each result is the next one's q); ``tile`` is (block_q,
+    block_k), None leaving the tile to the kernel. TFLOP/s counts the
+    band's pairs only."""
+    import jax
+    import jax.numpy as jnp
+
+    from edl_tpu.ops.flash_attention import flash_attention
+
+    q = jax.random.normal(jax.random.PRNGKey(0),
+                          (batch, kv_heads, group * seq, dim), jnp.bfloat16)
+    k, v = (jax.random.normal(jax.random.PRNGKey(i),
+                              (batch, kv_heads, seq, dim), jnp.bfloat16)
+            for i in (1, 2))
+
+    @jax.jit
+    def fn(q, k, v):
+        def body(carry, _):
+            return flash_attention(carry, k, v, causal=True, window=window,
+                                   group=group, block_q=tile and tile[0],
+                                   block_k=tile and tile[1]), None
+        return jax.lax.scan(body, q, None, length=inner)[0]
+
+    ms = _ms_a_call(fn, (q, k, v), iters, warmup) / inner
+    flops = 4.0 * batch * kv_heads * group * band_pairs(seq, window) * dim
+    tflops = flops / (ms / 1e3) / 1e12
+    rec = {"metric": "flash_fwd_ms", "cell": name,
+           "tile": tile and "%dx%d" % tile,
+           "batch": batch, "kv_heads": kv_heads, "group": group, "seq": seq,
+           "dim": dim, "window": window, "inner": inner,
+           "value": round(ms, 4), "unit": "ms", "tflops": round(tflops, 2),
+           "peak_pct": round(100 * tflops / peak_tflops, 2)}
+    if tflops > peak_tflops * 1.25:
+        rec["suspect_fast_path"] = True
+    return rec
 
 
 def bench_one(impl, batch, heads, seq, dim, causal, iters, warmup,
@@ -64,16 +142,7 @@ def bench_one(impl, batch, heads, seq, dim, causal, iters, warmup,
     else:
         fn = jax.jit(fwd)
 
-    out = None
-    for _ in range(warmup):
-        out = fn(q, k, v)
-    if out is not None:
-        jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(q, k, v)
-    jax.block_until_ready(out)
-    ms = (time.perf_counter() - t0) / iters * 1e3
+    ms = _ms_a_call(fn, (q, k, v), iters, warmup)
     # 4*b*h*s^2*d multiply-adds fwd (qk + av), causal halves it. The
     # backward: dense keeps the probs as residuals (no recompute) —
     # ~2x fwd of grad matmuls, 3x total; flash recomputes per block —
@@ -130,6 +199,12 @@ def main(argv=None):
                    help="chain N attention applications inside one "
                    "jit call (lax.scan) — lifts short kernels above "
                    "the host dispatch floor")
+    p.add_argument("--cells", action="store_true",
+                   help="time the flash forward at CELL_SHAPES instead")
+    p.add_argument("--tiles", default="128,256,512",
+                   help="with --cells: explicit tiles (512, or 512x256 for "
+                   "block_q x block_k) to time beside the kernel's own "
+                   "choice")
     args = p.parse_args(argv)
     from edl_tpu.parallel import costmodel
     device = costmodel.device_identity()
@@ -142,6 +217,21 @@ def main(argv=None):
     # not judge a measurement against some other chip's roofline
     peak_tflops = costmodel.chip_peaks(device["device_kind"])["bf16_tflops"]
     rc = 0
+    if args.cells:
+        for shape in CELL_SHAPES:
+            for tile in [None] + [
+                    tuple(int(e) for e in (t.split("x") * 2)[:2])
+                    for t in args.tiles.split(",") if t]:
+                try:
+                    out = bench_cell_forward(
+                        *shape, tile, args.iters, args.warmup, args.inner,
+                        peak_tflops)
+                except Exception as e:  # noqa: BLE001 — a tile VMEM refuses
+                    out = {"cell": shape[0], "tile": tile,
+                           "error": repr(e)[:300]}
+                    rc = 1
+                print(json.dumps(dict(out, **device)), flush=True)
+        return rc
     for seq in [int(s) for s in args.seqs.split(",") if s]:
         for impl in ("dense", "flash"):
             passes = (False, True) if args.grad else (False,)
