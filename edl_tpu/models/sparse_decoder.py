@@ -11,6 +11,15 @@ attention, or the expert layer's own normed input); the experts' gate
 (``expert_activation``: ReLU or SiLU); RMSNorm over the head width on
 queries and keys (``qk_norm``). The defaults are SmallThinker's.
 
+A model with a ``block_length`` is trained by DIFFUSION OVER BLOCKS
+(BD3-LM, arXiv:2503.09573) instead of next-token prediction: every sequence
+runs through the layers as a stream [x_t ; x_0] — a noised copy, some tokens
+replaced by the mask token block by block (:func:`block_diffusion_noise`, on
+the input side), before the clean copy — with both copies of token i at
+position i and attention under the two-stream block mask
+(``ops/block_diffusion_attention.py``); the head reads the noised half only
+and a masked position's target is its own clean token.
+
 One chip's share of an expert-parallel, head-parallel, vocabulary-parallel
 deployment: a layer is told how many query and key-value heads, which
 experts (``first_expert``, ``experts_held``) and how many rows of the
@@ -25,7 +34,7 @@ attention softmax and logits. Attention goes through the one dispatch
 kernels).
 """
 
-from typing import Any, Optional, Sequence
+from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -33,7 +42,9 @@ import jax.numpy as jnp
 import optax
 
 from edl_tpu.ops import sparse_attention
-from edl_tpu.ops.attention import attention_context, selected_attention
+from edl_tpu.ops.attention import (attention_context,
+                                   block_diffusion_attention,
+                                   selected_attention)
 from edl_tpu.parallel import moe
 
 #: what a layer counts about its routing each step (float32 scalars);
@@ -45,6 +56,11 @@ COUNTERS = ("rows_held", "load_max", "load_mean", "tokens_unserved",
 #: min(position + 1, select_topk) (exact ties at the threshold only), and
 #: the layer's index loss (mean over its tokens); running sums
 SELECT_COUNTERS = ("pairs_kept", "rows_off_count", "index_loss")
+#: and, in a model trained by diffusion over blocks: per layer, the (query,
+#: key) pairs of one head that the attention forward counted under the mask
+#: it applied; and ``loss_tokens``, one scalar for the model: the masked
+#: positions that carried loss; running sums
+BLOCK_DIFFUSION_COUNTERS = ("pairs_attended",)
 
 
 def _init(std=0.02):
@@ -64,13 +80,18 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(x.dtype)
 
 
-def rope(x, theta):
+def rope(x, theta, positions=None):
     """Rotary positions, half-split convention: x [b, s, h, d]; pair i is
-    (x[i], x[i + d/2]), turned by position * theta ** (-2 i / d)."""
+    (x[i], x[i + d/2]), turned by position * theta ** (-2 i / d).
+    ``positions`` [s] are an argument: each row's position, for a stream in
+    which a row's index is not its position (two copies of one sequence);
+    None counts them 0 .. s - 1."""
     d = x.shape[-1]
     half = d // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq[None]
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    ang = positions.astype(jnp.float32)[:, None] * freq[None]
     cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
     x32 = x.astype(jnp.float32)
     a, b = x32[..., :half], x32[..., half:]
@@ -103,6 +124,7 @@ class SparseDecoderLayer(nn.Module):
     router_input: str = "attn_norm"     # or "moe_norm"
     expert_activation: str = "relu"     # or "silu"
     qk_norm: bool = False
+    streams: Optional[Tuple[int, int]] = None   # the two-stream block mask
 
     def _route(self, x):
         b, s, d = x.shape
@@ -141,19 +163,23 @@ class SparseDecoderLayer(nn.Module):
         return qi, ki, wi, tau
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         b, s, d = x.shape
         dt = self.dtype
         proj = lambda name, shape: self.param(name, _init(), shape,
                                               jnp.float32).astype(dt)
         if self.router_input not in ("attn_norm", "moe_norm"):
             raise ValueError("router_input %r" % (self.router_input,))
+        if self.streams and (self.select_topk or self.window):
+            raise ValueError("the two-stream block mask takes no selection "
+                             "and no window")
         h = RMSNorm(self.eps, name="norm_attn")(x)
         if self.router_input == "attn_norm":
             idx, p = self._route(h)
         select = self._index(h, proj) if self.select_topk else None
-        with jax.named_scope("attn.select" if select else "attn.window"
-                             if self.window else "attn.full"):
+        with jax.named_scope("attn.select" if select else
+                             "attn.block_diffusion" if self.streams else
+                             "attn.window" if self.window else "attn.full"):
             q = jnp.einsum("bsd,dhk->bshk", h,
                            proj("query", (d, self.heads, self.head_dim)))
             k = jnp.einsum("bsd,dhk->bshk", h,
@@ -164,10 +190,15 @@ class SparseDecoderLayer(nn.Module):
                 q = RMSNorm(self.eps, name="norm_query")(q)
                 k = RMSNorm(self.eps, name="norm_key")(k)
             if self.use_rope:
-                q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+                q = rope(q, self.rope_theta, positions)
+                k = rope(k, self.rope_theta, positions)
             if select:
                 a, kl, kept = selected_attention(
                     q, k, v, select, dtype=dt, use_flash=self.use_flash)
+            elif self.streams:
+                a, pairs = block_diffusion_attention(
+                    q, k, v, self.streams, dtype=dt,
+                    use_flash=self.use_flash)
             else:
                 a = attention_context(q, k, v, causal=True, mask=None,
                                       dtype=dt, use_flash=self.use_flash,
@@ -193,11 +224,17 @@ class SparseDecoderLayer(nn.Module):
                     rows_off_count=jnp.sum(kept != want[None]).astype(
                         jnp.float32),
                     index_loss=kl.mean())
+        if self.streams:
+            counters = dict(counters, pairs_attended=pairs.sum())
         return x + m.reshape(b, s, d), counters
 
 
 class SparseDecoder(nn.Module):
-    """ids [b, s] -> (float32 logits [b, s, vocab], counters {name: [L]})."""
+    """ids [b, s] -> (float32 logits [b, s, vocab], counters {name: [L]}).
+    ``positions`` [s] come with the ids (default: 0 .. s - 1) and
+    ``streams`` = (block_length, clean_from) is the two-stream block mask
+    of a stream [x_t ; x_0]: the logits are then of the noised half alone,
+    [b, clean_from, vocab]."""
     vocab_size: int            # rows of the vocabulary held here
     d_model: int
     num_layers: int
@@ -225,6 +262,7 @@ class SparseDecoder(nn.Module):
     expert_activation: str = "relu"
     qk_norm: bool = False
     index_loss_weight: float = 1.0
+    block_length: int = 0           # > 0: trained by diffusion over blocks
 
     def select_layers(self):
         """Per layer: whether it reads a learned selection."""
@@ -235,7 +273,7 @@ class SparseDecoder(nn.Module):
         return any(self.select_layers())
 
     @nn.compact
-    def __call__(self, ids):
+    def __call__(self, ids, positions=None, streams=None):
         embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
                            jnp.float32)
         x = jnp.take(embed, ids, axis=0).astype(self.dtype)
@@ -248,6 +286,8 @@ class SparseDecoder(nn.Module):
             SparseDecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(*saved))
             if self.remat else SparseDecoderLayer)
+        # a model that counts its positions calls its layers as it did
+        positions_arg = () if positions is None else (positions,)
         per_layer = []
         for i, select in enumerate(self.select_layers()):
             x, counters = layer_cls(
@@ -265,34 +305,45 @@ class SparseDecoder(nn.Module):
                 index_heads=self.index_heads, index_dim=self.index_dim,
                 router_input=self.router_input,
                 expert_activation=self.expert_activation,
-                qk_norm=self.qk_norm, name="layer_%d" % i)(x)
+                qk_norm=self.qk_norm, streams=streams,
+                name="layer_%d" % i)(x, *positions_arg)
             if self.selects() and not select:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in SELECT_COUNTERS})
             per_layer.append(counters)
+        if streams:                 # the clean half fed keys and values
+            x = x[:, :streams[1]]
         x = RMSNorm(self.eps, name="norm_final")(x)
-        with jax.named_scope("lm_head"):
+        with jax.named_scope("loss.block_diffusion" if streams
+                             else "lm_head"):
             head = self.param("lm_head", _init(),
                               (self.d_model, self.vocab_size), jnp.float32)
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
                                 preferred_element_type=jnp.float32)
         return logits, {n: jnp.stack([c[n] for c in per_layer])
-                        for n in counter_names(self.selects())}
+                        for n in counter_names(self.selects(),
+                                               bool(streams))}
 
 
-def counter_names(selects=False):
-    return COUNTERS + (SELECT_COUNTERS if selects else ())
+def counter_names(selects=False, block_diffusion=False):
+    """The per-layer counters of a model: the routing's and, by what the
+    model does, the selection's or the two-stream attention's."""
+    return (COUNTERS + (SELECT_COUNTERS if selects else ())
+            + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ()))
 
 
-def init_counters(num_layers, selects=False):
+def init_counters(num_layers, selects=False, block_diffusion=False):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
-    model with a selecting layer, the selection's."""
+    model with a selecting layer, the selection's; for one trained by
+    diffusion over blocks, the attention's pairs and the scalar
+    ``loss_tokens``."""
     # one buffer each: the trainer donates its state to the step
+    scalars = ("steps",) + (("loss_tokens",) if block_diffusion else ())
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
-         for n in counter_names(selects)},
-        steps=jnp.zeros((), jnp.float32))}
+         for n in counter_names(selects, block_diffusion)},
+        **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 
 
 def accumulate_counters(extra, step_counters):
@@ -303,18 +354,63 @@ def accumulate_counters(extra, step_counters):
     return dict(extra, counters=new)
 
 
+def block_diffusion_noise(ids, key, block_length, mask_token_id, t_min=1e-3):
+    """The input side of training by diffusion over blocks, for a pipeline
+    to call under jit: ids [rows, T] -> (noisy_ids [rows, T], loss_weight
+    [rows, T] float32). One t ~ U[t_min, 1] a (row, block); each token of
+    the block becomes ``mask_token_id`` independently with probability t
+    (linear schedule, absorbing state); the weight of a masked position is
+    1 / t of its block, of any other 0."""
+    rows, t_len = ids.shape
+    key_t, key_m = jax.random.split(key)
+    t = jax.random.uniform(key_t, (rows, t_len // block_length), jnp.float32,
+                           t_min, 1.0)
+    t = jnp.repeat(t, block_length, axis=1)
+    masked = jax.random.uniform(key_m, (rows, t_len), jnp.float32) < t
+    return (jnp.where(masked, jnp.int32(mask_token_id), ids),
+            jnp.where(masked, 1.0 / t, 0.0))
+
+
+def _block_diffusion_loss(model, params, batch):
+    """(loss, step counters): the stream [x_t ; x_0] with both copies of
+    token i at position i, the head on the noised half, and the mean over
+    rows x T of weight * cross-entropy against the position's OWN clean
+    token (no shift)."""
+    ids, noisy = batch["input_ids"], batch["noisy_ids"]
+    t_len = ids.shape[1]
+    logits, counters = model.apply(
+        {"params": params}, jnp.concatenate([noisy, ids], axis=1),
+        jnp.tile(jnp.arange(t_len), 2), (model.block_length, t_len))
+    with jax.named_scope("loss.block_diffusion"):
+        weight = batch["loss_weight"].astype(jnp.float32)
+        loss = jnp.mean(weight * optax.softmax_cross_entropy_with_integer_labels(
+            logits, ids))
+        counters = dict(counters,
+                        loss_tokens=jnp.sum(weight > 0).astype(jnp.float32))
+    return loss, counters
+
+
 def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
     """(model, params, extra_state, loss_fn) for ElasticTrainer with
     ``has_aux=True``: next-token cross-entropy over batch["input_ids"]
     (shift inside) and, where a layer selects its keys, ``index_loss_weight``
-    times the mean over those layers of their index loss; the extra state
-    carries the routing and selection counters on the device
-    (``trainer.extra_state["counters"]``), which the trainer mirrors into
-    obs.metrics where it synchronises anyway."""
+    times the mean over those layers of their index loss; for a model with a
+    ``block_length``, the block-diffusion loss over batch["input_ids"]
+    (clean), batch["noisy_ids"] and batch["loss_weight"] (m / t, float32:
+    :func:`block_diffusion_noise`). The extra state carries the model's
+    counters on the device (``trainer.extra_state["counters"]``), which the
+    trainer mirrors into obs.metrics where it synchronises anyway."""
     dummy = jnp.zeros((dummy_batch, dummy_seq), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), dummy)["params"]
+    block_diffusion = model.block_length > 0
+    if block_diffusion and model.selects():
+        raise ValueError("diffusion over blocks takes no learned selection")
 
     def loss_fn(params, extra, batch, rng):
+        if block_diffusion:
+            loss, counters = _block_diffusion_loss(model, params, batch)
+            return loss, accumulate_counters(
+                extra, jax.lax.stop_gradient(counters))
         ids = batch["input_ids"]
         logits, counters = model.apply({"params": params}, ids)
         loss = optax.softmax_cross_entropy_with_integer_labels(
@@ -325,5 +421,5 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
         return loss, accumulate_counters(
             extra, jax.lax.stop_gradient(counters))
 
-    return (model, params, init_counters(model.num_layers, model.selects()),
-            loss_fn)
+    return (model, params, init_counters(model.num_layers, model.selects(),
+                                         block_diffusion), loss_fn)

@@ -2,9 +2,10 @@
 sparse decoder): in-shard ring / ring over the sp mesh axis / Pallas flash
 kernel / dense — one copy of the -1e30 mask convention, sm_scale, and the
 interpret mode CPU tests use. The flash and dense paths take a causal
-``window`` or a learned selection of keys (``select``) and fewer key-value
-heads than query heads (grouped-query attention); K and V are never
-repeated in memory. Lives in ops/ (neutral
+``window``, a learned selection of keys (``select``) or the two-stream
+block mask of training by diffusion over blocks (``streams``), and fewer
+key-value heads than query heads (grouped-query attention); K and V are
+never repeated in memory. Lives in ops/ (neutral
 layer) so model modules don't import each other for infrastructure.
 
 ``use_flash=None`` (the default) auto-dispatches: on TPU, shapes the
@@ -27,7 +28,8 @@ _FLASH_HEAD_MULT = 8
 
 
 def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
-                          seq_kv=None, offset=None):
+                          seq_kv=None, offset=None, streams=None,
+                          itemsize=2):
     """Why auto-dispatch would (not) pick flash for this shape.
 
     The kernel takes any number of query heads per key-value head and,
@@ -56,6 +58,14 @@ def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
     any non-None offset is dense-only for the same reason decode is —
     the offset-prefill path in models/gpt.py owns its masked dense
     attention against the cache.
+
+    ``streams`` = (block_length, clean_from): the TWO-STREAM BLOCK MASK
+    (a noised copy of each sequence before its clean copy;
+    ops/block_diffusion_attention.py). Its kernels are refused, and the
+    dense path over the whole [2T, 2T] mask taken, where the clean half is
+    no whole number of tiles, the block length does not divide a tile, or
+    the clean half's k + v (``itemsize`` bytes an element) pass the
+    kernels' resident limit.
     """
     if mask is not None:
         return "attention_mask set (flash kernel has no mask support)"
@@ -75,6 +85,10 @@ def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
     if head_dim % _FLASH_HEAD_MULT != 0:
         return "head_dim %d not a multiple of %d" % (head_dim,
                                                      _FLASH_HEAD_MULT)
+    if streams is not None:
+        from edl_tpu.ops import block_diffusion_attention
+        return block_diffusion_attention.kernel_reason(
+            seq_len, head_dim, streams, itemsize)
     if seq_len > _FLASH_BLOCK and seq_len % _FLASH_BLOCK != 0:
         # ragged q blocks are not masked by the kernel; ragged kv is.
         # Stay conservative: only whole-block (or single-block) seqs.
@@ -119,9 +133,32 @@ def selected_attention(q, k, v, select, *, dtype, use_flash=None):
     return out.astype(dtype), kl, kept
 
 
+def block_diffusion_attention(q, k, v, streams, *, dtype, use_flash=None):
+    """Attention under the TWO-STREAM BLOCK MASK
+    (ops/block_diffusion_attention.py): ``streams`` = (block_length,
+    clean_from) for a stream [x_t ; x_0] of 2 * clean_from rows. Returns
+    (context [b, s, heads, dim], pairs [b, s] float32: the keys each row
+    read under the mask the path applied). The same dispatch as
+    :func:`attention_context`: the Pallas kernels on a TPU for shapes they
+    take (``use_flash=True`` forces them, in the interpreter on the CPU),
+    else dense, with :func:`flash_dispatch_reason` saying why."""
+    from edl_tpu.ops import block_diffusion_attention as bda
+    streams = bda.check_streams(streams, q.shape[1])
+    if use_flash is None:
+        use_flash = flash_dispatch_reason(
+            q.shape[1], q.shape[-1], seq_kv=k.shape[1], streams=streams,
+            itemsize=k.dtype.itemsize) is None
+    if use_flash:
+        out, pairs = bda.attend(q, k, v, streams,
+                                interpret=jax.default_backend() == "cpu")
+    else:
+        out, pairs = bda.dense_attend(q, k, v, streams)
+    return out.astype(dtype), pairs
+
+
 def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
                       use_ring=False, use_flash=None, mesh=None,
-                      window=None, select=None):
+                      window=None, select=None, streams=None):
     """The shared attention-impl dispatch for BERT, GPT and the sparse
     decoder: in-shard ring (already inside a shard_map over
     ``ring_axis``) / ring over the sp mesh axis / Pallas flash kernel /
@@ -137,7 +174,12 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
     ``window``, a padding mask and the ring): (qi, ki, wi, tau) of a learned
     indexer — a query reads the keys of its causal prefix whose index score
     reaches its threshold (:func:`selected_attention`, which also returns
-    the indexer's loss and the keys kept).
+    the indexer's loss and the keys kept). ``streams`` (flash and dense
+    paths; excludes ``causal``, ``window``, ``select``, a padding mask and
+    the ring — it is the whole of what a query may read): (block_length,
+    clean_from), the two-stream block mask of training by diffusion over
+    blocks (:func:`block_diffusion_attention`, which also returns the pairs
+    each row read).
 
     ``use_flash``: ``True`` forces the Pallas flash kernel, ``False``
     forces dense, ``None`` (default) auto-dispatches by
@@ -154,6 +196,19 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
                          % (heads, kv_heads))
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
+    if streams is not None:
+        if causal or window is not None or select is not None:
+            raise ValueError("the two-stream block mask is the whole mask: "
+                             "no causal flag, window or selection beside it")
+        if ring_axis or use_ring:
+            raise ValueError("ring attention takes no two-stream block mask")
+        if mask is not None:
+            raise ValueError("the two-stream block mask takes no padding "
+                             "mask")
+        if q.shape[1] != k.shape[1]:
+            raise ValueError("the two-stream block mask needs square q/kv")
+        return block_diffusion_attention(q, k, v, streams, dtype=dtype,
+                                         use_flash=use_flash)[0]
     if select is not None:
         if not causal:
             raise ValueError("a selection needs causal=True")

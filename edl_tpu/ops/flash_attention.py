@@ -42,7 +42,14 @@ the model's [batch, seq, heads, dim] layout, does the regrouping, and
 keeps its residuals in that layout). A learned SELECTION of single keys
 (``select=``) is no band: ``mha`` and ``flash_attention`` hand it to the
 masked kernels of ops/sparse_attention.py, which share this file's
-conventions and helpers; without one, nothing here changes.
+conventions and helpers; without one, nothing here changes. The TWO-STREAM
+BLOCK MASK of training by diffusion over blocks (``streams=``: a noised and
+a clean copy of each sequence in one stream; a band over the clean keys
+plus one tile of noised ones) is no band of one diagonal either: ``mha``
+and ``flash_attention`` hand it to ops/block_diffusion_attention.py, whose
+two kernels use this file's tile arithmetic (``_softmax_update``,
+``_p_and_ds_kept``); it takes no window and no selection, and needs
+``causal`` unset, the mask being the whole of what a query may read.
 """
 
 import functools
@@ -156,22 +163,14 @@ def _band_mask(q_pos, k_pos, causal, window, kv_len):
     return keep
 
 
-def _softmax_tile(q, k, v, carry, q_lo, k_lo, *, sm_scale, masked, causal,
-                  window, kv_len):
+def _softmax_update(q, k, v, carry, keep, sm_scale):
     """One kv tile of the online softmax: (acc, m, l), float32, moved on
     by p = exp(scale * q.k^T - m). The scores are the expression
     ``_p_and_ds`` rebuilds p from; l sums the float32 p, which is rounded
-    only where it enters p.v. ``masked`` is static: a tile wholly inside
-    the band builds no positions, no compare and no select."""
+    only where it enters p.v. ``keep`` [TQ, TK] is the tile's mask, or None
+    for a tile that needs none (no compare and no select are built)."""
     acc, m, l = carry
     scores = _dot(q, k, _NT) * sm_scale                      # [TQ, TK]
-    keep = None
-    if masked:
-        tq, tk = scores.shape
-        keep = _band_mask(
-            q_lo + lax.broadcasted_iota(jnp.int32, (tq, 1), 0),
-            k_lo + lax.broadcasted_iota(jnp.int32, (1, tk), 1),
-            causal, window, kv_len)
     if keep is not None:
         scores = jnp.where(keep, scores, _NEG_INF)
     m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
@@ -181,6 +180,20 @@ def _softmax_tile(q, k, v, carry, q_lo, k_lo, *, sm_scale, masked, causal,
     correction = jnp.exp(m - m_new)
     return (acc * correction + _dot(p.astype(v.dtype), v, _NN), m_new,
             l * correction + p.sum(axis=-1, keepdims=True))
+
+
+def _softmax_tile(q, k, v, carry, q_lo, k_lo, *, sm_scale, masked, causal,
+                  window, kv_len):
+    """``_softmax_update`` for a tile of the causal band. ``masked`` is
+    static: a tile wholly inside the band builds no positions."""
+    keep = None
+    if masked:
+        tq, tk = q.shape[0], k.shape[0]
+        keep = _band_mask(
+            q_lo + lax.broadcasted_iota(jnp.int32, (tq, 1), 0),
+            k_lo + lax.broadcasted_iota(jnp.int32, (1, tk), 1),
+            causal, window, kv_len)
+    return _softmax_update(q, k, v, carry, keep, sm_scale)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
@@ -452,21 +465,28 @@ def _q_band(xp, k_lo, block_q, block_k, n_q, n_k, causal, window, ragged):
     return first, ufirst, xp.clip(ulast, ufirst, last), last
 
 
-def _p_and_ds(a, b, da, db, lse, delta, q_pos, k_pos, *, sm_scale, masked,
-              causal, window, kv_len):
+def _p_and_ds_kept(a, b, da, db, lse, delta, keep, sm_scale):
     """One tile of the recomputed softmax and of its gradient, float32:
     p = exp(scale * a.b^T - lse), ds = p * (da.db^T - delta). (a, b) is
-    (q, k) with lse, delta and q_pos columns, or (k, q) with them rows: the
-    tile is then the transpose, with no transposing done. The scale
-    goes to the float32 scores, so q is not rounded again. ``masked`` is
-    static: only a tile that straddles the diagonal, the band's far edge
-    or the padded tail builds a mask."""
+    (q, k) with lse and delta columns, or (k, q) with them rows: the tile
+    is then the transpose, with no transposing done. The scale goes to the
+    float32 scores, so q is not rounded again. ``keep`` is the tile's mask
+    or None."""
     p = jnp.exp(_dot(a, b, _NT) * sm_scale - lse)
-    keep = _band_mask(q_pos, k_pos, causal, window, kv_len) if masked \
-        else None
     if keep is not None:            # else a loop of no trips, traced
         p = jnp.where(keep, p, 0.0)
     return p, p * (_dot(da, db, _NT) - delta)
+
+
+def _p_and_ds(a, b, da, db, lse, delta, q_pos, k_pos, *, sm_scale, masked,
+              causal, window, kv_len):
+    """``_p_and_ds_kept`` for a tile of the causal band; q_pos is a column
+    with (a, b) = (q, k) and a row with (k, q). ``masked`` is static: only
+    a tile that straddles the diagonal, the band's far edge or the padded
+    tail builds a mask."""
+    keep = _band_mask(q_pos, k_pos, causal, window, kv_len) if masked \
+        else None
+    return _p_and_ds_kept(a, b, da, db, lse, delta, keep, sm_scale)
 
 
 def _bwd_kernel_resident(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -791,9 +811,24 @@ def _selected(q, k, v, causal, sm_scale, window, interpret, select):
                                           interpret=interpret)[0]
 
 
+def _two_streams(q, k, v, causal, sm_scale, window, interpret, select,
+                 streams):
+    """The two-stream block mask's kernels live in
+    ops/block_diffusion_attention.py (`bdiff_*`): a band over the clean
+    half plus one tile of the noised half, which `_kv_band` cannot say.
+    Model layout in and out."""
+    from edl_tpu.ops import block_diffusion_attention
+    if causal or window is not None or select is not None:
+        raise ValueError("the two-stream block mask is the whole mask: no "
+                         "causal flag, window or selection beside it")
+    return block_diffusion_attention.attend(q, k, v, streams,
+                                            sm_scale=sm_scale,
+                                            interpret=interpret)[0]
+
+
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
                     block_k=None, interpret=False, window=None, group=1,
-                    select=None):
+                    select=None, streams=None):
     """Blockwise exact attention; k/v are [batch, kv_heads, seq, dim] and
     q/out [batch, kv_heads, group * seq, dim]: the ``group`` query heads
     of a kv head one run of the sequence after another (``group=1``: the
@@ -802,9 +837,15 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     ``block_q`` / ``block_k`` fix the forward's tile, which is otherwise
     chosen from the sequence (``_tile_edge``), as the backward's always is;
     ``select`` = (qi, ki, wi, tau) in the models' layout keeps the keys a
-    learned indexer chose (ops/sparse_attention.py)."""
+    learned indexer chose (ops/sparse_attention.py); ``streams`` =
+    (block_length, clean_from) is the two-stream block mask
+    (ops/block_diffusion_attention.py)."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if streams is not None:
+        return _kernel_layout(_two_streams(
+            _model_layout(q, group), _model_layout(k), _model_layout(v),
+            causal, sm_scale, window, interpret, select, streams), group)
     if select is not None:
         return _kernel_layout(_selected(
             _model_layout(q, group), _model_layout(k), _model_layout(v),
@@ -814,11 +855,14 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
 
 
 def mha(q, k, v, causal=False, sm_scale=None, window=None, block_q=None,
-        block_k=None, interpret=False, select=None):
+        block_k=None, interpret=False, select=None, streams=None):
     """The same for [batch, seq, heads, dim] arrays (the model code's
     layout). k and v may have fewer heads than q (grouped-query attention:
     query head i reads kv head i // group); they are not repeated in
     memory."""
+    if streams is not None:
+        return _two_streams(q, k, v, causal, sm_scale, window, interpret,
+                            select, streams)
     if select is not None:
         return _selected(q, k, v, causal, sm_scale, window, interpret,
                          select)

@@ -589,13 +589,26 @@ def test_keye_head_shares_add_up_to_the_uncut_layer(keye):
     np.testing.assert_allclose(total, want, atol=1e-5, rtol=1e-4)
 
 
+#: configurations whose layer holds SiLU experts behind a post-attention
+#: router, and the key that names the router's width in each
+SILU_EXPERT_CONFIGS = {KEYE: "num_local_experts",
+                       "sdar-30b-a3b-chat": "num_router_outputs"}
+
+
+@pytest.mark.parametrize("config", sorted(SILU_EXPERT_CONFIGS))
 @pytest.mark.parametrize("who", ["reference", "program"])
-def test_keye_expert_shares_add_up_to_the_uncut_layer(keye, who):
+def test_silu_expert_shares_add_up_to_the_uncut_layer(config, who):
     """Sixteen shares of 1 SiLU expert each, the router whole in every
-    one."""
-    _, ref, _, _, _ = keye
-    whole, lw, x = _keye_uncut_layer(keye)
-    u = x.reshape(-1, x.shape[-1])
+    one, add up to the uncut reference's expert layer (16 experts of a
+    16-wide router at the tiny widths)."""
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         config + ".json"))
+    ref = harness.load_module("reference", config)
+    whole = dict(cfg, **cfg["tiny"])
+    whole.update({"num_experts": 16, SILU_EXPERT_CONFIGS[config]: 16,
+                  "num_hidden_layers": 1, "first_expert": 0})
+    lw = ref.layer_weights(ref.init_weights(whole, jax.random.PRNGKey(7)), 0)
+    u = jax.random.normal(jax.random.PRNGKey(8), (64, whole["hidden_size"]))
     idx, p = ref.route(u, lw["w_r"], whole)
     want = ref.experts_part(u, idx, p, lw, whole)
     total = jnp.zeros_like(want)
