@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from edl_tpu.ops import block_diffusion_attention as bd_attention
 from edl_tpu.ops import sparse_attention
 from edl_tpu.ops.attention import (attention_context,
                                    block_diffusion_attention,
@@ -61,6 +62,18 @@ SELECT_COUNTERS = ("pairs_kept", "rows_off_count", "index_loss")
 #: it applied; and ``loss_tokens``, one scalar for the model: the masked
 #: positions that carried loss; running sums
 BLOCK_DIFFUSION_COUNTERS = ("pairs_attended",)
+#: what a layer under remat keeps for its backward, the one policy of every
+#: model of the family (a name that no layer of a model emits saves
+#: nothing): the chosen experts with the two grouped products' results (the
+#: part whose cost follows the routing); a selecting layer's indexer
+#: operands and thresholds (the choice WITH what it was made from); and the
+#: masked attention kernels' own residuals, result and lse — 34 MB a layer
+#: at a 16384-row stream of 8 heads of 128, against a second ``dsa_fwd``
+#: (10 ms) or ``bdiff_fwd`` (2.7 ms) in every layer's backward that would
+#: only rebuild them. The band kernels name no residual and run twice.
+SAVED_UNDER_REMAT = (moe.SAVED_UNDER_REMAT
+                     + sparse_attention.SAVED_UNDER_REMAT
+                     + bd_attention.SAVED_UNDER_REMAT)
 
 
 def _init(std=0.02):
@@ -277,14 +290,10 @@ class SparseDecoder(nn.Module):
         embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
                            jnp.float32)
         x = jnp.take(embed, ids, axis=0).astype(self.dtype)
-        # remat by layer, keeping the chosen experts and the two grouped
-        # products' results: the experts' forward is the one part whose
-        # cost follows the routing
-        saved = moe.SAVED_UNDER_REMAT + (
-            sparse_attention.SAVED_UNDER_REMAT if self.selects() else ())
         layer_cls = (nn.remat(
             SparseDecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(*saved))
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *SAVED_UNDER_REMAT))
             if self.remat else SparseDecoderLayer)
         # a model that counts its positions calls its layers as it did
         positions_arg = () if positions is None else (positions,)

@@ -30,7 +30,11 @@ served by one rebuilt tile of I:
 
 ``select_attend`` ties them into one ``custom_vjp``; ``index_thresholds``
 and ``dense_select_attend`` are the plain ``jax.numpy`` twins the dense
-dispatch and the tests use. Products take their operands in the dtype they
+dispatch and the tests use. The forward rule NAMES the residuals it hands
+the backward (``SAVED_RESIDUALS``: the result, lse, the indexer's lse), so
+that a layer rematerialised under a policy that saves the names keeps them
+and its recomputation holds no ``dsa_fwd``: the kernel runs once a layer
+and step. Products take their operands in the dtype they
 arrive in with float32 accumulation; scores, thresholds, probabilities and
 statistics are float32.
 """
@@ -41,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,14 +57,23 @@ FWD_NAME = "dsa_fwd"
 KL_NAME = "dsa_index_kl"
 BWD_NAME = "dsa_bwd"
 
+#: `checkpoint_name`s of what ``dsa_fwd`` leaves its backward — the result
+#: (model layout), lse, the indexer's lse — put on them INSIDE the
+#: `custom_vjp`'s forward rule (a name on the layer's context outside it
+#: would keep the context and still rerun the kernel for lse). At 16384
+#: positions and 8 heads of 128 a layer under remat keeps 34 MB against the
+#: 10 ms of a second call whose every value the first had made; the kernels
+#: are deterministic, so the gradients are bit for bit a recomputation's.
+#: kl and kept are results of the one forward call: no backward reads them.
+SAVED_RESIDUALS = ("attn.select_out", "attn.select_lse", "attn.select_lse_i")
+#: all a layer under remat keeps of its selection: first the
 #: `checkpoint_name`s of the indexer's queries, keys and head weights and of
-#: the thresholds: a layer under remat keeps the choice WITH what it was
-#: made from (35 MiB a layer at 16384 positions). A recomputed score that
-#: differs in its last bit from the one its threshold was found among moves
-#: a key across it, as a recomputed top-k of the router breaks a near tie
-#: the other way.
+#: the thresholds — the choice WITH what it was made from (35 MiB a layer at
+#: 16384 positions). A recomputed score that differs in its last bit from
+#: the one its threshold was found among moves a key across it, as a
+#: recomputed top-k of the router breaks a near tie the other way.
 SAVED_UNDER_REMAT = ("attn.index_q", "attn.index_k", "attn.index_w",
-                     "attn.tau")
+                     "attn.tau") + SAVED_RESIDUALS
 
 _BLOCK = 512
 _TAU_BLOCK_Q = 128
@@ -524,11 +538,13 @@ def _select_attend(q, k, v, qi, ki, wi, tau, sm_scale, block, interpret):
 
 def _select_attend_fwd(q, k, v, qi, ki, wi, tau, sm_scale, block, interpret):
     """Arguments, results and residuals in the models' layout (the kernels'
-    copies live for the length of a kernel, as in ops/flash_attention)."""
+    copies live for the length of a kernel, as in ops/flash_attention). The
+    residuals the kernel made carry ``SAVED_RESIDUALS``' names."""
     out, lse, lse_i, kept, kl = _forward(
         _heads_first(q), _heads_first(k), _heads_first(v), _heads_first(qi),
         ki, _scaled(wi, qi), tau[:, None], sm_scale, block, interpret)
-    out = _heads_first(out)
+    out, lse, lse_i = (checkpoint_name(x, n) for x, n in zip(
+        (_heads_first(out), lse, lse_i), SAVED_RESIDUALS))
     return ((out, kl[:, 0], kept[:, 0]),
             (q, k, v, qi, ki, wi, tau, out, lse, lse_i))
 

@@ -6,6 +6,8 @@ test of the `sdar-30b-a3b-chat` configuration.
 
 - the kernels (Pallas interpreter) and the dense path against a plain masked
   softmax built from the four rules: result, lse, pairs, dq, dk, dv;
+- under remat: a policy that saves the forward rule's named residuals
+  leaves the gradient ONE forward kernel, with the same bits;
 - the tiles the kernels visit are the tiles the mask reaches, and the pairs
   are T^2 + T x block length;
 - the decoder's loss and gradient against `benchmark/reference/
@@ -22,6 +24,7 @@ import numpy as np
 import pytest
 
 from benchmark.lib import harness, kernel_readers
+from jaxpr_kernels import gradient_kernel_calls, pallas_call_names
 from edl_tpu.models import sparse_decoder
 from edl_tpu.ops import attention
 from edl_tpu.ops import block_diffusion_attention as bda
@@ -111,6 +114,31 @@ def test_kernel_backward_matches_the_plain_mask(t_len, b_len, dtype):
     for a, b in zip(got, want):
         assert a.dtype == q.dtype
         _close(a, b, dtype)
+
+
+@pytest.mark.parametrize("saved,forwards", [(bda.SAVED_UNDER_REMAT, 1),
+                                            ((), 2)])
+def test_remat_that_saves_the_residuals_runs_the_forward_once(saved,
+                                                              forwards):
+    """The forward rule names what it leaves the backward (out, lse): under
+    a policy that saves the names the recomputation holds no forward
+    kernel, under one without them it holds a second; and either way the
+    gradients are bit for bit those of no remat at all."""
+    t_len, b_len = 128, 4
+    q, k, v, w = _inputs(t_len)
+
+    def layer(q, k, v):
+        out, _ = bda.attend(q * 1.5, k, v, (b_len, t_len), interpret=True)
+        return jnp.sum(out * out * w)
+
+    rematted = jax.grad(jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(*saved)),
+        (0, 1, 2))
+    assert sorted(pallas_call_names(jax.make_jaxpr(rematted)(q, k, v).jaxpr)) \
+        == sorted([bda.FWD_NAME] * forwards + [bda.BWD_NAME])
+    for got, want in zip(jax.jit(rematted)(q, k, v),
+                         jax.jit(jax.grad(layer, (0, 1, 2)))(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("t_len,b_len", SHAPES)
@@ -291,6 +319,28 @@ def test_matches_reference_bfloat16(sdar, reference):
     num = sum(float(jnp.sum(jnp.square(got[k] - want[k]))) for k in want)
     den = sum(float(jnp.sum(jnp.square(want[k]))) for k in want)
     assert (num / den) ** 0.5 < 0.05
+
+
+def test_remat_keeps_what_the_attention_left_its_backward(sdar):
+    """Under remat the layer saves the two-stream kernel's own residuals
+    (`bda.SAVED_UNDER_REMAT`, in the family's one policy) beside the chosen
+    experts: in bf16 every gradient leaf matches the layer that recomputes
+    nothing, and the gradient holds `bdiff_fwd` once a layer, as without
+    remat."""
+    cfg, _, fam, w, batch = sdar
+    plain = _leaves(_loss_and_grad(cfg, fam, w, batch, jnp.bfloat16,
+                                   remat=False)[1])
+    rematted = _leaves(_loss_and_grad(cfg, fam, w, batch, jnp.bfloat16,
+                                      remat=True)[1])
+    for leaf, want in plain.items():
+        err = float(jnp.linalg.norm(rematted[leaf] - want)
+                    / jnp.linalg.norm(want))
+        assert err < 0.02, (leaf, err)
+    assert set(bda.SAVED_UNDER_REMAT) <= set(sparse_decoder.SAVED_UNDER_REMAT)
+    for remat in (True, False):
+        calls = gradient_kernel_calls(fam, cfg, w, batch, remat)
+        for kernel in (bda.FWD_NAME, bda.BWD_NAME):
+            assert calls[kernel] == cfg["num_hidden_layers"]
 
 
 def test_int8_control_is_far_from_the_reference(sdar, reference):

@@ -7,6 +7,8 @@ and its place in the one dispatch (`ops/attention.py`):
   the dense masked path at 4 x and 8 x the top-k with tiles that cross the
   thresholds: the result, the indexer's loss, the keys kept, and every
   gradient — the indexer's ON ITS OWN;
+- under remat: a policy that saves the forward rule's named residuals
+  leaves the gradient ONE forward kernel, with the same bits;
 - the dispatch: what a selection excludes, and that WITHOUT one the band
   kernels take the operands they took before (no dummy selection threaded
   through the plain path).
@@ -17,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
+from jaxpr_kernels import pallas_call_names
 
 from edl_tpu.ops import attention, flash_attention
 from edl_tpu.ops import sparse_attention as sa
@@ -168,6 +171,35 @@ def test_kernel_names_do_not_collide_with_the_readers_of_the_others():
     for a in ours:
         assert not any(t in a for t in theirs)
         assert not any(a in b for b in ours if b != a)
+
+
+@pytest.mark.parametrize("saved,forwards", [
+    (sa.SAVED_UNDER_REMAT, 1), (sa.SAVED_UNDER_REMAT[:4], 2)])
+def test_remat_that_saves_the_residuals_runs_the_forward_once(saved,
+                                                              forwards):
+    """The forward rule names what it leaves the backward (out, lse, the
+    indexer's lse): under a policy that saves the names the recomputation
+    holds no forward kernel, under one without them it holds a second; and
+    either way the gradients are bit for bit those of no remat at all."""
+    q, k, v, qi, ki, wi = _inputs(jnp.float32, s=32)
+    tau = _tau_between(qi, ki, wi, 8)
+
+    def layer(q, k, v, qi, ki, wi):
+        out, kl, _ = sa.select_attend(q * 1.5, k, v, (qi, ki, wi, tau),
+                                      interpret=True, block=8)
+        return jnp.sum(out * out) + kl.sum()
+
+    rematted = jax.grad(jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(*saved)),
+        range(6))
+    names = pallas_call_names(
+        jax.make_jaxpr(rematted)(q, k, v, qi, ki, wi).jaxpr)
+    assert sorted(names) == sorted(
+        [sa.FWD_NAME] * forwards + [sa.KL_NAME, sa.BWD_NAME])
+    for got, want in zip(jax.jit(rematted)(q, k, v, qi, ki, wi),
+                         jax.jit(jax.grad(layer, range(6)))(
+                             q, k, v, qi, ki, wi)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_kernels_refuse_what_does_not_fit_vmem():

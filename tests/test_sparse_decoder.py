@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from benchmark.lib import harness, kernel_readers
+from jaxpr_kernels import gradient_kernel_calls
 from edl_tpu.models import sparse_decoder
 from edl_tpu.ops import grouped_matmul as gm
 from edl_tpu.parallel import moe
@@ -109,7 +110,10 @@ def test_remat_keeps_the_choice_with_the_saved_products(tiny):
     """Under remat the layer saves the chosen experts WITH the two grouped
     products (`moe.SAVED_UNDER_REMAT`): in bf16, where a recomputed top-k
     can break a near tie the other way, every gradient leaf — the routers'
-    above all — matches the layer that recomputes nothing."""
+    above all — matches the layer that recomputes nothing. The policy is
+    the family's one (`sparse_decoder.SAVED_UNDER_REMAT`), which also holds
+    the masked attention kernels' residual names: a model of band layers
+    alone emits none of them, builds and differentiates as it did."""
     cfg, _, fam, w, batch = tiny
 
     def grads(remat):
@@ -124,6 +128,23 @@ def test_remat_keeps_the_choice_with_the_saved_products(tiny):
         err = float(jnp.linalg.norm(rematted[leaf] - want)
                     / jnp.linalg.norm(want))
         assert err < 0.02, (leaf, err)
+
+
+def test_band_layers_emit_none_of_the_names_and_still_run_forward_twice(
+        tiny):
+    """The band kernels name no residual (their cost count in
+    benchmark/program/sparse_decoder.py holds two forwards under remat: the
+    band path follows once that count reads the trace's calls): under the
+    widened policy a rematerialised band layer holds the forward kernel
+    twice, as it did, and once without remat."""
+    from edl_tpu.ops import flash_attention as fa
+    cfg, _, fam, w, batch = tiny
+    layers = cfg["num_hidden_layers"]
+    assert set(moe.SAVED_UNDER_REMAT) <= set(sparse_decoder.SAVED_UNDER_REMAT)
+    for remat, forwards in ((True, 2), (False, 1)):
+        calls = gradient_kernel_calls(fam, cfg, w, batch, remat)
+        assert calls[fa.FWD_RESIDENT_NAME] == forwards * layers
+        assert calls[fa.BWD_NAME] == layers
 
 
 def test_int8_control_is_far_from_the_reference(tiny):
@@ -534,7 +555,9 @@ def test_keye_matches_reference_bfloat16(keye):
 def test_keye_remat_keeps_the_thresholds_with_what_depends_on_them(keye):
     """Under remat the layer saves the thresholds WITH the indexer's
     operands they were found from, beside the chosen experts: in bf16
-    every gradient leaf matches the layer that recomputes nothing."""
+    every gradient leaf matches the layer that recomputes nothing. It saves
+    the selection kernel's own residuals too, so the gradient holds
+    `dsa_fwd` once a layer, as without remat."""
     cfg, _, fam, w, batch = keye
     plain = _leaves(_keye_loss_and_grad(cfg, fam, w, batch, jnp.bfloat16,
                                         remat=False)[1])
@@ -546,6 +569,17 @@ def test_keye_remat_keeps_the_thresholds_with_what_depends_on_them(keye):
         assert err < 0.02, (leaf, err)
     from edl_tpu.ops import sparse_attention
     assert sparse_attention.SAVED_UNDER_REMAT[3] == "attn.tau"
+    assert set(sparse_attention.SAVED_RESIDUALS) \
+        <= set(sparse_attention.SAVED_UNDER_REMAT) \
+        <= set(sparse_decoder.SAVED_UNDER_REMAT)
+    layers = cfg["num_hidden_layers"]
+    for remat in (True, False):
+        calls = gradient_kernel_calls(fam, cfg, w, batch, remat)
+        # (the thresholds come from `lax.top_k` off the chip)
+        assert {n: c for n, c in calls.items() if n.startswith("dsa")} == {
+            sparse_attention.FWD_NAME: layers,
+            sparse_attention.KL_NAME: layers,
+            sparse_attention.BWD_NAME: layers}
 
 
 def test_keye_int8_control_is_far_from_the_reference(keye):
