@@ -28,7 +28,10 @@ save, never per step or per request) are always opened and land in the
 ring whatever the sampling switch says — the benchmark's readers and
 the flight recorder need a resize's stages in every run; ``EDL_TPU_OBS=0``
 keeps them out of the ring (the span object is still handed back, so a
-caller that reads its duration keeps working).
+caller that reads its duration keeps working). A recorded stage span
+also says how long the garbage collector ran while it was open (tag
+``gc_ms``, only where it did): a collection holds the interpreter, so it
+stalls whichever thread's stage is open (:func:`gc_seconds`).
 
 One clock with the profiler: a span records its start on
 ``time.monotonic()`` (``t0``) beside the unix ``ts``, and a span opened
@@ -42,6 +45,7 @@ annotated: an RPC span may close on another thread than it opened on.
 """
 
 import contextlib
+import gc
 import os
 import random
 import sys
@@ -61,16 +65,40 @@ TRACE_ENV = "EDL_TPU_TRACE"
 ANNOTATION_PREFIX = "edl:"
 
 
+#: the collector's account: [seconds inside collections so far, start of
+#: the one running or None, callback installed]
+_GC = [0.0, None, False]
+
+
+def _on_gc(phase, info):
+    if phase == "start":
+        _GC[1] = time.monotonic()
+    elif _GC[1] is not None:
+        _GC[0] += time.monotonic() - _GC[1]
+        _GC[1] = None
+
+
+def gc_seconds():
+    """Seconds this process has spent inside garbage collections since
+    the first recorded stage span asked (one ``gc.callbacks`` entry,
+    installed then). A stage span reads it when it opens and when it
+    closes; nothing is paid per step."""
+    if not _GC[2]:
+        _GC[2] = True
+        gc.callbacks.append(_on_gc)
+    return _GC[0]
+
+
 def _new_id():
     return "%016x" % random.getrandbits(64)
 
 
 class Span(object):
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "kind",
-                 "ts", "_t0", "dur_ms", "tags", "pid", "recorded")
+                 "ts", "_t0", "dur_ms", "tags", "pid", "recorded", "_gc0")
 
     def __init__(self, trace_id, span_id, parent_id, name, kind, tags,
-                 recorded=True):
+                 recorded=True, stage=False):
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
@@ -84,6 +112,8 @@ class Span(object):
         # False: timed for its caller, kept out of the ring and off the
         # profiler's line (a stage span under EDL_TPU_OBS=0)
         self.recorded = recorded
+        # the collector's account as a recorded stage span opens
+        self._gc0 = gc_seconds() if stage and recorded else None
 
     @property
     def seconds(self):
@@ -91,7 +121,9 @@ class Span(object):
         return self.dur_ms / 1e3
 
     def tag(self, **tags):
-        """Add tags to a span that is still open."""
+        """Add tags to a span. The ring keeps the span itself and reads
+        its tags when the ring is read, so an account worked out after
+        the span closed (off the path it times) still reaches it."""
         self.tags = dict(self.tags or {}, **tags)
 
     def to_dict(self):
@@ -202,7 +234,7 @@ def begin_span(name, kind="local", parent=None, root=False, tags=None,
     else:
         trace_id, parent_id = _new_id() + _new_id(), None
     return Span(trace_id, _new_id(), parent_id, name, kind, tags,
-                recorded=_metrics._ENABLED or not stage)
+                recorded=_metrics._ENABLED or not stage, stage=stage)
 
 
 def end_span(span, **extra_tags):
@@ -213,6 +245,8 @@ def end_span(span, **extra_tags):
     span.dur_ms = (time.monotonic() - span._t0) * 1e3
     if extra_tags:
         span.tag(**extra_tags)
+    if span._gc0 is not None and _GC[0] != span._gc0:
+        span.tag(gc_ms=(_GC[0] - span._gc0) * 1e3)
     if span.recorded:
         TRACER._record(span)
 

@@ -245,15 +245,31 @@ def _untag_array(arr, tag):
 def _start_host_transfers(tree):
     """Kick off non-blocking device->host DMAs for every addressable
     shard of every jax leaf, so the per-shard np.asarray fetches that
-    follow overlap instead of serializing (phase 1 of the async save)."""
-    for leaf in jax.tree_util.tree_leaves(tree):
-        for s in getattr(leaf, "addressable_shards", ()):
+    follow overlap instead of serializing (phase 1 of the async save).
+    Returns (calls started, bytes of the shards they were started on).
+    Best-effort: the first call that raises ends it, and the rest of the
+    tree is then fetched serially."""
+    # a leaf's shards are of one size. It is read before the first
+    # transfer is asked for: between two of those calls the same read
+    # costs several times more (on four v5e chips 7 us, 12 ms a save)
+    leaves = [x for x in jax.tree_util.tree_leaves(tree)
+              if getattr(x, "addressable_shards", None)]
+    sizes = [int(x.addressable_shards[0].data.nbytes) for x in leaves]
+    calls = nbytes = 0
+    for leaf, each in zip(leaves, sizes):
+        for s in leaf.addressable_shards:
             start = getattr(s.data, "copy_to_host_async", None)
             if start is not None:
                 try:
                     start()
-                except Exception:  # pragma: no cover — best-effort
-                    return
+                except Exception as e:  # noqa: BLE001 — best-effort
+                    logger.debug("host transfers stopped after %d calls "
+                                 "(%r): the rest is fetched serially",
+                                 calls, e)
+                    return calls, nbytes
+                calls += 1
+                nbytes += each
+    return calls, nbytes
 
 
 class _HostBufferPool(object):
@@ -264,6 +280,7 @@ class _HostBufferPool(object):
 
     def __init__(self):
         self._bufs = {}
+        self.allocated = 0  # buffers made so far (a reuse makes none)
 
     def copy_in(self, key, arr):
         arr = np.asarray(arr)
@@ -271,8 +288,55 @@ class _HostBufferPool(object):
         if buf is None or buf.shape != arr.shape or buf.dtype != arr.dtype:
             buf = np.empty(arr.shape, arr.dtype)
             self._bufs[key] = buf
+            self.allocated += 1
         np.copyto(buf, arr)
         return buf
+
+
+class _SnapshotAccount(object):
+    """What one ``save.snapshot`` is made of. Its three parts interleave
+    per leaf, so only the first is a span of its own
+    (``save.snapshot.start_transfers``); the other two are summed in
+    seconds and handed to the span as tags, with the bytes kept and
+    asked for. ``on`` False (a span ``EDL_TPU_OBS=0`` keeps out of the
+    ring): the same calls, no account."""
+
+    def __init__(self, pool, on):
+        self._pool, self.on = pool, on
+        self._bufs0 = pool.allocated
+        self.fetch_s = self.copy_s = 0.0
+        self.started = (0, 0)
+
+    def start(self, tree):
+        with obs_trace.span("save.snapshot.start_transfers", stage=True):
+            self.started = _start_host_transfers(tree)
+
+    def fetch(self, x):
+        """np.asarray of a leaf or shard: the wait for the device's copy
+        and the read of it."""
+        if not self.on:
+            return np.asarray(x)
+        t = time.perf_counter()
+        arr = np.asarray(x)
+        self.fetch_s += time.perf_counter() - t
+        return arr
+
+    def keep(self, skey, arr):
+        """The copy into the pool's buffer for ``skey``."""
+        if not self.on:
+            return self._pool.copy_in(skey, arr)
+        t = time.perf_counter()
+        buf = self._pool.copy_in(skey, arr)
+        self.copy_s += time.perf_counter() - t
+        return buf
+
+    def tags(self, leaves, entries):
+        return {"fetch_s": self.fetch_s, "copy_s": self.copy_s,
+                "bytes": sum(int(a.nbytes) for a in entries.values()),
+                "leaves": leaves,
+                "transfers_started": self.started[0],
+                "transfer_bytes_started": self.started[1],
+                "bufs_new": self._pool.allocated - self._bufs0}
 
 
 class SaveHandle(object):
@@ -605,11 +669,23 @@ class CheckpointManager(object):
 
     # -- async save: snapshot phase ------------------------------------------
 
-    def _snapshot_dense(self, tree):
-        """Phase-1 snapshot of a full tree: {span_key: host ndarray}
-        (wire dtypes) + dtype tags, copied into the reused buffer pool
-        so later steps may donate/mutate the originals."""
-        _start_host_transfers(tree)
+    def _snapshot(self, take, tree, *args):
+        """Phase 1 of an async save, the only part the training thread
+        pays: the stage span ``save.snapshot`` round ``take`` (one of the
+        two below), tagged with what the snapshot was made of. Returns
+        (entries, dtypes, the span's seconds)."""
+        with obs_trace.span("save.snapshot", stage=True) as sp:
+            acct = _SnapshotAccount(self._host_bufs, sp.recorded)
+            leaves, entries, dtypes = take(tree, acct, *args)
+            if acct.on:
+                sp.tag(**acct.tags(leaves, entries))
+        return entries, dtypes, sp.seconds
+
+    def _snapshot_dense(self, tree, acct):
+        """Snapshot of a full tree: {span_key: host ndarray} (wire
+        dtypes) + dtype tags, copied into the reused buffer pool so
+        later steps may donate/mutate the originals."""
+        acct.start(tree)
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)
         entries = {}
         dtypes = {}
@@ -618,30 +694,30 @@ class CheckpointManager(object):
             if not getattr(leaf, "is_fully_addressable", True):
                 from jax.experimental import multihost_utils
                 leaf = multihost_utils.process_allgather(leaf, tiled=True)
-            arr, tag = _wire_entry(np.asarray(leaf))
+            arr, tag = _wire_entry(acct.fetch(leaf))
             if tag:
                 dtypes[key] = tag
             skey = self._shard_key(key, tuple(slice(0, d)
                                               for d in arr.shape),
                                    arr.shape)
-            entries[skey] = self._host_bufs.copy_in(skey, arr)
-        return entries, dtypes
+            entries[skey] = acct.keep(skey, arr)
+        return len(flat), entries, dtypes
 
-    def _snapshot_sharded(self, tree, rank):
-        """Phase-1 snapshot of this rank's OWNED shards (replica_id 0
-        dedup; host/replicated-only leaves land on rank 0), mirroring
-        what the sync sharded writer persists."""
-        _start_host_transfers(tree)
+    def _snapshot_sharded(self, tree, acct, rank):
+        """Snapshot of this rank's OWNED shards (replica_id 0 dedup;
+        host/replicated-only leaves land on rank 0), mirroring what the
+        sync sharded writer persists."""
+        acct.start(tree)
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)
         entries = {}
         dtypes = {}
 
         def add(key, index, shape, arr):
-            arr, tag = _wire_entry(np.asarray(arr))
+            arr, tag = _wire_entry(acct.fetch(arr))
             if tag:
                 dtypes[key] = tag
             skey = self._shard_key(key, index, shape)
-            entries[skey] = self._host_bufs.copy_in(skey, arr)
+            entries[skey] = acct.keep(skey, arr)
 
         for path, leaf in flat:
             key = _path_key(path)
@@ -651,10 +727,10 @@ class CheckpointManager(object):
                     if s.replica_id == 0:
                         add(key, s.index, leaf.shape, s.data)
             elif rank == 0:
-                arr = np.asarray(leaf)
+                arr = acct.fetch(leaf)
                 add(key, tuple(slice(0, d) for d in arr.shape),
                     arr.shape, arr)
-        return entries, dtypes
+        return len(flat), entries, dtypes
 
     # -- async save: persist phase -------------------------------------------
 
@@ -786,11 +862,11 @@ class CheckpointManager(object):
         never surfaced as a save failure."""
         self.drain()
         # the snapshot is the async save's only training-thread cost
-        with obs_trace.span("save.snapshot", stage=True) as sp, \
-                obs_ledger.LEDGER.state("ckpt_block"):
-            entries, dtypes = self._snapshot_dense(tree)
+        with obs_ledger.LEDGER.state("ckpt_block"):
+            entries, dtypes, blocked_s = self._snapshot(
+                self._snapshot_dense, tree)
         handle = SaveHandle(version)
-        handle.blocked_s = sp.seconds
+        handle.blocked_s = blocked_s
         save_span = obs_trace.current()  # the trainer's `save`, or None
 
         def persist():
@@ -1096,10 +1172,10 @@ class CheckpointManager(object):
         shardmeta/MANIFEST carry ``format: "stream"`` with the per-file
         entry tables instead of per-rank npz crcs."""
         self.drain()
-        with obs_trace.span("save.snapshot", stage=True) as sp:
-            entries, dtypes = self._snapshot_sharded(tree, rank)
+        entries, dtypes, blocked_s = self._snapshot(
+            self._snapshot_sharded, tree, rank)
         handle = SaveHandle(version)
-        handle.blocked_s = sp.seconds
+        handle.blocked_s = blocked_s
         save_span = obs_trace.current()  # the trainer's `save`, or None
         vdir = self._vdir(version)
 

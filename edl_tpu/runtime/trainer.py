@@ -115,6 +115,34 @@ def _jax_stage_seconds(seen):
     return {tag: round(v, 6) for tag, v in out.items()}
 
 
+def _bytes_moved(avals, left, taken):
+    """Bytes a reshard had to land on a device that did not hold that
+    index of that leaf before: 0 for a shrink of replicated state, the
+    tree's bytes times the chips gained for a grow of it. Per leaf: its
+    abstract value, the sharding it left (None: it was on the host) and
+    the one it took. ``devices_indices_map`` is walked once per distinct
+    (shape, dtype, sharding left, sharding taken), not per leaf; still
+    it is kept out of ``resize.live``: ``_first_step`` runs it behind
+    the device's first step."""
+    def spans(sharding, shape):
+        return {(dev, tuple(sl.indices(n)[:2] for sl, n in zip(idx, shape)))
+                for dev, idx in sharding.devices_indices_map(shape).items()}
+
+    known = {}
+    total = 0
+    for aval, old, new in zip(avals, left, taken):
+        if aval is None:
+            continue
+        key = (aval.shape, aval.dtype, old, new)
+        if key not in known:
+            held = spans(old, aval.shape) if old is not None else ()
+            known[key] = getattr(aval.dtype, "itemsize", 0) * sum(
+                int(np.prod([b - a for a, b in placed[1]], dtype=np.int64))
+                for placed in spans(new, aval.shape) if placed not in held)
+        total += known[key]
+    return total
+
+
 _distributed_initialized = False
 
 
@@ -666,6 +694,9 @@ class ElasticTrainer(object):
         # [trace_id, span_id] of the last live resize's root span, for
         # the first step after it to join; None for a fresh incarnation
         self._resize_trace = None
+        # (the span `resize.device_put`, the arguments of _bytes_moved)
+        # of a live resize whose first step has not run yet
+        self._put_account = (None, None)
         self._prewarm_s = 0.0  # see _try_load_prewarmed_step
         # live-resize protocol state (enable_live_resize)
         self._live_watcher = None
@@ -1130,7 +1161,8 @@ class ElasticTrainer(object):
         step (trace, lowering, compile or cache load, enqueue: its tags
         say how much of each), ``resize.first_result`` the wait for the
         first real step — a block_until_ready that costs nothing the
-        caller would not pay anyway, once per resize."""
+        caller would not pay anyway, once per resize (the account of what
+        the reshard moved runs at its start, while the device works)."""
         prewarm_s = 0.0
         if self._example_batch_sds is None:
             self._example_batch_sds, self._example_rng_sds = \
@@ -1154,6 +1186,12 @@ class ElasticTrainer(object):
                 sp_dispatch.tag(**_jax_stage_seconds(seen))
             with obs_trace.span("resize.first_result",
                                 stage=True) as sp_result:
+                sp_put, placements = self._put_account
+                self._put_account = (None, None)
+                if placements is not None:
+                    # what the reshard had to move, worked out while the
+                    # device runs the step: host time the wait hides
+                    sp_put.tag(bytes_moved=_bytes_moved(*placements))
                 jax.block_until_ready(loss)
         self._stamp_first_step = False
         self._resize_trace = None
@@ -1348,29 +1386,45 @@ class ElasticTrainer(object):
             return "uncomputable target spans: %s" % why
         return None
 
-    def _reshard_tree(self, tree, shardings):
+    def _reshard_tree(self, tree, shardings, account=False):
         """Reshard the live pytree onto ``shardings``. Fully-addressable
         leaves (the single-process live scope) take the zero-wire fast
         path: jax.device_put lays the new placement out straight from
-        the live device arrays. Anything else runs the placed ladder —
-        local-span paste, peer range-reads at the committed version,
-        per-span FS fill (live_resize.reshard_placed). Returns
-        (new_tree, stats)."""
+        the live device arrays (``resize.device_put.dispatch``: the
+        call; ``resize.device_put.wait``: until the result is ready).
+        Anything else runs the placed ladder — local-span paste, peer
+        range-reads at the committed version, per-span FS fill
+        (live_resize.reshard_placed). Returns (new_tree, stats);
+        ``account`` adds ``placements`` on the fast path: the arguments
+        of :func:`_bytes_moved`, which waits for the first step."""
+        leaves = jax.tree_util.tree_leaves(tree)
         if self._fully_addressable(tree):
-            out = jax.device_put(tree, shardings)
-            jax.block_until_ready(out)
-            nbytes = sum(int(getattr(x, "nbytes", 0))
-                         for x in jax.tree_util.tree_leaves(tree))
-            return out, {"source": "local", "local_bytes": nbytes,
-                         "peer_bytes": 0, "peers": 0, "fs_keys": []}
+            with obs_trace.span("resize.device_put.dispatch", stage=True):
+                out = jax.device_put(tree, shardings)
+            with obs_trace.span("resize.device_put.wait", stage=True):
+                jax.block_until_ready(out)
+            stats = {"source": "local", "leaves": len(leaves),
+                     "local_bytes": sum(int(getattr(x, "nbytes", 0))
+                                        for x in leaves),
+                     "peer_bytes": 0, "peers": 0, "fs_keys": []}
+            targets = jax.tree_util.tree_leaves(shardings)
+            if account and len(targets) == len(leaves):
+                # objects the leaves hold already, in three lists: the
+                # pause allocates next to nothing for the account
+                stats["placements"] = (
+                    [getattr(x, "aval", None) for x in leaves],
+                    [getattr(x, "sharding", None) for x in leaves],
+                    targets)
+            return out, stats
         from edl_tpu.runtime import live_resize as live_mod
         version = (self._state_server.version
                    if self._state_server is not None else None)
-        return live_mod.reshard_placed(
+        out, stats = live_mod.reshard_placed(
             tree, shardings, coord=self.coord, ckpt=self._ckpt,
             version=version,
             self_endpoint=(self._state_server.endpoint
                            if self._state_server is not None else None))
+        return out, dict(stats, leaves=len(leaves))
 
     def live_resize(self, n_devices, mesh_shape=None):
         """Reshape the mesh to ``n_devices`` IN PLACE: wait for the
@@ -1490,11 +1544,23 @@ class ElasticTrainer(object):
                             "uncomputable target spans: %s" % why_t)
                     self._bind_mesh(new_mesh)
                 with obs_trace.span("resize.device_put", stage=True) as sp_put:
+                    inflight = (sp_put.recorded and self._ckpt is not None
+                                and self._ckpt.persisting())
                     self.train_state, reshard_stats = self._reshard_tree(
-                        self.train_state, new_shardings)
-                    sp_put.tag(source=reshard_stats["source"],
-                               bytes=(reshard_stats["local_bytes"]
-                                      + reshard_stats["peer_bytes"]))
+                        self.train_state, new_shardings,
+                        account=sp_put.recorded)
+                    put_tags = {"source": reshard_stats["source"],
+                                "bytes": (reshard_stats["local_bytes"]
+                                          + reshard_stats["peer_bytes"])}
+                    if sp_put.recorded:
+                        put_tags.update(leaves=reshard_stats["leaves"],
+                                        persist_inflight=inflight)
+                        if "placements" not in reshard_stats:
+                            # the placed ladder counted what it fetched
+                            put_tags["bytes_moved"] = (
+                                reshard_stats["peer_bytes"]
+                                + reshard_stats.get("parity_bytes", 0))
+                    sp_put.tag(**put_tags)
                 self._state_shardings = new_shardings
                 # where the new world's step comes from: the table
                 # ("memory": nothing to build, name, load or trace), a
@@ -1572,6 +1638,7 @@ class ElasticTrainer(object):
                 self._resize_timing["version"] = self._state_server.version
             self._stamp_first_step = True
             self._resize_trace = [sp_live.trace_id, sp_live.span_id]
+            self._put_account = (sp_put, reshard_stats.get("placements"))
             obs_events.emit("resize.live.done", cause=start_id,
                             rank=self.env.global_rank,
                             from_devices=old_n, to_devices=n_devices,

@@ -1,10 +1,11 @@
 """The benchmark's readers of the program's stage spans
 (benchmark/lib/progspans.py and the fifteen files under
-benchmark/metrics/ that use it), each on a hand-made ring and `view`:
-medians by direction, None without the span, roots outside the window
-left out, the arithmetic of `resize_other_ms`. The cell's own end-to-end
-test runs with the benchmark's tests (benchmark/tests/); these are in
-tier-1 so that every PR counts them.
+benchmark/metrics/ that use it; benchmark/lib/stagespans.py and the
+fifteen that read what a save and a reshard are made of), each on a
+hand-made ring and `view`: medians by direction, None without the span,
+roots outside the window left out, the arithmetic of `resize_other_ms`.
+The cell's own end-to-end test runs with the benchmark's tests
+(benchmark/tests/); these are in tier-1 so that every PR counts them.
 """
 
 import json
@@ -12,7 +13,7 @@ import os
 
 import pytest
 
-from benchmark.lib import harness, progspans
+from benchmark.lib import harness, progspans, stagespans
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "resnet50vd-dp4-elastic"
@@ -23,6 +24,25 @@ NEW = ["shrink_pause_ms", "grow_pause_ms", "shrink_device_put_ms",
        "save_snapshot_ms"]
 #: the wait a resize takes for the save in flight, and that save's write
 LATER = ["resize_drain_ms", "save_persist_ms"]
+#: `save.snapshot` and `resize.device_put` from inside (lib/stagespans.py):
+#: name -> (unit, better)
+INSIDE = {
+    "save_snapshot_start_ms": ("ms", "lower"),
+    "save_snapshot_fetch_ms": ("ms", "lower"),
+    "save_snapshot_copy_ms": ("ms", "lower"),
+    "save_snapshot_gb_s": ("GB/s", "higher"),
+    "save_transfer_started_over_kept": ("ratio", "lower"),
+    "shrink_put_dispatch_ms": ("ms", "lower"),
+    "grow_put_dispatch_ms": ("ms", "lower"),
+    "shrink_put_wait_ms": ("ms", "lower"),
+    "grow_put_wait_ms": ("ms", "lower"),
+    "shrink_put_moved_mb": ("MB", "lower"),
+    "grow_put_moved_mb": ("MB", "lower"),
+    "resize_put_beside_persist_pct": ("%", "lower"),
+    "resize_gc_ms": ("ms", "lower"),
+    "resize_drain_deferred_pct": ("%", "higher"),
+    "resize_step_from_memory_pct": ("%", "higher"),
+}
 
 
 def _span(trace, name, t0, ms, parent="root", **tags):
@@ -325,3 +345,215 @@ def test_benchmark_names_every_new_reader_once_for_the_elastic_cell():
             "workloads": [CELL]}
         assert os.path.exists(os.path.join(
             REPO, "benchmark", "metrics", name + ".py"))
+
+
+# -- `save.snapshot` and `resize.device_put` from inside ---------------------
+
+
+STATE = 2.0e8       # bytes of the job's state, one copy
+
+
+@pytest.fixture()
+def deep(world, monkeypatch):
+    """`world`, run by a program that says what a save and a reshard are
+    made of: each `resize.device_put` has its two children and its tags,
+    each `save.snapshot` its child and its tags, the roots say what
+    became of the save in flight and where the step came from, and the
+    collector ran inside two of the resizes."""
+    roots = {"s1": ("deferred", "memory"), "s2": ("deferred", "memory"),
+             "s3": ("idle", "disk"), "g1": ("deferred", "memory"),
+             "g2": ("waited", "compile"), "warm": ("idle", "compile"),
+             "late": ("idle", "compile")}
+    gc_ms = {("s1", "resize.live"): 3.0, ("s1", "resize.first_step"): 1.0,
+             ("g1", "resize.live"): 2.0, ("s1", "resize.device_put"): 3.0}
+    asked = {"v1": 4, "v2": 2, "v3": 4, "vwarm": 4}
+    ring = []
+    for sp in world["ring"]:
+        sp = dict(sp, tags=dict(sp["tags"]))
+        key, name = sp["trace_id"], sp["name"]
+        if (key, name) in gc_ms:
+            sp["tags"]["gc_ms"] = gc_ms[key, name]
+        if name == "resize.live":
+            sp["tags"].update(drain=roots[key][0], step_source=roots[key][1])
+        elif name == "resize.device_put":
+            grow = key.startswith("g")
+            sp["tags"].update(source="local", bytes=STATE, leaves=445,
+                              bytes_moved=2 * STATE if grow else 0,
+                              persist_inflight=True)
+            # the call is four fifths of it, the wait the rest less 1 ms
+            ring.append(_span(key, "resize.device_put.dispatch", sp["t0"],
+                              0.8 * sp["dur_ms"],
+                              parent="resize.device_put"))
+            ring.append(_span(key, "resize.device_put.wait",
+                              sp["t0"] + 0.8 * sp["dur_ms"] / 1e3,
+                              0.2 * sp["dur_ms"] - 1.0,
+                              parent="resize.device_put"))
+        elif name == "save.snapshot":
+            # start 10% of the span, the fetches 70%, the copies 15%
+            sp["tags"].update(fetch_s=0.7 * sp["dur_ms"] / 1e3,
+                              copy_s=0.15 * sp["dur_ms"] / 1e3,
+                              bytes=STATE, leaves=445,
+                              transfers_started=445 * asked[key],
+                              transfer_bytes_started=asked[key] * STATE,
+                              bufs_new=0)
+            ring.append(_span(key, "save.snapshot.start_transfers",
+                              sp["t0"], 0.1 * sp["dur_ms"],
+                              parent="save.snapshot"))
+        ring.append(sp)
+    ring.sort(key=lambda s: s["t0"] + s["dur_ms"] / 1e3)
+    monkeypatch.setattr(progspans, "ring", lambda: list(ring))
+    return dict(world, ring=ring)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("save_snapshot_start_ms", 43.0),      # a tenth of 430, 410, 450
+    ("save_snapshot_fetch_ms", 301.0),
+    ("save_snapshot_copy_ms", 64.5),
+    ("save_snapshot_gb_s", STATE / 1e9 / 0.430),
+    ("save_transfer_started_over_kept", 10.0 / 3),   # 4, 2 and 4 copies
+    ("shrink_put_dispatch_ms", 176.0),     # four fifths of 200, 240, 220
+    ("grow_put_dispatch_ms", 136.0),       # of 180, 160
+    ("shrink_put_wait_ms", 43.0),
+    ("grow_put_wait_ms", 33.0),
+    ("shrink_put_moved_mb", 0.0),
+    ("grow_put_moved_mb", 400.0),
+    ("resize_put_beside_persist_pct", 0.0),    # every write ended before
+    ("resize_gc_ms", 0.0),                 # of 4, 0, 0, 2, 0
+    ("resize_drain_deferred_pct", 60.0),   # s1, s2, g1 of five
+    ("resize_step_from_memory_pct", 60.0),
+])
+def test_what_a_save_and_a_reshard_are_made_of(deep, name, want):
+    assert _read(name, deep["view"]) == pytest.approx(want)
+
+
+def test_gc_is_summed_per_resize_over_the_two_spans_of_the_pause(
+        deep, monkeypatch):
+    # s1: 3 inside `resize.live` (its device_put's 3 is the same
+    # collection, not counted again) + 1 inside the first step; g1: 2
+    ring = [s for s in deep["ring"] if s["trace_id"] in ("s1", "g1", "s2")]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("resize_gc_ms", deep["view"]) == pytest.approx(2.0)
+    ring = [s for s in ring if s["trace_id"] == "s1"]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("resize_gc_ms", deep["view"]) == pytest.approx(4.0)
+
+
+@pytest.mark.parametrize("name", list(INSIDE))
+def test_inside_readers_give_none_on_an_empty_ring(monkeypatch, name):
+    monkeypatch.setattr(progspans, "ring", lambda: [])
+    assert _read(name, {"window": [100.0, 200.0]}) is None
+
+
+@pytest.mark.parametrize("name", list(INSIDE))
+def test_inside_readers_give_none_without_their_child_or_tag(
+        world, monkeypatch, name):
+    """`world` is the ring of a program from before: `save.snapshot` and
+    `resize.device_put` are there, with nothing inside and no tag, and
+    its tracer keeps no account of the collector. One reader lays spans
+    side by side that such a program has."""
+    monkeypatch.setattr(stagespans, "_program_counts_gc", lambda: False)
+    got = _read(name, world["view"])
+    if name == "resize_put_beside_persist_pct":
+        assert got == 0.0
+    else:
+        assert got is None
+    if name == "resize_gc_ms":
+        # the same ring from a program that counts: no collection ran
+        monkeypatch.setattr(stagespans, "_program_counts_gc", lambda: True)
+        assert _read(name, world["view"]) == 0.0
+
+
+def test_the_tracer_of_this_tree_counts_the_collector():
+    assert stagespans._program_counts_gc()
+
+
+@pytest.mark.parametrize("name,want", [
+    # s2, g2, v2 and v3 start inside the capture: s1 and s3, g1, v1
+    ("save_snapshot_start_ms", 43.0),
+    ("save_snapshot_gb_s", STATE / 1e9 / 0.43),
+    ("save_transfer_started_over_kept", 4.0),
+    ("shrink_put_dispatch_ms", 168.0),     # of 160, 176
+    ("grow_put_wait_ms", 35.0),
+    ("grow_put_moved_mb", 400.0),
+    ("resize_gc_ms", 2.0),                 # of 4, 0, 2
+    ("resize_drain_deferred_pct", 100 * 2 / 3.0),
+    ("resize_step_from_memory_pct", 100 * 2 / 3.0),
+])
+def test_inside_readers_leave_out_a_trace_under_the_capture(deep, name,
+                                                            want):
+    view = {"window": [100.0, 200.0],
+            "spans": [("trace_window", 132.0, 168.0)]}
+    assert _read(name, view) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", list(INSIDE))
+def test_one_resize_of_each_kind_is_enough_for_every_reader(deep, name):
+    """A traced window of the cell holds ONE period outside the capture
+    (one shrink, one grow, two saves), and a metric the cell lists has
+    to be in that run's line: no reader may need two of a kind."""
+    view = {"window": [100.0, 125.0]}          # s1, g1 and v1 alone
+    assert _read(name, view) is not None
+    assert _read("shrink_put_dispatch_ms", view) == pytest.approx(160.0)
+
+
+def test_a_resize_without_its_first_step_is_no_reshard_either(
+        deep, monkeypatch):
+    """The same resizes as the stage's own reader counts, so that the
+    children's medians can be laid beside `*_device_put_ms`."""
+    ring = [s for s in deep["ring"]
+            if not (s["trace_id"] == "s2"
+                    and s["name"].startswith("resize.first"))]
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("shrink_put_dispatch_ms", deep["view"]) == pytest.approx(
+        0.8 * _read("shrink_device_put_ms", deep["view"]))
+
+
+@pytest.mark.parametrize("writes,want", [
+    # s1's reshard runs [110.006, 110.206]. A write that starts before
+    # it and ends inside it, 50 ms in: a quarter of that reshard
+    ({"v1": (109.0, 1056.0)}, 100 * 50.0 / 1000),
+    # one that covers it whole, and the warm-up's write, which ended
+    ({"v1": (109.0, 2000.0), "vwarm": (41.0, 900.0)}, 100 * 200.0 / 1000),
+    # one that starts inside it and outlives it; s2's is covered whole
+    ({"v1": (110.106, 5000.0), "v2": (139.0, 3000.0)},
+     100 * (100.0 + 240.0) / 1000),
+    # every reshard inside a write
+    ({"v1": (100.0, 80000.0)}, 100.0),
+    # a write that ends as the reshard begins
+    ({"v1": (109.0, 1006.0)}, 0.0),
+])
+def test_put_beside_persist_is_the_overlap_with_the_ring_s_writes(
+        deep, monkeypatch, writes, want):
+    """200 + 240 + 220 + 180 + 160 = 1000 ms of reshard in the window."""
+    ring = []
+    for sp in deep["ring"]:
+        if sp["name"] == "save.persist":
+            if sp["trace_id"] not in writes:
+                continue
+            t0, ms = writes[sp["trace_id"]]
+            sp = dict(sp, t0=t0, dur_ms=ms)
+        ring.append(sp)
+    monkeypatch.setattr(progspans, "ring", lambda: ring)
+    assert _read("resize_put_beside_persist_pct",
+                 deep["view"]) == pytest.approx(want)
+
+
+def test_benchmark_names_the_inside_readers_once_together_at_the_end():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    names = [m["name"] for m in bench["per_layer"]]
+    for name in INSIDE:
+        assert names.count(name) == 1, name
+    at = names.index(next(iter(INSIDE)))
+    assert names[at:at + len(INSIDE)] == list(INSIDE)
+    assert at > names.index(LATER[-1])     # appended after what was there
+    for m in bench["per_layer"][at:at + len(INSIDE)]:
+        unit, better = INSIDE[m["name"]]
+        layer, moves = (("checkpoint", "elastic_samples_s_chip")
+                        if m["name"].startswith("save_")
+                        else ("live resize", "resize_pause_ms"))
+        assert m == {"name": m["name"], "unit": unit, "better": better,
+                     "source": "program_span", "layer": layer,
+                     "moves": moves, "workloads": [CELL]}
+        assert os.path.exists(os.path.join(
+            REPO, "benchmark", "metrics", m["name"] + ".py"))

@@ -36,6 +36,8 @@ BATCHES = [linear.synthetic_batch(TOTAL_BATCH, seed=i) for i in range(8)]
 
 RESIZE_STAGES = ["resize.drain", "resize.mesh", "resize.device_put",
                  "resize.build_step"]
+#: what `resize.device_put` is made of: its children, in order
+PUT_STAGES = ["resize.device_put.dispatch", "resize.device_put.wait"]
 PREWARM_STAGES = ["resize.prewarm_fingerprint", "resize.prewarm_load"]
 
 
@@ -58,6 +60,20 @@ def _end(span):
 def _named(spans, name, **tags):
     return [s for s in spans if s["name"] == name
             and all(s["tags"].get(k) == v for k, v in tags.items())]
+
+
+def _tags(span):
+    """A span's tags less `gc_ms`, which any recorded stage span carries
+    when the collector happened to run while it was open."""
+    return {k: v for k, v in span["tags"].items() if k != "gc_ms"}
+
+
+def _in_order_inside(parent, children, eps=1e-6):
+    """`children` (sorted by start) lie inside `parent`, none overlaps."""
+    assert children[0]["t0"] >= parent["t0"] - eps
+    assert _end(children[-1]) <= _end(parent) + eps
+    for a, b in zip(children, children[1:]):
+        assert _end(a) <= b["t0"] + eps, (a["name"], b["name"])
 
 
 @pytest.fixture()
@@ -143,7 +159,7 @@ def _resize(arc, case):
 def test_resize_leaves_one_root_with_the_table_s_children(arc, case):
     root, trace = _resize(arc, case)
     assert root["parent_id"] is None
-    tags = dict(root["tags"])
+    tags = _tags(root)
     # the second trainer saves nothing; the first one's few bytes may be
     # written before its resize looks (tests/test_live_resize.py holds
     # the write open to tell the cases apart)
@@ -159,6 +175,14 @@ def test_resize_leaves_one_root_with_the_table_s_children(arc, case):
     want = RESIZE_STAGES + (PREWARM_STAGES if case == "disk" else [])
     assert [s["name"] for s in sorted(children, key=lambda s: s["t0"])] \
         == want
+    # the table's one stage with stages of its own: the reshard
+    [put] = [s for s in children if s["name"] == "resize.device_put"]
+    inner = [s for s in trace if s["parent_id"] == put["span_id"]]
+    assert [s["name"] for s in sorted(inner, key=lambda s: s["t0"])] \
+        == PUT_STAGES
+    assert sorted(s["name"] for s in trace
+                  if s["name"].startswith("resize.device_put.")) \
+        == PUT_STAGES
 
 
 @ALL_CASES
@@ -168,11 +192,11 @@ def test_resize_children_lie_inside_the_root_and_do_not_overlap(arc, case):
                        if s["parent_id"] == root["span_id"]
                        and s["name"] != "resize.first_step"),
                       key=lambda s: s["t0"])
-    eps = 1e-6
-    assert children[0]["t0"] >= root["t0"] - eps
-    assert _end(children[-1]) <= _end(root) + eps
-    for a, b in zip(children, children[1:]):
-        assert _end(a) <= b["t0"] + eps, (a["name"], b["name"])
+    _in_order_inside(root, children)
+    [put] = [s for s in children if s["name"] == "resize.device_put"]
+    _in_order_inside(put, sorted((s for s in trace
+                                  if s["parent_id"] == put["span_id"]),
+                                 key=lambda s: s["t0"]))
 
 
 @ALL_CASES
@@ -191,25 +215,27 @@ def test_first_step_joins_the_trace_of_its_resize(arc, case):
     assert _end(inner[1]) <= _end(first) + 1e-6
     assert {s["name"] for s in trace} <= set(
         ["resize.live", "resize.first_step", "resize.first_dispatch",
-         "resize.first_result"] + RESIZE_STAGES + PREWARM_STAGES)
+         "resize.first_result"] + RESIZE_STAGES + PUT_STAGES
+        + PREWARM_STAGES)
 
 
 @ALL_CASES
 def test_first_dispatch_says_what_jax_did(arc, case):
     _, trace = _resize(arc, case)
     [d] = [s for s in trace if s["name"] == "resize.first_dispatch"]
-    assert set(d["tags"]) == {"jax_trace_s", "jax_lower_s", "jax_compile_s",
-                              "jax_cache_load_s"}
-    total = sum(d["tags"].values())
+    jax_s = _tags(d)
+    assert set(jax_s) == {"jax_trace_s", "jax_lower_s", "jax_compile_s",
+                          "jax_cache_load_s"}
+    total = sum(jax_s.values())
     assert total <= d["dur_ms"] / 1e3 + 1e-3
     if case != "compile":
         # an executable held ready or loaded: nothing to trace or build
         assert total == 0.0
     else:
         # a world nobody compiled for: the step is traced and built
-        assert d["tags"]["jax_trace_s"] > 0
-        assert d["tags"]["jax_lower_s"] > 0
-        assert d["tags"]["jax_compile_s"] > 0
+        assert jax_s["jax_trace_s"] > 0
+        assert jax_s["jax_lower_s"] > 0
+        assert jax_s["jax_compile_s"] > 0
 
 
 @ALL_CASES
@@ -246,6 +272,80 @@ def test_ledger_pause_is_bounded_by_the_spans(arc, case):
     # the drain nests ckpt_block over the pause and takes its seconds
     [drain] = [s for s in trace if s["name"] == "resize.drain"]
     assert spans_s - drain["dur_ms"] / 1e3 - 5e-3 <= pause <= wall_s + 5e-3
+
+
+@ALL_CASES
+def test_device_put_says_what_it_moved_and_what_ran_beside_it(arc, case):
+    _, trace = _resize(arc, case)
+    [put] = [s for s in trace if s["name"] == "resize.device_put"]
+    tags = _tags(put)
+    assert set(tags) == {"source", "bytes", "leaves", "bytes_moved",
+                         "persist_inflight"}
+    assert tags["source"] == "local"
+    state = _trainer(1)
+    try:
+        leaves = jax.tree_util.tree_leaves(state.train_state)
+    finally:
+        state.close()
+    assert tags["leaves"] == len(leaves)
+    assert tags["bytes"] == sum(x.nbytes for x in leaves) > 0
+    # the state is replicated: a shrink leaves every chip that stays
+    # with what it held, a grow lands the whole tree on each chip gained
+    gained = CASES[case]["to_devices"] - CASES[case]["from_devices"]
+    assert tags["bytes_moved"] == tags["bytes"] * max(0, gained)
+    assert tags["persist_inflight"] in (True, False)
+    if not case.startswith("memory"):
+        assert tags["persist_inflight"] is False    # it saves nothing
+
+
+@pytest.mark.parametrize("old,new,want", [
+    # replicated, 4 -> 2 chips and back: nothing, then all of it twice
+    ((4, None), (2, None), 0),
+    ((2, None), (4, None), 2 * 8 * 6 * 4),
+    # rows split over the chips: 4 -> 2 gives each chip that stays a
+    # half where it held a quarter (the whole half lands: another index)
+    ((4, "dp"), (2, "dp"), 2 * 4 * 6 * 4),
+    # replicated -> split: each chip keeps a slice of what it held, but
+    # under another index, so it counts as landed
+    ((2, None), (2, "dp"), 2 * 4 * 6 * 4),
+    ((2, "dp"), (2, "dp"), 0),
+    # a leaf that was on the host lands whole on every chip
+    (None, (2, None), 2 * 8 * 6 * 4),
+])
+def test_bytes_moved_counts_indices_a_device_did_not_hold(old, new, want):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def sharding(spec):
+        if spec is None:
+            return None
+        n, axis = spec
+        return NamedSharding(make_mesh(devices=jax.devices()[:n]),
+                             PartitionSpec(axis))
+
+    aval = jax.ShapeDtypeStruct((8, 6), jax.numpy.float32)
+    # a leaf with no abstract value (a host scalar) is not counted
+    got = trainer_mod._bytes_moved([aval, None],
+                                   [sharding(old), None],
+                                   [sharding(new), sharding(new)])
+    assert got == want
+
+
+def test_bytes_moved_is_attached_at_the_first_step(clean_ring):
+    """The account walks the leaves' placements, so it is kept out of
+    `resize.live`: it runs behind the device's first step, and the span
+    in the ring gains the tag then."""
+    tr = _trainer(2)
+    try:
+        _step(tr, 0)
+        tr.live_resize(4)
+        [put] = clean_ring.find(name="resize.device_put")
+        assert "bytes_moved" not in put["tags"]
+        _step(tr, 1)
+        [put] = clean_ring.find(name="resize.device_put")
+        assert put["tags"]["bytes_moved"] == 2 * put["tags"]["bytes"]
+        assert tr._put_account == (None, None)
+    finally:
+        tr.close()
 
 
 def test_rollback_closes_the_spans_it_opened(clean_ring):
@@ -312,10 +412,10 @@ def test_prewarm_spans_at_an_incarnation_s_first_step_are_roots(
     [load] = _named(spans, "resize.prewarm_load")
     [d] = _named(spans, "resize.first_dispatch")
     assert fp["parent_id"] is None and load["parent_id"] is None
-    assert fp["tags"] == {"world": 2}
+    assert _tags(fp) == {"world": 2}
     assert rec["compile_s"] == pytest.approx(
         (fp["dur_ms"] + load["dur_ms"] + d["dur_ms"]) / 1e3, abs=1e-9)
-    assert sum(d["tags"].values()) == 0.0   # loaded, not built
+    assert sum(_tags(d).values()) == 0.0   # loaded, not built
 
 
 # -- a save ----------------------------------------------------------------
@@ -326,16 +426,23 @@ def test_save_root_with_its_stages_and_the_writer_s_span(arc):
     assert len(roots) == 2
     for root in roots:
         assert root["parent_id"] is None
-        assert set(root["tags"]) == {"version"}
+        assert set(_tags(root)) == {"version"}
         trace = [s for s in arc["spans"]
                  if s["trace_id"] == root["trace_id"]]
         assert sorted(s["name"] for s in trace) == [
             "save", "save.drain_prev", "save.persist", "save.snapshot",
-            "save.state_json"]
+            "save.snapshot.start_transfers", "save.state_json"]
+        [snap] = [s for s in trace if s["name"] == "save.snapshot"]
+        [start] = [s for s in trace
+                   if s["name"] == "save.snapshot.start_transfers"]
+        # the snapshot's one part that is a span: the first thing it does
+        assert start["parent_id"] == snap["span_id"]
+        _in_order_inside(snap, [start])
         assert all(s["parent_id"] == root["span_id"]
-                   for s in trace if s["name"] != "save")
+                   for s in trace if s not in (root, start))
         on_thread = sorted((s for s in trace
-                            if s["name"] not in ("save", "save.persist")),
+                            if s["name"] not in ("save", "save.persist")
+                            and s is not start),
                            key=lambda s: s["t0"])
         assert [s["name"] for s in on_thread] == [
             "save.state_json", "save.drain_prev", "save.snapshot"]
@@ -368,10 +475,160 @@ def test_blocked_s_is_the_snapshot_span(tmp_path, clean_ring, sharded):
                                              abs=1e-9)
     # called outside a trainer's `save`: each is a root of its own
     assert snap["parent_id"] is None and persist["parent_id"] is None
-    assert persist["tags"] == {"version": 3}
+    assert _tags(persist) == {"version": 3}
+
+
+def _replicated_tree(dp):
+    from jax.sharding import NamedSharding, PartitionSpec
+    rep = NamedSharding(make_mesh(devices=jax.devices()[:dp]),
+                        PartitionSpec())
+    return jax.device_put(
+        {"w": jax.numpy.arange(48.0).reshape(6, 8),
+         "b": jax.numpy.arange(8, dtype=jax.numpy.bfloat16),
+         "n": jax.numpy.zeros((), jax.numpy.int32)}, rep)
+
+
+@pytest.mark.parametrize("dp", [2, 4])
+@pytest.mark.parametrize("sharded", [False, True])
+def test_snapshot_says_what_it_is_made_of(tmp_path, clean_ring, sharded,
+                                          dp):
+    mgr = CheckpointManager(str(tmp_path))
+    tree = _replicated_tree(dp)
+    try:
+        take = (mgr._snapshot_sharded, tree, 0) if sharded \
+            else (mgr._snapshot_dense, tree)
+        entries, _, seconds = mgr._snapshot(*take)
+        again, _, _ = mgr._snapshot(*take)
+    finally:
+        mgr.close()
+    first, second = sorted(_named(clean_ring.spans(), "save.snapshot"),
+                           key=lambda s: s["t0"])
+    starts = sorted(_named(clean_ring.spans(),
+                           "save.snapshot.start_transfers"),
+                    key=lambda s: s["t0"])
+    assert [s["parent_id"] for s in starts] == [first["span_id"],
+                                                second["span_id"]]
+    tags = _tags(first)
+    assert set(tags) == {"fetch_s", "copy_s", "bytes", "leaves",
+                         "transfers_started", "transfer_bytes_started",
+                         "bufs_new"}
+    assert seconds == pytest.approx(first["dur_ms"] / 1e3, abs=1e-9)
+    # the host bytes kept: one copy of each leaf, whoever held it
+    kept = sum(a.nbytes for a in entries.values())
+    assert tags["bytes"] == kept == 48 * 4 + 8 * 2 + 4
+    assert tags["leaves"] == 3
+    # a transfer is asked of every chip that holds the leaf
+    assert tags["transfers_started"] == 3 * dp
+    assert tags["transfer_bytes_started"] == dp * kept
+    # the three parts lie inside the span and are not counted twice
+    _in_order_inside(first, [starts[0]])
+    assert tags["fetch_s"] > 0 and tags["copy_s"] > 0
+    assert (tags["fetch_s"] + tags["copy_s"]
+            + starts[0]["dur_ms"] / 1e3) <= first["dur_ms"] / 1e3 + 1e-6
+    # the second save finds its buffers in the pool
+    assert tags["bufs_new"] == 3 and _tags(second)["bufs_new"] == 0
+    assert sorted(again) == sorted(entries)
+
+
+def test_arc_snapshots_add_up(arc):
+    snaps = _named(arc["spans"], "save.snapshot")
+    assert len(snaps) == 2
+    for snap in snaps:
+        [start] = [s for s in arc["spans"]
+                   if s["parent_id"] == snap["span_id"]]
+        tags = snap["tags"]
+        assert (tags["fetch_s"] + tags["copy_s"] + start["dur_ms"] / 1e3
+                <= snap["dur_ms"] / 1e3 + 1e-6)
+    # saved at dp=4, then at dp=2, the same replicated state
+    at4, at2 = sorted(snaps, key=lambda s: s["t0"])
+    assert at4["tags"]["bytes"] == at2["tags"]["bytes"] > 0
+    assert at4["tags"]["transfer_bytes_started"] == 4 * at4["tags"]["bytes"]
+    assert at2["tags"]["transfer_bytes_started"] == 2 * at2["tags"]["bytes"]
+
+
+def test_host_transfers_count_what_they_started_and_say_when_they_stop():
+    import logging
+
+    from edl_tpu.runtime import checkpoint as ckpt_mod
+
+    class Shard(object):
+        def __init__(self, fail=False):
+            self.data = self
+            self.nbytes = 16
+            self._fail = fail
+
+        def copy_to_host_async(self):
+            if self._fail:
+                raise RuntimeError("no transfer")
+
+    class Leaf(object):
+        def __init__(self, *shards):
+            self.addressable_shards = shards
+
+    assert ckpt_mod._start_host_transfers(
+        [Leaf(Shard(), Shard()), 3.0, Leaf(Shard())]) == (3, 48)
+    records = []
+    seen = logging.Handler(level=logging.DEBUG)
+    seen.emit = records.append
+    level = ckpt_mod.logger.level
+    ckpt_mod.logger.addHandler(seen)
+    ckpt_mod.logger.setLevel(logging.DEBUG)
+    try:
+        got = ckpt_mod._start_host_transfers(
+            [Leaf(Shard(), Shard(fail=True)), Leaf(Shard(fail=True))])
+    finally:
+        ckpt_mod.logger.setLevel(level)
+        ckpt_mod.logger.removeHandler(seen)
+    assert got == (1, 16)
+    # once, where it stopped: the second leaf is not tried
+    [stopped] = records
+    assert stopped.levelname == "DEBUG"
+    assert "stopped after 1 calls" in stopped.getMessage()
 
 
 # -- the span API ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("collect", [True, False])
+def test_stage_span_says_how_long_the_collector_ran(clean_ring, collect):
+    import gc
+    was = gc.isenabled()
+    gc.disable()        # no collection but the one asked for
+    try:
+        with obs_trace.span("gc.outer", stage=True):
+            with obs_trace.span("gc.inner", stage=True):
+                if collect:
+                    gc.collect()
+            with obs_trace.span("gc.quiet", stage=True):
+                pass
+            with obs_trace.span("gc.sampled"):    # not a stage span
+                if collect:
+                    gc.collect()
+    finally:
+        if was:
+            gc.enable()
+    [outer] = clean_ring.find(name="gc.outer")
+    [inner] = clean_ring.find(name="gc.inner")
+    [quiet] = clean_ring.find(name="gc.quiet")
+    [sampled] = clean_ring.find(name="gc.sampled")
+    assert "gc_ms" not in quiet["tags"] and "gc_ms" not in sampled["tags"]
+    if collect:
+        # both collections stalled the thread that held `outer` open
+        assert 0 < inner["tags"]["gc_ms"] < outer["tags"]["gc_ms"]
+        assert outer["tags"]["gc_ms"] <= outer["dur_ms"]
+    else:
+        assert "gc_ms" not in inner["tags"]
+        assert "gc_ms" not in outer["tags"]
+
+
+def test_collector_account_is_one_callback_for_the_process():
+    import gc
+    before = obs_trace.gc_seconds()
+    n = gc.callbacks.count(obs_trace._on_gc)
+    gc.collect()
+    assert obs_trace.gc_seconds() > before
+    assert n == gc.callbacks.count(obs_trace._on_gc) == 1
+
 
 
 def test_span_carries_its_start_on_the_monotonic_clock(clean_ring):
@@ -400,7 +657,7 @@ def test_stage_span_is_recorded_with_sampling_off(clean_ring):
     names = [s["name"] for s in clean_ring.spans()]
     assert names == ["sampled.child", "stage.one"]
     [stage] = clean_ring.find(name="stage.one")
-    assert stage["tags"] == {"k": 1, "more": 2}
+    assert _tags(stage) == {"k": 1, "more": 2}
     [child] = clean_ring.find(name="sampled.child")
     assert child["parent_id"] == stage["span_id"]
     assert child["trace_id"] == stage["trace_id"]
@@ -424,21 +681,58 @@ def test_span_on_another_thread_keeps_the_parent_s_trace(clean_ring):
     assert got["ctx"] == (root.trace_id, child["span_id"])
 
 
-def test_obs_kill_switch_records_nothing_and_keeps_the_stamps(clean_ring):
+def _no_account(monkeypatch):
+    """Every account a recorded stage span keeps now raises."""
+    from edl_tpu.runtime import checkpoint as ckpt_mod
+
+    def ran(*a, **kw):
+        raise AssertionError("an account ran under EDL_TPU_OBS=0")
+
+    monkeypatch.setattr(obs_trace, "gc_seconds", ran)
+    monkeypatch.setattr(trainer_mod, "_bytes_moved", ran)
+    monkeypatch.setattr(ckpt_mod._SnapshotAccount, "tags", ran)
+
+
+def test_obs_kill_switch_records_nothing_and_keeps_the_stamps(
+        clean_ring, monkeypatch):
     tr = _trainer(4)
     prev = obs_metrics.set_enabled(False)
     try:
         _step(tr, 0)
+        _no_account(monkeypatch)
         rec = tr.live_resize(2)
+        assert tr._put_account[1] is None
         _step(tr, 1)
         rec = dict(rec, **tr.resize_timing)
     finally:
+        monkeypatch.undo()
         obs_metrics.set_enabled(prev)
         tr.close()
     assert clean_ring.spans() == []
     # the span objects still time their callers
     assert rec["reshard_s"] > 0 and rec["compile_s"] > 0
     assert rec["first_step_s"] >= 0 and rec["drain_s"] >= 0
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_obs_kill_switch_keeps_blocked_s_and_runs_no_account(
+        tmp_path, clean_ring, monkeypatch, sharded):
+    mgr = CheckpointManager(str(tmp_path))
+    tree = _replicated_tree(2)
+    prev = obs_metrics.set_enabled(False)
+    try:
+        _no_account(monkeypatch)
+        if sharded:
+            entries, _, blocked_s = mgr._snapshot(mgr._snapshot_sharded,
+                                                  tree, 0)
+        else:
+            entries, _, blocked_s = mgr._snapshot(mgr._snapshot_dense, tree)
+    finally:
+        monkeypatch.undo()
+        obs_metrics.set_enabled(prev)
+        mgr.close()
+    assert clean_ring.spans() == []
+    assert blocked_s > 0 and len(entries) == 3
 
 
 def test_obs_stays_off_jax():

@@ -73,6 +73,15 @@ STOPWATCH_ALLOWLIST = {
         "the async persist driver is a background thread whose "
         "concurrency is deliberately NOT ledgered; persist_s lands on "
         "the SaveHandle and _SAVE_MS",
+    ("edl_tpu/runtime/checkpoint.py", "fetch"):
+        "_SnapshotAccount: the per-leaf fetches of one save.snapshot "
+        "interleave with its copies, so their seconds are summed and "
+        "handed to that stage span as its tag fetch_s (a span per leaf "
+        "would flood the ring); the interval itself is ledgered as "
+        "ckpt_block by save_async",
+    ("edl_tpu/runtime/checkpoint.py", "keep"):
+        "_SnapshotAccount: as fetch, for the copies into the host "
+        "pool: summed into the save.snapshot span's tag copy_s",
     ("edl_tpu/serve/decode_engine.py", "_prefill"):
         "prefill_ms feeds admission.observe_prefill_ms (the TTFT "
         "projection EWMA) and the _TTFT histogram; the serving device "
