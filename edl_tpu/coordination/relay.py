@@ -349,9 +349,9 @@ class WatchRelay(object):
     MAX_CHILD_WAIT_S = 60.0
     #: a feed with no waiter for this long retires its pump
     FEED_IDLE_S = 90.0
-    #: min gap between upstream lease batches (the coalesce window)
+    #: min gap between upstream lease batches; a ttl must exceed it
     LEASE_COALESCE_S = 1.0
-    #: forget child leases not refreshed through us for this long
+    #: forget a lease's verdict this long after its last upstream batch
     LEASE_FORGET_S = 120.0
     #: drop obs cells whose publisher went silent for this long (far
     #: beyond the staleness detector's threshold, so dead pods are
@@ -385,8 +385,8 @@ class WatchRelay(object):
         self._feeds = {}         # prefix -> _Feed
         self._children = set()   # child ids seen (metrics only)
         self._cells = {}         # obs key -> obs_pub/v1 doc
-        self._child_leases = {}  # lease_id -> last monotonic refresh
-        self._lease_verdicts = {}
+        self._child_leases = set()  # beat since the last upstream batch
+        self._lease_verdicts = {}   # lease_id -> (ok, monotonic of batch)
         self._last_lease_beat = 0.0
         self._resolved = (0.0, [])  # (monotonic, endpoints) cache
         self._pod_ids = []
@@ -628,33 +628,33 @@ class WatchRelay(object):
 
     def relay_lease_refresh_many(self, lease_ids, child=None):
         """Coalesced keepalive: children's beats are merged into ONE
-        upstream ``lease_refresh_many`` per LEASE_COALESCE_S window.
-        An id we have no verdict for yet forces a synchronous batch
-        (fresh registrations must learn their fate immediately); known
-        ids between windows are answered from the cached verdicts —
-        one window of staleness, well inside the ttl/3 beat slack."""
+        upstream ``lease_refresh_many`` per LEASE_COALESCE_S window. It
+        carries the ids that beat since the previous batch and no
+        others, so a silent owner's lease dies with its ttl. An id we
+        have no verdict for yet forces a synchronous batch (fresh
+        registrations must learn their fate immediately); known ids
+        between windows are answered from the cached verdicts — one
+        beat of staleness, inside the ttl/3 beat slack."""
         now = time.monotonic()
         ids = [int(lid) for lid in lease_ids]
+        batch = None
         with self._lock:
-            for lid in ids:
-                self._child_leases[lid] = now
-            for lid in [l for l, ts in self._child_leases.items()
-                        if now - ts > self.LEASE_FORGET_S]:
-                del self._child_leases[lid]
-                self._lease_verdicts.pop(lid, None)
-            need_sync = any(lid not in self._lease_verdicts
-                            for lid in ids)
-            due = now - self._last_lease_beat >= self.LEASE_COALESCE_S
-            batch = (sorted(self._child_leases)
-                     if (need_sync or due) else None)
-            if batch is not None:
+            self._child_leases.update(ids)
+            if (now - self._last_lease_beat >= self.LEASE_COALESCE_S
+                    or not self._lease_verdicts.keys() >= set(ids)):
+                batch = sorted(self._child_leases)
                 self._last_lease_beat = now
         if batch is not None:
             verdicts = self._upstream_refresh(batch)
-            with self._lock:
-                self._lease_verdicts.update(verdicts)
+            with self._lock:  # a failed batch's beats ride the next one
+                self._child_leases.difference_update(batch)
+                self._lease_verdicts.update(
+                    (lid, (ok, now)) for lid, ok in verdicts.items())
+                for lid in [l for l, v in self._lease_verdicts.items()
+                            if now - v[1] > self.LEASE_FORGET_S]:
+                    del self._lease_verdicts[lid]
         with self._lock:
-            return [[lid, bool(self._lease_verdicts.get(lid, True))]
+            return [[lid, self._lease_verdicts.get(lid, (True,))[0]]
                     for lid in ids]
 
     # -- upward: obs aggregation ---------------------------------------
@@ -727,4 +727,4 @@ class WatchRelay(object):
                     "children": len(self._children),
                     "feeds": len(self._feeds),
                     "cells": len(self._cells),
-                    "child_leases": len(self._child_leases)}
+                    "child_leases": len(self._lease_verdicts)}  # known

@@ -11,7 +11,7 @@ Loss = (1-w) * hard next-token CE + w * per-position soft CE against
 the teacher's probs (positions 0..L-2 predict token t+1, matching the
 teacher's alignment).
 
-Bring-up (scripted in tests/test_examples_and_resize.py):
+Bring-up (scripted in tests/test_examples_standalone.py):
   1. store server, 2. gpt teacher(s) + registry, 3. discovery server,
   4. this student.
 """

@@ -5,7 +5,7 @@ wraps its reader in a DistillReader and adds a soft-label term to the loss
 (reference :103-104,445-449); teachers are ResNeXt-class models served by
 edl_tpu.distill.teacher_server instead of Paddle Serving.
 
-Bring-up (see tests/test_examples_and_resize.py for a scripted version):
+Bring-up (see tests/test_examples_standalone.py for a scripted version):
   1. store server, 2. teacher(s) + registry, 3. discovery server,
   4. this student (fixed or dynamic teacher list).
 """

@@ -7,8 +7,10 @@ strategy (SURVEY.md §4).
 """
 
 import atexit
+import json
 import os
 import shutil
+import subprocess
 import sys
 import tempfile
 
@@ -31,6 +33,7 @@ atexit.register(shutil.rmtree, _TEST_CACHE, ignore_errors=True)
 os.environ["JAX_COMPILATION_CACHE_DIR"] = _TEST_CACHE
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from edl_tpu.coordination.embedded import (  # noqa: E402
@@ -48,6 +51,36 @@ def cpu_subprocess_env(n_devices=2, **extra):
     env["PYTHONPATH"] = REPO
     env.update(extra)
     return env
+
+
+def _run_example(path, args, timeout=240, device_count=2):
+    env = cpu_subprocess_env(device_count)
+    proc = subprocess.run(
+        [sys.executable, "-u", os.path.join(REPO, path)] + args,
+        env=env, capture_output=True, text=True, timeout=timeout)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    result = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
+    return json.loads(result)
+
+
+def _make_real_dataset(root, classes=4, per_class=48, size=48, seed=0):
+    """Real JPEGs on disk with visually-learnable classes (distinct base
+    colors + noise) in class-per-subdirectory layout."""
+    from PIL import Image
+    rng = np.random.RandomState(seed)
+    palette = [(220, 40, 40), (40, 220, 40), (40, 40, 220), (220, 220, 40),
+               (220, 40, 220), (40, 220, 220), (230, 140, 30),
+               (130, 70, 200), (110, 190, 90), (160, 160, 160)]
+    assert classes <= len(palette)
+    for c in range(classes):
+        d = os.path.join(root, "class_%d" % c)
+        os.makedirs(d, exist_ok=True)
+        for i in range(per_class):
+            img = np.ones((size, size, 3), np.float32) * palette[c]
+            img += rng.randn(size, size, 3) * 25.0
+            Image.fromarray(np.clip(img, 0, 255).astype(np.uint8)).save(
+                os.path.join(d, "img%03d.jpg" % i))
+    return root
 
 
 @pytest.fixture()

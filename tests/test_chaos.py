@@ -537,13 +537,24 @@ def test_chaos_frame_corrupt_fails_all_pipelined_inflight(plane):
         # stray writer from another component must not eat the only
         # firing before our request goes out
         corrupt = plane.inject("rpc.frame.write", "corrupt")
-        futs = [c.call_async("gated", i) for i in range(4)]
+        # (a send that meets the stream request 0 already killed is
+        # refused with the same ConnectError at once, by _send's own
+        # "connection died" or its failed write: no future to wait on)
+        futs, failed = [], 0
+        for i in range(4):
+            try:
+                futs.append(c.call_async("gated", i))
+            except errors.ConnectError:
+                failed += 1
+        assert futs  # request 0 went out on the warmed connection
         gate.set()
         # the armed write replaced request 0 with a garbage magic: the
         # server kills the stream, so EVERY in-flight future fails
         for fut in futs:
             with pytest.raises(errors.ConnectError):
                 fut.result(timeout=10)
+            failed += 1
+        assert failed == 4  # no request was lost or answered
         assert corrupt.fired >= 1
         plane.clear("rpc.frame.write")
         assert c.call("echo", "recovered") == "recovered"  # fresh dial

@@ -135,7 +135,7 @@ def test_resnet_example_trains_with_native_loader(loader_lib, tmp_path):
     import sys
 
     sys.path.insert(0, os.path.join(REPO, "tests"))
-    from test_examples_and_resize import _make_real_dataset
+    from conftest import _make_real_dataset
 
     data = _make_real_dataset(str(tmp_path / "train"), classes=2,
                               per_class=16, size=40)

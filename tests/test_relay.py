@@ -308,7 +308,7 @@ def test_lease_coalescing_through_relay(store):
         lids = [c.lease_grant(30.0) for _ in range(3)]
         verdicts = att.lease_refresh_many(lids)
         assert verdicts == {lid: True for lid in lids}
-        # the relay now carries all three child leases in its batch
+        # the relay holds a verdict for each lease that beat through it
         assert root.stats()["child_leases"] == 3
         # a dead lease comes back False once the upstream batch runs
         c.lease_revoke(lids[0])
@@ -322,6 +322,91 @@ def test_lease_coalescing_through_relay(store):
         assert verdicts[lids[1]] is True
     finally:
         att.close()
+        root.stop()
+
+
+def _beat(att, lid, period, until, read=None):
+    """Beat ``lid`` through ``att`` every ``period`` s until the
+    monotonic time ``until``; returns what ``read()`` saw at each beat
+    (one entry a beat)."""
+    seen = []
+    while time.monotonic() < until:
+        assert att.lease_refresh_many([lid]) == {lid: True}
+        seen.append(read and read())
+        time.sleep(period)
+    return seen
+
+
+def test_stopped_child_lease_dies_with_its_ttl(store):
+    """A relay refreshes a lease upstream only for a beat that reached
+    it: a leaf that falls silent loses its registration within its ttl
+    (+ a window), however hard its sibling goes on beating through the
+    same relay. Read from the registry — what the leader's generator
+    sees — because a direct refresh would itself be a beat."""
+    ttl = 3.0
+    c = _client(store)
+    root = _start_relay(store, "p0", [])
+    atts = [RelayAttachment(lambda: [root.endpoint], pod_id=p)
+            for p in ("a", "b")]
+    try:
+        lids = [c.set_server_with_lease("pods", p, "x", ttl)
+                for p in ("a", "b")]
+        assert atts[0].lease_refresh_many(lids[:1]) == {lids[0]: True}
+        stopped = time.monotonic()  # leaf a's last beat
+        _beat(atts[1], lids[1], ttl / 3, stopped + ttl + 2.0)
+        assert dict(c.get_service("pods")) == {"b": "x"}
+        assert c.lease_refresh_many(lids, relay=False) \
+            == {lids[0]: False, lids[1]: True}
+    finally:
+        for att in atts:
+            att.close()
+        root.stop()
+
+
+def test_live_child_lease_never_lapses_and_beats_coalesce(store):
+    """The other half of the contract: leaves beating at ttl/3 through
+    one relay for 10 ttls keep their keys at every read; the relay goes
+    upstream at most once a LEASE_COALESCE_S window (plus the one
+    synchronous batch per fresh id); and a lease rides upstream no more
+    often than its owner beat — the faster sibling's batches do not
+    carry the slower one's id."""
+    import threading
+
+    ttls = {"a": 6.0, "b": 3.0}
+    c = _client(store)
+    root = _start_relay(store, "p0", [])
+    calls = []
+    upstream = root._upstream_refresh
+
+    def counted(ids):
+        calls.append(list(ids))
+        return upstream(ids)
+
+    root._upstream_refresh = counted
+    atts = {p: RelayAttachment(lambda: [root.endpoint], pod_id=p)
+            for p in ttls}
+    try:
+        lids = {p: c.set_server_with_lease("pods", p, "x", ttl)
+                for p, ttl in ttls.items()}
+        t0 = time.monotonic()
+        fast = threading.Thread(target=_beat, args=(
+            atts["b"], lids["b"], ttls["b"] / 3, t0 + 30.0))
+        fast.start()
+        time.sleep(0.5)  # out of phase: a's beats land mid-window
+        seen = _beat(atts["a"], lids["a"], ttls["a"] / 3, t0 + 30.0,
+                     read=lambda: sorted(dict(c.get_service("pods"))))
+        fast.join()
+        elapsed = time.monotonic() - t0
+        assert len(seen) >= 10 and all(s == ["a", "b"] for s in seen), \
+            seen
+        assert c.lease_refresh_many(list(lids.values()), relay=False) \
+            == {lid: True for lid in lids.values()}
+        assert len(calls) <= elapsed / root.LEASE_COALESCE_S + 2, \
+            (len(calls), elapsed)
+        assert sum(lids["a"] in b for b in calls) <= len(seen), calls
+    finally:
+        for att in atts.values():
+            att.close()
         root.stop()
 
 

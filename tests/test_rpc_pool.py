@@ -39,7 +39,8 @@ def test_pool_shares_one_client_per_endpoint(server):
         b = pool.get(server.endpoint)
         assert a is b                       # one client carries everyone
         assert pool.call(server.endpoint, "echo", 7) == 7
-        assert pool.stats() == {"open": 1, "dials": 1}
+        assert pool.stats() == {"open": 1, "dials": 1, "reaps": 0,
+                                "retires": 0}
 
 
 def test_pool_channels_are_distinct_connections(server):
@@ -51,7 +52,8 @@ def test_pool_channels_are_distinct_connections(server):
         assert pool.call(server.endpoint, "echo", 1, channel="ctl") == 1
         assert pool.call(server.endpoint, "echo", 2,
                          channel="assign") == 2
-        assert pool.stats() == {"open": 2, "dials": 2}
+        assert pool.stats() == {"open": 2, "dials": 2, "reaps": 0,
+                                "retires": 0}
 
 
 def test_pool_call_async_pipelines(server):
@@ -70,7 +72,8 @@ def test_pool_idle_reap_and_redial(server):
         assert _wait(lambda: pool.stats()["open"] == 0)
         # next caller transparently redials
         assert pool.call(server.endpoint, "echo", 2) == 2
-        assert pool.stats() == {"open": 1, "dials": 2}
+        assert pool.stats() == {"open": 1, "dials": 2, "reaps": 1,
+                                "retires": 0}
 
 
 def test_pool_lease_blocks_reaper(server):
@@ -104,9 +107,11 @@ def test_pool_retire_drops_all_channels_and_features(server):
         pool.call(server.endpoint, "echo", 1, channel="ctl")
         pool.call(server.endpoint, "echo", 1, channel="hb")
         assert pool.features(server.endpoint)  # default-channel probe
-        assert pool.stats() == {"open": 3, "dials": 3}
+        assert pool.stats() == {"open": 3, "dials": 3, "reaps": 0,
+                                "retires": 0}
         pool.retire(server.endpoint)
         assert pool.stats()["open"] == 0    # every channel dropped
+        assert pool.stats()["retires"] == 3
         assert pool._features == {}         # cache invalidated
         # next checkout redials fresh (peer may be a new generation)
         assert pool.call(server.endpoint, "echo", 2) == 2
