@@ -1,15 +1,24 @@
-"""Sparse decoder family: a causal LM whose every layer is grouped-query
-attention followed by a top-k mixture of gated-linear-unit experts, with
-RMSNorm, no biases and an untied output head. What differs between the
-models of the family is said by attributes: per layer, rotary positions or
-none, and which keys a query reads — the full causal prefix, a causal
+"""Sparse decoder family: a causal LM whose every layer is a token MIXER
+followed by a top-k mixture of gated-linear-unit experts, with RMSNorm, no
+biases and an untied output head. What differs between the models of the
+family is said by attributes. Per layer, the mixer's kind
+(``mixer_layout``): grouped-query softmax attention, or the GATED DELTA RULE
+(``ops/gated_delta.py``: a linear-attention layer whose memory is a
+[key width, value width] float32 matrix a value head, behind a causal
+depthwise convolution and in front of a gated RMSNorm). For an attention
+layer: rotary positions or none, over the whole head or its leading
+``rotary_dim``; which keys a query reads — the full causal prefix, a causal
 window, or the ``select_topk`` keys a learned indexer chose
 (DeepSeek-Sparse-Attention: ``ops/sparse_attention.py``; the indexer
-learns from a loss term of its own and from nothing else); the router's
-input (``router_input``: the attention's normed input, i.e. tapped BEFORE
-attention, or the expert layer's own normed input); the experts' gate
-(``expert_activation``: ReLU or SiLU); RMSNorm over the head width on
-queries and keys (``qk_norm``). The defaults are SmallThinker's.
+learns from a loss term of its own and from nothing else); RMSNorm over the
+head width on queries and keys (``qk_norm``); a sigmoid gate on the
+attention's result from a second half of the query projection
+(``attn_gate``). For the expert part: the router's input (``router_input``:
+the mixer's normed input, i.e. tapped BEFORE the mixer, or the expert
+layer's own normed input); the experts' gate (``expert_activation``: ReLU
+or SiLU); a SHARED expert every token passes through beside the routed ones
+(``shared_expert_width``). And whether an RMSNorm's gain is zero-centred,
+``1 + g`` (``zero_centered_norm``). The defaults are SmallThinker's.
 
 A model with a ``block_length`` is trained by DIFFUSION OVER BLOCKS
 (BD3-LM, arXiv:2503.09573) instead of next-token prediction: every sequence
@@ -42,7 +51,7 @@ import jax.numpy as jnp
 import optax
 
 from edl_tpu.ops import block_diffusion_attention as bd_attention
-from edl_tpu.ops import sparse_attention
+from edl_tpu.ops import gated_delta, sparse_attention
 from edl_tpu.ops.attention import (attention_context,
                                    block_diffusion_attention,
                                    selected_attention)
@@ -62,6 +71,15 @@ SELECT_COUNTERS = ("pairs_kept", "rows_off_count", "index_loss")
 #: it applied; and ``loss_tokens``, one scalar for the model: the masked
 #: positions that carried loss; running sums
 BLOCK_DIFFUSION_COUNTERS = ("pairs_attended",)
+#: and, in a model with a gated-delta-rule layer, per such layer (0 for an
+#: attention layer): the most negative cumulative log decay a chunk of any
+#: step reached (what a form that takes ``exp(-gamma)`` would overflow on;
+#: a running minimum) and the largest |S| at a chunk's end (a running
+#: maximum)
+GATED_DELTA_COUNTERS = ("gdn_chunk_log_decay_min", "gdn_state_absmax")
+#: how a counter is kept over the steps, where not as a running sum
+_RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
+            "gdn_chunk_log_decay_min": jnp.minimum}
 #: what a layer under remat keeps for its backward, the one policy of every
 #: model of the family (a name that no layer of a model emits saves
 #: nothing): the chosen experts with the two grouped products' results (the
@@ -70,10 +88,13 @@ BLOCK_DIFFUSION_COUNTERS = ("pairs_attended",)
 #: masked attention kernels' own residuals, result and lse — 34 MB a layer
 #: at a 16384-row stream of 8 heads of 128, against a second ``dsa_fwd``
 #: (10 ms) or ``bdiff_fwd`` (2.7 ms) in every layer's backward that would
-#: only rebuild them. The band kernels name no residual and run twice.
+#: only rebuild them; and the gated delta rule's result and chunk-end
+#: states (335 MB a layer at 16384 tokens of 16 value heads, against a
+#: second ``gdn_fwd``). The band kernels name no residual and run twice.
 SAVED_UNDER_REMAT = (moe.SAVED_UNDER_REMAT
                      + sparse_attention.SAVED_UNDER_REMAT
-                     + bd_attention.SAVED_UNDER_REMAT)
+                     + bd_attention.SAVED_UNDER_REMAT
+                     + gated_delta.SAVED_UNDER_REMAT)
 
 
 def _init(std=0.02):
@@ -81,24 +102,35 @@ def _init(std=0.02):
 
 
 class RMSNorm(nn.Module):
+    """``zero_centered``: the gain is 1 + scale, scale seeded at zero."""
     eps: float = 1e-6
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param("scale", nn.initializers.zeros if
+                           self.zero_centered else nn.initializers.ones,
+                           (x.shape[-1],), jnp.float32)
+        if self.zero_centered:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
                                 + self.eps)
         return (y * scale).astype(x.dtype)
 
 
-def rope(x, theta, positions=None):
+def rope(x, theta, positions=None, rotary_dim=None):
     """Rotary positions, half-split convention: x [b, s, h, d]; pair i is
     (x[i], x[i + d/2]), turned by position * theta ** (-2 i / d).
     ``positions`` [s] are an argument: each row's position, for a stream in
     which a row's index is not its position (two copies of one sequence);
-    None counts them 0 .. s - 1."""
+    None counts them 0 .. s - 1. ``rotary_dim``: only the LEADING
+    ``rotary_dim`` of the head are turned (half-split inside them), the
+    rest passes untouched."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate([
+            rope(x[..., :rotary_dim], theta, positions),
+            x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
@@ -112,9 +144,20 @@ def rope(x, theta, positions=None):
                            axis=-1).astype(x.dtype)
 
 
+def _log_uniform(low, high, transform=jnp.log):
+    """An initializer: ``transform`` of a value drawn log-uniformly from
+    [low, high]."""
+    def init(key, shape, dtype=jnp.float32):
+        x = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                       jnp.log(low), jnp.log(high)))
+        return transform(x).astype(dtype)
+    return init
+
+
 class SparseDecoderLayer(nn.Module):
-    """h = norm(x); x' = x + attention(h); u = norm(x'); out = x' + held
-    experts(u), routed on h or on u (``router_input``). Returns (out,
+    """h = norm(x); x' = x + mixer(h), attention or the gated delta rule
+    (``mixer``); u = norm(x'); out = x' + held experts(u) [+ shared
+    expert(u)], routed on h or on u (``router_input``). Returns (out,
     counters); a selecting layer's counters hold its index loss, which is
     differentiable (towards the indexer alone)."""
     heads: int                 # query heads held here
@@ -138,6 +181,15 @@ class SparseDecoderLayer(nn.Module):
     expert_activation: str = "relu"     # or "silu"
     qk_norm: bool = False
     streams: Optional[Tuple[int, int]] = None   # the two-stream block mask
+    mixer: str = "attention"            # or "gated_delta"
+    gdn_key_heads: int = 0              # key heads of a gated-delta layer
+    gdn_value_heads: int = 0            # its value heads, held here
+    gdn_head_dim: int = 0               # the width of both
+    conv_width: int = 4
+    attn_gate: bool = False             # sigmoid gate on attention's result
+    rotary_dim: Optional[int] = None    # None: the whole head
+    zero_centered_norm: bool = False
+    shared_expert_width: int = 0        # 0: no shared expert
 
     def _route(self, x):
         b, s, d = x.shape
@@ -175,6 +227,100 @@ class SparseDecoderLayer(nn.Module):
         self.sow("intermediates", "select", (qi, ki, wi, tau))
         return qi, ki, wi, tau
 
+    def _norm(self, name):
+        return RMSNorm(self.eps, self.zero_centered_norm, name=name)
+
+    def _gated_delta(self, h, proj):
+        """The linear-attention mixer on the normed input h: (its part of
+        the residual, the rule's two statistics). The projection's columns
+        are grouped by KEY head — q, k, then that head's value heads' v and
+        their z; b and a likewise — so a contiguous split of the heads over
+        chips is a split over key heads."""
+        b, s, d = h.shape
+        dt, f32 = self.dtype, jnp.float32
+        hk, hv, dh = self.gdn_key_heads, self.gdn_value_heads, \
+            self.gdn_head_dim
+        r = hv // hk
+        with jax.named_scope("mixer.gdn.proj"):
+            qkvz = jnp.einsum("bsd,dhk->bshk", h, proj(
+                "in_proj_qkvz", (d, hk, (2 + 2 * r) * dh)))
+            ba = jnp.einsum("bsd,dhk->bshk", h,
+                            proj("in_proj_ba", (d, hk, 2 * r)),
+                            preferred_element_type=f32)
+            flat = lambda x: x.reshape(b, s, -1)
+            q, k, v = (flat(qkvz[..., lo * dh:hi * dh]) for lo, hi in (
+                (0, 1), (1, 2), (2, 2 + r)))
+            z = qkvz[..., (2 + r) * dh:].reshape(b, s, hv, dh)
+            beta, a = flat(ba[..., :r]), flat(ba[..., r:])
+        with jax.named_scope("mixer.gdn.conv"):
+            conv = self.param("conv", _init(), ((2 * hk + hv) * dh,
+                                                self.conv_width), f32)
+            mixed = jax.nn.silu(gated_delta.causal_conv(
+                jnp.concatenate([q, k, v], axis=-1), conv))
+            q, k = (mixed[..., i * hk * dh:(i + 1) * hk * dh].reshape(
+                b, s, hk, dh) for i in (0, 1))
+            v = mixed[..., 2 * hk * dh:].reshape(b, s, hv, dh).astype(dt)
+        with jax.named_scope("mixer.gdn.scan"):
+            a_log = self.param("A_log", _log_uniform(1.0, 16.0), (hv,), f32)
+            dt_bias = self.param(
+                "dt_bias", _log_uniform(1e-3, 1e-1,
+                                        lambda x: jnp.log(jnp.expm1(x))),
+                (hv,), f32)
+            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
+            unit = lambda x: x * jax.lax.rsqrt(
+                jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+            o, stats = gated_delta.gated_delta_rule(
+                (unit(q) * dh ** -0.5).astype(dt), unit(k).astype(dt), v, g,
+                jax.nn.sigmoid(beta), use_kernel=self.use_flash)
+        with jax.named_scope("mixer.gdn.out"):
+            y = (RMSNorm(self.eps, name="norm_gdn")(o.astype(f32))
+                 * jax.nn.silu(z.astype(f32))).astype(dt)
+            out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hv, dh, d)))
+        return out, stats
+
+    def _attention(self, h, proj, positions, select):
+        """Grouped-query attention on the normed input h: (its part of the
+        residual, what the masked paths counted: (kl, kept) of a selection,
+        the pairs of the two-stream block mask, else None)."""
+        d = h.shape[-1]
+        dt = self.dtype
+        hd = self.head_dim
+        counted = None
+        with jax.named_scope("attn.select" if select else
+                             "attn.block_diffusion" if self.streams else
+                             "attn.window" if self.window else "attn.full"):
+            q = jnp.einsum("bsd,dhk->bshk", h, proj(
+                "query", (d, self.heads, 2 * hd if self.attn_gate else hd)))
+            if self.attn_gate:      # a head at a time: its query, its gate
+                q, gate = q[..., :hd], q[..., hd:]
+            k = jnp.einsum("bsd,dhk->bshk", h,
+                           proj("key", (d, self.kv_heads, hd)))
+            v = jnp.einsum("bsd,dhk->bshk", h,
+                           proj("value", (d, self.kv_heads, hd)))
+            if self.qk_norm:
+                q = self._norm("norm_query")(q)
+                k = self._norm("norm_key")(k)
+            if self.use_rope:
+                q = rope(q, self.rope_theta, positions, self.rotary_dim)
+                k = rope(k, self.rope_theta, positions, self.rotary_dim)
+            if select:
+                a, *counted = selected_attention(
+                    q, k, v, select, dtype=dt, use_flash=self.use_flash)
+            elif self.streams:
+                a, counted = block_diffusion_attention(
+                    q, k, v, self.streams, dtype=dt,
+                    use_flash=self.use_flash)
+            else:
+                a = attention_context(q, k, v, causal=True, mask=None,
+                                      dtype=dt, use_flash=self.use_flash,
+                                      window=self.window)
+            if self.attn_gate:
+                with jax.named_scope("attn.gate"):
+                    a = (a * jax.nn.sigmoid(gate.astype(jnp.float32))
+                         ).astype(dt)
+            return jnp.einsum("bshk,hkd->bsd", a,
+                              proj("out", (self.heads, hd, d))), counted
+
     @nn.compact
     def __call__(self, x, positions=None):
         b, s, d = x.shape
@@ -183,42 +329,24 @@ class SparseDecoderLayer(nn.Module):
                                               jnp.float32).astype(dt)
         if self.router_input not in ("attn_norm", "moe_norm"):
             raise ValueError("router_input %r" % (self.router_input,))
+        if self.mixer not in ("attention", "gated_delta"):
+            raise ValueError("mixer %r" % (self.mixer,))
         if self.streams and (self.select_topk or self.window):
             raise ValueError("the two-stream block mask takes no selection "
                              "and no window")
-        h = RMSNorm(self.eps, name="norm_attn")(x)
+        linear = self.mixer == "gated_delta"
+        if linear and (self.streams or self.select_topk or self.window):
+            raise ValueError("a gated-delta-rule layer takes no mask")
+        h = self._norm("norm_attn")(x)
         if self.router_input == "attn_norm":
             idx, p = self._route(h)
         select = self._index(h, proj) if self.select_topk else None
-        with jax.named_scope("attn.select" if select else
-                             "attn.block_diffusion" if self.streams else
-                             "attn.window" if self.window else "attn.full"):
-            q = jnp.einsum("bsd,dhk->bshk", h,
-                           proj("query", (d, self.heads, self.head_dim)))
-            k = jnp.einsum("bsd,dhk->bshk", h,
-                           proj("key", (d, self.kv_heads, self.head_dim)))
-            v = jnp.einsum("bsd,dhk->bshk", h,
-                           proj("value", (d, self.kv_heads, self.head_dim)))
-            if self.qk_norm:
-                q = RMSNorm(self.eps, name="norm_query")(q)
-                k = RMSNorm(self.eps, name="norm_key")(k)
-            if self.use_rope:
-                q = rope(q, self.rope_theta, positions)
-                k = rope(k, self.rope_theta, positions)
-            if select:
-                a, kl, kept = selected_attention(
-                    q, k, v, select, dtype=dt, use_flash=self.use_flash)
-            elif self.streams:
-                a, pairs = block_diffusion_attention(
-                    q, k, v, self.streams, dtype=dt,
-                    use_flash=self.use_flash)
-            else:
-                a = attention_context(q, k, v, causal=True, mask=None,
-                                      dtype=dt, use_flash=self.use_flash,
-                                      window=self.window)
-            x = x + jnp.einsum("bshk,hkd->bsd", a,
-                               proj("out", (self.heads, self.head_dim, d)))
-        u = RMSNorm(self.eps, name="norm_moe")(x)
+        if linear:
+            mixed, counted = self._gated_delta(h, proj)
+        else:
+            mixed, counted = self._attention(h, proj, positions, select)
+        x = x + mixed
+        u = self._norm("norm_moe")(x)
         if self.router_input == "moe_norm":
             idx, p = self._route(u)
         f = self.expert_width
@@ -229,7 +357,17 @@ class SparseDecoderLayer(nn.Module):
         m, counters = moe.held_experts_ffn(
             u.reshape(b * s, d), idx, p, gate_up, down, self.first_expert,
             activation=self.expert_activation)
+        if self.shared_expert_width:
+            fs = self.shared_expert_width
+            m = m + moe.shared_expert_ffn(
+                u.reshape(b * s, d),
+                self.param("shared_gate_up", _init(), (d, 2 * fs),
+                           jnp.float32),
+                self.param("shared_down", _init(), (fs, d), jnp.float32),
+                self.param("shared_gate", _init(), (d,), jnp.float32),
+                activation=self.expert_activation)
         if select:
+            kl, kept = counted
             with jax.named_scope("attn.index_loss"):
                 want = jnp.minimum(jnp.arange(s) + 1, self.select_topk)
                 counters = dict(
@@ -238,7 +376,10 @@ class SparseDecoderLayer(nn.Module):
                         jnp.float32),
                     index_loss=kl.mean())
         if self.streams:
-            counters = dict(counters, pairs_attended=pairs.sum())
+            counters = dict(counters, pairs_attended=counted.sum())
+        if linear:
+            counters = dict(counters, **{"gdn_" + n: v
+                                         for n, v in counted.items()})
         return x + m.reshape(b, s, d), counters
 
 
@@ -276,6 +417,20 @@ class SparseDecoder(nn.Module):
     qk_norm: bool = False
     index_loss_weight: float = 1.0
     block_length: int = 0           # > 0: trained by diffusion over blocks
+    mixer_layout: Sequence[int] = ()    # per layer: 1 = gated delta rule
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_head_dim: int = 0
+    conv_width: int = 4
+    attn_gate: bool = False
+    rotary_dim: Optional[int] = None
+    zero_centered_norm: bool = False
+    shared_expert_width: int = 0
+
+    def gated_delta_layers(self):
+        """Per layer: whether its mixer is the gated delta rule."""
+        layout = tuple(self.mixer_layout) + (0,) * self.num_layers
+        return tuple(bool(flag) for flag in layout[:self.num_layers])
 
     def select_layers(self):
         """Per layer: whether it reads a learned selection."""
@@ -298,6 +453,7 @@ class SparseDecoder(nn.Module):
         # a model that counts its positions calls its layers as it did
         positions_arg = () if positions is None else (positions,)
         per_layer = []
+        linear = self.gated_delta_layers()
         for i, select in enumerate(self.select_layers()):
             x, counters = layer_cls(
                 heads=self.heads, kv_heads=self.kv_heads,
@@ -315,14 +471,24 @@ class SparseDecoder(nn.Module):
                 router_input=self.router_input,
                 expert_activation=self.expert_activation,
                 qk_norm=self.qk_norm, streams=streams,
+                mixer="gated_delta" if linear[i] else "attention",
+                gdn_key_heads=self.gdn_key_heads,
+                gdn_value_heads=self.gdn_value_heads,
+                gdn_head_dim=self.gdn_head_dim, conv_width=self.conv_width,
+                attn_gate=self.attn_gate, rotary_dim=self.rotary_dim,
+                zero_centered_norm=self.zero_centered_norm,
+                shared_expert_width=self.shared_expert_width,
                 name="layer_%d" % i)(x, *positions_arg)
             if self.selects() and not select:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in SELECT_COUNTERS})
+            if any(linear) and not linear[i]:
+                counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
+                                             for n in GATED_DELTA_COUNTERS})
             per_layer.append(counters)
         if streams:                 # the clean half fed keys and values
             x = x[:, :streams[1]]
-        x = RMSNorm(self.eps, name="norm_final")(x)
+        x = RMSNorm(self.eps, self.zero_centered_norm, name="norm_final")(x)
         with jax.named_scope("loss.block_diffusion" if streams
                              else "lm_head"):
             head = self.param("lm_head", _init(),
@@ -331,34 +497,38 @@ class SparseDecoder(nn.Module):
                                 preferred_element_type=jnp.float32)
         return logits, {n: jnp.stack([c[n] for c in per_layer])
                         for n in counter_names(self.selects(),
-                                               bool(streams))}
+                                               bool(streams), any(linear))}
 
 
-def counter_names(selects=False, block_diffusion=False):
+def counter_names(selects=False, block_diffusion=False, gated_delta=False):
     """The per-layer counters of a model: the routing's and, by what the
-    model does, the selection's or the two-stream attention's."""
+    model does, the selection's, the two-stream attention's or the gated
+    delta rule's."""
     return (COUNTERS + (SELECT_COUNTERS if selects else ())
-            + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ()))
+            + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ())
+            + (GATED_DELTA_COUNTERS if gated_delta else ()))
 
 
-def init_counters(num_layers, selects=False, block_diffusion=False):
+def init_counters(num_layers, selects=False, block_diffusion=False,
+                  gated_delta=False):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
     model with a selecting layer, the selection's; for one trained by
     diffusion over blocks, the attention's pairs and the scalar
-    ``loss_tokens``."""
+    ``loss_tokens``; for one with gated-delta-rule layers, the rule's two
+    (from zero: a log decay is never positive, a size never negative)."""
     # one buffer each: the trainer donates its state to the step
     scalars = ("steps",) + (("loss_tokens",) if block_diffusion else ())
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
-         for n in counter_names(selects, block_diffusion)},
+         for n in counter_names(selects, block_diffusion, gated_delta)},
         **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 
 
 def accumulate_counters(extra, step_counters):
     old = extra["counters"]
-    new = {n: (jnp.maximum(old[n], step_counters[n]) if n == "load_max"
-               else old[n] + step_counters[n]) for n in step_counters}
+    new = {n: _RUNNING.get(n, jnp.add)(old[n], step_counters[n])
+           for n in step_counters}
     new["steps"] = old["steps"] + 1.0
     return dict(extra, counters=new)
 
@@ -430,5 +600,6 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
         return loss, accumulate_counters(
             extra, jax.lax.stop_gradient(counters))
 
-    return (model, params, init_counters(model.num_layers, model.selects(),
-                                         block_diffusion), loss_fn)
+    return (model, params, init_counters(
+        model.num_layers, model.selects(), block_diffusion,
+        any(model.gated_delta_layers())), loss_fn)

@@ -362,3 +362,22 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
             plan["slot_choice"] < t * k).astype(jnp.float32),
     }
     return m.astype(u.dtype), counters
+
+
+def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu"):
+    """The SHARED expert of a layer that has one beside its routed experts:
+    every row passes through it, scaled by a learned sigmoid gate of its
+    own — sigmoid(u . w_gate) * down(act(gate u) * (up u)). A plain dense
+    gated linear unit: u [T, D], w_gate_up [D, 2 * F] (gate then up),
+    w_down [F, D], w_gate [D] -> [T, D] in u's dtype. Every chip of an
+    expert-parallel group computes it alike, so where the shares of a layer
+    are added up it is counted once."""
+    dt = u.dtype
+    with jax.named_scope("moe.shared"):
+        f = w_down.shape[0]
+        gu = jnp.dot(u, w_gate_up.astype(dt))
+        hid = EXPERT_ACTIVATIONS[activation](gu[:, :f]) * gu[:, f:]
+        y = jnp.dot(hid, w_down.astype(dt), preferred_element_type=jnp.float32)
+        gate = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
+                                      w_gate.astype(jnp.float32)))
+        return (y * gate[:, None]).astype(dt)
