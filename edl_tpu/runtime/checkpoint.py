@@ -242,34 +242,64 @@ def _untag_array(arr, tag):
     return arr.view(np.dtype(tag))
 
 
-def _start_host_transfers(tree):
-    """Kick off non-blocking device->host DMAs for every addressable
-    shard of every jax leaf, so the per-shard np.asarray fetches that
-    follow overlap instead of serializing (phase 1 of the async save).
-    Returns (calls started, bytes of the shards they were started on).
-    Best-effort: the first call that raises ends it, and the rest of the
-    tree is then fetched serially."""
-    # a leaf's shards are of one size. It is read before the first
-    # transfer is asked for: between two of those calls the same read
-    # costs several times more (on four v5e chips 7 us, 12 ms a save)
-    leaves = [x for x in jax.tree_util.tree_leaves(tree)
-              if getattr(x, "addressable_shards", None)]
-    sizes = [int(x.addressable_shards[0].data.nbytes) for x in leaves]
-    calls = nbytes = 0
-    for leaf, each in zip(leaves, sizes):
-        for s in leaf.addressable_shards:
-            start = getattr(s.data, "copy_to_host_async", None)
-            if start is not None:
-                try:
-                    start()
-                except Exception as e:  # noqa: BLE001 — best-effort
-                    logger.debug("host transfers stopped after %d calls "
-                                 "(%r): the rest is fetched serially",
-                                 calls, e)
-                    return calls, nbytes
-                calls += 1
-                nbytes += each
-    return calls, nbytes
+def _device_read(x):
+    """True for a leaf that ``np.asarray`` reads off this process's
+    devices: a jax array whose every block is addressable here. A host
+    leaf is there already, and a leaf that spans other processes is
+    gathered first: neither is asked for anything."""
+    return (hasattr(x, "copy_to_host_async")
+            and getattr(x, "is_fully_addressable", False))
+
+
+def _owned_shards(leaf):
+    """The shards a sharded save reads of a jax leaf and this process
+    writes: of the copies of a block, the one with ``replica_id`` 0.
+    None for a host value, which rank 0 writes whole."""
+    if not (hasattr(leaf, "addressable_shards")
+            and hasattr(leaf, "sharding")):
+        return None
+    return [s for s in leaf.addressable_shards if s.replica_id == 0]
+
+
+def _buffers_read(x):
+    """Device buffers that ``np.asarray(x)`` reads, and that
+    ``x.copy_to_host_async()`` therefore asks for: one of a replicated
+    array, whatever the number of chips that hold it, else one a
+    distinct block."""
+    if x.is_fully_replicated:
+        return 1
+    blocks = x.sharding.addressable_devices_indices_map(x.shape).values()
+    return len({_concrete_spans(index, x.shape) for index in blocks})
+
+
+def _start_host_transfers(reads):
+    """Kick off the non-blocking device->host DMAs of ``reads``, the jax
+    arrays (leaves, or the data of shards) that the snapshot is about to
+    fetch with ``np.asarray``, so that those fetches overlap instead of
+    serializing (phase 1 of the async save). The array's own
+    ``copy_to_host_async`` asks for the buffers its ``np.asarray`` reads
+    and for no other — and it has to be THAT object's: the host copy is
+    kept by the Python array that asked, not by the device buffer, so a
+    transfer asked through a shard's ``data`` is lost on the
+    ``np.asarray`` of its leaf, which then transfers again, blocking (on
+    four v5e chips 0.5 ms a leaf). Returns (transfers started, their
+    bytes). Best-effort: the first call that raises ends it, and the
+    rest is then fetched serially."""
+    # what a call will move is read before the first transfer is asked
+    # for: between two of those calls the same read costs several times
+    # more (on four v5e chips 7 us, 12 ms a save)
+    asked = [(x, _buffers_read(x), int(x.nbytes)) for x in reads]
+    started = nbytes = 0
+    for x, buffers, size in asked:
+        try:
+            x.copy_to_host_async()
+        except Exception as e:  # noqa: BLE001 — best-effort
+            logger.debug("host transfers stopped after %d (%r): the rest "
+                         "is fetched serially", started, e)
+            break
+        started += buffers
+        nbytes += size
+    return started, nbytes
 
 
 class _HostBufferPool(object):
@@ -307,9 +337,11 @@ class _SnapshotAccount(object):
         self.fetch_s = self.copy_s = 0.0
         self.started = (0, 0)
 
-    def start(self, tree):
+    def start(self, reads):
+        """Ask for the transfers of ``reads``: the arrays ``fetch`` will
+        be handed, the same objects."""
         with obs_trace.span("save.snapshot.start_transfers", stage=True):
-            self.started = _start_host_transfers(tree)
+            self.started = _start_host_transfers(reads)
 
     def fetch(self, x):
         """np.asarray of a leaf or shard: the wait for the device's copy
@@ -685,8 +717,8 @@ class CheckpointManager(object):
         """Snapshot of a full tree: {span_key: host ndarray} (wire
         dtypes) + dtype tags, copied into the reused buffer pool so
         later steps may donate/mutate the originals."""
-        acct.start(tree)
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+        acct.start([leaf for _, leaf in flat if _device_read(leaf)])
         entries = {}
         dtypes = {}
         for path, leaf in flat:
@@ -707,8 +739,9 @@ class CheckpointManager(object):
         """Snapshot of this rank's OWNED shards (replica_id 0 dedup;
         host/replicated-only leaves land on rank 0), mirroring what the
         sync sharded writer persists."""
-        acct.start(tree)
         flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+        owned = [_owned_shards(leaf) for _, leaf in flat]
+        acct.start([s.data for shards in owned for s in shards or ()])
         entries = {}
         dtypes = {}
 
@@ -719,13 +752,11 @@ class CheckpointManager(object):
             skey = self._shard_key(key, index, shape)
             entries[skey] = acct.keep(skey, arr)
 
-        for path, leaf in flat:
+        for (path, leaf), shards in zip(flat, owned):
             key = _path_key(path)
-            if hasattr(leaf, "addressable_shards") \
-                    and hasattr(leaf, "sharding"):
-                for s in leaf.addressable_shards:
-                    if s.replica_id == 0:
-                        add(key, s.index, leaf.shape, s.data)
+            if shards is not None:
+                for s in shards:
+                    add(key, s.index, leaf.shape, s.data)
             elif rank == 0:
                 arr = acct.fetch(leaf)
                 add(key, tuple(slice(0, d) for d in arr.shape),
@@ -918,18 +949,6 @@ class CheckpointManager(object):
     # -- sharded save --------------------------------------------------------
 
     @staticmethod
-    def _owned_shards(leaf):
-        """(index, ndarray) pairs this process must write: one entry per
-        distinct shard (replica_id 0 de-duplicates replicas), or the
-        whole array for host values / fully-replicated leaves on rank 0
-        handled by the caller."""
-        out = []
-        for s in leaf.addressable_shards:
-            if s.replica_id == 0:
-                out.append((s.index, np.asarray(s.data)))
-        return out
-
-    @staticmethod
     def _shard_key(key, index, shape):
         return "%s@%s" % (key, _spans_str(_concrete_spans(index, shape)))
 
@@ -981,14 +1000,14 @@ class CheckpointManager(object):
             to_save = {}
             for path, leaf in flat:
                 key = _path_key(path)
-                if hasattr(leaf, "addressable_shards") \
-                        and hasattr(leaf, "sharding"):
-                    shards = self._owned_shards(leaf)
+                shards = _owned_shards(leaf)
+                if shards is not None:
                     # fully-replicated leaves land on every process with
                     # replica_id spread; only write replica 0's copy
-                    for index, arr in shards:
-                        to_save[self._shard_key(key, index, leaf.shape)] \
-                            = arr
+                    for s in shards:
+                        arr = np.asarray(s.data)
+                        to_save[self._shard_key(key, s.index,
+                                                leaf.shape)] = arr
                         if _BFLOAT16 is not None \
                                 and arr.dtype == _BFLOAT16:
                             dtypes[key] = "bfloat16"

@@ -362,3 +362,64 @@ def test_sharded_async_two_ranks_sentinel_protocol(tmp_path):
     np.testing.assert_array_equal(restored["opt"]["mu"], host["opt"]["mu"])
     cm0.close()
     cm1.close()
+
+
+#: crc32 of each entry file of `_fixed_state`, as the tree before the
+#: snapshot asked one replica only (commit 1109e1a) wrote them
+_FIXED_STATE_CRCS = {"opt/mu@0:8;0:12": 3763356796,
+                     "params/b@0:12": 1461229387,
+                     "params/w@0:8;0:12": 3541670813,
+                     "step@": 1931258111}
+
+
+def _replicated(dp):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from edl_tpu.runtime.mesh import make_mesh
+    return NamedSharding(make_mesh(devices=jax.devices()[:dp]), P())
+
+
+def _fixed_state(dp):
+    """The same values whatever the world: replicated over ``dp`` chips."""
+    return jax.device_put(
+        {"params": {"w": jnp.arange(96.0).reshape(8, 12) / 7,
+                    "b": jnp.arange(12, dtype=jnp.bfloat16)},
+         "opt": {"mu": jnp.arange(96.0).reshape(8, 12) * 3e-3},
+         "step": jnp.asarray(45, jnp.int32)}, _replicated(dp))
+
+
+@pytest.mark.parametrize("engine", ["dense", "sharded"])
+@pytest.mark.parametrize("first,then", [(4, 2), (2, 4)])
+def test_state_crosses_worlds_and_back_with_the_same_bytes_on_disk(
+        tmp_path, engine, first, then):
+    """Saved on one world, restored onto the other, saved there and
+    restored back: the values come back, and every save's entry files
+    carry the bytes the parent's did for this state."""
+    cm = CheckpointManager(str(tmp_path), keep=5)
+    save = cm.save_async if engine == "dense" else cm.save_sharded_async
+
+    def crcs(vdir):
+        table = json.load(open(vdir + "/MANIFEST"))["entries"]
+        return {key: entry["crc"] for key, entry in table.items()}
+
+    def restored(version, dp):
+        onto = _replicated(dp)
+        return cm.restore_placed(
+            version, _struct_target(state),
+            jax.tree_util.tree_map(lambda _: onto, state))[1]
+
+    state = _fixed_state(first)
+    try:
+        assert crcs(save(1, state).result(60)) == _FIXED_STATE_CRCS
+        moved = restored(1, then)
+        assert all(len(leaf.sharding.device_set) == then
+                   for leaf in jax.tree_util.tree_leaves(moved))
+        assert crcs(save(2, moved).result(60)) == _FIXED_STATE_CRCS
+        back = restored(2, first)
+    finally:
+        cm.close()
+    for a, b in zip(jax.tree_util.tree_leaves(state),
+                    jax.tree_util.tree_leaves(back)):
+        assert a.dtype == b.dtype and a.sharding == b.sharding
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

@@ -16,6 +16,7 @@ import sys
 import threading
 
 import jax
+import numpy as np
 import optax
 import pytest
 
@@ -478,22 +479,30 @@ def test_blocked_s_is_the_snapshot_span(tmp_path, clean_ring, sharded):
     assert _tags(persist) == {"version": 3}
 
 
-def _replicated_tree(dp):
+def _state_tree(dp, split=False):
+    """A state held by ``dp`` chips. Replicated: every chip holds every
+    leaf. ``split``: `w` is cut in two over the model axis and each half
+    is held by ``dp`` / 2 chips; the other leaves stay replicated."""
     from jax.sharding import NamedSharding, PartitionSpec
-    rep = NamedSharding(make_mesh(devices=jax.devices()[:dp]),
-                        PartitionSpec())
-    return jax.device_put(
-        {"w": jax.numpy.arange(48.0).reshape(6, 8),
+    mesh = make_mesh(tp=2 if split else 1, devices=jax.devices()[:dp])
+    rep = NamedSharding(mesh, PartitionSpec())
+    tree = jax.device_put(
+        {"w": jax.numpy.arange(48.0).reshape(8, 6),
          "b": jax.numpy.arange(8, dtype=jax.numpy.bfloat16),
          "n": jax.numpy.zeros((), jax.numpy.int32)}, rep)
+    if split:
+        tree["w"] = jax.device_put(
+            tree["w"], NamedSharding(mesh, PartitionSpec("tp")))
+    return tree
 
 
 @pytest.mark.parametrize("dp", [2, 4])
+@pytest.mark.parametrize("split", [False, True])
 @pytest.mark.parametrize("sharded", [False, True])
 def test_snapshot_says_what_it_is_made_of(tmp_path, clean_ring, sharded,
-                                          dp):
+                                          split, dp):
     mgr = CheckpointManager(str(tmp_path))
-    tree = _replicated_tree(dp)
+    tree = _state_tree(dp, split)
     try:
         take = (mgr._snapshot_sharded, tree, 0) if sharded \
             else (mgr._snapshot_dense, tree)
@@ -517,17 +526,93 @@ def test_snapshot_says_what_it_is_made_of(tmp_path, clean_ring, sharded,
     kept = sum(a.nbytes for a in entries.values())
     assert tags["bytes"] == kept == 48 * 4 + 8 * 2 + 4
     assert tags["leaves"] == 3
-    # a transfer is asked of every chip that holds the leaf
-    assert tags["transfers_started"] == 3 * dp
-    assert tags["transfer_bytes_started"] == dp * kept
+    # a transfer is asked once of each distinct block, from the one chip
+    # the fetch reads it from, however many chips hold it: what is asked
+    # for is what is kept
+    blocks = (2 if split else 1) + 2
+    assert tags["transfers_started"] == blocks
+    assert tags["transfer_bytes_started"] == kept
     # the three parts lie inside the span and are not counted twice
     _in_order_inside(first, [starts[0]])
     assert tags["fetch_s"] > 0 and tags["copy_s"] > 0
     assert (tags["fetch_s"] + tags["copy_s"]
             + starts[0]["dur_ms"] / 1e3) <= first["dur_ms"] / 1e3 + 1e-6
-    # the second save finds its buffers in the pool
-    assert tags["bufs_new"] == 3 and _tags(second)["bufs_new"] == 0
+    # the second save finds its buffers in the pool: the dense snapshot
+    # keeps a leaf whole, the sharded one a buffer a block
+    assert tags["bufs_new"] == len(entries) == (blocks if sharded else 3)
+    assert _tags(second)["bufs_new"] == 0
     assert sorted(again) == sorted(entries)
+
+
+@pytest.mark.parametrize("split", [False, True])
+@pytest.mark.parametrize("sharded", [False, True])
+def test_snapshot_asks_for_the_buffers_it_reads(tmp_path, clean_ring,
+                                                monkeypatch, sharded, split):
+    """The request pass and the fetch pass name the same buffers of the
+    same array objects: every buffer a fetch reads was asked for once
+    before the first fetch, through the object the fetch reads it from
+    (the host copy a request brings is kept by the Python object that
+    asked, so `np.asarray` of another object over the same device buffer
+    transfers again), and nothing else was asked for."""
+    from jax._src import array as jax_array
+
+    from edl_tpu.runtime import checkpoint as ckpt_mod
+
+    def held(single, by):
+        return (id(by), single.unsafe_buffer_pointer())
+
+    def read_by_asarray(x):
+        """`ArrayImpl._value`: a replicated array reads its first buffer
+        as itself, a cut one the first single-device array of each
+        distinct block."""
+        if not isinstance(x, jax.Array):
+            return []
+        if x.is_fully_replicated:
+            return [held(x._arrays[0], x)]
+        first = {}
+        for shard in x.addressable_shards:
+            first.setdefault(str(shard.index), held(shard.data, shard.data))
+        return list(first.values())
+
+    log = []
+    ask = jax_array.ArrayImpl._copy_single_device_array_to_host_async
+    fetch = ckpt_mod._SnapshotAccount.fetch
+
+    def asking(self):
+        log.append(("ask", [held(self._arrays[0], self)]))
+        return ask(self)
+
+    def fetching(self, x):
+        log.append(("read", read_by_asarray(x)))
+        return fetch(self, x)
+
+    monkeypatch.setattr(jax_array.ArrayImpl,
+                        "_copy_single_device_array_to_host_async", asking)
+    monkeypatch.setattr(ckpt_mod._SnapshotAccount, "fetch", fetching)
+    tree = dict(_state_tree(4, split), host=np.ones(3, np.float32), py=2.5)
+    mgr = CheckpointManager(str(tmp_path))
+    try:
+        if sharded:
+            entries, _, _ = mgr._snapshot(mgr._snapshot_sharded, tree, 0)
+        else:
+            entries, _, _ = mgr._snapshot(mgr._snapshot_dense, tree)
+    finally:
+        monkeypatch.undo()
+        mgr.close()
+    first_read = [what for what, _ in log].index("read")
+    asked = [buf for _, bufs in log[:first_read] for buf in bufs]
+    after = log[first_read:]
+    read = [buf for what, bufs in after if what == "read" for buf in bufs]
+    assert len(asked) == len(set(asked)) == (4 if split else 3)
+    assert sorted(read) == sorted(asked)
+    # jax asks again inside a split leaf's own read: for nothing new
+    assert {buf for _, bufs in after for buf in bufs} <= set(asked)
+    [snap] = _named(clean_ring.spans(), "save.snapshot")
+    assert snap["tags"]["transfers_started"] == len(asked)
+    assert snap["tags"]["leaves"] == 5
+    assert snap["tags"]["transfer_bytes_started"] == 48 * 4 + 8 * 2 + 4
+    assert snap["tags"]["bytes"] == 48 * 4 + 8 * 2 + 4 + 3 * 4 + 8
+    assert len(entries) == (6 if sharded and split else 5)
 
 
 def test_arc_snapshots_add_up(arc):
@@ -539,11 +624,13 @@ def test_arc_snapshots_add_up(arc):
         tags = snap["tags"]
         assert (tags["fetch_s"] + tags["copy_s"] + start["dur_ms"] / 1e3
                 <= snap["dur_ms"] / 1e3 + 1e-6)
-    # saved at dp=4, then at dp=2, the same replicated state
+    # saved at dp=4, then at dp=2, the same replicated state: one replica
+    # is asked for on either world
     at4, at2 = sorted(snaps, key=lambda s: s["t0"])
     assert at4["tags"]["bytes"] == at2["tags"]["bytes"] > 0
-    assert at4["tags"]["transfer_bytes_started"] == 4 * at4["tags"]["bytes"]
-    assert at2["tags"]["transfer_bytes_started"] == 2 * at2["tags"]["bytes"]
+    for snap in (at4, at2):
+        assert snap["tags"]["transfer_bytes_started"] == snap["tags"]["bytes"]
+        assert snap["tags"]["transfers_started"] == snap["tags"]["leaves"]
 
 
 def test_host_transfers_count_what_they_started_and_say_when_they_stop():
@@ -551,22 +638,38 @@ def test_host_transfers_count_what_they_started_and_say_when_they_stop():
 
     from edl_tpu.runtime import checkpoint as ckpt_mod
 
-    class Shard(object):
-        def __init__(self, fail=False):
-            self.data = self
-            self.nbytes = 16
+    class Array(object):
+        """What the request pass sees of a jax array: replicated, or cut
+        into ``blocks`` that two chips each hold."""
+        nbytes = 16
+        shape = (4,)
+        is_fully_addressable = True
+
+        def __init__(self, fail=False, blocks=1):
+            self.is_fully_replicated = blocks == 1
+            self.sharding = self
+            self._blocks = blocks
             self._fail = fail
+
+        def addressable_devices_indices_map(self, shape):
+            cut = shape[0] // self._blocks
+            return {chip: (slice(cut * (chip % self._blocks),
+                                 cut * (chip % self._blocks + 1)),)
+                    for chip in range(2 * self._blocks)}
 
         def copy_to_host_async(self):
             if self._fail:
                 raise RuntimeError("no transfer")
 
-    class Leaf(object):
-        def __init__(self, *shards):
-            self.addressable_shards = shards
-
+    # one call an array; a transfer a distinct block
     assert ckpt_mod._start_host_transfers(
-        [Leaf(Shard(), Shard()), 3.0, Leaf(Shard())]) == (3, 48)
+        [Array(), Array(blocks=2), Array()]) == (4, 48)
+    # what a snapshot does not read off its devices is never handed over
+    elsewhere = Array()
+    elsewhere.is_fully_addressable = False
+    assert [ckpt_mod._device_read(x)
+            for x in (Array(), 3.0, np.ones(2), elsewhere)] == [
+        True, False, False, False]
     records = []
     seen = logging.Handler(level=logging.DEBUG)
     seen.emit = records.append
@@ -575,15 +678,15 @@ def test_host_transfers_count_what_they_started_and_say_when_they_stop():
     ckpt_mod.logger.setLevel(logging.DEBUG)
     try:
         got = ckpt_mod._start_host_transfers(
-            [Leaf(Shard(), Shard(fail=True)), Leaf(Shard(fail=True))])
+            [Array(), Array(fail=True), Array(fail=True)])
     finally:
         ckpt_mod.logger.setLevel(level)
         ckpt_mod.logger.removeHandler(seen)
     assert got == (1, 16)
-    # once, where it stopped: the second leaf is not tried
+    # once, where it stopped: the third array is not tried
     [stopped] = records
     assert stopped.levelname == "DEBUG"
-    assert "stopped after 1 calls" in stopped.getMessage()
+    assert "stopped after 1 " in stopped.getMessage()
 
 
 # -- the span API ----------------------------------------------------------
@@ -718,7 +821,7 @@ def test_obs_kill_switch_records_nothing_and_keeps_the_stamps(
 def test_obs_kill_switch_keeps_blocked_s_and_runs_no_account(
         tmp_path, clean_ring, monkeypatch, sharded):
     mgr = CheckpointManager(str(tmp_path))
-    tree = _replicated_tree(2)
+    tree = _state_tree(2)
     prev = obs_metrics.set_enabled(False)
     try:
         _no_account(monkeypatch)
