@@ -18,7 +18,20 @@ the mixer's normed input, i.e. tapped BEFORE the mixer, or the expert
 layer's own normed input); the experts' gate (``expert_activation``: ReLU
 or SiLU); a SHARED expert every token passes through beside the routed ones
 (``shared_expert_width``). And whether an RMSNorm's gain is zero-centred,
-``1 + g`` (``zero_centered_norm``). The defaults are SmallThinker's.
+``1 + g`` (``zero_centered_norm``). A layer that holds NO experts
+(``experts_held`` 0) has a dense gated feed-forward part of ``dense_width``
+in their place, and ``sandwich_norm`` puts an RMSNorm on each sublayer's
+result before it joins the residual. The defaults are SmallThinker's.
+
+A model with ``loop_steps`` U > 1 is a LOOPED decoder (arXiv:2510.25741):
+its ``num_layers`` layers are run U times over with the SAME parameters —
+one scan over passes, so the compiled step holds the stack once —, the
+final norm closes every pass and its result enters the next, the head and
+a learned exit gate read every pass's result, and the training loss is the
+expectation of the passes' losses under the exit distribution the gates
+give, less ``exit_entropy_weight`` times its entropy. A weight's gradient
+is the sum over its U uses, added up in float32: the cast to the compute
+dtype sits inside the pass.
 
 A model with a ``block_length`` is trained by DIFFUSION OVER BLOCKS
 (BD3-LM, arXiv:2503.09573) instead of next-token prediction: every sequence
@@ -43,6 +56,7 @@ attention softmax and logits. Attention goes through the one dispatch
 kernels).
 """
 
+import functools
 from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -77,9 +91,18 @@ BLOCK_DIFFUSION_COUNTERS = ("pairs_attended",)
 #: a running minimum) and the largest |S| at a chunk's end (a running
 #: maximum)
 GATED_DELTA_COUNTERS = ("gdn_chunk_log_decay_min", "gdn_state_absmax")
-#: how a counter is kept over the steps, where not as a running sum
+#: and, in a looped model, per PASS (``[loop_steps]`` each, not per layer):
+#: the mean over the predicted tokens of the exit distribution p(u) (sums to
+#: 1 over the passes, a running sum over the steps) and of pass u's own
+#: next-token loss, as the loss applied them; and the root mean square of
+#: the residual stream at the end of pass u, BEFORE the final norm (a
+#: running maximum: what says the recurrence stays bounded)
+LOOP_COUNTERS = ("loop_exit_mass", "loop_pass_loss", "loop_stream_rms_max")
+#: how a counter is kept over the steps (and, in a looped model, a layer's
+#: over the passes of a step), where not as a running sum
 _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
-            "gdn_chunk_log_decay_min": jnp.minimum}
+            "gdn_chunk_log_decay_min": jnp.minimum,
+            "loop_stream_rms_max": jnp.maximum}
 #: what a layer under remat keeps for its backward, the one policy of every
 #: model of the family (a name that no layer of a model emits saves
 #: nothing): the chosen experts with the two grouped products' results (the
@@ -157,9 +180,11 @@ def _log_uniform(low, high, transform=jnp.log):
 class SparseDecoderLayer(nn.Module):
     """h = norm(x); x' = x + mixer(h), attention or the gated delta rule
     (``mixer``); u = norm(x'); out = x' + held experts(u) [+ shared
-    expert(u)], routed on h or on u (``router_input``). Returns (out,
-    counters); a selecting layer's counters hold its index loss, which is
-    differentiable (towards the indexer alone)."""
+    expert(u)], routed on h or on u (``router_input``); with no expert held,
+    out = x' + dense feed-forward(u), no router and no routing counters;
+    under ``sandwich_norm`` each sublayer's result is normed before it is
+    added. Returns (out, counters); a selecting layer's counters hold its
+    index loss, which is differentiable (towards the indexer alone)."""
     heads: int                 # query heads held here
     kv_heads: int              # key-value heads held here
     head_dim: int
@@ -190,6 +215,8 @@ class SparseDecoderLayer(nn.Module):
     rotary_dim: Optional[int] = None    # None: the whole head
     zero_centered_norm: bool = False
     shared_expert_width: int = 0        # 0: no shared expert
+    dense_width: int = 0                # the feed-forward part's, no experts
+    sandwich_norm: bool = False         # a norm on each sublayer's result
 
     def _route(self, x):
         b, s, d = x.shape
@@ -337,26 +364,38 @@ class SparseDecoderLayer(nn.Module):
         linear = self.mixer == "gated_delta"
         if linear and (self.streams or self.select_topk or self.window):
             raise ValueError("a gated-delta-rule layer takes no mask")
+        dense = self.experts_held == 0
         h = self._norm("norm_attn")(x)
-        if self.router_input == "attn_norm":
+        if self.router_input == "attn_norm" and not dense:
             idx, p = self._route(h)
         select = self._index(h, proj) if self.select_topk else None
         if linear:
             mixed, counted = self._gated_delta(h, proj)
         else:
             mixed, counted = self._attention(h, proj, positions, select)
+        if self.sandwich_norm:
+            mixed = self._norm("norm_attn_out")(mixed)
         x = x + mixed
         u = self._norm("norm_moe")(x)
-        if self.router_input == "moe_norm":
-            idx, p = self._route(u)
-        f = self.expert_width
-        gate_up = self.param("experts_gate_up", _init(),
-                             (self.experts_held, d, 2 * f), jnp.float32)
-        down = self.param("experts_down", _init(),
-                          (self.experts_held, f, d), jnp.float32)
-        m, counters = moe.held_experts_ffn(
-            u.reshape(b * s, d), idx, p, gate_up, down, self.first_expert,
-            activation=self.expert_activation)
+        if dense:
+            m, counters = moe.dense_ffn(
+                u.reshape(b * s, d),
+                self.param("ffn_gate_up", _init(),
+                           (d, 2 * self.dense_width), jnp.float32),
+                self.param("ffn_down", _init(), (self.dense_width, d),
+                           jnp.float32),
+                activation=self.expert_activation), {}
+        else:
+            if self.router_input == "moe_norm":
+                idx, p = self._route(u)
+            f = self.expert_width
+            gate_up = self.param("experts_gate_up", _init(),
+                                 (self.experts_held, d, 2 * f), jnp.float32)
+            down = self.param("experts_down", _init(),
+                              (self.experts_held, f, d), jnp.float32)
+            m, counters = moe.held_experts_ffn(
+                u.reshape(b * s, d), idx, p, gate_up, down,
+                self.first_expert, activation=self.expert_activation)
         if self.shared_expert_width:
             fs = self.shared_expert_width
             m = m + moe.shared_expert_ffn(
@@ -380,7 +419,10 @@ class SparseDecoderLayer(nn.Module):
         if linear:
             counters = dict(counters, **{"gdn_" + n: v
                                          for n, v in counted.items()})
-        return x + m.reshape(b, s, d), counters
+        m = m.reshape(b, s, d)
+        if self.sandwich_norm:
+            m = self._norm("norm_ffn_out")(m)
+        return x + m, counters
 
 
 class SparseDecoder(nn.Module):
@@ -388,7 +430,10 @@ class SparseDecoder(nn.Module):
     ``positions`` [s] come with the ids (default: 0 .. s - 1) and
     ``streams`` = (block_length, clean_from) is the two-stream block mask
     of a stream [x_t ; x_0]: the logits are then of the noised half alone,
-    [b, clean_from, vocab]."""
+    [b, clean_from, vocab]. A looped model (``loop_steps`` U > 1) returns
+    (logits [U, b, s, vocab] of every pass, exit-gate scores [U - 1, b, s]
+    float32 before their sigmoid — the last pass takes what mass is left —,
+    counters {a layer's: [L], the loop's: [U]})."""
     vocab_size: int            # rows of the vocabulary held here
     d_model: int
     num_layers: int
@@ -426,6 +471,10 @@ class SparseDecoder(nn.Module):
     rotary_dim: Optional[int] = None
     zero_centered_norm: bool = False
     shared_expert_width: int = 0
+    dense_width: int = 0            # with experts_held 0: a dense layer's
+    sandwich_norm: bool = False
+    loop_steps: int = 1             # > 1: the stack run that often, looped
+    exit_entropy_weight: float = 0.05   # beta of the looped model's loss
 
     def gated_delta_layers(self):
         """Per layer: whether its mixer is the gated delta rule."""
@@ -440,11 +489,8 @@ class SparseDecoder(nn.Module):
     def selects(self):
         return any(self.select_layers())
 
-    @nn.compact
-    def __call__(self, ids, positions=None, streams=None):
-        embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
-                           jnp.float32)
-        x = jnp.take(embed, ids, axis=0).astype(self.dtype)
+    def _stack(self, x, positions, streams):
+        """The ``num_layers`` layers, each once: (x, its layers' counters)."""
         layer_cls = (nn.remat(
             SparseDecoderLayer,
             policy=jax.checkpoint_policies.save_only_these_names(
@@ -478,6 +524,8 @@ class SparseDecoder(nn.Module):
                 attn_gate=self.attn_gate, rotary_dim=self.rotary_dim,
                 zero_centered_norm=self.zero_centered_norm,
                 shared_expert_width=self.shared_expert_width,
+                dense_width=self.dense_width,
+                sandwich_norm=self.sandwich_norm,
                 name="layer_%d" % i)(x, *positions_arg)
             if self.selects() and not select:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
@@ -486,42 +534,98 @@ class SparseDecoder(nn.Module):
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in GATED_DELTA_COUNTERS})
             per_layer.append(counters)
-        if streams:                 # the clean half fed keys and values
-            x = x[:, :streams[1]]
+        return x, per_layer
+
+    def _head(self, x, streams=None):
+        """The final norm and the untied head: float32 logits."""
         x = RMSNorm(self.eps, self.zero_centered_norm, name="norm_final")(x)
         with jax.named_scope("loss.block_diffusion" if streams
                              else "lm_head"):
             head = self.param("lm_head", _init(),
                               (self.d_model, self.vocab_size), jnp.float32)
-            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
-                                preferred_element_type=jnp.float32)
-        return logits, {n: jnp.stack([c[n] for c in per_layer])
-                        for n in counter_names(self.selects(),
-                                               bool(streams), any(linear))}
+            return x, jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
+                                 preferred_element_type=jnp.float32)
+
+    def _looped(self, x, positions):
+        """The stack ``loop_steps`` times over with the same parameters, as
+        ONE scan over passes: the parameters are closed over (float32; a
+        layer casts them inside the pass, so the passes' contributions to
+        a weight's gradient are added in float32), a pass carries its
+        normed result into the next and yields its logits, its exit-gate
+        score and the root mean square of its stream before that norm."""
+        def one_pass(mdl, x, _):
+            with jax.named_scope("loop.pass"):
+                z, per_layer = mdl._stack(x, positions, None)
+                rms = jnp.sqrt(jnp.mean(jnp.square(z.astype(jnp.float32))))
+                x, logits = mdl._head(z)
+                with jax.named_scope("loop.exit_gate"):
+                    score = jnp.einsum(
+                        "bsd,d->bs", x.astype(jnp.float32),
+                        mdl.param("exit_gate", _init(), (mdl.d_model,),
+                                  jnp.float32)) + mdl.param(
+                        "exit_gate_bias", nn.initializers.zeros, (1,),
+                        jnp.float32)
+            return x, (logits, score, rms, per_layer)
+
+        _, (logits, scores, rms, per_layer) = nn.scan(
+            one_pass, variable_broadcast="params",
+            split_rngs={"params": False}, length=self.loop_steps)(
+                self, x, None)
+        # a layer's counters come out [passes] each: one number a step
+        per_layer = [{n: functools.reduce(_RUNNING.get(n, jnp.add), c[n])
+                      for n in c} for c in per_layer]
+        return logits, scores[:-1], per_layer, {"loop_stream_rms_max": rms}
+
+    @nn.compact
+    def __call__(self, ids, positions=None, streams=None):
+        embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
+                           jnp.float32)
+        x = jnp.take(embed, ids, axis=0).astype(self.dtype)
+        names = counter_names(self.selects(), bool(streams),
+                              any(self.gated_delta_layers()),
+                              self.experts_held > 0)
+        stacked = lambda per_layer: {
+            n: jnp.stack([c[n] for c in per_layer]) for n in names}
+        if self.loop_steps > 1:
+            if streams or self.selects():
+                raise ValueError("a looped model takes no block mask and no "
+                                 "learned selection")
+            logits, scores, per_layer, loop = self._looped(x, positions)
+            return logits, scores, dict(stacked(per_layer), **loop)
+        x, per_layer = self._stack(x, positions, streams)
+        if streams:                 # the clean half fed keys and values
+            x = x[:, :streams[1]]
+        return self._head(x, streams)[1], stacked(per_layer)
 
 
-def counter_names(selects=False, block_diffusion=False, gated_delta=False):
-    """The per-layer counters of a model: the routing's and, by what the
-    model does, the selection's, the two-stream attention's or the gated
-    delta rule's."""
-    return (COUNTERS + (SELECT_COUNTERS if selects else ())
+def counter_names(selects=False, block_diffusion=False, gated_delta=False,
+                  routed=True):
+    """The per-layer counters of a model: the routing's (none where no
+    layer holds an expert) and, by what the model does, the selection's,
+    the two-stream attention's or the gated delta rule's."""
+    return ((COUNTERS if routed else ())
+            + (SELECT_COUNTERS if selects else ())
             + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ())
             + (GATED_DELTA_COUNTERS if gated_delta else ()))
 
 
 def init_counters(num_layers, selects=False, block_diffusion=False,
-                  gated_delta=False):
+                  gated_delta=False, routed=True, loop_steps=1):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
     model with a selecting layer, the selection's; for one trained by
     diffusion over blocks, the attention's pairs and the scalar
     ``loss_tokens``; for one with gated-delta-rule layers, the rule's two
-    (from zero: a log decay is never positive, a size never negative)."""
+    (from zero: a log decay is never positive, a size never negative); for
+    a looped one, ``LOOP_COUNTERS``, ``[loop_steps]`` each."""
     # one buffer each: the trainer donates its state to the step
     scalars = ("steps",) + (("loss_tokens",) if block_diffusion else ())
+    per_pass = LOOP_COUNTERS if loop_steps > 1 else ()
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
-         for n in counter_names(selects, block_diffusion, gated_delta)},
+         for n in counter_names(selects, block_diffusion, gated_delta,
+                                routed)},
+        **{n: jnp.zeros((loop_steps,), jnp.float32) for n in per_pass},
         **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 
 
@@ -569,6 +673,35 @@ def _block_diffusion_loss(model, params, batch):
     return loss, counters
 
 
+def _exit_expectation_loss(model, params, batch):
+    """(loss, step counters) of a looped model: with l_i(u) the next-token
+    cross-entropy of pass u at token i and lambda_i(u) the sigmoid of its
+    exit-gate score, the exit distribution is p_i(u) = lambda_i(u) x the
+    product over j < u of (1 - lambda_i(j)), the LAST pass taking what mass
+    is left; the loss is the mean over the predicted tokens of
+    sum_u p_i(u) l_i(u) - beta H(p_i) (arXiv:2510.25741's first-stage
+    objective under a uniform prior over exits), worked out from log p so
+    that a gate near 0 or 1 gives no log of zero."""
+    ids = batch["input_ids"]
+    logits, scores, counters = model.apply({"params": params}, ids)
+    with jax.named_scope("loss.exit_expectation"):
+        ce = optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :, :-1],
+            jnp.broadcast_to(ids[None, :, 1:],
+                             logits.shape[:2] + (ids.shape[1] - 1,)))
+        scores = scores[:, :, :-1]      # of the positions that predict
+        stay = jnp.cumsum(jax.nn.log_sigmoid(-scores), axis=0)
+        log_p = jnp.concatenate([
+            jax.nn.log_sigmoid(scores[:1]),
+            jax.nn.log_sigmoid(scores[1:]) + stay[:-1], stay[-1:]], axis=0)
+        p = jnp.exp(log_p)
+        loss = jnp.mean(jnp.sum(
+            p * (ce + model.exit_entropy_weight * log_p), axis=0))
+        counters = dict(counters, loop_exit_mass=p.mean(axis=(1, 2)),
+                        loop_pass_loss=ce.mean(axis=(1, 2)))
+    return loss, counters
+
+
 def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
     """(model, params, extra_state, loss_fn) for ElasticTrainer with
     ``has_aux=True``: next-token cross-entropy over batch["input_ids"]
@@ -576,7 +709,9 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
     times the mean over those layers of their index loss; for a model with a
     ``block_length``, the block-diffusion loss over batch["input_ids"]
     (clean), batch["noisy_ids"] and batch["loss_weight"] (m / t, float32:
-    :func:`block_diffusion_noise`). The extra state carries the model's
+    :func:`block_diffusion_noise`); for a looped model, the expectation of
+    the passes' losses under its exit distribution
+    (:func:`_exit_expectation_loss`). The extra state carries the model's
     counters on the device (``trainer.extra_state["counters"]``), which the
     trainer mirrors into obs.metrics where it synchronises anyway."""
     dummy = jnp.zeros((dummy_batch, dummy_seq), jnp.int32)
@@ -584,10 +719,12 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
     block_diffusion = model.block_length > 0
     if block_diffusion and model.selects():
         raise ValueError("diffusion over blocks takes no learned selection")
+    own_loss = (_block_diffusion_loss if block_diffusion else
+                _exit_expectation_loss if model.loop_steps > 1 else None)
 
     def loss_fn(params, extra, batch, rng):
-        if block_diffusion:
-            loss, counters = _block_diffusion_loss(model, params, batch)
+        if own_loss:
+            loss, counters = own_loss(model, params, batch)
             return loss, accumulate_counters(
                 extra, jax.lax.stop_gradient(counters))
         ids = batch["input_ids"]
@@ -602,4 +739,5 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
 
     return (model, params, init_counters(
         model.num_layers, model.selects(), block_diffusion,
-        any(model.gated_delta_layers())), loss_fn)
+        any(model.gated_delta_layers()), model.experts_held > 0,
+        model.loop_steps), loss_fn)

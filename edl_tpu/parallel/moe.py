@@ -364,6 +364,17 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
     return m.astype(u.dtype), counters
 
 
+def _gated_linear_unit(u, w_gate_up, w_down, activation):
+    """down(act(gate u) * (up u)) of a dense gated linear unit, float32 out
+    of the last product: u [T, D], w_gate_up [D, 2 * F] (gate then up),
+    w_down [F, D] -> [T, D]."""
+    dt = u.dtype
+    f = w_down.shape[0]
+    gu = jnp.dot(u, w_gate_up.astype(dt))
+    hid = EXPERT_ACTIVATIONS[activation](gu[:, :f]) * gu[:, f:]
+    return jnp.dot(hid, w_down.astype(dt), preferred_element_type=jnp.float32)
+
+
 def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu"):
     """The SHARED expert of a layer that has one beside its routed experts:
     every row passes through it, scaled by a learned sigmoid gate of its
@@ -372,12 +383,18 @@ def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu"):
     w_down [F, D], w_gate [D] -> [T, D] in u's dtype. Every chip of an
     expert-parallel group computes it alike, so where the shares of a layer
     are added up it is counted once."""
-    dt = u.dtype
     with jax.named_scope("moe.shared"):
-        f = w_down.shape[0]
-        gu = jnp.dot(u, w_gate_up.astype(dt))
-        hid = EXPERT_ACTIVATIONS[activation](gu[:, :f]) * gu[:, f:]
-        y = jnp.dot(hid, w_down.astype(dt), preferred_element_type=jnp.float32)
+        y = _gated_linear_unit(u, w_gate_up, w_down, activation)
         gate = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
                                       w_gate.astype(jnp.float32)))
-        return (y * gate[:, None]).astype(dt)
+        return (y * gate[:, None]).astype(u.dtype)
+
+
+def dense_ffn(u, w_gate_up, w_down, activation="silu"):
+    """The feed-forward part of a layer WITHOUT experts: the same gated
+    linear unit for every row, no router, no gate of its own and nothing
+    to count. u [T, D], w_gate_up [D, 2 * F], w_down [F, D] -> [T, D] in
+    u's dtype."""
+    with jax.named_scope("ffn.dense"):
+        return _gated_linear_unit(u, w_gate_up, w_down,
+                                  activation).astype(u.dtype)
