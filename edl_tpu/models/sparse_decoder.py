@@ -74,7 +74,7 @@ from edl_tpu.parallel import moe
 #: what a layer counts about its routing each step (float32 scalars);
 #: `load_max` is kept as a running maximum, the others as running sums
 COUNTERS = ("rows_held", "load_max", "load_mean", "tokens_unserved",
-            "rows_dropped")
+            "rows_dropped", "rows_moved")
 #: and, in a model with a selecting layer, about its selection: the keys
 #: kept summed over the rows, the rows that kept another number than
 #: min(position + 1, select_topk) (exact ties at the threshold only), and

@@ -220,10 +220,11 @@ ROW_TILE = 512
 #: grouped products' results: a layer under remat that saves these
 #: (`checkpoint_policies.save_only_these_names`) does not run the experts'
 #: forward products a second time — the part of a step whose cost follows
-#: the routing — and recomputes the gathers and the gate around them. The
-#: choice is saved WITH the products: a recomputed top-k may break a near
-#: tie the other way, and rows laid out by one choice must not meet
-#: products saved under another.
+#: the routing — and recomputes the plan, the rows laid out for them and the
+#: gate around them (the combine is one rule that keeps what it is handed
+#: and is not run again). The choice is saved WITH the products: a
+#: recomputed top-k may break a near tie the other way, and rows laid out by
+#: one choice must not meet products saved under another.
 SAVED_UNDER_REMAT = ("moe.choice", "moe.gate_up", "moe.down")
 
 
@@ -244,9 +245,10 @@ def plan_held_rows(idx, first_expert, experts_held, tm=ROW_TILE):
     """Where every (token, choice) that picked a held expert goes among
     the rows sorted by expert. Static shapes: ``rows = T * k +
     experts_held * tm`` slots, the worst case, of which the used tiles
-    come first. Choices are numbered choice-major (``j * T + t``) and
-    their arrays are [k, T], so that what is gathered by them is k whole
-    [T, D] slabs and no short axis sits next to the lanes.
+    come first: ``n_used`` says how many, and whatever moves rows into or
+    out of this order (the passes below, the grouped products) visits
+    those and no more. Choices are numbered choice-major (``j * T + t``)
+    and their arrays are [k, T].
 
     Returns a dict: ``slot`` [k, T] (the row of each choice; ``rows`` =
     out of range where the expert is held elsewhere), ``slot_token``
@@ -287,28 +289,134 @@ def plan_held_rows(idx, first_expert, experts_held, tm=ROW_TILE):
             "counts": counts}
 
 
-@jax.custom_vjp
-def _take_rows(src, idx, inverse):
-    """src[idx] with zeros where idx is out of range. A permutation with
-    holes: ``inverse`` [r, len(src)] lists, for each row of src, the r
-    places of the (flattened) result that may read it (out of range =
-    none), so the backward pass is a gather too and never a
-    scatter-add."""
-    del inverse
-    return jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
+def _tile_of_rows(plan, i, tm, *arrays):
+    """Tile ``i``'s ``tm`` entries of ``slot_token``, of ``slot_choice`` and
+    of each of ``arrays`` (all laid out by row)."""
+    return [lax.dynamic_slice_in_dim(a, i * tm, tm) for a in
+            (plan["slot_token"], plan["slot_choice"]) + arrays]
 
 
-def _take_rows_fwd(src, idx, inverse):
-    return _take_rows(src, idx, inverse), inverse
+def _over_used_tiles(plan, tile, init):
+    """``tile(i, carry)`` for every tile in use, in order: a loop whose trip
+    count the device reads from the plan, so a pass costs what the routing
+    laid out and not the static worst case."""
+    return lax.fori_loop(0, plan["n_used"][0], tile, init)
 
 
-def _take_rows_bwd(inverse, g):
-    g = g.reshape((-1, g.shape[-1]))
-    back = jnp.take(g, inverse, axis=0, mode="fill", fill_value=0)
-    return back.sum(axis=0), None, None
+#: what XLA's scatter-add costs a row on the chip, in rows of its gather
+#: (TPU v5e, PR 47: 0.26–0.38 us a row added against 0.047 a row gathered)
+SCATTER_ROWS_PER_GATHERED = 6.0
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _sum_at_tokens(rows, plan, tm, weights=None):
+    """out [T, D] float32: for every token the sum over its held choices
+    of rows[the choice's row] (times ``weights`` [k * T], by choice) — the
+    token side of both rules, by whichever of two exact forms the routing
+    makes the cheaper. Where it filled few tiles, the used tiles are added
+    to their tokens one after the other (a tile's real rows name distinct
+    tokens wherever a token chose distinct experts, but its padding
+    repeats the empty token: nothing is promised about the indices, and
+    rows whose token is out of range are left out). A scatter-add costs
+    several gathers a row, so where the routing filled more than that
+    share of the [k, T] choices — a layer that holds most of its experts,
+    a batch learnt onto the held ones — every choice gathers its row
+    instead (out of range = none), which costs the same whatever was
+    served."""
+    k, t = plan["slot"].shape
+
+    def by_used_tile():
+        def tile(i, acc):
+            tok, choice, part = _tile_of_rows(plan, i, tm, rows)
+            part = part.astype(jnp.float32)
+            if weights is not None:
+                part = part * _row_weights(weights, choice)[:, None]
+            return acc.at[tok].add(part, mode="drop")
+        return _over_used_tiles(plan, tile, jnp.zeros((t, rows.shape[1]),
+                                                      jnp.float32))
+
+    def by_choice():
+        picked = jnp.take(rows, plan["slot"], axis=0, mode="fill",
+                          fill_value=0).astype(jnp.float32)
+        if weights is not None:
+            picked = picked * weights.reshape(k, t)[..., None]
+        return picked.sum(axis=0)
+
+    rows_moved = (plan["n_used"][0] * tm).astype(jnp.float32)
+    return lax.cond(rows_moved * SCATTER_ROWS_PER_GATHERED <= k * t,
+                    by_used_tile, by_choice)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lay_rows(u, plan, tm):
+    """u's rows in the plan's order: row r of the result is
+    ``u[slot_token[r]]`` in every tile in use; padding rows and the tiles
+    past ``n_used`` are zeros, and the latter are never visited."""
+    def tile(i, rows):
+        tok, _ = _tile_of_rows(plan, i, tm)
+        return lax.dynamic_update_slice_in_dim(
+            rows, jnp.take(u, tok, axis=0, mode="fill", fill_value=0),
+            i * tm, 0)
+    return _over_used_tiles(plan, tile, jnp.zeros(
+        (plan["slot_token"].shape[0], u.shape[1]), u.dtype))
+
+
+def _lay_rows_fwd(u, plan, tm):
+    return _lay_rows(u, plan, tm), plan
+
+
+def _lay_rows_bwd(tm, plan, g):
+    """The cotangent's rows added to their tokens, in float32: the terms
+    of a token are its held choices' rows."""
+    with jax.named_scope("moe.dispatch"):
+        d_u = _sum_at_tokens(g, plan, tm)
+    return d_u.astype(g.dtype), None
+
+
+_lay_rows.defvjp(_lay_rows_fwd, _lay_rows_bwd)
+
+
+def _row_weights(p_by_choice, choice):
+    """The weights [k * T] (p.T flattened: choices are numbered j * T + t) of
+    the choices in a tile's rows; 0 for a padding row."""
+    return jnp.take(p_by_choice, choice, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_rows(y, p, plan, tm):
+    """m [T, D] float32: for every token the sum over its held choices of
+    p * y[the choice's row]. The weighting is part of the rule: its
+    backward is one walk of the used tiles and keeps no [k, T, D] array."""
+    return _sum_at_tokens(y, plan, tm, p.T.reshape(-1))
+
+
+def _combine_rows_fwd(y, p, plan, tm):
+    return _combine_rows(y, p, plan, tm), (y, p, plan)
+
+
+def _combine_rows_bwd(tm, res, g):
+    """One walk over the used tiles: the cotangent's rows gathered by
+    token give d_y[row] = p_row * g[token] and d_p of the row's choice =
+    <g[token], y[row]>."""
+    y, p, plan = res
+    t, k = p.shape
+    p_by_choice = p.T.reshape(-1)
+
+    def tile(i, carry):
+        d_y, d_p = carry
+        tok, choice, part = _tile_of_rows(plan, i, tm, y)
+        g_rows = jnp.take(g, tok, axis=0, mode="fill", fill_value=0)
+        d_y = lax.dynamic_update_slice_in_dim(
+            d_y, (g_rows * _row_weights(p_by_choice, choice)[:, None]
+                  ).astype(y.dtype), i * tm, 0)
+        return d_y, d_p.at[choice].set(
+            jnp.sum(g_rows * part.astype(jnp.float32), axis=-1), mode="drop")
+    with jax.named_scope("moe.combine"):
+        d_y, d_p = _over_used_tiles(
+            plan, tile, (jnp.zeros_like(y), jnp.zeros((k * t,), jnp.float32)))
+    return d_y, d_p.reshape(k, t).T.astype(p.dtype), None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 #: the gate's activation in a gated-linear-unit expert, by name
@@ -325,9 +433,14 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
     w_down [held, F, D]. Returns (m [T, D] in u's dtype:
     sum over the chosen held experts of p * down(act(gate u) * (up u)),
     counters: float32 scalars ``rows_held``, ``load_max``, ``load_mean``,
-    ``tokens_unserved``, ``rows_dropped``). ``interpret`` (default: on
-    the CPU backend, as the attention dispatch does) runs the Pallas
-    kernels in the interpreter, for tests."""
+    ``tokens_unserved``, ``rows_dropped``, ``rows_moved``). The four passes
+    that move rows into the experts' order and back (`_lay_rows`,
+    `_combine_rows` and their backward rules) walk the ``n_used`` tiles
+    the routing filled, as the grouped products between them do: their
+    cost follows the rows served, their shapes stay those of the worst
+    case, and nothing compiles again with the routing. ``interpret``
+    (default: on the CPU backend, as the attention dispatch does) runs the
+    Pallas kernels in the interpreter, for tests."""
     from jax.ad_checkpoint import checkpoint_name
     from edl_tpu.ops.grouped_matmul import grouped_matmul
     if interpret is None:
@@ -336,7 +449,7 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
     t, k = idx.shape
     with jax.named_scope("moe.dispatch"):
         plan = plan_held_rows(idx, first_expert, held, tm)
-        rows = _take_rows(u, plan["slot_token"], plan["slot"])
+        rows = _lay_rows(u, plan, tm)
     with jax.named_scope("moe.experts"):
         gm = functools.partial(grouped_matmul, tile_group=plan["tile_group"],
                                n_used=plan["n_used"], tm=tm,
@@ -346,8 +459,7 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
         hid = act(gu[:, :f2 // 2]) * gu[:, f2 // 2:]
         y = checkpoint_name(gm(hid, w_down), SAVED_UNDER_REMAT[2])
     with jax.named_scope("moe.combine"):
-        picked = _take_rows(y, plan["slot"], plan["slot_choice"][None, :])
-        m = jnp.sum(picked.astype(jnp.float32) * p.T[..., None], axis=0)
+        m = _combine_rows(y, p, plan, tm)
     served = plan["slot"] < rows.shape[0]
     counts = plan["counts"].astype(jnp.float32)
     counters = {
@@ -360,6 +472,9 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
         # the choices counted, whatever the routing
         "rows_dropped": counts.sum() - jnp.sum(
             plan["slot_choice"] < t * k).astype(jnp.float32),
+        # what one pass into or out of the rows' order visits: the served
+        # rows and each held expert's padding to whole tiles
+        "rows_moved": (plan["n_used"][0] * tm).astype(jnp.float32),
     }
     return m.astype(u.dtype), counters
 

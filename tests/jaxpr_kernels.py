@@ -2,10 +2,16 @@
 test files, like fake_k8s.py: no test of its own)."""
 
 import collections
+import hashlib
+import os
+import re
 
 import jax
 
+from benchmark.lib import harness
 from edl_tpu.models import sparse_decoder
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def pallas_call_names(jaxpr):
@@ -29,3 +35,29 @@ def gradient_kernel_calls(fam, cfg, w, batch, remat):
     params, _ = fam.to_program(w, cfg)
     return collections.Counter(pallas_call_names(jax.make_jaxpr(jax.grad(
         lambda p: loss_fn(p, extra, batch, None)[0]))(params).jaxpr))
+
+
+def traced_gradient(config, remat):
+    """(parameter tree of shapes, sha256's first 16 hex digits of the
+    gradient's jaxpr — the whole traced program, loss and counters) of a
+    sparse-decoder configuration at its `tiny` sizes: equal text is an
+    equal program, so equal bits on any machine. The model's own parameter
+    tree must be the one `to_program` gives."""
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         config + ".json"))
+    cfg = dict(cfg, **cfg["tiny"])
+    ref = harness.load_module("reference", config)
+    fam = harness.load_module("program", cfg["family"])
+    w = jax.eval_shape(lambda: ref.init_weights(cfg, jax.random.PRNGKey(0)))
+    batch = jax.eval_shape(lambda: fam.make_batch(
+        cfg, {"seq_len": 32}, jax.random.PRNGKey(1), 2))
+    model = fam.build_model(cfg, {"remat": remat})
+    _, own, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
+    params = jax.eval_shape(lambda w: fam.to_program(w, cfg)[0], w)
+    assert (jax.tree_util.tree_structure(own)
+            == jax.tree_util.tree_structure(params))
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda p, b: loss_fn(p, extra, b, None), has_aux=True))(params,
+                                                               batch))
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    return params, hashlib.sha256(text.encode()).hexdigest()[:16]

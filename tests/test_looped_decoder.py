@@ -15,9 +15,7 @@ expectation over exits (``models/sparse_decoder.py``: ``loop_steps``,
 (e) the accepted decoders are the program they were.
 CPU, tiny sizes."""
 
-import hashlib
 import os
-import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +23,7 @@ import numpy as np
 import pytest
 
 from benchmark.lib import harness, kernel_readers
-from jaxpr_kernels import gradient_kernel_calls
+from jaxpr_kernels import gradient_kernel_calls, traced_gradient
 from edl_tpu.models import sparse_decoder
 from edl_tpu.parallel import moe
 
@@ -512,40 +510,48 @@ def test_family_refuses_a_program_without_the_loop(monkeypatch):
 
 #: sha256 (first 16 hex digits) of the gradient's jaxpr — the whole traced
 #: program, loss and counters, of the model at its `tiny` sizes under remat
-#: and without — as the commit before the loop traced it (1f834b3, PR 45).
-#: SmallThinker, Keye and SDAR are pinned at PR 42's hashes, which this
-#: tree still gives, by tests/test_gated_delta.py
-TRACED_AT_PR_45 = {
-    ("qwen3-next-80b-a3b", True): "2c595cdcd038c883",
-    ("qwen3-next-80b-a3b", False): "40d7c1a3f46fe10c",
+#: and without. Qwen3-Next's were recorded ANEW AT PR 47, whose expert layer
+#: walks the used tiles where it ran whole-size gathers: the four sparse
+#: programs changed on purpose there, and that the new passes compute what
+#: the old ones did is shown by arithmetic, not by text
+#: (tests/test_sparse_decoder.py::
+#: test_used_tile_passes_match_the_whole_size_gathers). A later PR that adds
+#: a model must leave these the same program. SmallThinker, Keye and SDAR
+#: are pinned beside them by tests/test_gated_delta.py. Ouro-2.6B, dense
+#: through the same block, holds no expert: its hashes are the commit
+#: before PR 47's (cdb8dcc), which this tree still gives
+EXPERTS_WALK_USED_TILES_SINCE_PR_47 = {
+    ("qwen3-next-80b-a3b", True): "edbb12ba3b995b82",
+    ("qwen3-next-80b-a3b", False): "ed4a7b979dd9c02f",
+}
+DENSE_TRACED_AT_PR_46 = {
+    ("ouro-2.6b", True): "5fc3d5b01d848853",
+    ("ouro-2.6b", False): "137c50419c432a84",
 }
 
 
-@pytest.mark.parametrize("config,remat", sorted(TRACED_AT_PR_45))
+@pytest.mark.parametrize("config,remat",
+                         sorted(EXPERTS_WALK_USED_TILES_SINCE_PR_47))
 def test_accepted_models_give_bit_for_bit_what_they_gave(config, remat):
     """Qwen3-Next (both kinds of mixer, the shared expert whose arithmetic
-    the dense feed-forward part now shares) with the decoder as it is now:
-    the same parameter tree and, operation for operation, the same program
-    for loss, counters and gradient as before the decoder could loop."""
-    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
-                                         config + ".json"))
-    cfg = dict(cfg, **cfg["tiny"])
-    ref = harness.load_module("reference", config)
-    fam = harness.load_module("program", cfg["family"])
-    w = jax.eval_shape(lambda: ref.init_weights(cfg, jax.random.PRNGKey(0)))
-    batch = jax.eval_shape(lambda: fam.make_batch(
-        cfg, {"seq_len": 32}, jax.random.PRNGKey(1), 2))
-    model = fam.build_model(cfg, {"remat": remat})
-    _, own, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
-    params = jax.eval_shape(lambda w: fam.to_program(w, cfg)[0], w)
-    assert (jax.tree_util.tree_structure(own)
-            == jax.tree_util.tree_structure(params))
-    assert not any(part in name for name in _leaves(params)
+    the dense feed-forward part shares) with the decoder as it is now: the
+    same parameter tree and, operation for operation, the same program for
+    loss, counters and gradient as PR 47 left."""
+    params, traced = traced_gradient(config, remat)
+    names = _leaves(params)
+    assert not any(part in name for name in names
                    for part in ("ffn_", "exit_gate", "norm_attn_out",
                                 "norm_ffn_out"))
-    text = str(jax.make_jaxpr(jax.value_and_grad(
-        lambda p, b: loss_fn(p, extra, b, None), has_aux=True))(params,
-                                                               batch))
-    text = re.sub(r"0x[0-9a-f]+", "0x", text)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == TRACED_AT_PR_45[(config, remat)]
+    assert traced == EXPERTS_WALK_USED_TILES_SINCE_PR_47[(config, remat)]
+
+
+@pytest.mark.parametrize("config,remat", sorted(DENSE_TRACED_AT_PR_46))
+def test_a_model_without_experts_traces_as_before_the_experts_changed(
+        config, remat):
+    """Ouro-2.6B runs `moe.dense_ffn` and no plan, no row pass: a change
+    to the held experts' layer leaves it the same program."""
+    params, traced = traced_gradient(config, remat)
+    names = _leaves(params)
+    assert any("ffn_" in name for name in names)
+    assert not any("experts_" in name for name in names)
+    assert traced == DENSE_TRACED_AT_PR_46[(config, remat)]

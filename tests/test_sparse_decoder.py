@@ -13,6 +13,7 @@ held-experts layer (`parallel/moe.py:held_experts_ffn`,
   metrics registry, and the benchmark's readers on a recorded view.
 """
 
+import functools
 import os
 
 import jax
@@ -284,6 +285,151 @@ def test_held_experts_gradients_match_dense():
                                    rtol=1e-4)
 
 
+# -- the passes over the used tiles against the whole-size gathers ----------
+
+@jax.custom_vjp
+def _take_rows(src, idx, inverse):
+    """The plain reference of the layer's four row passes, as the layer
+    ran them up to PR 46: src[idx] over the WHOLE static index space, zeros
+    where idx is out of range; ``inverse`` [r, len(src)] lists for each row
+    of src the r places of the result that may read it, so the backward
+    pass is a whole-size gather too."""
+    del inverse
+    return jnp.take(src, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(src, idx, inverse):
+    return _take_rows(src, idx, inverse), inverse
+
+
+def _take_rows_bwd(inverse, g):
+    g = g.reshape((-1, g.shape[-1]))
+    back = jnp.take(g, inverse, axis=0, mode="fill", fill_value=0)
+    return back.sum(axis=0), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _whole_size_held_experts_ffn(u, idx, p, w_gate_up, w_down, first, tm):
+    """`moe.held_experts_ffn` with every row pass at the static worst case
+    of T x k choices and T x k + held x tm rows: the same plan, the same
+    grouped products, the same names under remat."""
+    from jax.ad_checkpoint import checkpoint_name
+    f = w_down.shape[1]
+    plan = moe.plan_held_rows(idx, first, w_down.shape[0], tm)
+    rows = _take_rows(u, plan["slot_token"], plan["slot"])
+    product = functools.partial(
+        gm.grouped_matmul, tile_group=plan["tile_group"],
+        n_used=plan["n_used"], tm=tm, interpret=True)
+    gu = checkpoint_name(product(rows, w_gate_up), moe.SAVED_UNDER_REMAT[1])
+    y = checkpoint_name(product(jax.nn.relu(gu[:, :f]) * gu[:, f:], w_down),
+                        moe.SAVED_UNDER_REMAT[2])
+    picked = _take_rows(y, plan["slot"], plan["slot_choice"][None, :])
+    return jnp.sum(picked.astype(jnp.float32) * p.T[..., None],
+                   axis=0).astype(u.dtype)
+
+
+def _top_choices(seed, t, experts, k=3):
+    """k DISTINCT experts a token, as `lax.top_k` gives them."""
+    scores = jax.random.normal(jax.random.PRNGKey(seed), (t, experts))
+    return jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+
+
+def _rows_to_one_expert(t, n):
+    """The first ``n`` tokens' first choice is held expert 4; every other
+    choice of every token is held elsewhere."""
+    first = jnp.where(jnp.arange(t) < n, 4, 0)
+    return jnp.stack([first, jnp.full((t,), 1), jnp.full((t,), 2)],
+                     axis=1).astype(jnp.int32)
+
+
+#: name -> (idx of [96, 3] choices, first held expert); 4 experts held, tiles
+#: of 16 rows
+ROUTINGS = {
+    "all_to_one_held_expert": lambda: (
+        jnp.tile(jnp.array([[5, 6, 7]], jnp.int32), (96, 1)), 4),
+    "none_held": lambda: (
+        jnp.tile(jnp.array([[0, 1, 2]], jnp.int32), (96, 1)), 4),
+    "every_expert_held": lambda: (_top_choices(11, 96, 4), 0),
+    "exactly_a_tile_of_rows": lambda: (_rows_to_one_expert(96, 16), 4),
+    "a_tile_and_one_row": lambda: (_rows_to_one_expert(96, 17), 4),
+    "mixed": lambda: (_top_choices(12, 96, 16), 4),
+}
+
+
+def _used_tiles(idx, first, held, tm):
+    counts = [int(np.sum(np.asarray(idx) == first + e)) for e in range(held)]
+    return sum(max(-(-c // tm), 1) for c in counts)
+
+
+#: the token side's two forms, each forced whatever the routing (the cost
+#: of a scatter-added row in gathered rows: nothing, or beyond any layout),
+#: and the form the layer itself picks at these sizes
+TOKEN_SIDE = {"tile_by_tile": 0.0, "choice_by_choice": float("inf"),
+              "as_the_layer_picks": None}
+
+
+@pytest.mark.parametrize("token_side", sorted(TOKEN_SIDE))
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_used_tile_passes_match_the_whole_size_gathers(routing, remat,
+                                                       token_side,
+                                                       monkeypatch):
+    """Forward, and the gradients to u, p and both expert matrices, of the
+    layer whose row passes walk the used tiles against the same layer with
+    whole-size gathers, in float32 under a loss LINEAR in the result (so
+    both sides are handed the same cotangent): bit-equal where no sum
+    changed its order (the rows laid out and the row-side cotangent, hence
+    both products and both matrices' gradients), to 1e-6 of the largest
+    entry where one did (added tile by tile, a token's choices come in the
+    order of their experts; a row's <g, y> is one tile's reduction)."""
+    if TOKEN_SIDE[token_side] is not None:
+        monkeypatch.setattr(moe, "SCATTER_ROWS_PER_GATHERED",
+                            TOKEN_SIDE[token_side])
+    u, w_gate_up, w_down, p = _layer_inputs()
+    idx, first = ROUTINGS[routing]()
+    c = jax.random.normal(jax.random.PRNGKey(13), u.shape)
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *moe.SAVED_UNDER_REMAT)
+
+    def result_and_grads(layer):
+        run = lambda *a: layer(a[0], idx, a[1], a[2], a[3], first, tm=16)
+        if remat:
+            run = jax.checkpoint(run, policy=policy)
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (jnp.vdot(run(*a), c), run(*a)), (0, 1, 2, 3),
+            has_aux=True))(u, p, w_gate_up, w_down)
+    (_, m_got), got = result_and_grads(
+        lambda *a, **kw: moe.held_experts_ffn(*a, **kw)[0])
+    (_, m_want), want = result_and_grads(_whole_size_held_experts_ffn)
+
+    def close(a, b):
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=1e-6 * max(float(jnp.abs(b).max()), 1e-30))
+    close(m_got, m_want)
+    close(got[0], want[0])                       # d_u
+    close(got[1], want[1])                       # d_p
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
+    assert float(jnp.abs(want[0]).max()) > 0 or routing == "none_held"
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+def test_rows_moved_counts_the_used_tiles(routing):
+    """A pass visits ``n_used`` tiles of ``tm`` rows — the served rows and
+    each held expert's padding — whatever the static size of the layout."""
+    u, w_gate_up, w_down, p = _layer_inputs()
+    idx, first = ROUTINGS[routing]()
+    _, c = moe.held_experts_ffn(u, idx, p, w_gate_up, w_down, first, tm=16)
+    plan = moe.plan_held_rows(idx, first, 4, 16)
+    assert float(c["rows_moved"]) == 16 * int(plan["n_used"][0]) == (
+        16 * _used_tiles(idx, first, 4, 16))
+    assert float(c["rows_held"]) <= float(c["rows_moved"]) < (
+        float(c["rows_held"]) + 4 * 16 + 1)
+    assert float(c["rows_dropped"]) == 0.0
+
+
 @pytest.mark.parametrize("n_used", [1, 3, 6])
 def test_grouped_matmul_kernels_match_plain_products(n_used):
     tm, k, n = 16, 256, 128
@@ -349,6 +495,9 @@ def test_trainer_mirrors_routing_counters_into_the_registry(tiny):
     assert got["steps"] == [2.0]
     assert len(got["rows_held"]) == n and sum(got["rows_held"]) > 0
     assert got["rows_dropped"] == [0.0] * n
+    # whole tiles, at least the rows served
+    assert all(moved % moe.ROW_TILE == 0 and moved >= held
+               for moved, held in zip(got["rows_moved"], got["rows_held"]))
     assert kernel_readers.load_max_over_mean(got) >= 1.0
     assert kernel_readers.expert_rows_per_step(got) == pytest.approx(
         sum(got["rows_held"]) / n / 2.0)
@@ -414,12 +563,30 @@ def test_flash_bwd_reader_sums_the_backward_kernels_only(ops, want):
     assert fwd_ms.read(_view(ops)) == pytest.approx(0.7 / 30 * 1e3)
 
 
-def test_counter_readers_return_none_without_counters(monkeypatch):
+@pytest.mark.parametrize("name", ["moe_rows_dropped",
+                                  "moe_expert_load_max_over_mean",
+                                  "moe_rows_moved_over_served"])
+def test_counter_readers_return_none_without_counters(monkeypatch, name):
     monkeypatch.setattr(kernel_readers, "model_counters", lambda: {})
-    for name in ("moe_rows_dropped", "moe_expert_load_max_over_mean"):
-        mod = harness.load_module("metrics", name)
-        monkeypatch.setattr(mod, "model_counters", lambda: {})
-        assert mod.read({}) is None
+    mod = harness.load_module("metrics", name)
+    monkeypatch.setattr(mod, "model_counters", lambda: {})
+    assert mod.read({}) is None
+
+
+@pytest.mark.parametrize("counters,want", [
+    # two layers over three steps: 3072 + 4096 rows moved for 2560 + 2000
+    ({"rows_moved": [3072.0, 4096.0], "rows_held": [2560.0, 2000.0],
+      "steps": [3.0]}, 7168.0 / 4560.0),
+    # a program from before the counter (the parent's): nothing to read
+    ({"rows_held": [2560.0, 2000.0], "steps": [3.0]}, None),
+    # no row served in the whole run: no ratio
+    ({"rows_moved": [1024.0], "rows_held": [0.0], "steps": [1.0]}, None),
+])
+def test_rows_moved_over_served_reader(monkeypatch, counters, want):
+    mod = harness.load_module("metrics", "moe_rows_moved_over_served")
+    monkeypatch.setattr(mod, "model_counters", lambda: counters)
+    got = mod.read({})
+    assert got == (None if want is None else pytest.approx(want))
 
 
 def test_train_flops_counts_the_band_and_the_expected_rows():
