@@ -110,14 +110,18 @@ def layer_weights(w, i):
 
 def init_weights(cfg, key):
     """Seeded weights, traced inside the caller's ONE jitted call:
-    matrices N(0, initializer_range), RMSNorm gains 1 + N(0, range), so
-    that a path that drops a gain shows in `correct`."""
+    matrices N(0, initializer_range), the embedding and the routers at
+    ranges of their own (the configuration's `assumed.weights`), RMSNorm
+    gains 1 + N(0, range), so that a path that drops a gain shows in
+    `correct`."""
     std = cfg["initializer_range"]
+    own = {"embed": cfg["embedding_initializer_range"],
+           "w_r": cfg["router_initializer_range"]}
     out = {}
     for i, (name, (shape, kind)) in enumerate(sorted(
             weight_shapes(cfg).items())):
-        x = std * jax.random.normal(jax.random.fold_in(key, i), shape,
-                                    jnp.float32)
+        x = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+        x = x * own.get(name.rsplit("/", 1)[-1], std)
         out[name] = 1.0 + x if kind == "g" else x
     return out
 

@@ -43,13 +43,9 @@ def test_traced_elastic_line_reads_the_program_s_spans(tmp_path):
     spans = {k: v["value"] for k, v in got.items()
              if named[k]["source"] == "program_span"}
     assert all(v >= 0 for v in spans.values())
-    # the stages lie inside the pause they are stages of
+    # the stages lie inside the pause they are stages of (the readers of
+    # `resize.device_put` from inside are parts of a stage, and one is MB)
     for d in ("shrink", "grow"):
-        parts = sum(v for k, v in spans.items()
-                    if k.startswith(d + "_") and k != d + "_pause_ms")
+        parts = sum(spans.get("%s_%s_ms" % (d, stage), 0.0) for stage in
+                    ("device_put", "first_trace", "first_load", "first_run"))
         assert parts <= spans[d + "_pause_ms"] * 1.001
-    # the program's stamp and the span it is read from are one stopwatch:
-    # the old midpoint of both directions lies between the new two
-    lo, hi = sorted([spans["shrink_pause_ms"], spans["grow_pause_ms"]])
-    assert lo * 0.5 <= got["resize_reshard_ms"]["value"] \
-        + got["resize_compile_ms"]["value"] <= hi * 1.5

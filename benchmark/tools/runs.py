@@ -1,7 +1,12 @@
 """Run one cell several times, one process per run as the driver does,
 and print each metric's median and spread (distance between the first and
 third quartile of `statistics.quantiles(values, n=4)` over the median) —
-the numbers a bound is set from. This parent never touches JAX.
+the numbers a bound is set from — and beside it the spread as the driver's
+check takes it (`held`: largest less smallest over the median, the run
+farthest from the median left out where that narrows it; a cell measured
+anew may show at most half its bound as the mean of two sets' `held`, and
+its second median within the bound of the first: ledger, PRs 33, 38). This
+parent never touches JAX.
 
     python3 benchmark/tools/runs.py --workload <name> --seeds 1,2,3,4,5,6 \\
         [--sets 2] [--seconds S] [--trace 0|1] [--tag T]
@@ -23,10 +28,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def spread(values):
-    if len(values) < 2:
+    if len(values) < 2 or not statistics.median(values):
         return None
     q = statistics.quantiles(values, n=4)
     return (q[2] - q[0]) / statistics.median(values)
+
+
+def held_spread(values):
+    """The spread the driver holds a bound to; None as `spread`."""
+    if len(values) < 3 or not statistics.median(values):
+        return None
+    mid = statistics.median(values)
+    rest = sorted(values, key=lambda v: abs(v - mid))[:-1]
+    return min(max(values) - min(values), max(rest) - min(rest)) / mid
 
 
 def main(argv=None):
@@ -87,10 +101,10 @@ def main(argv=None):
         for name in good[0]["metrics"]:
             vals = [g["metrics"][name]["value"] for g in good
                     if name in g["metrics"]]
-            print("set %d %-28s median %.6g spread %s n=%d"
+            print("set %d %-28s median %.6g spread %s held %s n=%d"
                   % (k, name, statistics.median(vals),
-                     "%.4f" % spread(vals) if len(vals) > 1 else "-",
-                     len(vals)))
+                     *("-" if f(vals) is None else "%.4f" % f(vals)
+                       for f in (spread, held_spread)), len(vals)))
     return 0 if all(r["rc"] == 0 for r in rows) else 1
 
 
