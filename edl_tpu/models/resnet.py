@@ -22,19 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from edl_tpu.ops.batch_norm import SubsetBatchNorm
 
-
-def _make_norm(train, dtype, bn_stats_every):
-    """The BN constructor shared by stems and blocks: flax BatchNorm for
-    full-batch statistics, SubsetBatchNorm (same variable structure, so
-    checkpoint-compatible) when statistics come from a strided subset of
-    the batch — the BN-bandwidth lever measured in edl_tpu/ops/batch_norm.py."""
-    if bn_stats_every > 1:
-        return partial(SubsetBatchNorm, use_running_average=not train,
-                       momentum=0.9, epsilon=1e-5, dtype=dtype,
-                       param_dtype=jnp.float32,
-                       stats_every=bn_stats_every)
+def _make_norm(train, dtype):
+    """The BN constructor shared by stems and blocks: statistics over the
+    whole (global) batch in training, float32 parameters."""
     return partial(nn.BatchNorm, use_running_average=not train,
                    momentum=0.9, epsilon=1e-5, dtype=dtype,
                    param_dtype=jnp.float32)
@@ -97,7 +88,6 @@ class BottleneckBlock(nn.Module):
     stride: int
     vd: bool
     dtype: Any = jnp.bfloat16
-    bn_stats_every: int = 1
     # ResNeXt: cardinality (grouped 3x3) and per-group base width; the
     # inner width is filters * base_width/64 * groups (groups=1,
     # base_width=64 = plain ResNet)
@@ -107,7 +97,7 @@ class BottleneckBlock(nn.Module):
     @nn.compact
     def __call__(self, x, train):
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
-        norm = _make_norm(train, self.dtype, self.bn_stats_every)
+        norm = _make_norm(train, self.dtype)
         width = int(self.filters * self.base_width / 64.0) * self.groups
         residual = x
         y = conv(width, (1, 1), name="conv1")(x)
@@ -135,12 +125,11 @@ class BasicBlock(nn.Module):
     stride: int
     vd: bool
     dtype: Any = jnp.bfloat16
-    bn_stats_every: int = 1
 
     @nn.compact
     def __call__(self, x, train):
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
-        norm = _make_norm(train, self.dtype, self.bn_stats_every)
+        norm = _make_norm(train, self.dtype)
         residual = x
         y = conv(self.filters, (3, 3), strides=(self.stride, self.stride),
                  name="conv1")(x)
@@ -173,10 +162,6 @@ class ResNet(nn.Module):
     # MLPerf-style space-to-depth stem: exact, checkpoint-compatible
     # re-layout of the thin first conv (vd stems only)
     space_to_depth: bool = False
-    # train-time BN statistics from x[::bn_stats_every] (1 = full batch;
-    # 4 at batch 128/chip reproduces the reference's per-GPU stats batch
-    # of 32 — see edl_tpu/ops/batch_norm.py)
-    bn_stats_every: int = 1
     # ResNeXt cardinality/width (bottleneck depths only); the reference's
     # distill teacher config names ResNeXt101_32x16d_wsl (BASELINE.md)
     groups: int = 1
@@ -186,7 +171,7 @@ class ResNet(nn.Module):
     def __call__(self, x, train=False):
         blocks_per_stage, bottleneck = DEPTH_CONFIGS[self.depth]
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
-        norm = _make_norm(train, self.dtype, self.bn_stats_every)
+        norm = _make_norm(train, self.dtype)
         x = x.astype(self.dtype)
         if self.vd:
             if self.space_to_depth:
@@ -218,7 +203,6 @@ class ResNet(nn.Module):
             for i in range(n_blocks):
                 stride = 2 if stage > 0 and i == 0 else 1
                 x = block_cls(filters, stride, self.vd, self.dtype,
-                              self.bn_stats_every,
                               name="stage%d_block%d" % (stage, i),
                               **block_kw)(x, train)
 
@@ -249,13 +233,11 @@ def ResNeXt101_32x16d(**kw):
 def create_model_and_loss(depth=50, num_classes=1000, vd=True,
                           image_size=224, label_smoothing=0.1,
                           dtype=jnp.bfloat16, remat=False,
-                          space_to_depth=False, bn_stats_every=1,
-                          groups=1, base_width=64):
+                          space_to_depth=False, groups=1, base_width=64):
     """Build (model, params, batch_stats, loss_fn) wired for ElasticTrainer
     with has_aux=True — aux carries the BatchNorm running stats."""
     model = ResNet(depth=depth, num_classes=num_classes, vd=vd, dtype=dtype,
-                   remat=remat, space_to_depth=space_to_depth,
-                   bn_stats_every=bn_stats_every, groups=groups,
+                   remat=remat, space_to_depth=space_to_depth, groups=groups,
                    base_width=base_width)
     dummy = jnp.zeros((1, image_size, image_size, 3), jnp.float32)
     variables = model.init(jax.random.PRNGKey(0), dummy, train=False)

@@ -147,8 +147,8 @@ _distributed_initialized = False
 
 
 def make_train_state(params, tx, extra_state=None):
-    """The canonical train-state pytree shared by ElasticTrainer, bench.py
-    and the driver dry-run."""
+    """The canonical train-state pytree shared by ElasticTrainer, the
+    benchmark (benchmark/run.py) and the driver dry-run."""
     return {
         "params": params,
         "opt_state": tx.init(params),
@@ -218,33 +218,6 @@ def make_train_step(loss_fn, tx, has_aux=False, remat_policy=None):
             "step": train_state["step"] + 1,
             "extra": extra,
         }, loss
-
-    return step
-
-
-def make_multi_step(loss_fn, tx, steps_per_call, has_aux=False,
-                    remat_policy=None):
-    """A lax.scan over ``steps_per_call`` canonical train steps in ONE
-    dispatch: step(train_state, batches, rng) -> (train_state, losses)
-    where every leaf of ``batches`` has a leading [steps_per_call] axis
-    and losses is [steps_per_call].
-
-    Amortizes per-step host dispatch latency — the lever when the host
-    is slow relative to the device (small step times). The rng is
-    folded with the in-scan step counter so each scanned step sees a distinct stream, exactly as if single steps were
-    dispatched with rng = fold_in(rng, state["step"])."""
-    if steps_per_call < 1:
-        raise ValueError("steps_per_call must be >= 1")
-    base = make_train_step(loss_fn, tx, has_aux=has_aux,
-                           remat_policy=remat_policy)
-
-    def step(train_state, batches, rng):
-        def body(state, batch):
-            state2, loss = base(
-                state, batch, jax.random.fold_in(rng, state["step"]))
-            return state2, loss
-        return lax.scan(body, train_state, batches,
-                        length=steps_per_call)
 
     return step
 

@@ -163,10 +163,10 @@ def bench_one(impl, batch, heads, seq, dim, causal, iters, warmup,
            "tflops": round(tflops, 1)}
     if inner > 1:
         rec["inner"] = inner
-    # Physics gate (same margin as bench.py's): a wall time at the
-    # dispatch floor measures the launch, not the kernel, and implies a
-    # HARDWARE rate above the running chip's published peak — mark the
-    # sample as untrustworthy rather than letting it stand as a record.
+    # Physics gate: a wall time at the dispatch floor measures the
+    # launch, not the kernel, and implies a HARDWARE rate above the
+    # running chip's published peak — mark the sample as untrustworthy
+    # rather than letting it stand as a record.
     # The model flops above discount causal by 0.5, but dense executes
     # the full s^2 matmuls and masks after — undo the discount for the
     # physical-rate check.

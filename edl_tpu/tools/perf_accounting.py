@@ -1,10 +1,10 @@
 """Hardware-faithful static performance accounting — the TPU compiler's
 own cost model, WITHOUT a chip.
 
-Why this exists: every perf lever in this repo (BN subset statistics,
-flash attention, remat, fused multi-step, dp sharding) ultimately makes
-a claim about flops, HBM bytes, or live memory on a v5e. Measuring them
-needs a chip; but libtpu ships the full production TPU compiler, and
+Why this exists: every perf lever in this repo (flash attention, remat,
+tp/sp/pp sharding) ultimately makes a claim about flops, HBM bytes, or
+live memory on a v5e. Measuring them needs a chip; but libtpu ships
+the full production TPU compiler, and
 ``jax.experimental.topologies.get_topology_desc("v5e:2x2", "tpu")``
 yields a deviceless topology that ``jit(step).lower(...).compile()``
 compiles against CLIENT-SIDE — the real XLA-TPU/Mosaic pipeline, whose
@@ -15,8 +15,9 @@ chip time, and `tests/test_perf_accounting.py` pins the deltas so a
 lever cannot silently regress. A static account is never a measurement.
 
 Role parity: the reference publishes a measured perf table
-(/root/reference/README.md:81-85) as its performance contract; bench.py
-is this repo's live-measurement side, this tool is the static side.
+(/root/reference/README.md:81-85) as its performance contract;
+benchmark/run.py is this repo's live-measurement side, this tool is the
+static side.
 
 Run:  python -m edl_tpu.tools.perf_accounting --platform tpu \
           --out PERF_ACCOUNTING.json
@@ -193,120 +194,6 @@ def compile_stats(fn, arg_specs, devices, in_shardings=None,
     return out
 
 
-# -- account 1: BN subset statistics (jaxpr level, backend-free) ----------
-
-
-def bn_structural_account(bn_every, batch=128, image_size=224):
-    """Count the strided stats-subset slices in the ACTUAL traced loss
-    and account the stats-input bytes they remove. Backend-free: derived
-    from the jaxpr, so it pins the implementation, not a compiler's
-    fusion choices. NOTE the est_ms field is the UPPER BOUND assuming
-    the subset fuses like full-batch stats do — the TPU compiler's cost
-    model says it does NOT (fusion breaks; see ops/batch_norm.py PERF
-    CAVEAT), so this account bounds the prize, not the outcome."""
-    from edl_tpu.models import resnet
-    _, params, extra, loss_fn = resnet.create_model_and_loss(
-        depth=50, num_classes=1000, vd=True, image_size=image_size,
-        dtype=jnp.bfloat16, space_to_depth=True, bn_stats_every=bn_every)
-    bspec = {"image": jax.ShapeDtypeStruct((batch, image_size, image_size, 3),
-                                           jnp.bfloat16),
-             "label": jax.ShapeDtypeStruct((batch,), jnp.int32)}
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    jaxpr = jax.make_jaxpr(loss_fn)(params, extra, bspec, rng)
-    # a stats subset is a batch-axis-strided `slice` (ops/batch_norm.py
-    # uses lax.slice — deliberately NOT x[::k], whose iota+gather
-    # lowering XLA:TPU cannot fuse into the producing conv). At
-    # bn_every=1 no strided batch slice should exist at all, so scan
-    # for ANY plausible stride.
-    ratios = ({bn_every} if bn_every > 1 else set(range(2, 9)))
-    sites = []
-
-    def walk(jx):
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "slice":
-                st = eqn.params.get("strides")
-                i, o = eqn.invars[0].aval, eqn.outvars[0].aval
-                if (st and st[0] in ratios and st[0] > 1
-                        and all(s == 1 for s in st[1:])
-                        and i.shape[1:] == o.shape[1:]):
-                    sites.append((i.shape, o.shape,
-                                  np.dtype(i.dtype).itemsize))
-            for v in eqn.params.values():
-                for u in (v if isinstance(v, (tuple, list)) else (v,)):
-                    if isinstance(u, jax.extend.core.ClosedJaxpr):
-                        walk(u.jaxpr)
-    walk(jaxpr.jaxpr)
-    full = float(sum(np.prod(i) * b for i, _, b in sites))
-    sub = float(sum(np.prod(o) * b for _, o, b in sites))
-    return {
-        "account": "bn_subset_stats_structural",
-        "bn_stats_every": bn_every, "batch": batch,
-        "image_size": image_size,
-        "stat_subset_sites": len(sites),
-        "stats_read_bytes_full": full,  # what bn1 reads for the stats
-        "stats_read_bytes_subset": sub,
-        "stats_bytes_saved": full - sub,
-        "est_ms_saved_at_hbm": round((full - sub) / (V5E_HBM_GBPS * 1e6),
-                                     3),
-    }
-
-
-def _resnet_step_specs(bn_every, batch, image_size, steps_per_call=1):
-    from edl_tpu.models import resnet
-    from edl_tpu.runtime.trainer import (make_multi_step,
-                                         make_train_state,
-                                         make_train_step)
-    _, params, extra, loss_fn = resnet.create_model_and_loss(
-        depth=50, num_classes=1000, vd=True, image_size=image_size,
-        dtype=jnp.bfloat16, space_to_depth=True, bn_stats_every=bn_every)
-    tx = optax.sgd(0.1, momentum=0.9)
-    state = make_train_state(params, tx, extra)
-    if steps_per_call > 1:
-        step = make_multi_step(loss_fn, tx, steps_per_call, has_aux=True)
-        bshape = (steps_per_call, batch)
-    else:
-        step = make_train_step(loss_fn, tx, has_aux=True)
-        bshape = (batch,)
-    bspec = {"image": jax.ShapeDtypeStruct(bshape + (image_size,
-                                                     image_size, 3),
-                                           jnp.bfloat16),
-             "label": jax.ShapeDtypeStruct(bshape, jnp.int32)}
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    return step, (spec_like(state), bspec, rng)
-
-
-def resnet_bn_account(devices, bn_every, batch=128, image_size=224,
-                      n_devices=1):
-    """The judged headline step (bench.py's exact construction), on the
-    TPU compiler: what does bn_stats_every actually change in flops /
-    bytes / live memory? With ``n_devices`` > 1 the same step is
-    dp-sharded over that many topology chips — static proof the
-    multi-chip sharding compiles on the real TPU compiler, and of its
-    per-chip cost."""
-    step, (state_spec, bspec, rng) = _resnet_step_specs(
-        bn_every, batch, image_size)
-
-    def in_sh(mesh):
-        repl = NamedSharding(mesh, P())
-        data = NamedSharding(mesh, P("dp"))
-        return (jax.tree_util.tree_map(lambda _: repl, state_spec),
-                {"image": data, "label": data}, repl)
-
-    def out_sh(mesh):
-        repl = NamedSharding(mesh, P())
-        return (jax.tree_util.tree_map(lambda _: repl, state_spec), repl)
-
-    out = compile_stats(step, (state_spec, bspec, rng),
-                        devices[:n_devices],
-                        in_shardings=in_sh, out_shardings=out_sh,
-                        donate_argnums=(0,))
-    out.update({"account": "resnet50_vd_train_step"
-                + ("_dp%d" % n_devices if n_devices > 1 else ""),
-                "bn_stats_every": bn_every, "batch": batch,
-                "image_size": image_size, "n_devices": n_devices})
-    return out
-
-
 # -- account 2: attention — dense vs flash/blockwise ----------------------
 
 
@@ -420,9 +307,9 @@ def lm_batch_account(devices, batch, num_layers=12, d_model=768,
                 % (actual,))
         num_layers, d_model, vocab = actual
         if seq > model.max_len:
-            # bench.py clamps for the same reason: position indices
-            # past max_len would gather out of bounds (XLA clamps
-            # silently — the row would describe an impossible model)
+            # position indices past max_len would gather out of bounds
+            # (XLA clamps silently — the row would describe an
+            # impossible model)
             raise ValueError("seq %d > bert_base max_len %d"
                              % (seq, model.max_len))
         _, params, loss_fn = family.create_model_and_loss(
@@ -442,20 +329,6 @@ def lm_batch_account(devices, batch, num_layers=12, d_model=768,
     out.update({"account": "lm_batch", "kind": kind, "batch": batch,
                 "num_layers": num_layers, "d_model": d_model,
                 "seq": seq, "remat": remat, "use_flash": use_flash})
-    return out
-
-
-# -- account 4: fused multi-step (lax.scan over K train steps) ------------
-
-
-def multistep_account(devices, steps_per_call, batch=128, image_size=224):
-    step, (state_spec, bspec, rng) = _resnet_step_specs(
-        4, batch, image_size, steps_per_call=steps_per_call)
-    out = compile_stats(step, (state_spec, bspec, rng), devices[:1],
-                        donate_argnums=(0,))
-    out.update({"account": "resnet_multistep",
-                "steps_per_call": steps_per_call, "batch": batch,
-                "image_size": image_size})
     return out
 
 
@@ -574,8 +447,7 @@ def pipeline_pp_account(devices, pp=4, num_layers=8, d_model=256,
     return out
 
 
-ACCOUNTS = ("bn_structural", "resnet_bn", "attention", "remat",
-            "multistep", "sharded", "sharded_tp", "sharded_sp",
+ACCOUNTS = ("attention", "remat", "sharded_tp", "sharded_sp",
             "sharded_pp", "lm_batch")
 
 
@@ -599,12 +471,6 @@ def run_accounts(names, platform):
             traceback.print_exc()
             results.append(err)
 
-    if "bn_structural" in names:
-        for k in (1, 2, 4):
-            go("bn_structural", bn_structural_account, k)
-    if "resnet_bn" in names:
-        for k in (1, 2, 4):
-            go("resnet_bn", resnet_bn_account, devices, k)
     if "attention" in names:
         for seq in (2048, 8192):
             for impl in ("dense", "flash"):
@@ -615,12 +481,6 @@ def run_accounts(names, platform):
             go("remat", remat_account, devices, pol)
         go("remat_per_layer", remat_account, devices, None,
            per_layer=True)
-    if "multistep" in names:
-        for k in (1, 4):
-            go("multistep", multistep_account, devices, k)
-    if "sharded" in names and platform == "tpu":
-        go("sharded", resnet_bn_account, devices, 4, batch=512,
-           n_devices=len(devices))
     if "sharded_tp" in names and platform == "tpu":
         go("sharded_tp", bert_tp_account, devices)
         go("sharded_tp_zero1", bert_tp_account, devices, zero1=True)

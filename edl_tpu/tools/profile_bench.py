@@ -8,11 +8,11 @@ this image is ABI-mismatched with its TF) and aggregates device time by
 op class. This is the tool that located the round-2 BN bottleneck:
 of a 50 ms step, conv fusions took ~19 ms (~87% MFU over conv time)
 while BatchNorm statistic reductions (``convert_reduce_fusion``) took
-~15.8 ms — leading to ``edl_tpu/ops/batch_norm.py``.
+~15.8 ms.
 
 Usage:
     python -m edl_tpu.tools.profile_bench [--no-s2d] [--batch N]
-           [--bn_stats_every K] [--logdir DIR]
+           [--logdir DIR]
 
 Prints: XLA cost-model FLOPs/step, traced ms/step, and the per-op-class
 device-time table.
@@ -31,7 +31,7 @@ import time
 os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
 
 
-def build_step(batch, s2d, bn_stats_every):
+def build_step(batch, s2d):
     import jax
     import jax.numpy as jnp
     import optax
@@ -43,8 +43,7 @@ def build_step(batch, s2d, bn_stats_every):
 
     model, params, extra, loss_fn = resnet.create_model_and_loss(
         depth=50, num_classes=1000, vd=True, image_size=224,
-        dtype=jnp.bfloat16, space_to_depth=s2d,
-        bn_stats_every=bn_stats_every)
+        dtype=jnp.bfloat16, space_to_depth=s2d)
     mesh = make_mesh()
     repl = NamedSharding(mesh, P())
     data_sh = NamedSharding(mesh, P(DATA_AXIS))
@@ -120,7 +119,6 @@ def main(argv=None):
     ap.add_argument("--s2d", dest="s2d", action="store_true")
     ap.add_argument("--no-s2d", dest="s2d", action="store_false")
     ap.set_defaults(s2d=True)
-    ap.add_argument("--bn_stats_every", type=int, default=1)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--logdir", default="/tmp/edl_tpu_profile")
     args = ap.parse_args(argv)
@@ -128,7 +126,7 @@ def main(argv=None):
     import jax
 
     jit_step, jit_nodonate, state, staged, rng = build_step(
-        args.batch, args.s2d, args.bn_stats_every)
+        args.batch, args.s2d)
     for _ in range(3):
         state, loss = jit_step(state, staged, rng)
     jax.block_until_ready(loss)

@@ -13,8 +13,8 @@ budget (v3: 123 bf16 TFLOP/s vs 900 GB/s = 7.3 B/TF; v5e: 197 vs 819
 Inputs:
   * the measured xplane profile of the default bench step from the
     pre-PR-1 chip sweep, 2026-07-31 (git history; 50.03 ms device-op
-    time per step, s2d + bn_stats_every 1, batch 128), hardcoded below
-    with provenance, and
+    time per step, s2d stem, full-batch BN stats, batch 128), hardcoded
+    below with provenance, and
   * an analytic activation-byte account computed here from the
     resnet50_vd block structure (no JAX needed; stride placement
     matches edl_tpu/models/resnet.py — stride-2 on the 3x3, so the
@@ -48,7 +48,7 @@ MEASURED_MS = {
 # conservative for the "measured is close to roofline" claim).
 MEASURED_STEP_FLOPS = 3.280e12
 CONV_FLOP_FRACTION = 0.98
-MEASURED_WALL_MS = 52.4         # bench.py steady state (2444.2 img/s)
+MEASURED_WALL_MS = 52.4         # that sweep's steady state (2444.2 img/s)
 
 
 def activation_bytes(batch=128, bytes_per_el=2):

@@ -38,9 +38,6 @@ def main(argv=None):
     p.add_argument("--lr_schedule", choices=["cosine", "piecewise"],
                    default="cosine")
     p.add_argument("--dtype", choices=["bf16", "f32"], default="f32")
-    p.add_argument("--bn_stats_every", type=int, default=1,
-                   help="BN train statistics from every k-th batch row "
-                        "(throughput knob for large per-chip batches)")
     p.add_argument("--grad_accum", type=int, default=1,
                    help="microbatches per optimizer update; raise after "
                         "a scale-down to keep global batch AND per-chip "
@@ -101,8 +98,7 @@ def main(argv=None):
 
     model, params, extra, loss_fn = resnet.create_model_and_loss(
         depth=args.depth, num_classes=args.num_classes,
-        image_size=args.image_size, dtype=dtype,
-        bn_stats_every=args.bn_stats_every)
+        image_size=args.image_size, dtype=dtype)
     trainer = ElasticTrainer(
         loss_fn, params, optax.sgd(schedule, momentum=0.9),
         total_batch_size=args.total_batch_size, extra_state=extra,

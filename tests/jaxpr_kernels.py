@@ -37,15 +37,26 @@ def gradient_kernel_calls(fam, cfg, w, batch, remat):
         lambda p: loss_fn(p, extra, batch, None)[0]))(params).jaxpr))
 
 
+def _tiny_config(config):
+    """A configuration of the benchmark at its `tiny` sizes."""
+    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
+                                         config + ".json"))
+    return dict(cfg, **cfg["tiny"])
+
+
+def _program_digest(traced):
+    """A jaxpr's text, addresses scrubbed: sha256's first 16 hex digits."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(traced))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
 def traced_gradient(config, remat):
     """(parameter tree of shapes, sha256's first 16 hex digits of the
     gradient's jaxpr — the whole traced program, loss and counters) of a
     sparse-decoder configuration at its `tiny` sizes: equal text is an
     equal program, so equal bits on any machine. The model's own parameter
     tree must be the one `to_program` gives."""
-    cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
-                                         config + ".json"))
-    cfg = dict(cfg, **cfg["tiny"])
+    cfg = _tiny_config(config)
     ref = harness.load_module("reference", config)
     fam = harness.load_module("program", cfg["family"])
     w = jax.eval_shape(lambda: ref.init_weights(cfg, jax.random.PRNGKey(0)))
@@ -56,8 +67,27 @@ def traced_gradient(config, remat):
     params = jax.eval_shape(lambda w: fam.to_program(w, cfg)[0], w)
     assert (jax.tree_util.tree_structure(own)
             == jax.tree_util.tree_structure(params))
-    text = str(jax.make_jaxpr(jax.value_and_grad(
+    return params, _program_digest(jax.make_jaxpr(jax.value_and_grad(
         lambda p, b: loss_fn(p, extra, b, None), has_aux=True))(params,
                                                                batch))
-    text = re.sub(r"0x[0-9a-f]+", "0x", text)
-    return params, hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def traced_dense_gradient(config, job):
+    """The digest `traced_gradient` gives the sparse decoders, of a dense
+    cell's program as the benchmark hands it over: the configuration's
+    `tiny` sizes through its family's `train_parts` and `make_batch`, two
+    rows, loss and gradient (and the new BatchNorm statistics, where the
+    loss carries them)."""
+    cfg = _tiny_config(config)
+    fam = harness.load_module("program", cfg["family"])
+    loss_fn, has_aux, (params, extra) = fam.train_parts(cfg, job)
+    batch = jax.eval_shape(lambda: fam.make_batch(
+        cfg, job, jax.random.PRNGKey(1), 2))
+    if has_aux:
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            lambda p, e, b: loss_fn(p, e, b, None), has_aux=True))(
+                params, extra, batch)
+    else:
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            lambda p, b: loss_fn(p, b, None)))(params, batch)
+    return _program_digest(traced)

@@ -1,10 +1,8 @@
 """Aux subsystem tests: input pipeline, liveft layer."""
 
-import os
 import time
 
 import numpy as np
-import pytest
 from PIL import Image
 
 from edl_tpu.liveft import elastic
@@ -96,23 +94,3 @@ def test_profile_bench_breakdown_parser(tmp_path):
     from edl_tpu.tools import profile_bench
 
     assert profile_bench.xplane_op_breakdown(str(tmp_path), 10) is None
-
-
-@pytest.mark.integration
-@pytest.mark.parametrize("argv", [[], ["--_oneshot", "--model", "gpt"]])
-def test_bench_refuses_to_run_without_a_tpu(argv):
-    """bench.py has no CPU rung: with no TPU both the parent (whose
-    child is forced onto JAX_PLATFORMS=tpu) and a measurement child
-    started on the CPU backend exit non-zero and print nothing that
-    could be read as a device number."""
-    import subprocess
-    import sys
-
-    from conftest import REPO as repo, cpu_subprocess_env
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")] + argv,
-        env=cpu_subprocess_env(1), capture_output=True, text=True,
-        timeout=120)
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == "", proc.stdout
-    assert "TPU" in proc.stderr or "tpu" in proc.stderr

@@ -4,22 +4,13 @@ Every perf lever claims something about flops / bytes / live memory.
 These tests pin the STATIC side of each claim so a lever cannot
 silently regress between chip runs:
 
-- BN subset statistics: pinned at the jaxpr level (backend-free) — the
-  traced loss must actually subsample the stats reads.
 - dense-vs-blockwise attention: pinned on compiled memory growth —
   dense temp memory is quadratic in sequence length, blockwise (the
   flash kernel's semantic twin) and the flash backward are linear.
-- fused multi-step: pinned on compiled memory — scanning K train steps
-  into one executable must not inflate live memory.
 - the TPU compiler itself: `tools/perf_accounting.py` AOT-compiles the
   real steps against a deviceless v5e topology (libtpu's own compiler)
-  and writes PERF_ACCOUNTING.json; the pin here asserts that path stays
-  alive and that the hardware cost model still sees the bn win.
-
-Caveat recorded once: XLA's *CPU* cost model inverts some TPU claims
-(it materializes the strided BN subset, so bn4 shows MORE bytes on
-CPU), which is why the BN pin reads the jaxpr and the hardware pin
-uses the TPU AOT path rather than CPU `cost_analysis()`.
+  and writes PERF_ACCOUNTING.json; the pins here assert that path stays
+  alive: the flash kernels at the cells' shapes, the LM batch sweep.
 """
 
 import jax
@@ -27,37 +18,6 @@ import jax.numpy as jnp
 import pytest
 
 from edl_tpu.tools import perf_accounting as pa
-
-
-# -- BN subset statistics (jaxpr, backend-free) ---------------------------
-
-
-def test_bn_subset_stats_are_structural():
-    """bn_stats_every=4 must subsample EVERY BatchNorm's statistics
-    input by exactly 4x; full-batch mode must subsample nothing."""
-    acc4 = pa.bn_structural_account(4, batch=32, image_size=96)
-    # one stats gather per BN site; ResNet50_vd has 53 BNs (+2 from the
-    # stem path) — losing sites means some BN stopped subsampling
-    assert acc4["stat_subset_sites"] >= 50, acc4
-    assert acc4["stats_read_bytes_full"] > 0
-    # the saving is exactly 1 - 1/k of the stats reads, by construction
-    frac = acc4["stats_bytes_saved"] / acc4["stats_read_bytes_full"]
-    assert abs(frac - 0.75) < 1e-6, acc4
-
-    acc1 = pa.bn_structural_account(1, batch=32, image_size=96)
-    assert acc1["stat_subset_sites"] == 0, \
-        "full-batch stats must not emit subset gathers"
-
-
-def test_bn_subset_full_scale_account_matches_claim():
-    """At the bench shape (batch 128 @ 224) the structural account must
-    keep finding the full 2.29 GB/step of stats-input bytes removed —
-    the UPPER BOUND of the lever if the subset fused (the TPU compiler
-    says it does not; see the bn-tradeoff pin below). A drop here means
-    some BN stopped subsetting, independent of the fusion question."""
-    acc = pa.bn_structural_account(4, batch=128, image_size=224)
-    assert acc["stats_bytes_saved"] >= 2.0e9, acc
-    assert acc["est_ms_saved_at_hbm"] >= 2.4, acc
 
 
 # -- attention memory complexity (compiled, CPU) --------------------------
@@ -121,20 +81,6 @@ def test_dense_attention_memory_crossover_at_long_seq():
         (dense["temp_bytes"], block["temp_bytes"])
 
 
-# -- fused multi-step memory (compiled, CPU) ------------------------------
-
-
-@pytest.mark.integration
-def test_multistep_scan_adds_no_live_memory():
-    """lax.scan of 4 train steps in one executable must cost ~no extra
-    temp memory over a single step (the lever buys 4x fewer dispatches;
-    it must not pay for them in HBM headroom)."""
-    devs = jax.devices("cpu")
-    one = pa.multistep_account(devs, 1, batch=16, image_size=64)
-    four = pa.multistep_account(devs, 4, batch=16, image_size=64)
-    assert four["temp_bytes"] <= one["temp_bytes"] * 1.25, (one, four)
-
-
 # -- the TPU AOT accounting path itself -----------------------------------
 
 
@@ -143,28 +89,6 @@ def _tpu_topology_or_skip():
         return pa.v5e_devices()
     except Exception as e:  # noqa: BLE001
         pytest.skip("no local libtpu AOT compiler: %r" % e)
-
-
-@pytest.mark.integration
-def test_tpu_compiler_accounts_bn_tradeoff():
-    """The REAL TPU compiler (libtpu AOT against a deviceless v5e
-    topology — no chips) accounts the bn subset-stats
-    tradeoff. FINDING (r5, PERF_ACCOUNTING.json): the subset slice
-    BREAKS the conv->stats reduce fusion, so bn4 costs MORE bytes
-    accessed than bn1 (full-batch stats fuse into the conv and read
-    nothing extra) — the opposite of the r3 profile-era hypothesis,
-    and why bench.py's default stays bn1. This pin keeps the AOT
-    accounting path alive and bounds the regime: flops must not grow
-    (subsetting adds no compute), bytes must stay within 2.2x (a
-    runaway regression in either implementation trips it), and an
-    implementation that ever makes bn4 CHEAPER in bytes shows up as a
-    ratio < 1 here — re-evaluate the bench default then."""
-    devices = _tpu_topology_or_skip()
-    bn1 = pa.resnet_bn_account(devices, 1, batch=32, image_size=96)
-    bn4 = pa.resnet_bn_account(devices, 4, batch=32, image_size=96)
-    assert bn4["flops"] < bn1["flops"] * 1.02, (bn1, bn4)
-    ratio = bn4["bytes_accessed"] / bn1["bytes_accessed"]
-    assert 0.3 < ratio < 2.2, (bn1, bn4)
 
 
 @pytest.mark.integration

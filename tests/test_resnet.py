@@ -1,6 +1,8 @@
 """ResNet family tests: shapes, vd structure, training step with BN aux
 state through ElasticTrainer on the dp mesh."""
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,3 +175,89 @@ def test_resnext_rejects_basicblock_groups():
     with pytest.raises(ValueError, match="bottleneck"):
         model.init(jax.random.PRNGKey(0),
                    jnp.zeros((1, 32, 32, 3)), train=False)
+
+
+@pytest.mark.parametrize("dp", [2, 4])
+def test_batch_statistics_are_the_global_batch_s_under_dp(dp):
+    """One training-mode pass over a batch of 16 sharded over `dp` devices
+    (as the elastic cell's is) gives the logits, the loss and the new
+    running statistics of the same pass on one device: BatchNorm's mean
+    and variance are the whole batch's, not a shard's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    model, params, extra, loss_fn = resnet.create_model_and_loss(
+        depth=18, num_classes=10, image_size=32, dtype=jnp.float32)
+    # He-scaled kernels at init leave the last BN of a block at gain 0:
+    # lift every gain so that each layer's statistics reach the logits
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf + 0.5 if path[-1].key == "scale" else leaf,
+        params)
+    batch = {k: jnp.asarray(v) for k, v in
+             resnet.synthetic_image_batch(16, 32, 10, seed=3).items()}
+
+    def one_pass(params, extra, batch):
+        logits, _ = model.apply(
+            {"params": params, **extra}, batch["image"], train=True,
+            mutable=["batch_stats"])
+        loss, new_extra = loss_fn(params, extra, batch, None)
+        return logits, loss, new_extra["batch_stats"]
+
+    want = jax.jit(one_pass)(params, extra, batch)
+    mesh = Mesh(np.array(jax.devices()[:dp]), ("dp",))
+    whole = NamedSharding(mesh, P())
+    got = jax.jit(one_pass, in_shardings=(
+        whole, whole, NamedSharding(mesh, P("dp"))))(params, extra, batch)
+    assert len(got[0].sharding.device_set) == dp
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+    # a shard's own statistics would be another answer
+    shard = jax.jit(one_pass)(params, extra,
+                              {k: v[:16 // dp] for k, v in batch.items()})
+    assert float(jnp.abs(shard[2]["stem_bn1"]["var"]
+                         - want[2]["stem_bn1"]["var"]).max()) > 1e-3
+
+
+#: sha256 over the sorted (path, shape, dtype) of `params` and
+#: `batch_stats`, recorded on the commit before PR 52 (ac8177a), which
+#: removed the second BatchNorm: a renamed or reshaped variable would break
+#: every saved ResNet, and no benchmark cell (fresh weights each run) would
+#: show it
+TREE_AT_PR_51 = {
+    "18": (
+        dict(depth=18, vd=False),
+        "65ab0234581a51e39d7bf2e4c2e3484bee10737fe856148170600d2d56e0180c"),
+    "50": (
+        dict(depth=50, vd=False),
+        "f3f3f4e3c3b41fab83fb1e6698bab0e1484bccbec6be8cb8c4af7610b8145eb3"),
+    "50vd": (
+        dict(depth=50, vd=True, space_to_depth=True),
+        "e65cf5139161ed9fab7bc2a05ff306ebdc200d64b1ad28c37b430cbd2d24ff1d"),
+    # the other depths of DEPTH_CONFIGS, and the grouped bottleneck
+    "34": (
+        dict(depth=34, vd=False),
+        "01a06d4a1da7fac42d053d5818b5d7328354b601785adc63caef9afd30dee290"),
+    "101vd": (
+        dict(depth=101, vd=True),
+        "c884a7a37eab18ef061491cec2e689678cd3cce66228af009fdf6b18703eea9f"),
+    "152vd": (
+        dict(depth=152, vd=True),
+        "c2298db5ad0b7018fcfccc4323fccfd7cf49a5c15f6bb940d601c0ce087af94c"),
+    "101-32x16d": (
+        dict(depth=101, vd=False, groups=32, base_width=16),
+        "15fa9eebec1c98d7338c5d0039ccdc5e5af37180ad35b87b77c77f5c278160d5"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TREE_AT_PR_51))
+def test_parameter_tree_is_what_the_parent_s_checkpoints_hold(name):
+    kw, want = TREE_AT_PR_51[name]
+    params, extra = jax.eval_shape(
+        lambda: resnet.create_model_and_loss(image_size=64, **kw)[1:3])
+    leaves = sorted(
+        (jax.tree_util.keystr(path), tuple(leaf.shape), str(leaf.dtype))
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            {"params": params, **extra})[0])
+    assert any("batch_stats" in path for path, _, _ in leaves)
+    assert hashlib.sha256(repr(leaves).encode()).hexdigest() == want

@@ -51,44 +51,6 @@ def test_lr_schedules():
     assert lr_schedules.scale_lr_for_batch(0.1, 1024) == pytest.approx(0.4)
 
 
-def test_multi_step_matches_sequential_steps():
-    """make_multi_step(K) in one dispatch == K make_train_step calls
-    with the same per-step rng folding."""
-    from edl_tpu.models import linear
-    from edl_tpu.runtime.trainer import (make_multi_step, make_train_state,
-                                         make_train_step)
-
-    params = linear.init_params(feature_dim=4)
-    loss_fn = linear.loss_fn
-    tx = optax.sgd(0.1)
-    K = 3
-    rng = jax.random.PRNGKey(7)
-    rs = np.random.RandomState(0)
-    batches = {
-        "x": rs.randn(K, 8, 4).astype(np.float32),
-        "y": rs.randn(K, 8).astype(np.float32),
-    }
-
-    base = jax.jit(make_train_step(loss_fn, tx))
-    want = make_train_state(params, tx)
-    want_losses = []
-    for i in range(K):
-        b = {k: v[i] for k, v in batches.items()}
-        want, loss = base(want, b, jax.random.fold_in(rng, want["step"]))
-        want_losses.append(float(loss))
-
-    multi = jax.jit(make_multi_step(loss_fn, tx, steps_per_call=K))
-    got, losses = multi(make_train_state(params, tx), batches, rng)
-
-    assert int(got["step"]) == K
-    np.testing.assert_allclose(np.asarray(losses),
-                               np.asarray(want_losses), rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(got["params"]),
-                    jax.tree_util.tree_leaves(want["params"])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
-
-
 def test_state_roundtrip_and_adjust(coord):
     st = state_mod.State(total_batch_size=256)
     st.begin_epoch(0, world_size=8)
@@ -1301,3 +1263,23 @@ def test_prewarm_targets_respect_grad_accum_batch_axis(tmp_path,
     assert done == [4], done
     aot = tmp_path / "cache" / "aot_steps"
     assert list(aot.glob("step_w4_*.pkl"))
+
+
+#: sha256 (first 16 hex digits) of the jaxpr of loss and gradient as the
+#: benchmark's dense cells trace them — the configuration's `tiny` sizes
+#: through benchmark/program/<family>.py:train_parts — recorded on the
+#: commit before PR 52 (ac8177a), which removed the second BatchNorm and
+#: the scanned step builder beside this path. The two ResNet cells and
+#: gpt2s-train trace the program they traced; the sparse families are
+#: pinned by tests/test_gated_delta.py and tests/test_looped_decoder.py
+DENSE_TRACED_AT_PR_51 = {
+    "resnet50-vd": ({}, "60924de21a9e3faa"),
+    "gpt2-small": ({"seq_len": 32, "remat": False}, "6032410fce43f99c"),
+}
+
+
+@pytest.mark.parametrize("config", sorted(DENSE_TRACED_AT_PR_51))
+def test_dense_cells_differentiate_the_program_they_did(config):
+    from jaxpr_kernels import traced_dense_gradient
+    job, want = DENSE_TRACED_AT_PR_51[config]
+    assert traced_dense_gradient(config, job) == want
