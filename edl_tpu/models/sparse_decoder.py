@@ -21,7 +21,18 @@ or SiLU); a SHARED expert every token passes through beside the routed ones
 ``1 + g`` (``zero_centered_norm``). A layer that holds NO experts
 (``experts_held`` 0) has a dense gated feed-forward part of ``dense_width``
 in their place, and ``sandwich_norm`` puts an RMSNorm on each sublayer's
-result before it joins the residual. The defaults are SmallThinker's.
+result before it joins the residual. ``dense_layout`` says so a layer: the
+leading layer(s) of a stack dense, the rest with experts, in one parameter
+tree and one set of counters. An attention layer with a ``latent_dim`` is
+MULTI-HEAD LATENT ATTENTION (DeepSeek-V2's): keys and values are rebuilt, a
+head at a time, from ONE latent a token (a down-projection and an RMSNorm,
+then an up-projection to this chip's heads), the rotary part of the key
+(``rope_head_dim`` of the ``head_dim`` q/k width) is one head that all
+query heads read, and values and the result are ``v_head_dim`` wide. The
+router may score with a sigmoid and choose by score + a bias
+(``router_scoring``, ``routed_scaling``:
+``parallel/moe.py:route_sigmoid_top_k``), and the shared expert may go
+without its gate (``shared_expert_gate``). The defaults are SmallThinker's.
 
 A model with ``loop_steps`` U > 1 is a LOOPED decoder (arXiv:2510.25741):
 its ``num_layers`` layers are run U times over with the SAME parameters —
@@ -80,6 +91,11 @@ COUNTERS = ("rows_held", "load_max", "load_mean", "tokens_unserved",
 #: min(position + 1, select_topk) (exact ties at the threshold only), and
 #: the layer's index loss (mean over its tokens); running sums
 SELECT_COUNTERS = ("pairs_kept", "rows_off_count", "index_loss")
+#: and, in a model whose router scores with a sigmoid and chooses by score
+#: + bias, per expert layer (0 for a dense one): the (token, slot) choices
+#: the bias changed, and the sum over tokens of the chosen experts' weights
+#: (``routed_scaling`` a token); running sums
+ROUTE_COUNTERS = ("route_bias_flips", "route_weight_sum")
 #: and, in a model trained by diffusion over blocks: per layer, the (query,
 #: key) pairs of one head that the attention forward counted under the mask
 #: it applied; and ``loss_tokens``, one scalar for the model: the masked
@@ -114,6 +130,12 @@ _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
 #: only rebuild them; and the gated delta rule's result and chunk-end
 #: states (335 MB a layer at 16384 tokens of 16 value heads, against a
 #: second ``gdn_fwd``). The band kernels name no residual and run twice.
+#: A latent-attention layer names none either — neither the latent with its
+#: rotary key (576 values a token: 9.4 MB a layer at 8192 tokens) nor this
+#: chip's k and v (8 x 320 values a token: 42 MB): its backward starts from
+#: the layer's input and rebuilds both, 19 + 34 GFLOP a layer at 8192
+#: tokens of 8 heads, under 0.3 ms at the chip's peak beside the band
+#: kernels' second forward.
 SAVED_UNDER_REMAT = (moe.SAVED_UNDER_REMAT
                      + sparse_attention.SAVED_UNDER_REMAT
                      + bd_attention.SAVED_UNDER_REMAT
@@ -217,14 +239,27 @@ class SparseDecoderLayer(nn.Module):
     shared_expert_width: int = 0        # 0: no shared expert
     dense_width: int = 0                # the feed-forward part's, no experts
     sandwich_norm: bool = False         # a norm on each sublayer's result
+    latent_dim: int = 0                 # > 0: latent attention, this wide
+    rope_head_dim: int = 0              # its rotary part of head_dim
+    v_head_dim: int = 0                 # its values' width; 0: head_dim
+    router_scoring: str = "softmax"     # or "sigmoid" (score + bias)
+    routed_scaling: float = 1.0         # on a sigmoid router's weights
+    shared_expert_gate: bool = True     # a sigmoid gate on the shared expert
 
     def _route(self, x):
+        """(idx, weights, what a sigmoid router counts: {} for a softmax)"""
         b, s, d = x.shape
         with jax.named_scope("moe.route"):
             router = self.param("router", _init(), (d, self.num_experts),
                                 jnp.float32)
+            if self.router_scoring == "sigmoid":
+                return moe.route_sigmoid_top_k(
+                    x.reshape(b * s, d), router,
+                    self.param("router_bias", nn.initializers.zeros,
+                               (self.num_experts,), jnp.float32),
+                    self.experts_per_token, self.routed_scaling)
             return moe.route_top_k(x.reshape(b * s, d), router,
-                                   self.experts_per_token)
+                                   self.experts_per_token) + ({},)
 
     def _index(self, h, proj):
         """The indexer on stop_gradient(h): (qi, ki, wi, tau). Its four
@@ -305,6 +340,37 @@ class SparseDecoderLayer(nn.Module):
             out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hv, dh, d)))
         return out, stats
 
+    def _latent_attention(self, h, proj, positions):
+        """Multi-head latent attention on the normed input h: its part of
+        the residual. W_kv_down [d, latent + rope] is WHOLE on every chip of
+        a head-parallel group (the latent is not sharded); kv_up, query and
+        out are cut by heads. The 192-wide key is built by laying the one
+        rotary key beside every head's own part: the band kernels then read
+        it as any key."""
+        b, s, d = h.shape
+        dt = self.dtype
+        hd, dr, dc = self.head_dim, self.rope_head_dim, self.latent_dim
+        dn, dv = hd - dr, self.v_head_dim or self.head_dim
+        with jax.named_scope("attn.latent.down"):
+            down = jnp.einsum("bsd,dk->bsk", h, proj("kv_down", (d, dc + dr)))
+            c = self._norm("norm_latent")(down[..., :dc])
+        with jax.named_scope("attn.latent.up"):
+            kv = jnp.einsum("bsc,chk->bshk", c,
+                            proj("kv_up", (dc, self.heads, dn + dv)))
+        with jax.named_scope("attn.latent"):
+            q = jnp.einsum("bsd,dhk->bshk", h,
+                           proj("query", (d, self.heads, hd)))
+            turn = lambda x: rope(x, self.rope_theta, positions)
+            q = jnp.concatenate([q[..., :dn], turn(q[..., dn:])], axis=-1)
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+                turn(down[:, :, None, dc:]), (b, s, self.heads, dr))],
+                axis=-1)
+            a = attention_context(q, k, kv[..., dn:], causal=True, mask=None,
+                                  dtype=dt, use_flash=self.use_flash,
+                                  window=self.window)
+            return jnp.einsum("bshk,hkd->bsd", a,
+                              proj("out", (self.heads, dv, d)))
+
     def _attention(self, h, proj, positions, select):
         """Grouped-query attention on the normed input h: (its part of the
         residual, what the masked paths counted: (kl, kept) of a selection,
@@ -364,13 +430,25 @@ class SparseDecoderLayer(nn.Module):
         linear = self.mixer == "gated_delta"
         if linear and (self.streams or self.select_topk or self.window):
             raise ValueError("a gated-delta-rule layer takes no mask")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError("router_scoring %r" % (self.router_scoring,))
+        if self.latent_dim and (
+                linear or self.streams or self.select_topk or self.qk_norm
+                or self.attn_gate or not self.use_rope
+                or self.kv_heads != self.heads):
+            raise ValueError(
+                "a latent-attention layer is causal softmax attention with a "
+                "rotary part, as many key-value heads as query heads, and "
+                "no selection, block mask, q/k norm or gate")
         dense = self.experts_held == 0
         h = self._norm("norm_attn")(x)
         if self.router_input == "attn_norm" and not dense:
-            idx, p = self._route(h)
+            idx, p, routed = self._route(h)
         select = self._index(h, proj) if self.select_topk else None
         if linear:
             mixed, counted = self._gated_delta(h, proj)
+        elif self.latent_dim:
+            mixed, counted = self._latent_attention(h, proj, positions), None
         else:
             mixed, counted = self._attention(h, proj, positions, select)
         if self.sandwich_norm:
@@ -387,7 +465,7 @@ class SparseDecoderLayer(nn.Module):
                 activation=self.expert_activation), {}
         else:
             if self.router_input == "moe_norm":
-                idx, p = self._route(u)
+                idx, p, routed = self._route(u)
             f = self.expert_width
             gate_up = self.param("experts_gate_up", _init(),
                                  (self.experts_held, d, 2 * f), jnp.float32)
@@ -396,6 +474,7 @@ class SparseDecoderLayer(nn.Module):
             m, counters = moe.held_experts_ffn(
                 u.reshape(b * s, d), idx, p, gate_up, down,
                 self.first_expert, activation=self.expert_activation)
+            counters = dict(counters, **routed)
         if self.shared_expert_width:
             fs = self.shared_expert_width
             m = m + moe.shared_expert_ffn(
@@ -403,7 +482,8 @@ class SparseDecoderLayer(nn.Module):
                 self.param("shared_gate_up", _init(), (d, 2 * fs),
                            jnp.float32),
                 self.param("shared_down", _init(), (fs, d), jnp.float32),
-                self.param("shared_gate", _init(), (d,), jnp.float32),
+                self.param("shared_gate", _init(), (d,), jnp.float32)
+                if self.shared_expert_gate else None,
                 activation=self.expert_activation)
         if select:
             kl, kept = counted
@@ -475,6 +555,28 @@ class SparseDecoder(nn.Module):
     sandwich_norm: bool = False
     loop_steps: int = 1             # > 1: the stack run that often, looped
     exit_entropy_weight: float = 0.05   # beta of the looped model's loss
+    dense_layout: Sequence[int] = ()    # per layer: 1 = dense, no experts
+    latent_dim: int = 0             # > 0: multi-head latent attention
+    rope_head_dim: int = 0
+    v_head_dim: int = 0
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    shared_expert_gate: bool = True
+
+    def dense_layers(self):
+        """Per layer: whether its feed-forward part is dense (no experts,
+        no shared expert, no router)."""
+        layout = tuple(self.dense_layout) + (0,) * self.num_layers
+        return tuple(bool(flag) or self.experts_held == 0
+                     for flag in layout[:self.num_layers])
+
+    def counted(self):
+        """What `counter_names` and `init_counters` ask of a model."""
+        return dict(selects=self.selects(),
+                    gated_delta=any(self.gated_delta_layers()),
+                    routed=self.experts_held > 0,
+                    scored=self.experts_held > 0
+                    and self.router_scoring == "sigmoid")
 
     def gated_delta_layers(self):
         """Per layer: whether its mixer is the gated delta rule."""
@@ -500,11 +602,15 @@ class SparseDecoder(nn.Module):
         positions_arg = () if positions is None else (positions,)
         per_layer = []
         linear = self.gated_delta_layers()
+        dense = self.dense_layers()
+        counted = self.counted()
+        routing = counter_names(routed=counted["routed"],
+                                scored=counted["scored"])
         for i, select in enumerate(self.select_layers()):
             x, counters = layer_cls(
                 heads=self.heads, kv_heads=self.kv_heads,
                 head_dim=self.head_dim, num_experts=self.num_experts,
-                experts_held=self.experts_held,
+                experts_held=0 if dense[i] else self.experts_held,
                 first_expert=self.first_expert,
                 experts_per_token=self.experts_per_token,
                 expert_width=self.expert_width,
@@ -523,10 +629,20 @@ class SparseDecoder(nn.Module):
                 gdn_head_dim=self.gdn_head_dim, conv_width=self.conv_width,
                 attn_gate=self.attn_gate, rotary_dim=self.rotary_dim,
                 zero_centered_norm=self.zero_centered_norm,
-                shared_expert_width=self.shared_expert_width,
+                shared_expert_width=0 if dense[i]
+                else self.shared_expert_width,
                 dense_width=self.dense_width,
                 sandwich_norm=self.sandwich_norm,
+                latent_dim=self.latent_dim,
+                rope_head_dim=self.rope_head_dim,
+                v_head_dim=self.v_head_dim,
+                router_scoring=self.router_scoring,
+                routed_scaling=self.routed_scaling,
+                shared_expert_gate=self.shared_expert_gate,
                 name="layer_%d" % i)(x, *positions_arg)
+            if dense[i]:                # beside layers that route: zeros
+                counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
+                                             for n in routing})
             if self.selects() and not select:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in SELECT_COUNTERS})
@@ -581,9 +697,8 @@ class SparseDecoder(nn.Module):
         embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
                            jnp.float32)
         x = jnp.take(embed, ids, axis=0).astype(self.dtype)
-        names = counter_names(self.selects(), bool(streams),
-                              any(self.gated_delta_layers()),
-                              self.experts_held > 0)
+        names = counter_names(block_diffusion=bool(streams),
+                              **self.counted())
         stacked = lambda per_layer: {
             n: jnp.stack([c[n] for c in per_layer]) for n in names}
         if self.loop_steps > 1:
@@ -599,18 +714,21 @@ class SparseDecoder(nn.Module):
 
 
 def counter_names(selects=False, block_diffusion=False, gated_delta=False,
-                  routed=True):
+                  routed=True, scored=False):
     """The per-layer counters of a model: the routing's (none where no
-    layer holds an expert) and, by what the model does, the selection's,
-    the two-stream attention's or the gated delta rule's."""
+    layer holds an expert; a sigmoid router's two with them) and, by what
+    the model does, the selection's, the two-stream attention's or the
+    gated delta rule's."""
     return ((COUNTERS if routed else ())
+            + (ROUTE_COUNTERS if scored else ())
             + (SELECT_COUNTERS if selects else ())
             + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ())
             + (GATED_DELTA_COUNTERS if gated_delta else ()))
 
 
 def init_counters(num_layers, selects=False, block_diffusion=False,
-                  gated_delta=False, routed=True, loop_steps=1):
+                  gated_delta=False, routed=True, loop_steps=1,
+                  scored=False):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
     model with a selecting layer, the selection's; for one trained by
@@ -624,7 +742,7 @@ def init_counters(num_layers, selects=False, block_diffusion=False,
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
          for n in counter_names(selects, block_diffusion, gated_delta,
-                                routed)},
+                                routed, scored)},
         **{n: jnp.zeros((loop_steps,), jnp.float32) for n in per_pass},
         **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 
@@ -738,6 +856,5 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
             extra, jax.lax.stop_gradient(counters))
 
     return (model, params, init_counters(
-        model.num_layers, model.selects(), block_diffusion,
-        any(model.gated_delta_layers()), model.experts_held > 0,
-        model.loop_steps), loss_fn)
+        model.num_layers, block_diffusion=block_diffusion,
+        loop_steps=model.loop_steps, **model.counted()), loss_fn)

@@ -29,8 +29,12 @@ _FLASH_HEAD_MULT = 8
 
 def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
                           seq_kv=None, offset=None, streams=None,
-                          itemsize=2):
+                          itemsize=2, v_head_dim=None):
     """Why auto-dispatch would (not) pick flash for this shape.
+
+    ``head_dim`` is the width of q and k; ``v_head_dim`` that of v and of
+    the result where it is another (latent attention: 192 beside 128),
+    which the band kernels take as they take equal widths.
 
     The kernel takes any number of query heads per key-value head and,
     under ``causal``, a ``window`` (a query sees its own position and the
@@ -85,6 +89,13 @@ def flash_dispatch_reason(seq_len, head_dim, *, mask=None, platform=None,
     if head_dim % _FLASH_HEAD_MULT != 0:
         return "head_dim %d not a multiple of %d" % (head_dim,
                                                      _FLASH_HEAD_MULT)
+    if v_head_dim is not None and v_head_dim != head_dim:
+        if v_head_dim % _FLASH_HEAD_MULT != 0:
+            return "v_head_dim %d not a multiple of %d" % (
+                v_head_dim, _FLASH_HEAD_MULT)
+        if streams is not None:
+            return ("the two-stream block mask's kernels take one width "
+                    "for q, k and v (%d beside %d)" % (head_dim, v_head_dim))
     if streams is not None:
         from edl_tpu.ops import block_diffusion_attention
         return block_diffusion_attention.kernel_reason(
@@ -167,7 +178,9 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
 
     q is [batch, seq, heads, dim]; k and v are [batch, seq_kv, kv_heads,
     dim] with ``heads`` a multiple of ``kv_heads`` (query head i reads kv
-    head ``i // (heads // kv_heads)``). ``window`` (flash and dense paths,
+    head ``i // (heads // kv_heads)``). On the flash and dense paths v may
+    be of another width than q and k (latent attention): the result is v's
+    width, the scale q's and k's. ``window`` (flash and dense paths,
     needs ``causal``): a query reads its own position and the
     ``window - 1`` before it; ``None`` reads the whole causal prefix.
     ``select`` (flash and dense paths, needs ``causal``, excludes
@@ -187,7 +200,7 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
     dense otherwise). The old default was ``False``; auto is numerics-
     gated against dense in tier-1 (tests/test_attention_dispatch.py).
     """
-    head_dim = q.shape[-1]
+    head_dim, v_dim = q.shape[-1], v.shape[-1]
     scale = head_dim ** -0.5
     heads, kv_heads = q.shape[2], k.shape[2]
     group = heads // kv_heads
@@ -196,6 +209,11 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
                          % (heads, kv_heads))
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
+    if (select is not None or streams is not None or ring_axis
+            or use_ring) and v_dim != head_dim:
+        raise ValueError("only the band kernels and the dense path take v "
+                         "at another width (%d) than q and k (%d)"
+                         % (v_dim, head_dim))
     if streams is not None:
         if causal or window is not None or select is not None:
             raise ValueError("the two-stream block mask is the whole mask: "
@@ -235,8 +253,8 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
         return ring_attention(q, k, v, mesh, causal=causal)
     if use_flash is None:
         use_flash = flash_dispatch_reason(q.shape[1], head_dim,
-                                          mask=mask,
-                                          seq_kv=k.shape[1]) is None
+                                          mask=mask, seq_kv=k.shape[1],
+                                          v_head_dim=v_dim) is None
     if use_flash:
         if mask is not None:
             raise ValueError(
@@ -276,6 +294,6 @@ def attention_context(q, k, v, *, causal, mask, dtype, ring_axis=None,
     out = jnp.einsum("bhqk,bkhd->bqhd", probs,
                      v.astype(jnp.float32)).astype(dtype)
     if group > 1:
-        out = out.reshape(b, group, s, kv_heads, head_dim).transpose(
-            0, 2, 3, 1, 4).reshape(b, s, heads, head_dim)
+        out = out.reshape(b, group, s, kv_heads, v_dim).transpose(
+            0, 2, 3, 1, 4).reshape(b, s, heads, v_dim)
     return out

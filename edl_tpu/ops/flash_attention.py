@@ -31,7 +31,11 @@ composes with jit/grad/remat and with the ring-attention sp layer
 (edl_tpu/parallel/ring_attention.py), which shards the sequence BEFORE
 attention is applied per shard.
 
-Layout: q, k, v are [batch, heads, seq, head_dim]. With grouped-query
+Layout: q, k, v are [batch, heads, seq, head_dim]; v, and with it the
+result, dO and dv, may have a width of its own beside q's and k's (latent
+attention: a 192-wide score against 128-wide values): every kernel sizes
+its q/k tiles from q and its v tiles from v, and the default scale is the
+q/k width's. With grouped-query
 attention k and v have fewer heads and q is [batch, kv_heads, group * seq,
 head_dim] (the query heads of a kv head one after another: K/V are never
 repeated); a causal ``window`` keeps a query's own position and the
@@ -260,7 +264,7 @@ def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
 
     first, ufirst, ulast, last = _kv_band(jnp, q_lo, block_q, block_k, n_k,
                                           **band)
-    carry = (jnp.zeros(q.shape, jnp.float32),
+    carry = (jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32),
              jnp.full((block_q, 1), _NEG_INF, jnp.float32),
              jnp.zeros((block_q, 1), jnp.float32))
     # a program is a few tiles long, so its loops' set-up shows (GPT-2s's
@@ -307,16 +311,18 @@ def _statics(block_q, block_k, n_q_seq, n_k, sm_scale, causal, window,
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                window=None, group=1, resident_bytes=_RESIDENT_KV_BYTES):
     """q is [b, h, group * s, d]: the ``group`` query heads that share kv
-    head h, one run of the sequence after another; k, v are [b, h, s, d]
-    and are never repeated in memory. Under jit with the tiles and
-    ``resident_bytes`` static (the caller passes the module's)."""
+    head h, one run of the sequence after another; k [b, h, s, d] and v
+    [b, h, s, dv] are never repeated in memory; the result is dv wide.
+    Under jit with the tiles and ``resident_bytes`` static (the caller
+    passes the module's)."""
     b, h, rows, d = q.shape
+    dv = v.shape[-1]
     s = rows // group
     sk = k.shape[2]
     bh = b * h
     qf = q.reshape(bh, rows, d)
     kf = k.reshape(bh, sk, d)
-    vf = v.reshape(bh, sk, d)
+    vf = v.reshape(bh, sk, dv)
     block_q = min(block_q, s)
     block_k = min(block_k, sk)
     n_q = pl.cdiv(rows, block_q)
@@ -336,36 +342,43 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
     # the row statistic lse = m + log(l), float32, rows along the lanes:
     # 4 bytes a row, the residual the backward kernels rebuild p from
-    out_shape = (jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+    out_shape = (jax.ShapeDtypeStruct((bh, rows, dv), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, rows), jnp.float32))
-    q_block = pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0))
+    q_block, o_block = (pl.BlockSpec((1, block_q, w),
+                                     lambda i, j, *_: (i, j, 0))
+                        for w in (d, dv))
     q_stats = pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j))
-    kv_bytes = 2 * sk * d * k.dtype.itemsize
+    # k's and v's own bytes: a 192-wide k beside a 128-wide v is 5/4 of two
+    # 128-wide tensors
+    kv_bytes = sk * (d + dv) * k.dtype.itemsize
     if kv_bytes <= resident_bytes and not ragged:
-        kv_whole = pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0))
+        k_whole, v_whole = (pl.BlockSpec((1, sk, w), lambda i, j: (i, 0, 0))
+                            for w in (d, dv))
         out, lse = pl.pallas_call(
             functools.partial(
                 _fwd_kernel_resident, **shape, diagonal=_diagonal_tiles(
                     block_q, block_k, s, sk, causal, window)),
             grid=(bh, n_q),
-            in_specs=[q_block, kv_whole, kv_whole],
-            out_specs=(q_block, q_stats),
+            in_specs=[q_block, k_whole, v_whole],
+            out_specs=(o_block, q_stats),
             out_shape=out_shape,
             compiler_params=_compiler_params("parallel", "parallel"),
             interpret=interpret,
             name=FWD_RESIDENT_NAME,
         )(qf, kf, vf)
-        return out.reshape(b, h, rows, d), lse
+        return out.reshape(b, h, rows, dv), lse
 
-    kv_block = pl.BlockSpec((1, block_k, d), lambda i, j, kb: (i, kb, 0))
+    k_block, v_block = (pl.BlockSpec((1, block_k, w),
+                                     lambda i, j, kb: (i, kb, 0))
+                        for w in (d, dv))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, **shape),
         grid=(bh, n_q, n_k),
-        in_specs=[q_block, kv_block, kv_block],
-        out_specs=(q_block, q_stats),
+        in_specs=[q_block, k_block, v_block],
+        out_specs=(o_block, q_stats),
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -374,7 +387,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         interpret=interpret,
         name=FWD_STREAM_NAME,
     )(qf, kf, vf)
-    return out.reshape(b, h, rows, d), lse
+    return out.reshape(b, h, rows, dv), lse
 
 
 def _block_layout(k, v, block_k):
@@ -432,7 +445,7 @@ def _blockwise_reference(q, k, v, causal, sm_scale, block_k=512,
             "bhqk,bhkd->bhqd", p, v_blk)
         return (acc_new, m_new, l_new), None
 
-    acc0 = jnp.zeros((b, h, s, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, s, v.shape[-1]), jnp.float32)
     m0 = jnp.full((b, h, s), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, s), jnp.float32)
     (acc, m, l), _ = lax.scan(body, (acc0, m0, l0),
@@ -639,6 +652,7 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
     with ``block`` and ``resident_bytes`` static (the caller passes the
     module's), so the layers of a model share one trace and one lowering."""
     b, h, rows, d = q.shape
+    dv = v.shape[-1]                # v's, dO's and dv's width, beside q/k's
     s, sk, bh = rows // group, k.shape[2], b * h
     block_q, block_k = _tile_edge(s, block), _tile_edge(sk, block)
     s_pad = pl.cdiv(s, block_q) * block_q
@@ -649,35 +663,41 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
     ragged = not causal and sk_pad != sk
 
     qf = _pad_runs(q.reshape(bh, rows, d), 1, group, s, s_pad)
-    dof = _pad_runs(g.astype(q.dtype).reshape(bh, rows, d), 1, group, s,
+    dof = _pad_runs(g.astype(q.dtype).reshape(bh, rows, dv), 1, group, s,
                     s_pad)
     lse = _pad_runs(lse, 2, group, s, s_pad)
     delta = _pad_runs(delta.reshape(bh, 1, rows), 2, group, s, s_pad)
     kf = _pad_runs(k.reshape(bh, sk, d), 1, 1, sk, sk_pad)
-    vf = _pad_runs(v.reshape(bh, sk, d), 1, 1, sk, sk_pad)
+    vf = _pad_runs(v.reshape(bh, sk, dv), 1, 1, sk, sk_pad)
 
     shape = _statics(block_q, block_k, n_q_seq, n_k, sm_scale, causal,
                      window, sk if ragged else None)
     band = shape["band"]
     call = functools.partial(pl.pallas_call, interpret=interpret)
-    q_block = pl.BlockSpec((1, block_q, d), lambda i, j, *_: (i, j, 0))
+    q_block, do_block = (pl.BlockSpec((1, block_q, w),
+                                      lambda i, j, *_: (i, j, 0))
+                         for w in (d, dv))
     q_stats = pl.BlockSpec((1, 1, block_q), lambda i, j, *_: (i, 0, j))
-    kv_block = pl.BlockSpec((1, block_k, d), lambda i, j, *_: (i, j, 0))
+    k_block, v_block = (pl.BlockSpec((1, block_k, w),
+                                     lambda i, j, *_: (i, j, 0))
+                        for w in (d, dv))
     dq_shape = jax.ShapeDtypeStruct(qf.shape, q.dtype)
     dkv_shape = (jax.ShapeDtypeStruct(kf.shape, k.dtype),
                  jax.ShapeDtypeStruct(vf.shape, v.dtype))
 
-    if 2 * sk_pad * d * k.dtype.itemsize <= resident_bytes:
-        kv_whole = pl.BlockSpec((1, sk_pad, d), lambda i, j: (i, 0, 0))
-        dq, dk, dv = call(
+    if sk_pad * (d + dv) * k.dtype.itemsize <= resident_bytes:
+        k_whole, v_whole = (pl.BlockSpec((1, sk_pad, w),
+                                         lambda i, j: (i, 0, 0))
+                            for w in (d, dv))
+        dq, dk, dv_ = call(
             functools.partial(_bwd_kernel_resident, **shape),
             grid=(bh, n_q),
-            in_specs=[q_block, kv_whole, kv_whole, q_block, q_stats,
+            in_specs=[q_block, k_whole, v_whole, do_block, q_stats,
                       q_stats],
-            out_specs=(q_block, kv_whole, kv_whole),
+            out_specs=(q_block, k_whole, v_whole),
             out_shape=(dq_shape,) + dkv_shape,
             scratch_shapes=[pltpu.VMEM((sk_pad, d), jnp.float32),
-                            pltpu.VMEM((sk_pad, d), jnp.float32)],
+                            pltpu.VMEM((sk_pad, dv), jnp.float32)],
             compiler_params=_compiler_params("parallel", "arbitrary"),
             name=BWD_NAME)(qf, kf, vf, dof, lse, delta)
     else:
@@ -691,11 +711,12 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
                 **band)
             return i, jnp.minimum(first + t, last - 1), 0
 
-        kv_step = pl.BlockSpec((1, block_k, d), kv_of)
+        k_step, v_step = (pl.BlockSpec((1, block_k, w), kv_of)
+                          for w in (d, dv))
         dq = call(
             functools.partial(_bwd_dq_kernel, **shape),
             grid=(bh, n_q, int(np.max(last - first))),
-            in_specs=[q_block, kv_step, kv_step, q_block, q_stats, q_stats],
+            in_specs=[q_block, k_step, v_step, do_block, q_stats, q_stats],
             out_specs=q_block, out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
@@ -712,25 +733,26 @@ def _flash_bwd(q, k, v, lse, delta, g, causal, sm_scale, interpret=False,
             return (lax.div(t, n_steps) * n_q_seq
                     + jnp.minimum(first + lax.rem(t, n_steps), last - 1))
 
-        q_step = pl.BlockSpec((1, block_q, d),
-                              lambda i, j, t: (i, q_of(j, t), 0))
+        q_step, do_step = (pl.BlockSpec((1, block_q, w),
+                                        lambda i, j, t: (i, q_of(j, t), 0))
+                           for w in (d, dv))
         stats_step = pl.BlockSpec((1, 1, block_q),
                                   lambda i, j, t: (i, 0, q_of(j, t)))
-        dk, dv = call(
+        dk, dv_ = call(
             functools.partial(_bwd_dkv_kernel, n_steps=n_steps, **shape),
             grid=(bh, n_k, group * n_steps),
-            in_specs=[kv_block, kv_block, q_step, q_step, stats_step,
+            in_specs=[k_block, v_block, q_step, do_step, stats_step,
                       stats_step],
-            out_specs=(kv_block, kv_block), out_shape=dkv_shape,
+            out_specs=(k_block, v_block), out_shape=dkv_shape,
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
             compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
             name=BWD_DKV_NAME)(kf, vf, qf, dof, lse, delta)
 
     if s_pad != s:
         dq = dq.reshape(bh, group, s_pad, d)[:, :, :s]
     return (dq.reshape(b, h, rows, d), dk[:, :sk].reshape(b, h, sk, d),
-            dv[:, :sk].reshape(b, h, sk, d))
+            dv_[:, :sk].reshape(b, h, sk, dv))
 
 
 def _kernel_layout(x, group=1):
@@ -832,7 +854,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=None,
     """Blockwise exact attention; k/v are [batch, kv_heads, seq, dim] and
     q/out [batch, kv_heads, group * seq, dim]: the ``group`` query heads
     of a kv head one run of the sequence after another (``group=1``: the
-    usual [batch, heads, seq, dim]). ``window`` (needs ``causal``) keeps,
+    usual [batch, heads, seq, dim]); v and the result may be of another
+    width than q and k, whose width the default ``sm_scale`` is taken from. ``window`` (needs ``causal``) keeps,
     for each query, its own position and the ``window - 1`` before it;
     ``block_q`` / ``block_k`` fix the forward's tile, which is otherwise
     chosen from the sequence (``_tile_edge``), as the backward's always is;

@@ -241,6 +241,38 @@ def route_top_k(h, router, k):
     return idx, jax.nn.softmax(top, axis=-1)
 
 
+def route_sigmoid_top_k(h, router, bias, k, scaling=1.0):
+    """The router of a layer that scores with a SIGMOID and chooses by
+    score + bias (DeepSeek-V3's, one group): z = h router in float32 over
+    ALL experts, sc = sigmoid(z); the k chosen are the largest of
+    sc + ``bias`` [E] (the bias steers the load and is in nothing else: no
+    gradient reaches it, being read under the stop-gradient the choice is
+    made under); their weights come from the UNBIASED scores, normalised
+    over the chosen k — held here or not — and times ``scaling``:
+    w_e = scaling * sc_e / (sum over the chosen of sc + 1e-20). The choice
+    is saved under remat as :func:`route_top_k`'s. Returns (idx [T, k]
+    int32, w [T, k], counters: float32 scalars ``route_bias_flips`` — the
+    (token, slot) choices that the k largest of sc alone would not have
+    made — and ``route_weight_sum`` — the sum over tokens of their k
+    weights, ``scaling`` a token)."""
+    from jax.ad_checkpoint import checkpoint_name
+    sc = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    plain = lax.stop_gradient(sc)
+    idx = checkpoint_name(lax.top_k(
+        plain + bias.astype(jnp.float32), k)[1].astype(jnp.int32),
+        SAVED_UNDER_REMAT[0])
+    top = jnp.take_along_axis(sc, idx, axis=-1)
+    w = scaling * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
+    unbiased = lax.top_k(plain, k)[1]
+    kept = (idx[:, :, None] == unbiased[:, None, :]).any(axis=-1)
+    return idx, w, {
+        "route_bias_flips": jnp.sum(jnp.logical_not(kept)).astype(
+            jnp.float32),
+        "route_weight_sum": lax.stop_gradient(w).sum()}
+
+
 def plan_held_rows(idx, first_expert, experts_held, tm=ROW_TILE):
     """Where every (token, choice) that picked a held expert goes among
     the rows sorted by expert. Static shapes: ``rows = T * k +
@@ -493,13 +525,16 @@ def _gated_linear_unit(u, w_gate_up, w_down, activation):
 def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu"):
     """The SHARED expert of a layer that has one beside its routed experts:
     every row passes through it, scaled by a learned sigmoid gate of its
-    own — sigmoid(u . w_gate) * down(act(gate u) * (up u)). A plain dense
-    gated linear unit: u [T, D], w_gate_up [D, 2 * F] (gate then up),
+    own — sigmoid(u . w_gate) * down(act(gate u) * (up u)) — or, with
+    ``w_gate`` None, by nothing (:func:`dense_ffn`'s arithmetic). A plain
+    dense gated linear unit: u [T, D], w_gate_up [D, 2 * F] (gate then up),
     w_down [F, D], w_gate [D] -> [T, D] in u's dtype. Every chip of an
     expert-parallel group computes it alike, so where the shares of a layer
     are added up it is counted once."""
     with jax.named_scope("moe.shared"):
         y = _gated_linear_unit(u, w_gate_up, w_down, activation)
+        if w_gate is None:
+            return y.astype(u.dtype)
         gate = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
                                       w_gate.astype(jnp.float32)))
         return (y * gate[:, None]).astype(u.dtype)
