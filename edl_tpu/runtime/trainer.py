@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from edl_tpu.controller import train_status as train_status_mod
 from edl_tpu.controller.env import TrainerEnv
@@ -115,6 +116,13 @@ def _jax_stage_seconds(seen):
     return {tag: round(v, 6) for tag, v in out.items()}
 
 
+def _index_spans(sharding, shape):
+    """{device: ((start, stop), ...)}: the index of an array of ``shape``
+    that each device of ``sharding`` holds."""
+    return {dev: tuple(sl.indices(n)[:2] for sl, n in zip(idx, shape))
+            for dev, idx in sharding.devices_indices_map(shape).items()}
+
+
 def _bytes_moved(avals, left, taken):
     """Bytes a reshard had to land on a device that did not hold that
     index of that leaf before: 0 for a shrink of replicated state, the
@@ -124,10 +132,6 @@ def _bytes_moved(avals, left, taken):
     (shape, dtype, sharding left, sharding taken), not per leaf; still
     it is kept out of ``resize.live``: ``_first_step`` runs it behind
     the device's first step."""
-    def spans(sharding, shape):
-        return {(dev, tuple(sl.indices(n)[:2] for sl, n in zip(idx, shape)))
-                for dev, idx in sharding.devices_indices_map(shape).items()}
-
     known = {}
     total = 0
     for aval, old, new in zip(avals, left, taken):
@@ -135,12 +139,170 @@ def _bytes_moved(avals, left, taken):
             continue
         key = (aval.shape, aval.dtype, old, new)
         if key not in known:
-            held = spans(old, aval.shape) if old is not None else ()
+            held = (_index_spans(old, aval.shape).items()
+                    if old is not None else ())
             known[key] = getattr(aval.dtype, "itemsize", 0) * sum(
                 int(np.prod([b - a for a, b in placed[1]], dtype=np.int64))
-                for placed in spans(new, aval.shape) if placed not in held)
+                for placed in _index_spans(new, aval.shape).items()
+                if placed not in held)
         total += known[key]
     return total
+
+
+def _shard_sources(shape, dtype, old, new):
+    """Where each shard of a leaf resharded ``old`` -> ``new`` comes
+    from: [(target device, source device)], one per device of ``new`` —
+    the target itself where it already holds that index of the leaf (the
+    shard is reused in place), else a device that does (the shard is
+    copied across, the holders taken in turn). None when the leaf has to
+    be re-sliced: some target index is no index of ``old`` (a tp change,
+    ZeRO-sharded optimizer state on another dp), or the shards are not
+    plain device buffers of one memory kind."""
+    if (jax.dtypes.issubdtype(dtype, jax.dtypes.extended)
+            or getattr(old, "memory_kind", None)
+            != getattr(new, "memory_kind", None)):
+        return None
+    holders = {}
+    for dev, span in _index_spans(old, shape).items():
+        holders.setdefault(span, []).append(dev)
+    sources = []
+    for dev, span in _index_spans(new, shape).items():
+        held = holders.get(span)
+        if not held:
+            return None
+        sources.append(
+            (dev, dev if dev in held else held[len(sources) % len(held)]))
+    return sources
+
+
+#: a leaf larger than this crosses on its own. Stacking leaves buys the
+#: runtime's cost of one more copied array (0.15 ms on a v5e host, PR
+#: 54) at the price of a second copy of the leaf on the devices it
+#: leaves and on those it takes, until the reshard is done: a bargain
+#: for the many small leaves (8 MiB is 0.2 ms on the wire between two
+#: chips of a host), a risk to the memory for the few large ones
+_STACK_LEAF_BYTES = 8 << 20
+
+
+def _stacked(sharding):
+    """The sharding of a stack of leaves that each have ``sharding``
+    (a NamedSharding): the new leading axis is not split."""
+    return NamedSharding(sharding.mesh, P(None, *sharding.spec),
+                         memory_kind=sharding.memory_kind)
+
+
+def _stack_programs(groups):
+    """(pack, cut) for ``groups`` of leaves that cross as one array
+    each — per group (shape, dtype, sharding left, sharding taken, how
+    many leaves): ``pack(leaves)`` stacks each group's leaves where
+    they lie, ``cut(stacks)`` cuts the stacks, arrived on the new
+    shardings, into the leaves again."""
+    counts = [n for *_, n in groups]
+
+    def pack(leaves):
+        leaves = iter(leaves)
+        return [jnp.stack([next(leaves) for _ in range(n)]) for n in counts]
+
+    def cut(stacks):
+        return [stack[j] for stack, n in zip(stacks, counts)
+                for j in range(n)]
+
+    return (jax.jit(pack, out_shardings=[_stacked(old)
+                                         for _, _, old, _, _ in groups]),
+            jax.jit(cut, out_shardings=[new for _, _, _, new, n in groups
+                                        for _ in range(n)]))
+
+
+def _reshard_local(leaves, targets, programs):
+    """Move fully addressable ``leaves`` onto the shardings ``targets``
+    in a handful of transfers: -> (new leaves, arrays crossed, leaves
+    taken leaf by leaf). The move is planned once per distinct (shape,
+    dtype, sharding left, sharding taken) (:func:`_shard_sources`), and
+    only reads the old leaves:
+
+    - a shard that lies on its target device is shared with the old
+      leaf, as ``jax.device_put`` shares it: the whole of a shrink;
+    - the leaves of one such move that has shards to cross are first
+      stacked where they lie (one jitted program for all of them), so
+      that they cross as ONE array: the runtime's cost is per array
+      copied, not per byte. Small leaves only (``_STACK_LEAF_BYTES``),
+      on NamedShardings, where the stack's sharding can be named;
+    - every shard that has to cross, of all stacks and all other
+      leaves, goes to the runtime in ONE ``jax.device_put`` of
+      single-device arrays (a batched copy, not one slow-path call a
+      leaf), and each array is put together from its shards;
+    - one jitted program on the new shardings cuts the stacks into
+      leaves again: every device takes its cut, those that stay too
+      (their old buffers go with the old tree). ``programs`` keeps the
+      two programs by what they were built for, so a move made before
+      compiles nothing;
+    - a leaf that has to be re-sliced, or is no device array yet, is
+      left to ``jax.device_put(leaf, sharding)``, all in one call."""
+    by_move, leafwise = {}, []
+    for i, (x, new) in enumerate(zip(leaves, targets)):
+        if isinstance(x, jax.Array):
+            by_move.setdefault((x.shape, x.dtype, x.sharding, new),
+                               []).append(i)
+        else:
+            leafwise.append(i)
+    singles, stacks = [], []
+    for move, members in by_move.items():
+        shape, dtype, old, new = move
+        sources = _shard_sources(*move)
+        if sources is None:
+            leafwise.extend(members)
+        elif (len(members) > 1
+              and any(src is not dev for dev, src in sources)
+              and isinstance(old, NamedSharding)
+              and isinstance(new, NamedSharding)
+              and dtype.itemsize * int(np.prod(shape, dtype=np.int64))
+              <= _STACK_LEAF_BYTES):
+            stacks.append((move + (len(members),), sources, members))
+        else:
+            singles.extend((i, sources) for i in members)
+    # what moves shard by shard: (array, sharding it takes, sources)
+    movers = [(leaves[i], targets[i], sources) for i, sources in singles]
+    if stacks:
+        built_for = tuple(group for group, _, _ in stacks)
+        if built_for not in programs:
+            programs[built_for] = _stack_programs(built_for)
+        pack, cut = programs[built_for]
+        stacked = [i for _, _, members in stacks for i in members]
+        movers.extend(
+            (stack, _stacked(group[3]), sources) for stack, (group, sources, _)
+            in zip(pack([leaves[i] for i in stacked]), stacks))
+    onto, parts = {}, []
+    crossing, dests, slots = [], [], []
+    for x, _, sources in movers:
+        held = {s.device: s.data for s in x.addressable_shards}
+        shards = []
+        for dev, src in sources:
+            if src is not dev:
+                if dev not in onto:
+                    onto[dev] = SingleDeviceSharding(dev)
+                slots.append((shards, len(shards)))
+                crossing.append(held[src])
+                dests.append(onto[dev])
+            shards.append(held[src])
+        parts.append(shards)
+    if crossing:
+        for (shards, at), copy in zip(slots,
+                                      jax.device_put(crossing, dests)):
+            shards[at] = copy
+    moved = [jax.make_array_from_single_device_arrays(x.shape, new, shards)
+             for (x, new, _), shards in zip(movers, parts)]
+    out = list(leaves)
+    for (i, _), x in zip(singles, moved):
+        out[i] = x
+    if stacks:
+        for i, x in zip(stacked, cut(moved[len(singles):])):
+            out[i] = x
+    if leafwise:
+        for i, x in zip(leafwise, jax.device_put(
+                [leaves[i] for i in leafwise],
+                [targets[i] for i in leafwise])):
+            out[i] = x
+    return out, len(crossing), len(leafwise)
 
 
 _distributed_initialized = False
@@ -659,6 +821,10 @@ class ElasticTrainer(object):
         # _grad_accum, _remat_policy, _step_fn) is set above and never
         # again, so an entry stays good for the life of the trainer.
         self._ready_steps = {}
+        # and beside them the programs a live resize's reshard stacks
+        # and cuts small leaves with, by the moves they were built for
+        # (_reshard_local): a resize made before compiles nothing
+        self._reshard_programs = {}
         # the step that next stamps compile_s/first_step_s into
         # _resize_timing: the first step of this incarnation, and the
         # first step after every live_resize() (same record semantics
@@ -1362,26 +1528,40 @@ class ElasticTrainer(object):
     def _reshard_tree(self, tree, shardings, account=False):
         """Reshard the live pytree onto ``shardings``. Fully-addressable
         leaves (the single-process live scope) take the zero-wire fast
-        path: jax.device_put lays the new placement out straight from
-        the live device arrays (``resize.device_put.dispatch``: the
-        call; ``resize.device_put.wait``: until the result is ready).
+        path, :func:`_reshard_local`: the new placement is laid out
+        straight from the live device arrays, kept shards in place and
+        the crossing ones in one batched copy (``resize.device_put
+        .dispatch``: planning and issuing, until the last call returns;
+        ``resize.device_put.wait``: until the result is ready).
         Anything else runs the placed ladder — local-span paste, peer
         range-reads at the committed version, per-span FS fill
-        (live_resize.reshard_placed). Returns (new_tree, stats);
-        ``account`` adds ``placements`` on the fast path: the arguments
-        of :func:`_bytes_moved`, which waits for the first step."""
-        leaves = jax.tree_util.tree_leaves(tree)
+        (live_resize.reshard_placed). Returns (new_tree, stats); the
+        fast path's stats count ``arrays_crossed`` (arrays handed to the
+        runtime to copy across devices) and ``leaves_leafwise`` (leaves
+        that had to be re-sliced and went through ``jax.device_put`` one
+        by one), and ``account`` adds ``placements`` there: the
+        arguments of :func:`_bytes_moved`, which waits for the first
+        step."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
         if self._fully_addressable(tree):
+            targets = jax.tree_util.tree_leaves(shardings)
+            if len(targets) != len(leaves):
+                # a sharding that stands for a whole subtree
+                targets = jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+                    lambda sh, sub: jax.tree_util.tree_map(
+                        lambda _: sh, sub), shardings, tree))
             with obs_trace.span("resize.device_put.dispatch", stage=True):
-                out = jax.device_put(tree, shardings)
+                moved, crossed, leafwise = _reshard_local(
+                    leaves, targets, self._reshard_programs)
+                out = jax.tree_util.tree_unflatten(treedef, moved)
             with obs_trace.span("resize.device_put.wait", stage=True):
                 jax.block_until_ready(out)
             stats = {"source": "local", "leaves": len(leaves),
                      "local_bytes": sum(int(getattr(x, "nbytes", 0))
                                         for x in leaves),
-                     "peer_bytes": 0, "peers": 0, "fs_keys": []}
-            targets = jax.tree_util.tree_leaves(shardings)
-            if account and len(targets) == len(leaves):
+                     "peer_bytes": 0, "peers": 0, "fs_keys": [],
+                     "arrays_crossed": crossed, "leaves_leafwise": leafwise}
+            if account:
                 # objects the leaves hold already, in three lists: the
                 # pause allocates next to nothing for the account
                 stats["placements"] = (
@@ -1528,6 +1708,11 @@ class ElasticTrainer(object):
                     if sp_put.recorded:
                         put_tags.update(leaves=reshard_stats["leaves"],
                                         persist_inflight=inflight)
+                        # the fast path says how the state crossed
+                        put_tags.update(
+                            (tag, reshard_stats[tag])
+                            for tag in ("arrays_crossed", "leaves_leafwise")
+                            if tag in reshard_stats)
                         if "placements" not in reshard_stats:
                             # the placed ladder counted what it fetched
                             put_tags["bytes_moved"] = (

@@ -1,7 +1,7 @@
 """The benchmark's readers of the program's stage spans
 (benchmark/lib/progspans.py and the fifteen files under
 benchmark/metrics/ that use it, thirteen of them listed in BENCHMARK.json
-and two no longer; benchmark/lib/stagespans.py and the fifteen that read
+and two no longer; benchmark/lib/stagespans.py and the sixteen that read
 what a save and a reshard are made of), each on a
 hand-made ring and `view`: medians by direction, None without the span,
 roots outside the window left out, the arithmetic of `resize_other_ms`.
@@ -350,6 +350,8 @@ def test_benchmark_names_every_new_reader_once_for_the_elastic_cell():
 
 
 STATE = 2.0e8       # bytes of the job's state, one copy
+#: arrays each grow of `deep` handed to the runtime to cross (PR 54)
+CROSSED = {"g1": 890, "g2": 4}
 
 
 @pytest.fixture()
@@ -378,7 +380,9 @@ def deep(world, monkeypatch):
             grow = key.startswith("g")
             sp["tags"].update(source="local", bytes=STATE, leaves=445,
                               bytes_moved=2 * STATE if grow else 0,
-                              persist_inflight=True)
+                              persist_inflight=True,
+                              arrays_crossed=CROSSED.get(key, 0),
+                              leaves_leafwise=0)
             # the call is four fifths of it, the wait the rest less 1 ms
             ring.append(_span(key, "resize.device_put.dispatch", sp["t0"],
                               0.8 * sp["dur_ms"],
@@ -535,6 +539,43 @@ def test_put_beside_persist_is_the_overlap_with_the_ring_s_writes(
     monkeypatch.setattr(progspans, "ring", lambda: ring)
     assert _read("resize_put_beside_persist_pct",
                  deep["view"]) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("window,want", [
+    ([100.0, 200.0], 447.0),               # the median of g1 and g2
+    ([100.0, 125.0], 890.0),               # g1 alone: one grow is enough
+])
+def test_arrays_crossed_is_the_grows_median_of_the_tag(deep, window, want):
+    assert _read("grow_put_arrays_crossed", {"window": window}) == want
+
+
+def test_arrays_crossed_is_none_without_the_tag(world, monkeypatch):
+    """A program from before PR 54 (`world`: `resize.device_put` with no
+    tag) gives the reader nothing, as an empty ring does; a shrink's tag
+    is not read."""
+    assert _read("grow_put_arrays_crossed", world["view"]) is None
+    shrinks = [dict(s, tags=dict(s["tags"], arrays_crossed=0))
+               if s["name"] == "resize.device_put"
+               and s["trace_id"].startswith("s") else s
+               for s in world["ring"]]
+    monkeypatch.setattr(progspans, "ring", lambda: shrinks)
+    assert _read("grow_put_arrays_crossed", world["view"]) is None
+    monkeypatch.setattr(progspans, "ring", lambda: [])
+    assert _read("grow_put_arrays_crossed", {"window": [100.0, 200.0]}) is None
+
+
+def test_benchmark_names_arrays_crossed_once_and_last():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert [m["name"] for m in bench["per_layer"]].count(
+        "grow_put_arrays_crossed") == 1
+    assert bench["per_layer"][-1] == {
+        "name": "grow_put_arrays_crossed", "unit": "count",
+        "better": "lower", "source": "program_span",
+        "layer": "live resize", "moves": "elastic_samples_s_chip",
+        "workloads": [CELL]}
+    assert os.path.exists(os.path.join(
+        REPO, "benchmark", "metrics", "grow_put_arrays_crossed.py"))
 
 
 def test_benchmark_names_the_inside_readers_once_together_at_the_end():

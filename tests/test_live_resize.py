@@ -1068,6 +1068,212 @@ def test_live_resize_uncomputable_spans_fallback_names_reason():
     _steps(tr, batches[1:2])  # still training on the old mesh
 
 
+# -- the reshard from inside: kept shards in place, one batched copy --------
+
+
+#: (devices, model axes) of the world left and of the world taken, and
+#: what the plan has to say of the tree of `_reshard_case`: arrays handed
+#: to the runtime to cross, leaves that went through jax.device_put whole
+RESHARDS = {
+    # replicated leaves stay where they are; `rows` and `rows2` held
+    # quarters and take halves: another index, re-sliced
+    "4_to_2": ((4, {}), (2, {}), 0, 2),
+    # a and a2 land as ONE stack on each of the two chips gained; b, c
+    # and cols (alone in their moves) whole
+    "2_to_4": ((2, {}), (4, {}), 8, 2),
+    "1_to_4": ((1, {}), (4, {}), 12, 2),
+    # dp stays 2: the stack of rows and rows2 keeps its halves (one in
+    # place, three cross); a + a2, b and c cross twice each; `cols` is
+    # cut along tp: re-sliced
+    "dp_to_dp_tp": ((2, {}), (4, {"tp": 2}), 9, 1),
+}
+#: leaves that share their move with another: stacked where they cross
+TWINS = {"a": "a2", "rows": "rows2"}
+
+
+def _reshard_case(left, taken):
+    """A tree with replicated leaves (two of them of one shape), two
+    whose rows are split over dp and one whose columns are split over
+    tp, placed on the world `left`, and the shardings of the same specs
+    on `taken`."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    specs = {"a": P(), "a2": P(), "b": P(), "c": P(), "rows": P("dp"),
+             "rows2": P("dp"), "cols": P(None, "tp")}
+    rng = np.random.default_rng(54)
+    host = {k: rng.standard_normal((8, 6)).astype(np.float32)
+            for k in ("a", "a2", "rows", "rows2", "cols")}
+    host.update(b=rng.standard_normal((5,)).astype(np.float32),
+                c=np.int32(7))
+
+    def on(world):
+        n, axes = world
+        mesh = make_mesh(devices=jax.devices()[:n], **axes)
+        return {k: NamedSharding(mesh, spec) for k, spec in specs.items()}
+
+    return jax.device_put(host, on(left)), on(taken)
+
+
+def _shard_pointers(tree):
+    return {(i, s.device): s.data.unsafe_buffer_pointer()
+            for i, x in enumerate(jax.tree_util.tree_leaves(tree))
+            for s in x.addressable_shards}
+
+
+@pytest.fixture(scope="module")
+def resharder():
+    tr = _trainer(1)
+    yield tr
+    tr.close()
+
+
+@pytest.mark.parametrize("case", list(RESHARDS))
+def test_reshard_equals_device_put_bit_for_bit(resharder, case):
+    left, taken, _, _ = RESHARDS[case]
+    tree, shardings = _reshard_case(left, taken)
+    want = jax.device_put(tree, shardings)
+    got, _ = resharder._reshard_tree(tree, shardings)
+    assert set(got) == set(want)
+    for k in want:
+        assert got[k].sharding == want[k].sharding, k
+        assert got[k].dtype == want[k].dtype and got[k].shape == want[k].shape
+        assert np.asarray(got[k]).tobytes() == np.asarray(want[k]).tobytes()
+        ours = {s.device: np.asarray(s.data).tobytes()
+                for s in got[k].addressable_shards}
+        assert ours == {s.device: np.asarray(s.data).tobytes()
+                        for s in want[k].addressable_shards}, k
+
+
+@pytest.mark.parametrize("case", list(RESHARDS))
+def test_reshard_counts_what_crossed_and_what_was_resliced(
+        resharder, monkeypatch, case):
+    left, taken, crossed, leafwise = RESHARDS[case]
+    tree, shardings = _reshard_case(left, taken)
+    before = _shard_pointers(tree)
+    calls = []
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda *a, **kw: calls.append(a) or put(*a, **kw))
+    got, stats = resharder._reshard_tree(tree, shardings, account=True)
+    monkeypatch.undo()
+    assert stats["arrays_crossed"] == crossed
+    assert stats["leaves_leafwise"] == leafwise
+    assert stats["leaves"] == 7 and stats["source"] == "local"
+    # one call for all that crosses, one for all that is re-sliced
+    assert len(calls) == (crossed > 0) + (leafwise > 0)
+    # every shard a device held under the index it keeps is the old
+    # leaf's own buffer, but where the leaf crossed in a stack: then
+    # every device holds a cut of the stack that arrived
+    resliced = {"cols"} if taken[1] else {"rows", "rows2"}
+    stacked = {k for pair in TWINS.items() for k in pair} if crossed else set()
+    kept = 0
+    for k in sorted(set(tree) - resliced):
+        held = tree[k].sharding.devices_indices_map(tree[k].shape)
+        takes = shardings[k].devices_indices_map(tree[k].shape)
+        ours = {s.device: s.data.unsafe_buffer_pointer()
+                for s in tree[k].addressable_shards}
+        for s in got[k].addressable_shards:
+            if held.get(s.device) == takes[s.device]:
+                same = s.data.unsafe_buffer_pointer() == ours[s.device]
+                assert same == (k not in stacked), k
+                kept += same
+    assert kept > 0
+    # the plan only read the old tree
+    assert _shard_pointers(tree) == before
+
+
+def test_a_move_made_before_builds_no_program(resharder):
+    """The two programs that stack and cut a move's leaves are kept by
+    what they were built for: the same move again finds them."""
+    tree, shardings = _reshard_case((2, {}), (4, {}))
+    resharder._reshard_tree(tree, shardings)
+    held = dict(resharder._reshard_programs)
+    assert held
+    [key] = [k for k in held if [g[4] for g in k] == [2]
+             and k[0][2] == tree["a"].sharding]
+    again, _ = _reshard_case((2, {}), (4, {}))
+    resharder._reshard_tree(again, shardings)
+    assert resharder._reshard_programs == held
+    pack, cut = held[key]
+    assert pack._cache_size() == 1 and cut._cache_size() == 1
+
+
+def test_a_large_leaf_crosses_on_its_own(resharder, monkeypatch):
+    """Stacking holds a second copy of what it stacks: leaves above the
+    size where that is a bargain cross shard by shard."""
+    tree, shardings = _reshard_case((2, {}), (4, {}))
+    monkeypatch.setattr(trainer_mod, "_STACK_LEAF_BYTES", 8 * 6 * 4 - 1)
+    got, stats = resharder._reshard_tree(tree, shardings)
+    assert stats["arrays_crossed"] == 10       # a and a2 apart: 2 more
+    assert np.array_equal(np.asarray(got["a2"]), np.asarray(tree["a2"]))
+
+
+def test_a_shrink_moves_nothing_and_keeps_every_shard():
+    """4 -> 2 of replicated state: each chip that stays holds the very
+    buffers it held; nothing is handed to the runtime."""
+    tr = _trainer(4)
+    try:
+        _steps(tr, BATCHES[:1])
+        before = _shard_pointers(tr.train_state)
+        obs_trace.TRACER.clear()
+        tr.live_resize(2)
+        after = _shard_pointers(tr.train_state)
+        assert len(after) == len(before) // 2
+        assert all(before[at] == ptr for at, ptr in after.items())
+        _steps(tr, BATCHES[1:2])
+        [put] = obs_trace.TRACER.find(name="resize.device_put")
+        assert put["tags"]["arrays_crossed"] == 0
+        assert put["tags"]["leaves_leafwise"] == 0
+        assert put["tags"]["bytes_moved"] == 0
+    finally:
+        tr.close()
+
+
+def test_a_grow_crosses_in_one_batched_call(monkeypatch):
+    """2 -> 4 of three replicated leaves: six arrays cross, all in one
+    `jax.device_put`; the chips that stay keep their buffers."""
+    tr = _trainer(2)
+    try:
+        _steps(tr, BATCHES[:1])
+        before = _shard_pointers(tr.train_state)
+        obs_trace.TRACER.clear()
+        calls = []
+        put = jax.device_put
+        monkeypatch.setattr(
+            jax, "device_put",
+            lambda *a, **kw: calls.append(a) or put(*a, **kw))
+        tr.live_resize(4)
+        monkeypatch.undo()
+        assert 1 <= len(calls) <= 2
+        assert [len(a[0]) for a in calls if isinstance(a[0], list)] == [6]
+        after = _shard_pointers(tr.train_state)
+        assert len(after) == 2 * len(before)
+        assert all(after[at] == ptr for at, ptr in before.items())
+        _steps(tr, BATCHES[1:2])
+        [put_span] = obs_trace.TRACER.find(name="resize.device_put")
+        tags = put_span["tags"]
+        assert tags["leaves"] == 3
+        assert tags["arrays_crossed"] == 6
+        assert tags["leaves_leafwise"] == 0
+        assert tags["bytes_moved"] == 2 * tags["bytes"]
+    finally:
+        tr.close()
+
+
+def test_a_subtree_s_one_sharding_stands_for_its_leaves(resharder):
+    """`shardings` may be a prefix of the tree, as `jax.device_put`
+    takes it: one sharding for a whole subtree."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    tree, _ = _reshard_case((2, {}), (4, {}))
+    repl = NamedSharding(make_mesh(devices=jax.devices()[:4]), P())
+    nested = {"params": {k: tree[k] for k in "abc"}, "step": tree["cols"]}
+    got, stats = resharder._reshard_tree(
+        nested, {"params": repl, "step": repl}, account=True)
+    assert stats["arrays_crossed"] == 8 and stats["leaves_leafwise"] == 0
+    assert len(stats["placements"][2]) == 4
+    for x in jax.tree_util.tree_leaves(got):
+        assert x.sharding == repl
+
+
 def test_job_doctor_reshard_fallback_finding():
     """scope=True fallbacks get their own detector, ranked apart from
     mid-flight rollbacks, with the _live_scope_check reason verbatim in

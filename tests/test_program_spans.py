@@ -281,7 +281,8 @@ def test_device_put_says_what_it_moved_and_what_ran_beside_it(arc, case):
     [put] = [s for s in trace if s["name"] == "resize.device_put"]
     tags = _tags(put)
     assert set(tags) == {"source", "bytes", "leaves", "bytes_moved",
-                         "persist_inflight"}
+                         "persist_inflight", "arrays_crossed",
+                         "leaves_leafwise"}
     assert tags["source"] == "local"
     state = _trainer(1)
     try:
@@ -294,6 +295,9 @@ def test_device_put_says_what_it_moved_and_what_ran_beside_it(arc, case):
     # with what it held, a grow lands the whole tree on each chip gained
     gained = CASES[case]["to_devices"] - CASES[case]["from_devices"]
     assert tags["bytes_moved"] == tags["bytes"] * max(0, gained)
+    # and it crosses as one array a leaf a chip gained, none re-sliced
+    assert tags["arrays_crossed"] == tags["leaves"] * max(0, gained)
+    assert tags["leaves_leafwise"] == 0
     assert tags["persist_inflight"] in (True, False)
     if not case.startswith("memory"):
         assert tags["persist_inflight"] is False    # it saves nothing
