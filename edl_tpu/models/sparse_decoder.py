@@ -262,7 +262,8 @@ class SparseDecoderLayer(nn.Module):
                                    self.experts_per_token) + ({},)
 
     def _index(self, h, proj):
-        """The indexer on stop_gradient(h): (qi, ki, wi, tau). Its four
+        """The indexer on stop_gradient(h): (qi, ki, wi, tau, the packed
+        kept set, lse_i) — `selected_attention`'s ``select``. Its four
         tensors learn from the index loss alone, and nothing upstream of
         them learns from it."""
         from jax.ad_checkpoint import checkpoint_name
@@ -281,13 +282,14 @@ class SparseDecoderLayer(nn.Module):
                 ki = rope(ki[:, :, None], self.rope_theta)[:, :, 0]
             qi, ki, wi = (checkpoint_name(x, n) for x, n in zip(
                 (qi, ki, wi), sparse_attention.SAVED_UNDER_REMAT))
-            tau = checkpoint_name(sparse_attention.index_thresholds(
-                qi, ki, wi, self.select_topk),
-                sparse_attention.SAVED_UNDER_REMAT[3])
+            # the kernels' dispatch as `selected_attention` makes it
+            tau, kept, lse_i = sparse_attention.index_selection(
+                qi, ki, wi, self.select_topk, use_kernel=self.use_flash,
+                interpret=jax.default_backend() == "cpu")
         # for tools that compare the choice (a no-op unless a caller makes
         # the collection mutable)
         self.sow("intermediates", "select", (qi, ki, wi, tau))
-        return qi, ki, wi, tau
+        return qi, ki, wi, tau, kept, lse_i
 
     def _norm(self, name):
         return RMSNorm(self.eps, self.zero_centered_norm, name=name)

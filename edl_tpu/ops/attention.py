@@ -121,8 +121,10 @@ def selected_attention(q, k, v, select, *, dtype, use_flash=None):
     """Causal attention over a LEARNED selection of keys
     (ops/sparse_attention.py): ``select`` = (qi [b, s, hi, di], ki [b, s,
     di], wi [b, s, hi], tau [b, s]) — the indexer's queries, keys and head
-    weights and each query's threshold (``index_thresholds``); query t
-    reads the keys s <= t whose score I[t, s] reaches tau[t]. Returns
+    weights and each query's threshold — and, where ``index_selection``
+    made them with tau, the packed kept set and the indexer's lse that the
+    forward kernel reads in place of the scores; query t reads the keys
+    s <= t whose score I[t, s] reaches tau[t]. Returns
     (context [b, s, heads, dim], kl [b, s], kept [b, s]): the context, the
     indexer's loss per row (its only source of gradient) and the number of
     keys each query read. The same dispatch as :func:`attention_context`:
@@ -135,7 +137,7 @@ def selected_attention(q, k, v, select, *, dtype, use_flash=None):
                                   seq_kv=k.shape[1]) is None
             and sparse_attention.kernel_reason(
                 q.shape[1], k.shape[2], q.shape[-1], select[0].shape[-1],
-                k.dtype.itemsize) is None)
+                k.dtype.itemsize, select[0].shape[2]) is None)
     if use_flash:
         out, kl, kept = sparse_attention.select_attend(
             q, k, v, select, interpret=jax.default_backend() == "cpu")

@@ -723,8 +723,11 @@ def test_keye_remat_keeps_the_thresholds_with_what_depends_on_them(keye):
     """Under remat the layer saves the thresholds WITH the indexer's
     operands they were found from, beside the chosen experts: in bf16
     every gradient leaf matches the layer that recomputes nothing. It saves
-    the selection kernel's own residuals too, so the gradient holds
-    `dsa_fwd` once a layer, as without remat."""
+    the selection kernels' own residuals too — the forward's result and
+    lse, the thresholds' kernel's lse of the indexer — so the gradient
+    holds `dsa_index_tau` and `dsa_fwd` once a layer, as without remat:
+    the kept set the first hands the second has no name and no later
+    reader."""
     cfg, _, fam, w, batch = keye
     plain = _leaves(_keye_loss_and_grad(cfg, fam, w, batch, jnp.bfloat16,
                                         remat=False)[1])
@@ -739,11 +742,14 @@ def test_keye_remat_keeps_the_thresholds_with_what_depends_on_them(keye):
     assert set(sparse_attention.SAVED_RESIDUALS) \
         <= set(sparse_attention.SAVED_UNDER_REMAT) \
         <= set(sparse_decoder.SAVED_UNDER_REMAT)
+    # the kept set lives from the thresholds to the one forward: no name
+    assert len(sparse_attention.SAVED_UNDER_REMAT) == 7 and not any(
+        "mask" in n or "kept" in n for n in sparse_decoder.SAVED_UNDER_REMAT)
     layers = cfg["num_hidden_layers"]
     for remat in (True, False):
         calls = gradient_kernel_calls(fam, cfg, w, batch, remat)
-        # (the thresholds come from `lax.top_k` off the chip)
         assert {n: c for n, c in calls.items() if n.startswith("dsa")} == {
+            sparse_attention.TAU_NAME: layers,
             sparse_attention.FWD_NAME: layers,
             sparse_attention.KL_NAME: layers,
             sparse_attention.BWD_NAME: layers}
