@@ -24,11 +24,12 @@ from benchmark.lib.harness import BenchError, key_from_seed, log
 from benchmark.lib.stats import median
 
 
-def _tree_rel_err(a, b):
-    """||a - b|| / ||b|| over two trees of one structure, float32."""
+def _tree_rel_err(a, b, scale=1.0):
+    """||a / scale - b|| / ||b|| over two trees of one structure, float32,
+    summed leaf by leaf: nothing the size of a tree is made."""
     import jax
     import jax.numpy as jnp
-    num = sum(jnp.sum(jnp.square(x.astype(jnp.float32) - y))
+    num = sum(jnp.sum(jnp.square(x.astype(jnp.float32) / scale - y))
               for x, y in zip(jax.tree_util.tree_leaves(a),
                               jax.tree_util.tree_leaves(b)))
     den = sum(jnp.sum(jnp.square(y)) for y in jax.tree_util.tree_leaves(b))
@@ -77,44 +78,89 @@ def make_trainer(run, j):
         has_aux=has_aux, async_save=True)
 
 
-def _reference_step_fn(j, q):
-    """One jitted plain step, traced once per (job, precision)."""
+#: `last` takes the weights this many times more, unused: the TPU compiler
+#: sizes a program's image (it lies in the device's memory while the program
+#: is loaded, and is read from the compile cache in every run's set-up) by
+#: the arguments it sees, not by what the chip holds — 700 MB for the
+#: weights alone, 110 MB with the 12 bytes a parameter of a trainer beside
+#: them (PERF.md section 6, PR 56). The trainer IS beside them, so the
+#: compiler is shown its size: the same buffers again, not a byte more.
+_WEIGHTS_SHOWN_AGAIN = 3
+
+
+def _reference_step_fns(j, q):
+    """The plain step as the two programs a check can need, each traced
+    once per (job, precision) and only when called: `last(w, batch, *w
+    again) -> (loss, gradient in the program's layout)`, with no optimizer
+    update in it, and `advance(w, m, v, t, batch) -> (loss, gradient, w',
+    m', v')`, the whole step, with m and v donated (never w: the first
+    step's is j["w"], which the controls read again)."""
     import jax
     if q not in j["ref_steps"]:
-        cfg, ref, spec = j["cfg"], j["ref"], j["job"]["optimizer"]
+        cfg, ref, fam = j["cfg"], j["ref"], j["fam"]
+        spec = j["job"]["optimizer"]
 
-        @jax.jit
-        def step(w, m, v, t, batch):
+        def to_program(g):
+            return fam.to_program(g, cfg)[0]
+
+        def last(w, batch, *shown_again):
+            loss, g = ref.loss_and_grad(w, batch, cfg, q)
+            return loss, to_program(g)
+
+        def advance(w, m, v, t, batch):
             loss, g = ref.loss_and_grad(w, batch, cfg, q)
             w2, st = optim.ref_update(spec, w, g, {"m": m, "v": v, "t": t})
-            return loss, j["fam"].to_program(g, cfg)[0], w2, st["m"], st["v"]
+            return loss, to_program(g), w2, st["m"], st["v"]
 
-        j["ref_steps"][q] = step
+        j["ref_steps"][q] = {
+            "last": jax.jit(last, keep_unused=True),
+            "advance": jax.jit(advance, donate_argnums=(1, 2))}
     return j["ref_steps"][q]
 
 
 def reference_steps(j, n_steps, q=None):
     """Losses of the first `n_steps` plain steps and the gradient of the
-    first, in the program's parameter layout."""
-    step = _reference_step_fn(j, q)
-    w = j["w"]
-    st = optim.ref_init(j["job"]["optimizer"], w)
-    m, v = st["m"], st["v"]
+    first, in the program's parameter layout. One step alone (`check_steps`
+    1: the cells whose parameters fill the chip) is `last`: no moments, no
+    new weights. Several steps are all `advance`, the last one's update
+    unread: ONE program to trace and load, as small cells' set-up had."""
+    import jax
+    import jax.numpy as jnp
+    fns = _reference_step_fns(j, q)
+    w, batch = j["w"], j["batch"]
+    if n_steps == 1:
+        loss, g0 = fns["last"](w, batch, *[w] * _WEIGHTS_SHOWN_AGAIN)
+        return [float(loss)], g0
+    # two trees of zeros, not optim.ref_init's one: each is donated
+    m = jax.tree_util.tree_map(jnp.zeros_like, w)
+    v = jax.tree_util.tree_map(jnp.zeros_like, w)
     losses, g0 = [], None
     for t in range(n_steps):
-        loss, g, w, m, v = step(w, m, v, t, j["batch"])
+        loss, g, w, m, v = fns["advance"](w, m, v, t, batch)
         losses.append(float(loss))
         g0 = g if t == 0 else g0
     return losses, g0
 
 
+def _peak_bytes(devices):
+    """The fullest device's high-water mark, as the result line's
+    `memory_peak_bytes` counts it (lib/harness.py)."""
+    stats = [d.memory_stats() or {} for d in devices]
+    return max(int(st.get("peak_bytes_in_use", 0))
+               + int(st.get("peak_bytes_reserved", 0)) for st in stats)
+
+
 def check_first_steps(run, j, trainer, staged):
     """The comparison that decides `correct`; see the module docstring.
-    Returns the numbers compared, for tools/limits.py."""
+    Returns the numbers compared, for tools/limits.py. Where `check_steps`
+    is 1 the device holds, while the reference's step runs, the trainer's
+    state, the reference's weights and its gradient, and nothing else the
+    size of the parameters: the first moment is compared where it lies.
+    A later step donates the state, so there a copy is taken first."""
     import jax
+    import jax.numpy as jnp
     job = j["job"]
     n = job["check_steps"]
-    scale = optim.moment_scale(job["optimizer"])
     losses = []
     for i in range(n):
         loss = trainer.train_step(staged)
@@ -122,11 +168,14 @@ def check_first_steps(run, j, trainer, staged):
         if i == 0:
             moment = optim.first_moment(trainer.train_state["opt_state"],
                                         trainer.train_state["params"])
-            moment = jax.tree_util.tree_map(lambda x: x / scale, moment)
+            if n > 1:
+                moment = jax.tree_util.tree_map(jnp.copy, moment)
         losses.append(float(loss))
     with run.span("setup:reference"):
         ref_losses, g0 = reference_steps(j, n)
-    grad_err = float(jax.jit(_tree_rel_err)(moment, g0))
+    grad_err = float(jax.jit(_tree_rel_err)(
+        moment, g0, optim.moment_scale(job["optimizer"])))
+    run.record(check_peak_bytes=_peak_bytes(run.devices))
     loss_err = max(abs(a - b) / abs(b) for a, b in zip(losses, ref_losses))
     log("first losses: program %s reference %s" % (losses, ref_losses))
     return {"grad_rel_err": grad_err, "loss_rel_err": loss_err}

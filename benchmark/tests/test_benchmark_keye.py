@@ -1,8 +1,8 @@
 """The `keye-dsa-train-16k` cell: end to end at its `tiny` sizes on the CPU
-(one process, as the driver runs it), and its full-size step and its plain
-reference step compiled for a described (not attached) TPU v5e, with
-`memory_analysis` printed — nothing runs there, and a compile that passes
-is not a chip run.
+(one process, as the driver runs it), and its full-size step compiled for a
+described (not attached) TPU v5e, with `memory_analysis` printed — nothing
+runs there, and a compile that passes is not a chip run. The reference's
+step beside the trainer is in test_benchmark_check_memory.py.
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests/test_benchmark_keye.py -s
 """
@@ -126,31 +126,3 @@ def test_train_step_compiles_and_fits(one_chip, monkeypatch):
                      "dsa_bwd": 4, "moe_gmm": 16, "moe_tgmm": 8,
                      "flash_": 0}
     assert total < HBM
-
-
-def test_reference_step_fits_beside_the_trainer(one_chip):
-    """What `correct` holds on the chip at once (kinds/train.py): the
-    trainer's 12 bytes a parameter and its first moment over the scale
-    (4), beside the reference step's arguments, outputs and temporaries."""
-    cfg, job, fam = _cell()
-    ref = harness.load_module("reference", "keye-vl2-30b-a3b")
-    spec = job["optimizer"]
-    w = jax.eval_shape(lambda: ref.init_weights(cfg, jax.random.PRNGKey(0)))
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(w))
-    batch = {"input_ids": jax.ShapeDtypeStruct(
-        (job["batch_per_chip"], job["seq_len"]), jnp.int32)}
-
-    def step(w, m, v, batch):
-        loss, g = ref.loss_and_grad(w, batch, cfg, None)
-        w2, st = optim.ref_update(spec, w, g, {"m": m, "v": v, "t": 0})
-        return loss, fam.to_program(g, cfg)[0], w2, st["m"], st["v"]
-
-    compiled = jax.jit(step).lower(
-        _on(one_chip, w), _on(one_chip, w), _on(one_chip, w),
-        _on(one_chip, batch)).compile()
-    total, m = _report("%s reference step" % CELL, compiled)
-    # m and v are ONE tree of zeros in the run (optim.ref_init)
-    held = (total - 4.0 * n_params) + 16.0 * n_params
-    print("memory_analysis " + json.dumps({
-        "parameters": n_params, "check_resident_gib": held / GIB}))
-    assert held < HBM

@@ -3,6 +3,7 @@
 
 import argparse
 import collections
+import json
 import os
 import time
 
@@ -182,14 +183,84 @@ def test_no_result_for_an_unknown_cell(capfd):
     assert capfd.readouterr().out == ""
 
 
-def _train_numbers(workload, control):
-    train = harness.load_module("kinds", "train")
+def _tiny_run(workload, seed=3):
     run = harness.Run(argparse.Namespace(
-        workload=workload, seed=3, seconds=0, trace=0, cpu_tiny=True),
+        workload=workload, seed=seed, seconds=0, trace=0, cpu_tiny=True),
         time.monotonic())
     run.claim_devices()
+    return run
+
+
+def _train_numbers(workload, control):
+    train = harness.load_module("kinds", "train")
+    run = _tiny_run(workload)
     return (train.compared_numbers(run, [control]).get(control),
             run.traffic["limits"])
+
+
+def _plain_steps(j, n_steps):
+    """The oracle: ONE un-donated step that updates after every gradient,
+    read or not — what kinds/train.py ran until PR 56."""
+    import jax
+    from benchmark.lib import optim
+    cfg, ref, spec = j["cfg"], j["ref"], j["job"]["optimizer"]
+
+    @jax.jit
+    def step(w, m, v, t, batch):
+        loss, g = ref.loss_and_grad(w, batch, cfg, None)
+        w2, st = optim.ref_update(spec, w, g, {"m": m, "v": v, "t": t})
+        return loss, j["fam"].to_program(g, cfg)[0], w2, st["m"], st["v"]
+
+    w = j["w"]
+    st = optim.ref_init(spec, w)
+    m, v = st["m"], st["v"]
+    losses, g0 = [], None
+    for t in range(n_steps):
+        loss, g, w, m, v = step(w, m, v, t, j["batch"])
+        losses.append(float(loss))
+        g0 = g if t == 0 else g0
+    return losses, g0
+
+
+@pytest.mark.parametrize("workload", ["gpt2s-train", "resnet50vd-train",
+                                      "smallthinker-moe-train-8k"])
+@pytest.mark.parametrize("n_steps", [1, 2])
+def test_reference_steps_are_the_plain_loop_s(workload, n_steps):
+    """`last` (one step, no update) and `advance` (several, m and v
+    donated) give the losses and the first gradient of the plain loop,
+    and the seed's weights outlive them: the controls read them again."""
+    import jax
+    train = harness.load_module("kinds", "train")
+    j = train.make_job(_tiny_run(workload))
+    want_losses, want_g0 = _plain_steps(j, n_steps)
+    for _ in range(2):       # a second call finds j["w"] as it was
+        losses, g0 = train.reference_steps(j, n_steps)
+        assert losses == pytest.approx(want_losses, rel=1e-6)
+        assert float(train._tree_rel_err(g0, want_g0)) <= 1e-6
+    assert not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(j["w"]))
+    assert set(j["ref_steps"]) == {None}
+
+
+@pytest.mark.parametrize("workload", ["gpt2s-train",
+                                      "smallthinker-moe-train-8k"])
+def test_program_then_controls_in_one_process(workload):
+    """tools/limits.py's call: the program's check, then the sound
+    reference and the control again, all on the one job's weights — at
+    `check_steps` 2 and at 1."""
+    train = harness.load_module("kinds", "train")
+    run = _tiny_run(workload)
+    got = train.compared_numbers(run, [None, "int8"])
+    assert set(got) == {None, "int8"}
+    for name, limit in run.traffic["limits"].items():
+        assert got[None][name] <= limit
+    assert got["int8"]["grad_rel_err"] > 3 * got[None]["grad_rel_err"]
+    assert got["int8"]["grad_rel_err"] > run.traffic["limits"][
+        "grad_rel_err"]
+    with open(run.out_path) as f:
+        peaks = [json.loads(line)["check_peak_bytes"] for line in f
+                 if "check_peak_bytes" in line]
+    assert len(peaks) == 1    # once a check; the CPU reports no memory
 
 
 def test_int8_control_is_refused_for_a_training_cell():

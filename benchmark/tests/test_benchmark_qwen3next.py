@@ -1,9 +1,9 @@
 """The `qwen3next-gdn-train-16k` cell: end to end at its `tiny` sizes on the
 CPU (one process, as the driver runs it) with every new reader returning a
-number, and its full-size step and its plain reference step compiled for a
-described (not attached) TPU v5e, with `memory_analysis` printed and the
-kernels' calls a step counted — nothing runs there, and a compile that
-passes is not a chip run.
+number, and its full-size step compiled for a described (not attached) TPU
+v5e, with `memory_analysis` printed and the kernels' calls a step counted —
+nothing runs there, and a compile that passes is not a chip run. The
+reference's step beside the trainer is in test_benchmark_check_memory.py.
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests/test_benchmark_qwen3next.py -s
 """
@@ -184,30 +184,3 @@ def test_train_step_compiles_and_fits(one_chip, monkeypatch):
                      "flash_bwd_dkv": 1, "moe_gmm": 16, "moe_tgmm": 8,
                      "dsa_": 0, "bdiff_": 0}
     assert total < HBM
-
-
-def test_reference_step_fits_beside_the_trainer(one_chip):
-    """What `correct` holds on the chip at once (kinds/train.py): the
-    trainer's 12 bytes a parameter and its first moment over the scale
-    (4), beside the reference step's arguments, outputs and temporaries."""
-    cfg, job, fam = _cell()
-    ref = harness.load_module("reference", "qwen3-next-80b-a3b")
-    spec = job["optimizer"]
-    w = jax.eval_shape(lambda: ref.init_weights(cfg, jax.random.PRNGKey(0)))
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(w))
-
-    def step(w, m, v, batch):
-        loss, g = ref.loss_and_grad(w, batch, cfg, None)
-        w2, st = optim.ref_update(spec, w, g, {"m": m, "v": v, "t": 0})
-        return loss, fam.to_program(g, cfg)[0], w2, st["m"], st["v"]
-
-    compiled = jax.jit(step).lower(
-        _on(one_chip, w), _on(one_chip, w), _on(one_chip, w),
-        _on(one_chip, _batch(job))).compile()
-    total, m = _report("%s reference step" % CELL, compiled)
-    # m and v are ONE tree of zeros in the run (optim.ref_init)
-    held = (total - 4.0 * n_params) + 16.0 * n_params
-    print("memory_analysis " + json.dumps({
-        "parameters": n_params, "check_resident_gib": held / GIB}))
-    assert n_params == 259468256
-    assert held < HBM
