@@ -1,11 +1,18 @@
-"""Sparse decoder family: a causal LM whose every layer is a token MIXER
-followed by a top-k mixture of gated-linear-unit experts, with RMSNorm, no
-biases and an untied output head. What differs between the models of the
-family is said by attributes. Per layer, the mixer's kind
-(``mixer_layout``): grouped-query softmax attention, or the GATED DELTA RULE
+"""Sparse decoder family: a causal LM whose layers are a token MIXER, a
+top-k mixture of experts, or — every model but one — the first followed by
+the second, with RMSNorm, no biases in the matrices and an untied output
+head. What differs between the models of the family is said by attributes.
+Per layer, what it holds (``part_layout``: a mixer AND a feed-forward part,
+each behind a norm of its own — the default —, or ONE of the two behind the
+layer's one norm, x + f(norm(x))) and the mixer's kind (``mixer_layout``):
+grouped-query softmax attention, the GATED DELTA RULE
 (``ops/gated_delta.py``: a linear-attention layer whose memory is a
 [key width, value width] float32 matrix a value head, behind a causal
-depthwise convolution and in front of a gated RMSNorm). For an attention
+depthwise convolution and in front of a gated RMSNorm), or a MAMBA-2
+state-space mixer (``ops/ssd.py``: a [head width, state size] float32 matrix
+a head, decayed by a scalar, B and C shared by the heads of a group, behind
+a causal depthwise convolution WITH a bias and in front of a gated RMSNorm
+over each group). For an attention
 layer: rotary positions or none, over the whole head or its leading
 ``rotary_dim``; which keys a query reads — the full causal prefix, a causal
 window, or the ``select_topk`` keys a learned indexer chose
@@ -16,7 +23,9 @@ attention's result from a second half of the query projection
 (``attn_gate``). For the expert part: the router's input (``router_input``:
 the mixer's normed input, i.e. tapped BEFORE the mixer, or the expert
 layer's own normed input); the experts' gate (``expert_activation``: ReLU
-or SiLU); a SHARED expert every token passes through beside the routed ones
+or SiLU) or, for UNGATED experts of two matrices (``expert_gated`` False),
+their hidden layer's activation (``relu2``: squared ReLU); a SHARED expert
+every token passes through beside the routed ones
 (``shared_expert_width``). And whether an RMSNorm's gain is zero-centred,
 ``1 + g`` (``zero_centered_norm``). A layer that holds NO experts
 (``experts_held`` 0) has a dense gated feed-forward part of ``dense_width``
@@ -76,7 +85,7 @@ import jax.numpy as jnp
 import optax
 
 from edl_tpu.ops import block_diffusion_attention as bd_attention
-from edl_tpu.ops import gated_delta, sparse_attention
+from edl_tpu.ops import gated_delta, sparse_attention, ssd
 from edl_tpu.ops.attention import (attention_context,
                                    block_diffusion_attention,
                                    selected_attention)
@@ -107,6 +116,9 @@ BLOCK_DIFFUSION_COUNTERS = ("pairs_attended",)
 #: a running minimum) and the largest |S| at a chunk's end (a running
 #: maximum)
 GATED_DELTA_COUNTERS = ("gdn_chunk_log_decay_min", "gdn_state_absmax")
+#: and, in a model with a Mamba-2 layer, per such layer (0 for any other):
+#: the same two of the SSD scan (``ops/ssd.py``)
+SSD_COUNTERS = ("ssd_chunk_log_decay_min", "ssd_state_absmax")
 #: and, in a looped model, per PASS (``[loop_steps]`` each, not per layer):
 #: the mean over the predicted tokens of the exit distribution p(u) (sums to
 #: 1 over the passes, a running sum over the steps) and of pass u's own
@@ -118,6 +130,8 @@ LOOP_COUNTERS = ("loop_exit_mass", "loop_pass_loss", "loop_stream_rms_max")
 #: over the passes of a step), where not as a running sum
 _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
             "gdn_chunk_log_decay_min": jnp.minimum,
+            "ssd_state_absmax": jnp.maximum,
+            "ssd_chunk_log_decay_min": jnp.minimum,
             "loop_stream_rms_max": jnp.maximum}
 #: what a layer under remat keeps for its backward, the one policy of every
 #: model of the family (a name that no layer of a model emits saves
@@ -129,7 +143,9 @@ _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
 #: (10 ms) or ``bdiff_fwd`` (2.7 ms) in every layer's backward that would
 #: only rebuild them; and the gated delta rule's result and chunk-end
 #: states (335 MB a layer at 16384 tokens of 16 value heads, against a
-#: second ``gdn_fwd``). The band kernels name no residual and run twice.
+#: second ``gdn_fwd``); and the SSD scan's likewise (101 MB a layer at 8192
+#: tokens of 32 heads of 64 x 128, against a second ``ssd_fwd``). The band
+#: kernels name no residual and run twice.
 #: A latent-attention layer names none either — neither the latent with its
 #: rotary key (576 values a token: 9.4 MB a layer at 8192 tokens) nor this
 #: chip's k and v (8 x 320 values a token: 42 MB): its backward starts from
@@ -139,7 +155,8 @@ _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
 SAVED_UNDER_REMAT = (moe.SAVED_UNDER_REMAT
                      + sparse_attention.SAVED_UNDER_REMAT
                      + bd_attention.SAVED_UNDER_REMAT
-                     + gated_delta.SAVED_UNDER_REMAT)
+                     + gated_delta.SAVED_UNDER_REMAT
+                     + ssd.SAVED_UNDER_REMAT)
 
 
 def _init(std=0.02):
@@ -162,6 +179,24 @@ class RMSNorm(nn.Module):
         y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
                                 + self.eps)
         return (y * scale).astype(x.dtype)
+
+
+class GroupRMSNorm(nn.Module):
+    """RMSNorm over each of ``groups`` equal parts of the last axis, one
+    plain gain an entry: a part's statistics are its own, so a chip that
+    holds whole groups norms what it holds as the uncut layer would."""
+    groups: int
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32).reshape(
+            x.shape[:-1] + (self.groups, x.shape[-1] // self.groups))
+        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
+                                + self.eps)
+        return (y.reshape(x.shape) * scale).astype(x.dtype)
 
 
 def rope(x, theta, positions=None, rotary_dim=None):
@@ -200,13 +235,15 @@ def _log_uniform(low, high, transform=jnp.log):
 
 
 class SparseDecoderLayer(nn.Module):
-    """h = norm(x); x' = x + mixer(h), attention or the gated delta rule
-    (``mixer``); u = norm(x'); out = x' + held experts(u) [+ shared
-    expert(u)], routed on h or on u (``router_input``); with no expert held,
-    out = x' + dense feed-forward(u), no router and no routing counters;
-    under ``sandwich_norm`` each sublayer's result is normed before it is
-    added. Returns (out, counters); a selecting layer's counters hold its
-    index loss, which is differentiable (towards the indexer alone)."""
+    """h = norm(x); x' = x + mixer(h), attention, the gated delta rule or a
+    Mamba-2 state-space mixer (``mixer``); u = norm(x'); out = x' + held
+    experts(u) [+ shared expert(u)], routed on h or on u (``router_input``);
+    with no expert held, out = x' + dense feed-forward(u), no router and no
+    routing counters; under ``sandwich_norm`` each sublayer's result is
+    normed before it is added. A layer of ``parts`` "mixer" ends at x', one
+    of "ffn" starts there (x' = x): one sublayer behind one norm. Returns
+    (out, counters); a selecting layer's counters hold its index loss,
+    which is differentiable (towards the indexer alone)."""
     heads: int                 # query heads held here
     kv_heads: int              # key-value heads held here
     head_dim: int
@@ -228,7 +265,7 @@ class SparseDecoderLayer(nn.Module):
     expert_activation: str = "relu"     # or "silu"
     qk_norm: bool = False
     streams: Optional[Tuple[int, int]] = None   # the two-stream block mask
-    mixer: str = "attention"            # or "gated_delta"
+    mixer: str = "attention"            # or "gated_delta", "mamba2"
     gdn_key_heads: int = 0              # key heads of a gated-delta layer
     gdn_value_heads: int = 0            # its value heads, held here
     gdn_head_dim: int = 0               # the width of both
@@ -245,6 +282,14 @@ class SparseDecoderLayer(nn.Module):
     router_scoring: str = "softmax"     # or "sigmoid" (score + bias)
     routed_scaling: float = 1.0         # on a sigmoid router's weights
     shared_expert_gate: bool = True     # a sigmoid gate on the shared expert
+    parts: str = "both"                 # or "mixer", "ffn": that one alone
+    expert_gated: bool = True           # False: experts of two matrices
+    ssm_heads: int = 0                  # Mamba-2 heads held here
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0                 # groups held here: B, C a group
+    ssm_state: int = 0
+    ssm_chunk: int = ssd.CHUNK
+    ssm_first_head: int = 0             # of the whole model's (A's seeding)
 
     def _route(self, x):
         """(idx, weights, what a sigmoid router counts: {} for a softmax)"""
@@ -342,6 +387,57 @@ class SparseDecoderLayer(nn.Module):
             out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hv, dh, d)))
         return out, stats
 
+    def _mamba2(self, h, proj):
+        """The Mamba-2 mixer on the normed input h: (its part of the
+        residual, the scan's two statistics). The projection's columns are
+        laid a GROUP at a time — the group's heads' z, their x, the group's
+        B and C; the heads' steps likewise —, and the convolution's channels
+        with them, so a contiguous split over chips is a split over whole
+        groups, and the gated norm's statistics are a group's own."""
+        b, s, d = h.shape
+        dt, f32 = self.dtype, jnp.float32
+        hh, p, g, n = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                       self.ssm_state)
+        wide = hh // g * p              # a group's heads, side by side
+        with jax.named_scope("ssm.in_proj"):
+            zxbc = jnp.einsum("bsd,dgk->bsgk", h, proj(
+                "in_proj_zxbc", (d, g, 2 * wide + 2 * n)))
+            step = jnp.einsum("bsd,dgk->bsgk", h,
+                              proj("in_proj_dt", (d, g, hh // g)),
+                              preferred_element_type=f32)
+            z = zxbc[..., :wide].reshape(b, s, hh, p)
+        with jax.named_scope("ssm.conv"):
+            channels = g * (wide + 2 * n)
+            mixed = jax.nn.silu(gated_delta.causal_conv(
+                zxbc[..., wide:].reshape(b, s, channels),
+                self.param("conv", _init(), (channels, self.conv_width),
+                           f32),
+                self.param("conv_bias", _init(), (channels,), f32))).reshape(
+                b, s, g, wide + 2 * n).astype(dt)
+            x = mixed[..., :wide].reshape(b, s, hh, p)
+            bm, cm = mixed[..., wide:wide + n], mixed[..., wide + n:]
+        with jax.named_scope("ssm.scan"):
+            first = self.ssm_first_head
+            a_log = self.param(
+                "A_log", lambda key, shape, dtype: jnp.log(
+                    1.0 + first + jnp.arange(shape[0], dtype=dtype)),
+                (hh,), f32)
+            dt_bias = self.param(
+                "dt_bias", _log_uniform(1e-3, 1e-1, lambda x: jnp.log(
+                    jnp.expm1(jnp.maximum(x, 1e-4)))), (hh,), f32)
+            skip = self.param("D", nn.initializers.ones, (hh,), f32)
+            y, stats = ssd.ssd_scan(
+                x, jax.nn.softplus(step.reshape(b, s, hh) + dt_bias), a_log,
+                bm, cm, skip, chunk=self.ssm_chunk,
+                use_kernel=self.use_flash)
+        with jax.named_scope("ssm.norm"):
+            y = GroupRMSNorm(g, self.eps, name="norm_ssm")(
+                (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(
+                    b, s, hh * p)).astype(dt).reshape(b, s, hh, p)
+        with jax.named_scope("ssm.out"):
+            out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hh, p, d)))
+        return out, stats
+
     def _latent_attention(self, h, proj, positions):
         """Multi-head latent attention on the normed input h: its part of
         the residual. W_kv_down [d, latent + rope] is WHOLE on every chip of
@@ -416,6 +512,46 @@ class SparseDecoderLayer(nn.Module):
             return jnp.einsum("bshk,hkd->bsd", a,
                               proj("out", (self.heads, hd, d))), counted
 
+    def _feed_forward(self, x, dense, routing):
+        """The feed-forward part on x, behind its norm: (its part of the
+        residual [b * s, d], the routing's counters; {} for a dense one).
+        ``routing``: (idx, p, counted) where the router read the mixer's
+        normed input, else None and it reads this part's own."""
+        b, s, d = x.shape
+        u = self._norm("norm_moe")(x)
+        gated = self.expert_gated
+        # a gated linear unit's first matrix is gate then up, side by side
+        first = ("gate_up", 2) if gated else ("up", 1)
+        weight = lambda name, shape: self.param(name, _init(), shape,
+                                                jnp.float32)
+        if dense:
+            m, counters = moe.dense_ffn(
+                u.reshape(b * s, d),
+                weight("ffn_" + first[0], (d, first[1] * self.dense_width)),
+                weight("ffn_down", (self.dense_width, d)),
+                activation=self.expert_activation, gated=gated), {}
+        else:
+            idx, p, routed = routing or self._route(u)
+            f = self.expert_width
+            up = weight("experts_" + first[0],
+                        (self.experts_held, d, first[1] * f))
+            down = weight("experts_down", (self.experts_held, f, d))
+            m, counters = moe.held_experts_ffn(
+                u.reshape(b * s, d), idx, p, up, down,
+                self.first_expert, activation=self.expert_activation,
+                gated=gated)
+            counters = dict(counters, **routed)
+        if self.shared_expert_width:
+            fs = self.shared_expert_width
+            m = m + moe.shared_expert_ffn(
+                u.reshape(b * s, d),
+                weight("shared_" + first[0], (d, first[1] * fs)),
+                weight("shared_down", (fs, d)),
+                weight("shared_gate", (d,))
+                if self.shared_expert_gate else None,
+                activation=self.expert_activation, gated=gated)
+        return m, counters
+
     @nn.compact
     def __call__(self, x, positions=None):
         b, s, d = x.shape
@@ -424,69 +560,56 @@ class SparseDecoderLayer(nn.Module):
                                               jnp.float32).astype(dt)
         if self.router_input not in ("attn_norm", "moe_norm"):
             raise ValueError("router_input %r" % (self.router_input,))
-        if self.mixer not in ("attention", "gated_delta"):
+        if self.mixer not in ("attention", "gated_delta", "mamba2"):
             raise ValueError("mixer %r" % (self.mixer,))
+        if self.parts not in ("both", "mixer", "ffn"):
+            raise ValueError("parts %r" % (self.parts,))
         if self.streams and (self.select_topk or self.window):
             raise ValueError("the two-stream block mask takes no selection "
                              "and no window")
-        linear = self.mixer == "gated_delta"
-        if linear and (self.streams or self.select_topk or self.window):
-            raise ValueError("a gated-delta-rule layer takes no mask")
+        has_mixer, has_ffn = self.parts != "ffn", self.parts != "mixer"
+        linear = has_mixer and self.mixer == "gated_delta"
+        state_space = has_mixer and self.mixer == "mamba2"
+        if (linear or state_space) and (self.streams or self.select_topk
+                                        or self.window):
+            raise ValueError("a %s layer takes no mask" % (
+                "gated-delta-rule" if linear else "Mamba-2"))
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError("router_scoring %r" % (self.router_scoring,))
         if self.latent_dim and (
-                linear or self.streams or self.select_topk or self.qk_norm
-                or self.attn_gate or not self.use_rope
+                linear or state_space or self.streams or self.select_topk
+                or self.qk_norm or self.attn_gate or not self.use_rope
                 or self.kv_heads != self.heads):
             raise ValueError(
                 "a latent-attention layer is causal softmax attention with a "
                 "rotary part, as many key-value heads as query heads, and "
                 "no selection, block mask, q/k norm or gate")
         dense = self.experts_held == 0
-        h = self._norm("norm_attn")(x)
-        if self.router_input == "attn_norm" and not dense:
-            idx, p, routed = self._route(h)
-        select = self._index(h, proj) if self.select_topk else None
-        if linear:
-            mixed, counted = self._gated_delta(h, proj)
-        elif self.latent_dim:
-            mixed, counted = self._latent_attention(h, proj, positions), None
-        else:
-            mixed, counted = self._attention(h, proj, positions, select)
-        if self.sandwich_norm:
-            mixed = self._norm("norm_attn_out")(mixed)
-        x = x + mixed
-        u = self._norm("norm_moe")(x)
-        if dense:
-            m, counters = moe.dense_ffn(
-                u.reshape(b * s, d),
-                self.param("ffn_gate_up", _init(),
-                           (d, 2 * self.dense_width), jnp.float32),
-                self.param("ffn_down", _init(), (self.dense_width, d),
-                           jnp.float32),
-                activation=self.expert_activation), {}
-        else:
-            if self.router_input == "moe_norm":
-                idx, p, routed = self._route(u)
-            f = self.expert_width
-            gate_up = self.param("experts_gate_up", _init(),
-                                 (self.experts_held, d, 2 * f), jnp.float32)
-            down = self.param("experts_down", _init(),
-                              (self.experts_held, f, d), jnp.float32)
-            m, counters = moe.held_experts_ffn(
-                u.reshape(b * s, d), idx, p, gate_up, down,
-                self.first_expert, activation=self.expert_activation)
-            counters = dict(counters, **routed)
-        if self.shared_expert_width:
-            fs = self.shared_expert_width
-            m = m + moe.shared_expert_ffn(
-                u.reshape(b * s, d),
-                self.param("shared_gate_up", _init(), (d, 2 * fs),
-                           jnp.float32),
-                self.param("shared_down", _init(), (fs, d), jnp.float32),
-                self.param("shared_gate", _init(), (d,), jnp.float32)
-                if self.shared_expert_gate else None,
-                activation=self.expert_activation)
+        if self.parts != "both" and (self.sandwich_norm or (
+                self.router_input == "attn_norm" and not dense)):
+            raise ValueError("a layer of one sublayer has one norm: no "
+                             "sandwich norm, and the router reads its own "
+                             "layer's normed input")
+        select = counted = routing = None
+        if has_mixer:
+            h = self._norm("norm_attn")(x)
+            if self.router_input == "attn_norm" and not dense:
+                routing = self._route(h)
+            select = self._index(h, proj) if self.select_topk else None
+            if linear:
+                mixed, counted = self._gated_delta(h, proj)
+            elif state_space:
+                mixed, counted = self._mamba2(h, proj)
+            elif self.latent_dim:
+                mixed, counted = self._latent_attention(h, proj,
+                                                        positions), None
+            else:
+                mixed, counted = self._attention(h, proj, positions, select)
+            if self.sandwich_norm:
+                mixed = self._norm("norm_attn_out")(mixed)
+            x = x + mixed
+        m, counters = (self._feed_forward(x, dense, routing) if has_ffn
+                       else (None, {}))
         if select:
             kl, kept = counted
             with jax.named_scope("attn.index_loss"):
@@ -498,9 +621,12 @@ class SparseDecoderLayer(nn.Module):
                     index_loss=kl.mean())
         if self.streams:
             counters = dict(counters, pairs_attended=counted.sum())
-        if linear:
-            counters = dict(counters, **{"gdn_" + n: v
+        if linear or state_space:
+            prefix = "gdn_" if linear else "ssd_"
+            counters = dict(counters, **{prefix + n: v
                                          for n, v in counted.items()})
+        if m is None:
+            return x, counters
         m = m.reshape(b, s, d)
         if self.sandwich_norm:
             m = self._norm("norm_ffn_out")(m)
@@ -544,7 +670,8 @@ class SparseDecoder(nn.Module):
     qk_norm: bool = False
     index_loss_weight: float = 1.0
     block_length: int = 0           # > 0: trained by diffusion over blocks
-    mixer_layout: Sequence[int] = ()    # per layer: 1 = gated delta rule
+    #: per layer: 0 = attention, 1 = gated delta rule, 2 = Mamba-2
+    mixer_layout: Sequence[int] = ()
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
     gdn_head_dim: int = 0
@@ -564,6 +691,29 @@ class SparseDecoder(nn.Module):
     router_scoring: str = "softmax"
     routed_scaling: float = 1.0
     shared_expert_gate: bool = True
+    #: per layer: 0 = a mixer and a feed-forward part, 1 = the mixer alone,
+    #: 2 = the feed-forward part alone
+    part_layout: Sequence[int] = ()
+    expert_gated: bool = True       # False: ungated experts, two matrices
+    ssm_heads: int = 0              # a Mamba-2 layer's heads held here
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_chunk: int = ssd.CHUNK
+    ssm_first_head: int = 0
+
+    def parts(self):
+        """Per layer: "both", "mixer" or "ffn" (``part_layout``)."""
+        layout = tuple(self.part_layout) + (0,) * self.num_layers
+        return tuple(("both", "mixer", "ffn")[flag]
+                     for flag in layout[:self.num_layers])
+
+    def mixers(self):
+        """Per layer: its mixer's kind (``mixer_layout``); of a layer that
+        is a feed-forward part alone, the kind it does not have."""
+        layout = tuple(self.mixer_layout) + (0,) * self.num_layers
+        return tuple(("attention", "gated_delta", "mamba2")[flag]
+                     for flag in layout[:self.num_layers])
 
     def dense_layers(self):
         """Per layer: whether its feed-forward part is dense (no experts,
@@ -576,14 +726,20 @@ class SparseDecoder(nn.Module):
         """What `counter_names` and `init_counters` ask of a model."""
         return dict(selects=self.selects(),
                     gated_delta=any(self.gated_delta_layers()),
+                    ssd=any(self.mamba_layers()),
                     routed=self.experts_held > 0,
                     scored=self.experts_held > 0
                     and self.router_scoring == "sigmoid")
 
     def gated_delta_layers(self):
         """Per layer: whether its mixer is the gated delta rule."""
-        layout = tuple(self.mixer_layout) + (0,) * self.num_layers
-        return tuple(bool(flag) for flag in layout[:self.num_layers])
+        return tuple(part != "ffn" and mixer == "gated_delta"
+                     for part, mixer in zip(self.parts(), self.mixers()))
+
+    def mamba_layers(self):
+        """Per layer: whether its mixer is a Mamba-2 state-space mixer."""
+        return tuple(part != "ffn" and mixer == "mamba2"
+                     for part, mixer in zip(self.parts(), self.mixers()))
 
     def select_layers(self):
         """Per layer: whether it reads a learned selection."""
@@ -604,6 +760,8 @@ class SparseDecoder(nn.Module):
         positions_arg = () if positions is None else (positions,)
         per_layer = []
         linear = self.gated_delta_layers()
+        state_space = self.mamba_layers()
+        parts, mixers = self.parts(), self.mixers()
         dense = self.dense_layers()
         counted = self.counted()
         routing = counter_names(routed=counted["routed"],
@@ -625,7 +783,7 @@ class SparseDecoder(nn.Module):
                 router_input=self.router_input,
                 expert_activation=self.expert_activation,
                 qk_norm=self.qk_norm, streams=streams,
-                mixer="gated_delta" if linear[i] else "attention",
+                mixer=mixers[i],
                 gdn_key_heads=self.gdn_key_heads,
                 gdn_value_heads=self.gdn_value_heads,
                 gdn_head_dim=self.gdn_head_dim, conv_width=self.conv_width,
@@ -641,8 +799,14 @@ class SparseDecoder(nn.Module):
                 router_scoring=self.router_scoring,
                 routed_scaling=self.routed_scaling,
                 shared_expert_gate=self.shared_expert_gate,
+                parts=parts[i], expert_gated=self.expert_gated,
+                ssm_heads=self.ssm_heads, ssm_head_dim=self.ssm_head_dim,
+                ssm_groups=self.ssm_groups, ssm_state=self.ssm_state,
+                ssm_chunk=self.ssm_chunk,
+                ssm_first_head=self.ssm_first_head,
                 name="layer_%d" % i)(x, *positions_arg)
-            if dense[i]:                # beside layers that route: zeros
+            # a layer without the part counts zeros beside those with it
+            if dense[i] or parts[i] == "mixer":
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in routing})
             if self.selects() and not select:
@@ -651,6 +815,9 @@ class SparseDecoder(nn.Module):
             if any(linear) and not linear[i]:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in GATED_DELTA_COUNTERS})
+            if any(state_space) and not state_space[i]:
+                counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
+                                             for n in SSD_COUNTERS})
             per_layer.append(counters)
         return x, per_layer
 
@@ -716,35 +883,36 @@ class SparseDecoder(nn.Module):
 
 
 def counter_names(selects=False, block_diffusion=False, gated_delta=False,
-                  routed=True, scored=False):
+                  routed=True, scored=False, ssd=False):
     """The per-layer counters of a model: the routing's (none where no
     layer holds an expert; a sigmoid router's two with them) and, by what
-    the model does, the selection's, the two-stream attention's or the
-    gated delta rule's."""
+    the model does, the selection's, the two-stream attention's, the
+    gated delta rule's or the SSD scan's."""
     return ((COUNTERS if routed else ())
             + (ROUTE_COUNTERS if scored else ())
             + (SELECT_COUNTERS if selects else ())
             + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ())
-            + (GATED_DELTA_COUNTERS if gated_delta else ()))
+            + (GATED_DELTA_COUNTERS if gated_delta else ())
+            + (SSD_COUNTERS if ssd else ()))
 
 
 def init_counters(num_layers, selects=False, block_diffusion=False,
                   gated_delta=False, routed=True, loop_steps=1,
-                  scored=False):
+                  scored=False, ssd=False):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
     model with a selecting layer, the selection's; for one trained by
     diffusion over blocks, the attention's pairs and the scalar
-    ``loss_tokens``; for one with gated-delta-rule layers, the rule's two
-    (from zero: a log decay is never positive, a size never negative); for
-    a looped one, ``LOOP_COUNTERS``, ``[loop_steps]`` each."""
+    ``loss_tokens``; for one with gated-delta-rule or Mamba-2 layers, the
+    scan's two (from zero: a log decay is never positive, a size never
+    negative); for a looped one, ``LOOP_COUNTERS``, ``[loop_steps]`` each."""
     # one buffer each: the trainer donates its state to the step
     scalars = ("steps",) + (("loss_tokens",) if block_diffusion else ())
     per_pass = LOOP_COUNTERS if loop_steps > 1 else ()
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
          for n in counter_names(selects, block_diffusion, gated_delta,
-                                routed, scored)},
+                                routed, scored, ssd)},
         **{n: jnp.zeros((loop_steps,), jnp.float32) for n in per_pass},
         **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 

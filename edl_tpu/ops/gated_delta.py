@@ -72,16 +72,18 @@ _mm = functools.partial(jnp.einsum, "...ij,...jk->...ik",
                         precision=lax.Precision.HIGHEST)
 
 
-def causal_conv(u, w):
-    """Causal depthwise convolution over the sequence, no bias: u [b, s, c],
-    w [c, width] -> out[t] = sum_j w[:, j] * u[t - (width - 1) + j], u zero
-    before the sequence starts. Float32 sums, float32 out (what follows,
-    an activation, rounds once)."""
+def causal_conv(u, w, bias=None):
+    """Causal depthwise convolution over the sequence: u [b, s, c],
+    w [c, width] -> out[t] = sum_j w[:, j] * u[t - (width - 1) + j] (+
+    ``bias`` [c], where the layer has one), u zero before the sequence
+    starts. Float32 sums, float32 out (what follows, an activation, rounds
+    once)."""
     width = w.shape[1]
     s = u.shape[1]
     padded = jnp.pad(u.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)
-    return sum(padded[:, j:j + s] * w[:, j] for j in range(width))
+    out = sum(padded[:, j:j + s] * w[:, j] for j in range(width))
+    return out if bias is None else out + bias.astype(jnp.float32)
 
 
 @jax.custom_vjp

@@ -28,13 +28,18 @@ GMM_NAME = "moe_gmm"
 TGMM_NAME = "moe_tgmm"
 
 
-def tile_of(n, cap=1024):
-    """Largest multiple of 128 that divides ``n`` and is at most ``cap``;
-    ``n`` itself where there is none (a block may span a whole axis)."""
-    for t in range(cap - cap % 128, 0, -128):
+def tile_of(n, cap=1024, unit=128):
+    """Largest multiple of ``unit`` (128: a block's lanes) that divides
+    ``n`` and is at most ``cap``; ``n`` itself where there is none (a block
+    may span a whole axis)."""
+    for t in range(cap - cap % unit, 0, -unit):
         if n % t == 0:
             return t
     return n
+
+
+#: bytes of one float32 block of ``moe_tgmm``'s result (it is held twice)
+_TGMM_OUT_BYTES = 4 << 20
 
 
 def _gmm_kernel(tile_group_ref, n_used_ref, x_ref, w_ref, o_ref):
@@ -106,7 +111,12 @@ def _tgmm(x, dy, tile_group, n_used, groups, tm, interpret):
     every block of the result is written."""
     m, k = x.shape
     n = dy.shape[1]
-    tk, tn = tile_of(k), tile_of(n)
+    tn = tile_of(n)
+    # a width that no multiple of 128 divides (1856) spans its axis as
+    # lanes; as rows it is cut by the rows' own unit, and the rows are cut
+    # finer where the lanes had to stay whole, so the result's block fits
+    tk = tile_of(k, min(1024, _TGMM_OUT_BYTES // (4 * tn)),
+                 128 if k % 128 == 0 else 16)
     last = lambda i, nu: jnp.minimum(i, nu[0] - 1)
     return pl.pallas_call(
         _tgmm_kernel,

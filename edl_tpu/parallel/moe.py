@@ -225,7 +225,9 @@ ROW_TILE = 512
 #: and is not run again). The choice is saved WITH the products: a
 #: recomputed top-k may break a near tie the other way, and rows laid out by
 #: one choice must not meet products saved under another.
-SAVED_UNDER_REMAT = ("moe.choice", "moe.gate_up", "moe.down")
+#: An UNGATED expert (two matrices) keeps its one up product under a name of
+#: its own, "moe.up", beside the choice and the down product.
+SAVED_UNDER_REMAT = ("moe.choice", "moe.gate_up", "moe.down", "moe.up")
 
 
 def route_top_k(h, router, k):
@@ -451,18 +453,25 @@ def _combine_rows_bwd(tm, res, g):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
-#: the gate's activation in a gated-linear-unit expert, by name
-EXPERT_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+#: an expert's activation, by name: the gate's in a gated linear unit, the
+#: hidden layer's own in an ungated expert (``relu2``: relu(x) squared)
+EXPERT_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+                      "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
-                     tm=ROW_TILE, interpret=None, activation="relu"):
-    """The held experts' part of a top-k gated-linear-unit expert layer:
-    ReGLU (``activation="relu"``, the default) or SwiGLU (``"silu"``).
+                     tm=ROW_TILE, interpret=None, activation="relu",
+                     gated=True):
+    """The held experts' part of a top-k expert layer of gated linear
+    units: ReGLU (``activation="relu"``, the default) or SwiGLU
+    (``"silu"``) — or, with ``gated=False``, of UNGATED experts of two
+    matrices each, down(act(up u)): one grouped product up, the activation,
+    one down, and no gate's product anywhere.
 
     u [T, D] (the layer's normed input), idx/p [T, k] from
-    :func:`route_top_k`, w_gate_up [held, D, 2 * F] (gate then up),
-    w_down [held, F, D]. Returns (m [T, D] in u's dtype:
+    :func:`route_top_k`, w_gate_up [held, D, 2 * F] (gate then up; an
+    ungated expert's up alone, [held, D, F]), w_down [held, F, D]. Returns
+    (m [T, D] in u's dtype:
     sum over the chosen held experts of p * down(act(gate u) * (up u)),
     counters: float32 scalars ``rows_held``, ``load_max``, ``load_mean``,
     ``tokens_unserved``, ``rows_dropped``, ``rows_moved``). The four passes
@@ -486,9 +495,13 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
         gm = functools.partial(grouped_matmul, tile_group=plan["tile_group"],
                                n_used=plan["n_used"], tm=tm,
                                interpret=interpret)
-        gu = checkpoint_name(gm(rows, w_gate_up), SAVED_UNDER_REMAT[1])
         act = EXPERT_ACTIVATIONS[activation]
-        hid = act(gu[:, :f2 // 2]) * gu[:, f2 // 2:]
+        if gated:
+            gu = checkpoint_name(gm(rows, w_gate_up), SAVED_UNDER_REMAT[1])
+            hid = act(gu[:, :f2 // 2]) * gu[:, f2 // 2:]
+        else:
+            hid = act(checkpoint_name(gm(rows, w_gate_up),
+                                      SAVED_UNDER_REMAT[3]))
         y = checkpoint_name(gm(hid, w_down), SAVED_UNDER_REMAT[2])
     with jax.named_scope("moe.combine"):
         m = _combine_rows(y, p, plan, tm)
@@ -511,28 +524,32 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
     return m.astype(u.dtype), counters
 
 
-def _gated_linear_unit(u, w_gate_up, w_down, activation):
+def _gated_linear_unit(u, w_gate_up, w_down, activation, gated=True):
     """down(act(gate u) * (up u)) of a dense gated linear unit, float32 out
     of the last product: u [T, D], w_gate_up [D, 2 * F] (gate then up),
-    w_down [F, D] -> [T, D]."""
+    w_down [F, D] -> [T, D]. ``gated=False``: down(act(up u)), w_gate_up the
+    up matrix alone, [D, F]."""
     dt = u.dtype
     f = w_down.shape[0]
     gu = jnp.dot(u, w_gate_up.astype(dt))
-    hid = EXPERT_ACTIVATIONS[activation](gu[:, :f]) * gu[:, f:]
+    act = EXPERT_ACTIVATIONS[activation]
+    hid = act(gu[:, :f]) * gu[:, f:] if gated else act(gu)
     return jnp.dot(hid, w_down.astype(dt), preferred_element_type=jnp.float32)
 
 
-def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu"):
+def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu",
+                      gated=True):
     """The SHARED expert of a layer that has one beside its routed experts:
     every row passes through it, scaled by a learned sigmoid gate of its
     own — sigmoid(u . w_gate) * down(act(gate u) * (up u)) — or, with
     ``w_gate`` None, by nothing (:func:`dense_ffn`'s arithmetic). A plain
     dense gated linear unit: u [T, D], w_gate_up [D, 2 * F] (gate then up),
-    w_down [F, D], w_gate [D] -> [T, D] in u's dtype. Every chip of an
+    w_down [F, D], w_gate [D] -> [T, D] in u's dtype; ``gated=False``: an
+    ungated one of two matrices, w_gate_up [D, F]. Every chip of an
     expert-parallel group computes it alike, so where the shares of a layer
     are added up it is counted once."""
     with jax.named_scope("moe.shared"):
-        y = _gated_linear_unit(u, w_gate_up, w_down, activation)
+        y = _gated_linear_unit(u, w_gate_up, w_down, activation, gated)
         if w_gate is None:
             return y.astype(u.dtype)
         gate = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32),
@@ -540,11 +557,11 @@ def shared_expert_ffn(u, w_gate_up, w_down, w_gate, activation="silu"):
         return (y * gate[:, None]).astype(u.dtype)
 
 
-def dense_ffn(u, w_gate_up, w_down, activation="silu"):
+def dense_ffn(u, w_gate_up, w_down, activation="silu", gated=True):
     """The feed-forward part of a layer WITHOUT experts: the same gated
-    linear unit for every row, no router, no gate of its own and nothing
-    to count. u [T, D], w_gate_up [D, 2 * F], w_down [F, D] -> [T, D] in
-    u's dtype."""
+    linear unit (``gated=False``: ungated, of two matrices) for every row,
+    no router, no gate of its own and nothing to count. u [T, D], w_gate_up
+    [D, 2 * F] ([D, F] ungated), w_down [F, D] -> [T, D] in u's dtype."""
     with jax.named_scope("ffn.dense"):
-        return _gated_linear_unit(u, w_gate_up, w_down,
-                                  activation).astype(u.dtype)
+        return _gated_linear_unit(u, w_gate_up, w_down, activation,
+                                  gated).astype(u.dtype)

@@ -370,10 +370,14 @@ def test_routed_layers_in_a_loop_count_once_a_step():
 
 # -- (c) the counters, through the trainer, to the readers -------------------
 
-def test_trainer_mirrors_the_loop_s_counters(ouro):
+def test_trainer_mirrors_the_loop_s_counters(ouro, monkeypatch):
     import optax
+    from edl_tpu.runtime import trainer as trainer_mod
     from edl_tpu.runtime.mesh import make_mesh
     from edl_tpu.runtime.trainer import ElasticTrainer
+    # the gauge is the process's: what a routed model's test mirrored before
+    # this one (another file, the same worker) is not this model's to report
+    monkeypatch.setattr(trainer_mod._MODEL_COUNTER, "_children", {})
     cfg, _, fam, w, batch = ouro
     loss_fn, has_aux, _ = fam.train_parts(cfg, {"remat": False})
     # the trainer donates its state; the fixture's arrays stay the tests'
