@@ -20,7 +20,16 @@ thresholds' kernel to the forward and no further.
 
 Four Pallas kernels, each gridded over (batch, query blocks) with k, v and
 ki of the sequence whole in VMEM and every held query head of the block
-served by one tile of the kept set:
+served by one tile of the kept set — and, in the forward, the query heads
+that share a kv head (``group = heads // kv_heads`` of them) by ONE score
+product against each key block, as one block of ``group * block_q`` rows:
+the key block is laid into the MXU once a kv head and not once a head
+(7.33 -> 6.45, 5.65, 4.44 ms a layer at groups of 2, 4, 8, 16384 positions
+and 8 heads of 128 on a v5e, the same bits; PERF.md, PR 58). The whole
+group always: every size measured is faster than a product a head, and a
+group of 1 is a product a head. Softmax and p . v stay a head at a time
+(one p . v a group is slower), and so do the KL's and the backward's
+products (stacked, the one is no faster and the other slower):
 
 - ``dsa_index_tau`` reads qi, ki, wi: the thresholds, EXACT — the row's
   scores go to VMEM as order-preserving integers and the k-th largest is
@@ -29,9 +38,10 @@ served by one tile of the kept set:
   layout: a key block is whole lane slabs of words, unpacked by one AND)
   and the indexer's log-sum-exp over it;
 - ``dsa_fwd`` reads q, k, v and the packed set — no indexer operand, no
-  threshold: online-softmax attention over the kept keys; beside the
-  result the row statistic lse (per head) and the number of keys of the
-  set it applied (what the step really ran);
+  threshold: online-softmax attention over the kept keys, a kv head's
+  query heads scored together; beside the result the row statistic lse
+  (per head) and the number of keys of the set it applied (what the step
+  really ran);
 - ``dsa_index_kl`` reads q, k, lse, qi, ki, wi, tau and the indexer's lse:
   the KL term per row (needs the final lse, so a second pass over the
   tiles, and I's values, so it rebuilds them);
@@ -354,19 +364,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, kept_ref,
             bits.append(mask_ref[0, :, pl.ds(at, lanes)] & one)
         keep = jnp.concatenate(bits, axis=1) != 0
         cnt = cnt + jnp.where(keep, 1.0, 0.0).sum(axis=1, keepdims=True)
-        for h in range(heads):
-            k_blk = k_ref[0, h // group, pl.ds(k_lo, block_k), :]
-            v_blk = v_ref[0, h // group, pl.ds(k_lo, block_k), :]
-            s = jnp.where(keep, _dot(q_ref[0, h], k_blk, _NT) * sm_scale,
-                          _NEG_INF)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-            corr = jnp.exp(m_prev - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * corr + _dot(p.astype(v_blk.dtype),
-                                                  v_blk, _NN)
+        for kv in range(heads // group):
+            k_blk = k_ref[0, kv, pl.ds(k_lo, block_k), :]
+            v_blk = v_ref[0, kv, pl.ds(k_lo, block_k), :]
+            # the kv head's query heads as ONE block of group * block_q
+            # rows: the key block goes through the MXU once for all of
+            # them (a row's scores do not change with the rows beside it)
+            first = kv * group
+            scores = _dot(q_ref[0, first:first + group].reshape(
+                group * block_q, -1), k_blk, _NT).reshape(
+                    group, block_q, block_k)
+            for g in range(group):
+                h = first + g
+                s = jnp.where(keep, scores[g] * sm_scale, _NEG_INF)
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+                corr = jnp.exp(m_prev - m_new)
+                m_ref[h] = m_new
+                l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * corr + _dot(p.astype(v_blk.dtype),
+                                                      v_blk, _NN)
         return cnt
 
     cnt = lax.fori_loop(0, _n_blocks(q_lo, block_q, block_k), body,

@@ -180,21 +180,21 @@ def test_forward_counts_the_keys_of_the_kept_set_it_was_handed(s, topk,
 
 # -- the selection kernels against the dense masked path ---------------------
 
+def _run(fn, inputs, tau, **kw):
+    """((a loss of the result and the index loss, (out, kl, kept)), its
+    gradients by q, k, v, qi, ki, wi)."""
+    def f(q, k, v, qi, ki, wi):
+        out, kl, kept = fn(q, k, v, (qi, ki, wi, tau), **kw)
+        return (jnp.sum(jnp.sin(out.astype(jnp.float32)))
+                + 3.0 * kl.sum()), (out, kl, kept)
+    return jax.value_and_grad(f, argnums=range(6), has_aux=True)(*inputs)
+
+
 def _both(dtype, s, topk, block, heads=4, kv_heads=2):
-    q, k, v, qi, ki, wi = _inputs(dtype, s=s, heads=heads,
-                                  kv_heads=kv_heads)
-    tau = _tau_between(qi, ki, wi, topk)
-
-    def run(fn, **kw):
-        def f(q, k, v, qi, ki, wi):
-            out, kl, kept = fn(q, k, v, (qi, ki, wi, tau), **kw)
-            return (jnp.sum(jnp.sin(out.astype(jnp.float32)))
-                    + 3.0 * kl.sum()), (out, kl, kept)
-        return jax.value_and_grad(f, argnums=range(6), has_aux=True)(
-            q, k, v, qi, ki, wi)
-
-    return (run(sa.select_attend, interpret=True, block=block),
-            run(sa.dense_select_attend), tau)
+    inputs = _inputs(dtype, s=s, heads=heads, kv_heads=kv_heads)
+    tau = _tau_between(*inputs[3:], topk)
+    return (_run(sa.select_attend, inputs, tau, interpret=True, block=block),
+            _run(sa.dense_select_attend, inputs, tau), tau)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
@@ -378,13 +378,17 @@ def _pallas_eqns(jaxpr):
             yield from _pallas_eqns(sub)
 
 
-def test_kernels_form_each_index_product_once_a_pass():
-    """A tile's products: the forward none (it reads the kept set) beside
-    attention's 2 a head; the KL one an index head beside 1 a head; the
-    backward ONE an index head — kept in VMEM for the indexer's backward,
-    whose dqi and dki are 2 more — beside attention's 5 a head."""
-    heads, hi = 4, 8
-    q, k, v, qi, ki, wi = _inputs(jnp.float32, s=32, heads=heads, hi=hi)
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (8, 1), (4, 4)])
+def test_kernels_form_each_index_product_once_a_pass(heads, kv_heads):
+    """A tile's products: the forward none of the indexer's (it reads the
+    kept set) beside attention's — the scores ONE a kv head, for all the
+    query heads that share it, and p . v one a head; the KL one an index
+    head beside 1 a head; the backward ONE an index head — kept in VMEM for
+    the indexer's backward, whose dqi and dki are 2 more — beside
+    attention's 5 a head."""
+    hi = 8
+    q, k, v, qi, ki, wi = _inputs(jnp.float32, s=32, heads=heads,
+                                  kv_heads=kv_heads, hi=hi)
     tau = jnp.zeros((2, 32))
 
     def f(q, k, v, qi, ki, wi):
@@ -395,8 +399,39 @@ def test_kernels_form_each_index_product_once_a_pass():
     found = {eqn.params["name"]: _dots(eqn.params["jaxpr"])
              for eqn in _pallas_eqns(jax.make_jaxpr(jax.grad(f, range(6)))(
                  q, k, v, qi, ki, wi).jaxpr)}
-    assert found == {sa.FWD_NAME: 2 * heads, sa.KL_NAME: hi + heads,
+    assert found == {sa.FWD_NAME: kv_heads + heads, sa.KL_NAME: hi + heads,
                      sa.BWD_NAME: 5 * heads + 3 * hi}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("block", [16, 32])
+def test_a_group_in_one_product_equals_a_product_a_head(dtype, tol, block):
+    """8 query heads on ONE kv head go through a key block as one block of
+    rows; on the same k and v repeated to 8 kv heads (groups of 1) each
+    head has a product of its own, the kernel as it was. A row's scores do
+    not change with the rows beside it: the result, the index loss, the
+    keys kept and every gradient a head owns are the same bits, and dk,
+    dv — a repeated head's share rounded on its own — the same sum."""
+    heads = 8
+    q, k, v, *index = _inputs(dtype, s=64, heads=heads, kv_heads=1)
+    tau = _tau_between(*index, 8)
+
+    def run(k, v):
+        return _run(sa.select_attend, (q, k, v, *index), tau, interpret=True,
+                    block=block)
+
+    ((_, results), grads) = run(k, v)
+    ((_, a_head), a_head_grads) = run(jnp.repeat(k, heads, axis=2),
+                                      jnp.repeat(v, heads, axis=2))
+    for got, want in zip(results + (grads[0],) + grads[3:],
+                         a_head + (a_head_grads[0],) + a_head_grads[3:]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+    for name, got, shares in zip("kv", grads[1:3], a_head_grads[1:3]):
+        want = f32(shares).sum(axis=2, keepdims=True)
+        err = float(np.linalg.norm(f32(got) - want) / np.linalg.norm(want))
+        assert 0 < float(np.abs(want).max()) and err < tol, (name, err)
 
 
 @pytest.mark.parametrize("block", [8, 32])
