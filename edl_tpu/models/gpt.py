@@ -191,23 +191,28 @@ class GptBlock(nn.Module):
     @nn.compact
     def __call__(self, x, decode=False, decode_index=None,
                  prefill=False, prefill_offset=None):
-        h = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
-                         name="ln_attn")(x)
-        x = x + CausalSelfAttention(
-            self.num_heads, self.max_len, self.dtype, self.use_ring,
-            self.use_flash, self.mesh, ring_axis=self.ring_axis,
-            name="attention")(h, decode=decode,
-                              decode_index=decode_index,
-                              prefill=prefill,
-                              prefill_offset=prefill_offset)
-        h = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
-                         name="ln_mlp")(x)
-        h = nn.Dense(self.mlp_dim, dtype=self.dtype,
-                     param_dtype=jnp.float32, name="mlp_up")(h)
-        h = nn.gelu(h)
-        h = nn.Dense(x.shape[-1], dtype=self.dtype,
-                     param_dtype=jnp.float32, name="mlp_down")(h)
-        return x + h
+        # device-side scopes: obs/devtime.py reads them from a profile
+        with jax.named_scope("norm"):
+            h = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
+                             name="ln_attn")(x)
+        with jax.named_scope("attn.full"):
+            x = x + CausalSelfAttention(
+                self.num_heads, self.max_len, self.dtype, self.use_ring,
+                self.use_flash, self.mesh, ring_axis=self.ring_axis,
+                name="attention")(h, decode=decode,
+                                  decode_index=decode_index,
+                                  prefill=prefill,
+                                  prefill_offset=prefill_offset)
+        with jax.named_scope("norm"):
+            h = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
+                             name="ln_mlp")(x)
+        with jax.named_scope("ffn.dense"):
+            h = nn.Dense(self.mlp_dim, dtype=self.dtype,
+                         param_dtype=jnp.float32, name="mlp_up")(h)
+            h = nn.gelu(h)
+            h = nn.Dense(x.shape[-1], dtype=self.dtype,
+                         param_dtype=jnp.float32, name="mlp_down")(h)
+            return x + h
 
 
 class Gpt(nn.Module):
@@ -241,7 +246,8 @@ class Gpt(nn.Module):
         embed = nn.Embed(self.vocab_size, self.d_model,
                          param_dtype=jnp.float32, dtype=jnp.float32,
                          name="word_embed")
-        x = embed(input_ids).astype(self.dtype)
+        with jax.named_scope("embed"):
+            x = embed(input_ids).astype(self.dtype)
         s = input_ids.shape[1]
         if decode:
             if decode_index is None:
@@ -260,9 +266,10 @@ class Gpt(nn.Module):
                 pos_ids = pos_ids + jnp.asarray(prefill_offset, jnp.int32)
             if self.ring_axis:
                 pos_ids = pos_ids + jax.lax.axis_index(self.ring_axis) * s
-        x = x + nn.Embed(self.max_len, self.d_model,
-                         param_dtype=jnp.float32, dtype=self.dtype,
-                         name="pos_embed")(pos_ids)
+        with jax.named_scope("embed"):
+            x = x + nn.Embed(self.max_len, self.d_model,
+                             param_dtype=jnp.float32, dtype=self.dtype,
+                             name="pos_embed")(pos_ids)
         # remat is a TRAINING lever; on the decode/prefill paths it is
         # useless AND nn.remat would trace the boolean kwargs into
         # abstract values (TracerBoolConversionError — caught by the
@@ -281,10 +288,12 @@ class Gpt(nn.Module):
             else:
                 x = block(x, decode=decode, decode_index=decode_index,
                           prefill=prefill, prefill_offset=prefill_offset)
-        x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
-                         name="ln_final")(x)
+        with jax.named_scope("norm"):
+            x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
+                             name="ln_final")(x)
         # weight-tied LM head (embed.attend = x @ embedding.T)
-        return embed.attend(x.astype(jnp.float32))
+        with jax.named_scope("lm_head"):
+            return embed.attend(x.astype(jnp.float32))
 
 
 class GptEmbed(nn.Module):
@@ -462,8 +471,9 @@ def create_model_and_loss(model=None, dummy_batch=1, dummy_seq=16, **kw):
         logits = model.apply({"params": params}, ids)
         # predict token t+1 from prefix <= t; integer-label form avoids
         # materializing a [b, s, vocab] one-hot at LM vocab sizes
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1], ids[:, 1:]).mean()
+        with jax.named_scope("loss.next_token"):
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], ids[:, 1:]).mean()
 
     return model, params, loss_fn
 

@@ -172,22 +172,25 @@ class ResNet(nn.Module):
         blocks_per_stage, bottleneck = DEPTH_CONFIGS[self.depth]
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         norm = _make_norm(train, self.dtype)
-        x = x.astype(self.dtype)
-        if self.vd:
-            if self.space_to_depth:
-                x = _S2DStemConv(32, self.dtype, name="stem1")(
-                    space_to_depth(x, 2))
+        # device-side scopes (obs/devtime.py): the stem, each stage with
+        # its batch norms (XLA fuses them into the convolutions), the head
+        with jax.named_scope("conv.stem"):
+            x = x.astype(self.dtype)
+            if self.vd:
+                if self.space_to_depth:
+                    x = _S2DStemConv(32, self.dtype, name="stem1")(
+                        space_to_depth(x, 2))
+                else:
+                    x = conv(32, (3, 3), strides=(2, 2), name="stem1")(x)
+                x = nn.relu(norm(name="stem_bn1")(x))
+                x = conv(32, (3, 3), name="stem2")(x)
+                x = nn.relu(norm(name="stem_bn2")(x))
+                x = conv(64, (3, 3), name="stem3")(x)
+                x = nn.relu(norm(name="stem_bn3")(x))
             else:
-                x = conv(32, (3, 3), strides=(2, 2), name="stem1")(x)
-            x = nn.relu(norm(name="stem_bn1")(x))
-            x = conv(32, (3, 3), name="stem2")(x)
-            x = nn.relu(norm(name="stem_bn2")(x))
-            x = conv(64, (3, 3), name="stem3")(x)
-            x = nn.relu(norm(name="stem_bn3")(x))
-        else:
-            x = conv(64, (7, 7), strides=(2, 2), name="stem")(x)
-            x = nn.relu(norm(name="stem_bn")(x))
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+                x = conv(64, (7, 7), strides=(2, 2), name="stem")(x)
+                x = nn.relu(norm(name="stem_bn")(x))
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
 
         block_cls = BottleneckBlock if bottleneck else BasicBlock
         if self.remat:
@@ -202,13 +205,16 @@ class ResNet(nn.Module):
                 zip(self.stage_filters, blocks_per_stage)):
             for i in range(n_blocks):
                 stride = 2 if stage > 0 and i == 0 else 1
-                x = block_cls(filters, stride, self.vd, self.dtype,
-                              name="stage%d_block%d" % (stage, i),
-                              **block_kw)(x, train)
+                with jax.named_scope(("conv.stage1", "conv.stage2",
+                                      "conv.stage3", "conv.stage4")[stage]):
+                    x = block_cls(filters, stride, self.vd, self.dtype,
+                                  name="stage%d_block%d" % (stage, i),
+                                  **block_kw)(x, train)
 
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32,
-                     param_dtype=jnp.float32, name="head")(x)
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32,
+                         param_dtype=jnp.float32, name="head")(x)
         return x
 
 
@@ -248,10 +254,11 @@ def create_model_and_loss(depth=50, num_classes=1000, vd=True,
         logits, updated = model.apply(
             {"params": params, "batch_stats": extra["batch_stats"]},
             batch["image"], train=True, mutable=["batch_stats"])
-        labels = batch["label"]
-        one_hot = optax.smooth_labels(
-            jax.nn.one_hot(labels, num_classes), label_smoothing)
-        loss = optax.softmax_cross_entropy(logits, one_hot).mean()
+        with jax.named_scope("head"):
+            labels = batch["label"]
+            one_hot = optax.smooth_labels(
+                jax.nn.one_hot(labels, num_classes), label_smoothing)
+            loss = optax.softmax_cross_entropy(logits, one_hot).mean()
         return loss, {"batch_stats": updated["batch_stats"]}
 
     return model, params, {"batch_stats": batch_stats}, loss_fn

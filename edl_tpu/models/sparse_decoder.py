@@ -339,6 +339,11 @@ class SparseDecoderLayer(nn.Module):
     def _norm(self, name):
         return RMSNorm(self.eps, self.zero_centered_norm, name=name)
 
+    def _own_norm(self, name, x):
+        """A norm of the layer's own, outside any mixer's scope."""
+        with jax.named_scope("norm"):
+            return self._norm(name)(x)
+
     def _gated_delta(self, h, proj):
         """The linear-attention mixer on the normed input h: (its part of
         the residual, the rule's two statistics). The projection's columns
@@ -518,7 +523,7 @@ class SparseDecoderLayer(nn.Module):
         ``routing``: (idx, p, counted) where the router read the mixer's
         normed input, else None and it reads this part's own."""
         b, s, d = x.shape
-        u = self._norm("norm_moe")(x)
+        u = self._own_norm("norm_moe", x)
         gated = self.expert_gated
         # a gated linear unit's first matrix is gate then up, side by side
         first = ("gate_up", 2) if gated else ("up", 1)
@@ -592,7 +597,7 @@ class SparseDecoderLayer(nn.Module):
                              "layer's normed input")
         select = counted = routing = None
         if has_mixer:
-            h = self._norm("norm_attn")(x)
+            h = self._own_norm("norm_attn", x)
             if self.router_input == "attn_norm" and not dense:
                 routing = self._route(h)
             select = self._index(h, proj) if self.select_topk else None
@@ -606,7 +611,7 @@ class SparseDecoderLayer(nn.Module):
             else:
                 mixed, counted = self._attention(h, proj, positions, select)
             if self.sandwich_norm:
-                mixed = self._norm("norm_attn_out")(mixed)
+                mixed = self._own_norm("norm_attn_out", mixed)
             x = x + mixed
         m, counters = (self._feed_forward(x, dense, routing) if has_ffn
                        else (None, {}))
@@ -629,7 +634,7 @@ class SparseDecoderLayer(nn.Module):
             return x, counters
         m = m.reshape(b, s, d)
         if self.sandwich_norm:
-            m = self._norm("norm_ffn_out")(m)
+            m = self._own_norm("norm_ffn_out", m)
         return x + m, counters
 
 
@@ -823,7 +828,9 @@ class SparseDecoder(nn.Module):
 
     def _head(self, x, streams=None):
         """The final norm and the untied head: float32 logits."""
-        x = RMSNorm(self.eps, self.zero_centered_norm, name="norm_final")(x)
+        with jax.named_scope("norm"):
+            x = RMSNorm(self.eps, self.zero_centered_norm,
+                        name="norm_final")(x)
         with jax.named_scope("loss.block_diffusion" if streams
                              else "lm_head"):
             head = self.param("lm_head", _init(),
@@ -839,23 +846,25 @@ class SparseDecoder(nn.Module):
         normed result into the next and yields its logits, its exit-gate
         score and the root mean square of its stream before that norm."""
         def one_pass(mdl, x, _):
-            with jax.named_scope("loop.pass"):
-                z, per_layer = mdl._stack(x, positions, None)
-                rms = jnp.sqrt(jnp.mean(jnp.square(z.astype(jnp.float32))))
-                x, logits = mdl._head(z)
-                with jax.named_scope("loop.exit_gate"):
-                    score = jnp.einsum(
-                        "bsd,d->bs", x.astype(jnp.float32),
-                        mdl.param("exit_gate", _init(), (mdl.d_model,),
-                                  jnp.float32)) + mdl.param(
-                        "exit_gate_bias", nn.initializers.zeros, (1,),
-                        jnp.float32)
+            z, per_layer = mdl._stack(x, positions, None)
+            rms = jnp.sqrt(jnp.mean(jnp.square(z.astype(jnp.float32))))
+            x, logits = mdl._head(z)
+            with jax.named_scope("loop.exit_gate"):
+                score = jnp.einsum(
+                    "bsd,d->bs", x.astype(jnp.float32),
+                    mdl.param("exit_gate", _init(), (mdl.d_model,),
+                              jnp.float32)) + mdl.param(
+                    "exit_gate_bias", nn.initializers.zeros, (1,),
+                    jnp.float32)
             return x, (logits, score, rms, per_layer)
 
-        _, (logits, scores, rms, per_layer) = nn.scan(
-            one_pass, variable_broadcast="params",
-            split_rngs={"params": False}, length=self.loop_steps)(
-                self, x, None)
+        # round the scan, not inside its body: the loop's own operations
+        # (the passes' saved values sliced and laid down) carry it too
+        with jax.named_scope("loop.pass"):
+            _, (logits, scores, rms, per_layer) = nn.scan(
+                one_pass, variable_broadcast="params",
+                split_rngs={"params": False}, length=self.loop_steps)(
+                    self, x, None)
         # a layer's counters come out [passes] each: one number a step
         per_layer = [{n: functools.reduce(_RUNNING.get(n, jnp.add), c[n])
                       for n in c} for c in per_layer]
@@ -865,7 +874,8 @@ class SparseDecoder(nn.Module):
     def __call__(self, ids, positions=None, streams=None):
         embed = self.param("embed", _init(), (self.vocab_size, self.d_model),
                            jnp.float32)
-        x = jnp.take(embed, ids, axis=0).astype(self.dtype)
+        with jax.named_scope("embed"):
+            x = jnp.take(embed, ids, axis=0).astype(self.dtype)
         names = counter_names(block_diffusion=bool(streams),
                               **self.counted())
         stacked = lambda per_layer: {
@@ -1017,11 +1027,13 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
                 extra, jax.lax.stop_gradient(counters))
         ids = batch["input_ids"]
         logits, counters = model.apply({"params": params}, ids)
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1], ids[:, 1:]).mean()
-        if model.selects():
-            loss = loss + model.index_loss_weight * (
-                counters["index_loss"].sum() / sum(model.select_layers()))
+        with jax.named_scope("loss.next_token"):
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits[:, :-1], ids[:, 1:]).mean()
+            if model.selects():
+                loss = loss + model.index_loss_weight * (
+                    counters["index_loss"].sum()
+                    / sum(model.select_layers()))
         return loss, accumulate_counters(
             extra, jax.lax.stop_gradient(counters))
 

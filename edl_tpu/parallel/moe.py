@@ -505,23 +505,24 @@ def held_experts_ffn(u, idx, p, w_gate_up, w_down, first_expert,
         y = checkpoint_name(gm(hid, w_down), SAVED_UNDER_REMAT[2])
     with jax.named_scope("moe.combine"):
         m = _combine_rows(y, p, plan, tm)
-    served = plan["slot"] < rows.shape[0]
-    counts = plan["counts"].astype(jnp.float32)
-    counters = {
-        "rows_held": counts.sum(),
-        "load_max": counts.max(),
-        "load_mean": counts.mean(),
-        "tokens_unserved": jnp.sum(
-            jnp.logical_not(served.any(axis=0))).astype(jnp.float32),
-        # every choice of a held expert has a row: the rows placed equal
-        # the choices counted, whatever the routing
-        "rows_dropped": counts.sum() - jnp.sum(
-            plan["slot_choice"] < t * k).astype(jnp.float32),
-        # what one pass into or out of the rows' order visits: the served
-        # rows and each held expert's padding to whole tiles
-        "rows_moved": (plan["n_used"][0] * tm).astype(jnp.float32),
-    }
-    return m.astype(u.dtype), counters
+    with jax.named_scope("moe.dispatch"):   # what the plan counted
+        served = plan["slot"] < rows.shape[0]
+        counts = plan["counts"].astype(jnp.float32)
+        counters = {
+            "rows_held": counts.sum(),
+            "load_max": counts.max(),
+            "load_mean": counts.mean(),
+            "tokens_unserved": jnp.sum(
+                jnp.logical_not(served.any(axis=0))).astype(jnp.float32),
+            # every choice of a held expert has a row: the rows placed
+            # equal the choices counted, whatever the routing
+            "rows_dropped": counts.sum() - jnp.sum(
+                plan["slot_choice"] < t * k).astype(jnp.float32),
+            # what one pass into or out of the rows' order visits: the
+            # served rows and each held expert's padding to whole tiles
+            "rows_moved": (plan["n_used"][0] * tm).astype(jnp.float32),
+        }
+        return m.astype(u.dtype), counters
 
 
 def _gated_linear_unit(u, w_gate_up, w_down, activation, gated=True):

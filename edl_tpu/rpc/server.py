@@ -91,9 +91,10 @@ MAX_PROFILE_S = 60.0
 
 def _try_jax_profile(duration_s):
     """Capture ``duration_s`` of ``jax.profiler`` activity into a temp
-    dir and parse the chrome trace back out. Returns the trace dict or
-    None wherever any part is unavailable (no jax, no profiler plugin,
-    no trace file emitted) — callers fall back to the tracer ring."""
+    dir and parse the chrome trace back out. Returns (the trace dict,
+    the device's self time by scope or None) or None wherever any part
+    is unavailable (no jax, no profiler plugin, no trace file emitted) —
+    callers fall back to the tracer ring."""
     import glob
     import gzip
     import shutil
@@ -116,11 +117,26 @@ def _try_jax_profile(duration_s):
             events = doc.get("traceEvents") or []
             if len(events) > MAX_PROFILE_EVENTS:
                 events = events[:MAX_PROFILE_EVENTS]
-            return {"traceEvents": events, "displayTimeUnit": "ms"}
+            return ({"traceEvents": events, "displayTimeUnit": "ms"},
+                    _device_by_scope(tmp))
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
     except BaseException as e:  # noqa: BLE001 — any failure => fallback
         logger.debug("jax.profiler capture unavailable: %r", e)
+        return None
+
+
+def _device_by_scope(logdir):
+    """``{"scope/phase": ms}`` of device self time over the capture under
+    ``logdir``, by the program's own scopes (``obs.devtime``; largest
+    first), or None where the capture holds no device operation."""
+    try:
+        from edl_tpu.obs import devtime
+        path = devtime.newest_trace(logdir)
+        table = devtime.by_scope(devtime.load(path)) if path else None
+        return devtime.table_ms(table) if table else None
+    except Exception as e:  # noqa: BLE001 — the trace itself still answers
+        logger.debug("device trace not reduced: %r", e)
         return None
 
 
@@ -131,13 +147,18 @@ def _profile_method(duration_s=2.0, source="auto"):
     ring (cheap — no device profiling session). Returns a
     ``profile/v1`` doc whose ``trace`` is chrome-trace JSON either
     way, so ``job_doctor --profile`` merges pods into one Perfetto
-    file without caring which path answered."""
+    file without caring which path answered. Where the capture held a
+    device trace, the doc's ``"device_by_scope"`` is the device's self
+    time over the window by the program's ``jax.named_scope``s,
+    ``{"scope/phase": ms}`` (docs/observability.md §1); where it held
+    none, the key is absent and the doc is what it was."""
     duration_s = max(0.0, min(float(duration_s), MAX_PROFILE_S))
-    trace = None
+    trace = by_scope = None
     used = "tracer_ring"
     if source == "auto":
-        trace = _try_jax_profile(duration_s)
-        if trace is not None:
+        got = _try_jax_profile(duration_s)
+        if got is not None:
+            trace, by_scope = got
             used = "jax.profiler"
     if trace is None:
         # ring fallback: wait out the window so activity DURING it is
@@ -146,9 +167,12 @@ def _profile_method(duration_s=2.0, source="auto"):
         if duration_s > 0:
             time.sleep(duration_s)
         trace = obs_trace.TRACER.chrome_trace()
-    return {"schema": "profile/v1", "ts": time.time(),
-            "pid": os.getpid(), "duration_s": duration_s,
-            "source": used, "trace": trace}
+    doc = {"schema": "profile/v1", "ts": time.time(),
+           "pid": os.getpid(), "duration_s": duration_s,
+           "source": used, "trace": trace}
+    if by_scope:
+        doc["device_by_scope"] = by_scope
+    return doc
 
 
 def _default_workers():

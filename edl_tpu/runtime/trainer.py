@@ -32,6 +32,7 @@ from jax.sharding import (NamedSharding, PartitionSpec as P,
 from edl_tpu.controller import train_status as train_status_mod
 from edl_tpu.controller.env import TrainerEnv
 from edl_tpu.coordination.client import CoordClient
+from edl_tpu.obs import devtime as obs_devtime
 from edl_tpu.obs import events as obs_events
 from edl_tpu.obs import flight as obs_flight
 from edl_tpu.obs import ledger as obs_ledger
@@ -371,9 +372,12 @@ def make_train_step(loss_fn, tx, has_aux=False, remat_policy=None):
                 return loss_fn(params, batch, rng)
             loss, grads = jax.value_and_grad(compute)(train_state["params"])
             extra = train_state["extra"]
-        updates, opt_state = tx.update(grads, train_state["opt_state"],
-                                       train_state["params"])
-        params = optax.apply_updates(train_state["params"], updates)
+        # a device-side scope (obs/devtime.py): whatever `tx` does to
+        # the gradient — its norm, a clip, the moments — and the update
+        with jax.named_scope("optim.update"):
+            updates, opt_state = tx.update(grads, train_state["opt_state"],
+                                           train_state["params"])
+            params = optax.apply_updates(train_state["params"], updates)
         return {
             "params": params,
             "opt_state": opt_state,
@@ -472,11 +476,14 @@ def make_accum_step(loss_fn, tx, accum_steps, has_aux=False,
         (extra, grad_sum, loss_sum), _ = lax.scan(
             body, (train_state["extra"], zeros, jnp.zeros((), jnp.float32)),
             (jnp.arange(accum_steps), batches), length=accum_steps)
-        grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grad_sum)
-        updates, opt_state = tx.update(grads, train_state["opt_state"],
-                                       params)
+        with jax.named_scope("optim.update"):
+            grads = jax.tree_util.tree_map(lambda g: g / accum_steps,
+                                           grad_sum)
+            updates, opt_state = tx.update(grads, train_state["opt_state"],
+                                           params)
+            new_params = optax.apply_updates(params, updates)
         return {
-            "params": optax.apply_updates(params, updates),
+            "params": new_params,
             "opt_state": opt_state,
             "step": train_state["step"] + 1,
             "extra": extra,
@@ -526,10 +533,12 @@ def _make_overlap_accum_step(loss_fn, tx, accum_steps, _maybe_remat,
         grads = jax.tree_util.tree_map(lambda g: g / accum_steps,
                                        grad_sum)
         loss = lax.pmean(loss_sum / accum_steps, axes)
-        updates, opt_state = tx.update(grads, train_state["opt_state"],
-                                       params)
+        with jax.named_scope("optim.update"):
+            updates, opt_state = tx.update(grads, train_state["opt_state"],
+                                           params)
+            new_params = optax.apply_updates(params, updates)
         return {
-            "params": optax.apply_updates(params, updates),
+            "params": new_params,
             "opt_state": opt_state,
             "step": train_state["step"] + 1,
             "extra": train_state["extra"],
@@ -814,6 +823,10 @@ class ElasticTrainer(object):
         self._jit_step = self._build_step()
         # captured at the first step
         self._example_batch_sds = self._example_rng_sds = None
+        # a reader of a device profile finds the step's operation names
+        # here, by mesh; nothing is built until one asks
+        self._scope_tables = {}
+        obs_devtime.register(self.step_scope_table)
         # step executables this process holds ready, by _step_key():
         # what prewarm_resize_compiles compiled, what was loaded from
         # its file, and the step of every world live_resize has left.
@@ -1074,6 +1087,25 @@ class ElasticTrainer(object):
             self._aot_step, key=self._step_key(mesh_n, state_sh, data_sh),
             repl=repl, jit_fallback=jitted)
         return lowered, h.hexdigest()[:24], adopt
+
+    def step_scope_table(self):
+        """{HLO instruction name: op_name} of the step this trainer runs
+        on its current mesh: what `obs.devtime` needs to lay a device
+        trace's operations against the program's `jax.named_scope`s (a
+        trace names an operation, not the scope it was traced under).
+        Built on the first call and kept for that mesh — the step is
+        lowered as `_build_step` lowers it and compiled, which with the
+        persistent compile cache on is a load — and NEVER on the training
+        path: only a reader of a profile calls this (`devtime.register`).
+        {} before the first step, whose batch names the shapes."""
+        if self._example_batch_sds is None:
+            return {}
+        key = self._step_key(self.mesh, self._state_shardings,
+                             self._batch_sharding)
+        if key not in self._scope_tables:
+            self._scope_tables[key] = obs_devtime.op_names(
+                self._step_lowered()[0].compile().as_text())
+        return self._scope_tables[key]
 
     def _prewarm_in_scope(self):
         """Same family as _live_scope_check: prewarm covers any mesh

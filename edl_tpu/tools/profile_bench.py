@@ -3,32 +3,33 @@ breakdown — the perf methodology for this framework (SURVEY.md §6 /
 VERDICT r1 next-step #2: "profile with jax.profiler, iterate").
 
 Captures a ``jax.profiler.trace`` of the ResNet50_vd train step, then
-parses the xplane protobuf directly (the tensorboard profiler plugin in
-this image is ABI-mismatched with its TF) and aggregates device time by
-op class. This is the tool that located the round-2 BN bottleneck:
+reduces it with ``edl_tpu.obs.devtime`` to device SELF time by the
+program's scopes (stem, stages, head, optimizer; forward / backward) and
+by op class. This is the tool that located the round-2 BN bottleneck:
 of a 50 ms step, conv fusions took ~19 ms (~87% MFU over conv time)
 while BatchNorm statistic reductions (``convert_reduce_fusion``) took
 ~15.8 ms.
+
+    python -m edl_tpu.tools.profile_bench --parse_only --logdir DIR \\
+           --steps N [--hlo STEP.txt]
+
+prints the same two tables for a profile that is already saved (an
+operator's capture of a running job). A trace names an operation, not the
+scope it was traced under: ``--hlo`` is the traced program's text
+(``compiled.as_text()``), which carries each operation's name stack;
+without it the by-class table alone says anything.
 
 Usage:
     python -m edl_tpu.tools.profile_bench [--no-s2d] [--batch N]
            [--logdir DIR]
 
-Prints: XLA cost-model FLOPs/step, traced ms/step, and the per-op-class
-device-time table.
+Prints: XLA cost-model FLOPs/step, traced ms/step, and the two
+device-time tables.
 """
 
 import argparse
-import collections
-import glob
-import os
-import re
 import sys
 import time
-
-# must be decided before the first google.protobuf import (jax/tf pull it
-# in): the pre-protobuf-4 generated xplane_pb2 needs the python impl
-os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
 
 
 def build_step(batch, s2d):
@@ -68,49 +69,28 @@ def build_step(batch, s2d):
     return jit_step, jit_nodonate, state, staged, rng
 
 
-def xplane_op_breakdown(logdir, steps):
-    """Aggregate the device 'XLA Ops' line by op class (unique-id suffix
-    stripped). Returns [(op_class, ms_per_step, events, us_per_event)]."""
-    # the generated xplane_pb2 in this image predates protobuf 4's
-    # C-extension descriptor check; the pure-python impl accepts it
-    os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION",
-                          "python")
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except (ImportError, TypeError) as e:
-        print("xplane proto unavailable (%s)" % e)
+def xplane_op_breakdown(logdir, steps, names=None):
+    """The newest trace under ``logdir`` as device SELF time per step
+    (`edl_tpu.obs.devtime`: a `while` or a `conditional` is charged only
+    what its body does not cover, so the rows add up to the device's busy
+    time): ``{"by_scope": [((scope, phase), ms_per_step)], "by_class":
+    [(op_class, ms_per_step)]}``, largest first, or None where the
+    directory holds no trace or the trace no device operation. ``names``:
+    the traced program's ``devtime.op_names`` (default: those of the
+    trainers alive in this process); without them every row of
+    ``by_scope`` reads `unscoped`."""
+    from edl_tpu.obs import devtime
+    path = devtime.newest_trace(logdir)
+    if path is None:
         return None
-
-    paths = glob.glob(os.path.join(logdir, "**/*.xplane.pb"),
-                      recursive=True)
-    if not paths:
+    events = devtime.load(path, names=names)
+    if not events:
         return None
-    space = xplane_pb2.XSpace()
-    with open(sorted(paths)[-1], "rb") as f:
-        space.ParseFromString(f.read())
-    # merge across device planes (one per chip running the same SPMD
-    # program) and report the PER-CHIP average, so multi-chip hosts don't
-    # inflate ms/step by n_chips
-    agg = collections.Counter()
-    cnt = collections.Counter()
-    n_planes = 0
-    for plane in space.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            n_planes += 1
-            for ev in line.events:
-                name = plane.event_metadata[ev.metadata_id].name
-                base = re.sub(r"\.\d+", "", name.split(" = ")[0])
-                agg[base] += ev.duration_ps
-                cnt[base] += 1
-    if n_planes == 0:
-        return None
-    rows = [(base, ps / 1e9 / steps / n_planes, cnt[base],
-             ps / 1e6 / cnt[base]) for base, ps in agg.most_common()]
-    return rows or None
+    rows = lambda table: sorted(((k, sec * 1e3 / steps)
+                                 for k, sec in table.items()),
+                                key=lambda kv: -kv[1])
+    return {"by_scope": rows(devtime.by_scope(events)),
+            "by_class": rows(devtime.by_class(events))}
 
 
 def main(argv=None):
@@ -121,7 +101,21 @@ def main(argv=None):
     ap.set_defaults(s2d=True)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--logdir", default="/tmp/edl_tpu_profile")
+    ap.add_argument("--parse_only", action="store_true",
+                    help="reduce the profile already under --logdir "
+                         "(--steps: the steps it holds); run nothing")
+    ap.add_argument("--hlo", default=None,
+                    help="with --parse_only: the traced program's text "
+                         "(compiled.as_text(), or XLA's dump after "
+                         "optimizations), for the table by scope")
     args = ap.parse_args(argv)
+    if args.parse_only:
+        names = None
+        if args.hlo:
+            from edl_tpu.obs import devtime
+            with open(args.hlo) as f:
+                names = devtime.op_names(f.read())
+        return _print_tables(args.logdir, args.steps, names=names)
 
     import jax
 
@@ -149,16 +143,27 @@ def main(argv=None):
           "overhead — use the device table below)"
           % (args.steps, ms), flush=True)
 
-    rows = xplane_op_breakdown(args.logdir, args.steps)
-    if rows is None:
+    from edl_tpu.obs import devtime
+    names = devtime.op_names(
+        jit_step.lower(state, staged, rng).compile().as_text())
+    return _print_tables(args.logdir, args.steps, flops, names)
+
+
+def _print_tables(logdir, steps, flops=None, names=None):
+    tables = xplane_op_breakdown(logdir, steps, names)
+    if tables is None:
         print("no xplane produced (platform without profiler support)")
         return 1
-    total = sum(r[1] for r in rows)
-    print("device XLA-op time: %.2f ms/step; implied %.1f TFLOP/s"
-          % (total, flops / 1e9 / total))
-    print("%9s %8s %7s  %s" % ("ms/step", "us/event", "events", "op class"))
-    for base, ms_step, n, us in rows[:25]:
-        print("%9.3f %8.1f %7d  %s" % (ms_step, us, n, base[:70]))
+    total = sum(ms for _, ms in tables["by_class"])
+    print("device busy time: %.2f ms/step" % total
+          + ("" if flops is None else "; implied %.1f TFLOP/s"
+             % (flops / 1e9 / total)))
+    print("%9s  %-6s %s" % ("ms/step", "phase", "scope (self time)"))
+    for (scope, phase), ms in tables["by_scope"][:25]:
+        print("%9.3f  %-6s %s" % (ms, phase, scope))
+    print("%9s  %s" % ("ms/step", "op class (self time)"))
+    for base, ms in tables["by_class"][:25]:
+        print("%9.3f  %s" % (ms, base[:70]))
     return 0
 
 
