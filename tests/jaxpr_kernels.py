@@ -26,15 +26,31 @@ def pallas_call_names(jaxpr):
     return names
 
 
-def gradient_kernel_calls(fam, cfg, w, batch, remat):
-    """{kernel name: calls} in the jaxpr of a sparse decoder's gradient
-    (``fam``: its benchmark/program module), the Pallas kernels forced: on
-    the CPU the interpreter's, traced and not run."""
+def gradient_jaxpr(fam, cfg, w, batch, remat):
+    """The jaxpr of a sparse decoder's gradient (``fam``: its
+    benchmark/program module), the Pallas kernels forced: on the CPU the
+    interpreter's, traced and not run."""
     model = fam.build_model(cfg, {"remat": remat}).clone(use_flash=True)
     _, _, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
     params, _ = fam.to_program(w, cfg)
-    return collections.Counter(pallas_call_names(jax.make_jaxpr(jax.grad(
-        lambda p: loss_fn(p, extra, batch, None)[0]))(params).jaxpr))
+    return jax.make_jaxpr(jax.grad(
+        lambda p: loss_fn(p, extra, batch, None)[0]))(params).jaxpr
+
+
+def gradient_kernel_calls(fam, cfg, w, batch, remat):
+    """{kernel name: calls} in :func:`gradient_jaxpr`."""
+    return collections.Counter(pallas_call_names(
+        gradient_jaxpr(fam, cfg, w, batch, remat)))
+
+
+def equations_outside_kernels(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs (remat, custom_vjp,
+    pjit), a `pallas_call`'s own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations_outside_kernels(sub)
 
 
 def _tiny_config(config):

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_kernels import gradient_kernel_calls
+from jaxpr_kernels import (equations_outside_kernels, gradient_jaxpr,
+                           gradient_kernel_calls)
 
 from benchmark.lib import harness, kernel_readers
 from edl_tpu.models import sparse_decoder
@@ -185,6 +186,13 @@ def test_remat_runs_ssd_fwd_once_a_layer(nemotron):
         assert calls[ssd.FWD_NAME] == calls[ssd.BWD_NAME] == 2
         assert calls["moe_gmm"] == 8 and calls["moe_tgmm"] == 4
         assert calls["flash_fwd_resident"] == (2 if remat else 1)
+    # and nothing of the scan is rebuilt round the kernels: no decay matrix
+    # exp(gamma_i - gamma_j) a (head, chunk), forward or backward
+    plane = (cfg["chunk_size"],) * 2
+    exps = [eqn.outvars[0].aval.shape for eqn in equations_outside_kernels(
+        gradient_jaxpr(fam, cfg, w, batch, True))
+        if eqn.primitive.name == "exp"]
+    assert exps and not [s for s in exps if s[-2:] == plane]
 
 
 @pytest.fixture(scope="module")

@@ -1,8 +1,10 @@
 """Mamba-2's state-space duality scan (`ops/ssd.py`): the chunked form, plain
 and in the two Pallas kernels (interpreter), against the recurrence run token
 by token — forward and every cotangent, at a chunk boundary, where a chunk
-forgets, and with the heads of one group sharing B and C; what a layer under
-remat saves; `causal_conv`'s bias; the ungated experts of `parallel/moe.py`.
+forgets, and with the heads of one group sharing B and C (a group of one
+head and one group of all heads among the cases); what a layer under remat
+saves; that nothing of a chunk's local algebra exists outside the kernels;
+`causal_conv`'s bias; the ungated experts of `parallel/moe.py`.
 """
 
 import jax
@@ -10,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_kernels import pallas_call_names
+from jaxpr_kernels import equations_outside_kernels, pallas_call_names
 
 from edl_tpu.ops import gated_delta, ssd
 from edl_tpu.ops.grouped_matmul import tile_of
@@ -21,8 +23,13 @@ HI = jax.lax.Precision.HIGHEST
 #: heads, their width, groups, state size; tokens a chunk in these tests
 H, P, G, N = 4, 8, 2, 16
 CHUNK = 16
-#: less than a chunk (the trainer's dummy), one chunk, several, no whole number
-LENGTHS = [8, 16, 48, 50]
+#: (tokens, groups): less than a chunk (the trainer's dummy), one chunk,
+#: several, no whole number; a group of ONE head, one group of ALL heads
+SHAPES = [(8, G), (16, G), (48, G), (50, G), (48, H), (48, 1)]
+#: what the cotangents are taken of: all six operands; and, over exactly two
+#: chunks, the steps and A alone (the hand-written cotangent of gamma)
+EVERY = tuple(range(6))
+COTANGENTS = [shape + (EVERY,) for shape in SHAPES] + [(32, G, (1, 2))]
 
 
 # -- (a) the scan against its recurrence --------------------------------------
@@ -46,16 +53,16 @@ def _recurrence(x, dt, a_log, b, c, d):
     return jnp.moveaxis(y, 0, 1) + d[:, None] * x
 
 
-def _inputs(s, seed=None):
+def _inputs(s, groups=G, state=N):
     """Steps from 0.001 to 0.4 and A from -1 to -4: a head forgets a chunk
     of 16 as far as exp(-25) and another remembers a thousand tokens."""
-    ks = jax.random.split(jax.random.PRNGKey(s if seed is None else seed), 7)
+    ks = jax.random.split(jax.random.PRNGKey(s), 7)
     x = jax.random.normal(ks[0], (2, s, H, P))
     dt = jnp.exp(jax.random.uniform(ks[1], (2, s, H), minval=-7.0,
                                     maxval=-0.9))
     a_log = jnp.log(1.0 + jnp.arange(H, dtype=jnp.float32))
-    b = jax.random.normal(ks[2], (2, s, G, N))
-    c = jax.random.normal(ks[3], (2, s, G, N))
+    b = jax.random.normal(ks[2], (2, s, groups, state))
+    c = jax.random.normal(ks[3], (2, s, groups, state))
     d = 1.0 + 0.1 * jax.random.normal(ks[4], (H,))
     return (x, dt, a_log, b, c, d), jax.random.normal(ks[5], (2, s, H, P))
 
@@ -64,10 +71,10 @@ def _scan(use_kernel):
     return lambda *a: ssd.ssd_scan(*a, chunk=CHUNK, use_kernel=use_kernel)
 
 
-@pytest.mark.parametrize("s", LENGTHS)
+@pytest.mark.parametrize("s,groups", SHAPES)
 @pytest.mark.parametrize("path", ["plain", "kernels"])
-def test_forward_matches_the_recurrence(s, path):
-    args, _ = _inputs(s)
+def test_forward_matches_the_recurrence(s, groups, path):
+    args, _ = _inputs(s, groups)
     want = _recurrence(*args)
     got, stats = _scan(path == "kernels")(*args)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
@@ -80,15 +87,15 @@ def test_forward_matches_the_recurrence(s, path):
     assert 0.0 < float(stats["state_absmax"]) < 10.0
 
 
-@pytest.mark.parametrize("s", LENGTHS)
+@pytest.mark.parametrize("s,groups,of", COTANGENTS)
 @pytest.mark.parametrize("path", ["plain", "kernels"])
-def test_every_cotangent_matches_the_recurrence(s, path):
-    args, w = _inputs(s)
+def test_every_cotangent_matches_the_recurrence(s, groups, of, path):
+    args, w = _inputs(s, groups)
     want = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * w),
-                    argnums=range(6))(*args)
+                    argnums=of)(*args)
     got = jax.grad(lambda *a: jnp.sum(_scan(path == "kernels")(*a)[0] * w),
-                   argnums=range(6))(*args)
-    assert [g.shape for g in got] == [a.shape for a in args]
+                   argnums=of)(*args)
+    assert [g.shape for g in got] == [args[i].shape for i in of]
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.abs(b).max()),
                                    rtol=2e-3)
@@ -192,21 +199,41 @@ def test_remat_that_saves_the_residuals_runs_the_forward_once(saved,
 
 
 def test_the_kernels_read_b_by_group():
-    """No copy of B a head reaches a kernel: `ssd_fwd`'s fifth operand is
-    [batch x groups, ...], its others [batch x heads, ...]."""
-    args, _ = _inputs(48)
-    jaxpr = jax.make_jaxpr(lambda *a: _scan(True)(*a)[0])(*args).jaxpr
+    """Nothing of a chunk's local algebra exists outside the kernels, forward
+    or backward: both read x's own array (leading size: the batch's) beside
+    B, C and the steps by GROUP (batch x groups), and no array of the
+    gradient's program — an operand or a result of either kernel or anything
+    round them — is a [chunk, chunk] plane a (head, chunk) or a copy of B or
+    C a head (the largest arrays are x's and the chunk-end states')."""
+    state = 24                          # no other size of these tests
+    args, w = _inputs(48, state=state)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jnp.sum(_scan(True)(*a)[0] * w), argnums=range(6)))(
+            *args).jaxpr
 
-    def calls(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from calls(sub)
+    def arrays(jaxpr):
+        """(equation, shape) of every array, a kernel's own body left out."""
+        return [(eqn, tuple(getattr(v.aval, "shape", ())))
+                for eqn in equations_outside_kernels(jaxpr)
+                for v in eqn.invars + eqn.outvars]
 
-    (call,) = list(calls(jaxpr))
-    leading = [v.aval.shape[0] for v in call.invars]
-    assert leading[:5] == [2 * H, 2 * H, 2 * H, 2 * H, 2 * G]
+    seen = arrays(jaxpr)
+    calls = {id(eqn): eqn for eqn, _ in seen
+             if eqn.primitive.name == "pallas_call"}.values()
+    assert sorted(eqn.params["name"] for eqn in calls) == [ssd.BWD_NAME,
+                                                           ssd.FWD_NAME]
+    for eqn in calls:
+        leading = {v.aval.shape[0] for v in eqn.invars + eqn.outvars}
+        assert leading == {2, 2 * G}, (eqn.params["name"], leading)
+    a_head = 2 * H * 48 * min(CHUNK, state)
+    for eqn, shape in seen:
+        assert shape[-2:] != (CHUNK, CHUNK), (eqn.primitive.name, shape)
+        assert int(np.prod(shape)) < a_head, (eqn.primitive.name, shape)
+    # the plain path is what this looks for: M and exp(gamma) * C a head
+    plain = [shape for _, shape in arrays(jax.make_jaxpr(
+        lambda *a: _scan(False)(*a)[0])(*args).jaxpr)]
+    assert (2, H, 3, CHUNK, CHUNK) in plain and (2, H, 3, CHUNK,
+                                                 state) in plain
 
 
 # -- (b) the convolution's bias -----------------------------------------------
