@@ -8,11 +8,13 @@ layer's one norm, x + f(norm(x))) and the mixer's kind (``mixer_layout``):
 grouped-query softmax attention, the GATED DELTA RULE
 (``ops/gated_delta.py``: a linear-attention layer whose memory is a
 [key width, value width] float32 matrix a value head, behind a causal
-depthwise convolution and in front of a gated RMSNorm), or a MAMBA-2
-state-space mixer (``ops/ssd.py``: a [head width, state size] float32 matrix
-a head, decayed by a scalar, B and C shared by the heads of a group, behind
-a causal depthwise convolution WITH a bias and in front of a gated RMSNorm
-over each group). For an attention
+depthwise convolution and in front of a gated RMSNorm), KIMI DELTA
+ATTENTION (the same rule with a decay a KEY CHANNEL, from a low-rank gate;
+a head-wise RMSNorm under a low-rank sigmoid gate on the way out), or a
+MAMBA-2 state-space mixer (``ops/ssd.py``: a [head width, state size]
+float32 matrix a head, decayed by a scalar, B and C shared by the heads of
+a group, behind a causal depthwise convolution WITH a bias and in front of
+a gated RMSNorm over each group). For an attention
 layer: rotary positions or none, over the whole head or its leading
 ``rotary_dim``; which keys a query reads — the full causal prefix, a causal
 window, or the ``select_topk`` keys a learned indexer chose
@@ -37,7 +39,9 @@ MULTI-HEAD LATENT ATTENTION (DeepSeek-V2's): keys and values are rebuilt, a
 head at a time, from ONE latent a token (a down-projection and an RMSNorm,
 then an up-projection to this chip's heads), the rotary part of the key
 (``rope_head_dim`` of the ``head_dim`` q/k width) is one head that all
-query heads read, and values and the result are ``v_head_dim`` wide. The
+query heads read — turned by the positions, or, in a layer without
+positions, as it comes —, and values and the result are ``v_head_dim``
+wide. The
 router may score with a sigmoid and choose by score + a bias
 (``router_scoring``, ``routed_scaling``:
 ``parallel/moe.py:route_sigmoid_top_k``), and the shared expert may go
@@ -119,6 +123,10 @@ GATED_DELTA_COUNTERS = ("gdn_chunk_log_decay_min", "gdn_state_absmax")
 #: and, in a model with a Mamba-2 layer, per such layer (0 for any other):
 #: the same two of the SSD scan (``ops/ssd.py``)
 SSD_COUNTERS = ("ssd_chunk_log_decay_min", "ssd_state_absmax")
+#: and, in a model with a Kimi-Delta-Attention layer, per such layer (0 for
+#: any other): the same two of the rule at a vector decay, the minimum over
+#: the key channels too
+KDA_COUNTERS = ("kda_chunk_log_decay_min", "kda_state_absmax")
 #: and, in a looped model, per PASS (``[loop_steps]`` each, not per layer):
 #: the mean over the predicted tokens of the exit distribution p(u) (sums to
 #: 1 over the passes, a running sum over the steps) and of pass u's own
@@ -132,6 +140,8 @@ _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
             "gdn_chunk_log_decay_min": jnp.minimum,
             "ssd_state_absmax": jnp.maximum,
             "ssd_chunk_log_decay_min": jnp.minimum,
+            "kda_state_absmax": jnp.maximum,
+            "kda_chunk_log_decay_min": jnp.minimum,
             "loop_stream_rms_max": jnp.maximum}
 #: what a layer under remat keeps for its backward, the one policy of every
 #: model of the family (a name that no layer of a model emits saves
@@ -143,7 +153,8 @@ _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
 #: (10 ms) or ``bdiff_fwd`` (2.7 ms) in every layer's backward that would
 #: only rebuild them; and the gated delta rule's result and chunk-end
 #: states (335 MB a layer at 16384 tokens of 16 value heads, against a
-#: second ``gdn_fwd``); and the SSD scan's likewise (101 MB a layer at 8192
+#: second ``gdn_fwd``; a vector decay's likewise, under names of their own,
+#: against a second ``kda_fwd``); and the SSD scan's (101 MB a layer at 8192
 #: tokens of 32 heads of 64 x 128, against a second ``ssd_fwd``). The band
 #: kernels name no residual and run twice.
 #: A latent-attention layer names none either — neither the latent with its
@@ -156,6 +167,7 @@ SAVED_UNDER_REMAT = (moe.SAVED_UNDER_REMAT
                      + sparse_attention.SAVED_UNDER_REMAT
                      + bd_attention.SAVED_UNDER_REMAT
                      + gated_delta.SAVED_UNDER_REMAT
+                     + gated_delta.KDA_SAVED_UNDER_REMAT
                      + ssd.SAVED_UNDER_REMAT)
 
 
@@ -235,9 +247,10 @@ def _log_uniform(low, high, transform=jnp.log):
 
 
 class SparseDecoderLayer(nn.Module):
-    """h = norm(x); x' = x + mixer(h), attention, the gated delta rule or a
-    Mamba-2 state-space mixer (``mixer``); u = norm(x'); out = x' + held
-    experts(u) [+ shared expert(u)], routed on h or on u (``router_input``);
+    """h = norm(x); x' = x + mixer(h), attention, the gated delta rule,
+    Kimi Delta Attention or a Mamba-2 state-space mixer (``mixer``);
+    u = norm(x'); out = x' + held experts(u) [+ shared expert(u)], routed on
+    h or on u (``router_input``);
     with no expert held, out = x' + dense feed-forward(u), no router and no
     routing counters; under ``sandwich_norm`` each sublayer's result is
     normed before it is added. A layer of ``parts`` "mixer" ends at x', one
@@ -265,7 +278,7 @@ class SparseDecoderLayer(nn.Module):
     expert_activation: str = "relu"     # or "silu"
     qk_norm: bool = False
     streams: Optional[Tuple[int, int]] = None   # the two-stream block mask
-    mixer: str = "attention"            # or "gated_delta", "mamba2"
+    mixer: str = "attention"            # or "gated_delta", "mamba2", "kda"
     gdn_key_heads: int = 0              # key heads of a gated-delta layer
     gdn_value_heads: int = 0            # its value heads, held here
     gdn_head_dim: int = 0               # the width of both
@@ -290,6 +303,9 @@ class SparseDecoderLayer(nn.Module):
     ssm_state: int = 0
     ssm_chunk: int = ssd.CHUNK
     ssm_first_head: int = 0             # of the whole model's (A's seeding)
+    kda_heads: int = 0                  # Kimi-Delta-Attention heads held here
+    kda_head_dim: int = 0               # the width of q, k and v alike
+    kda_gate_rank: int = 0              # of the two low-rank gates
 
     def _route(self, x):
         """(idx, weights, what a sigmoid router counts: {} for a softmax)"""
@@ -392,6 +408,58 @@ class SparseDecoderLayer(nn.Module):
             out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hv, dh, d)))
         return out, stats
 
+    def _kda(self, h, proj):
+        """Kimi Delta Attention on the normed input h: (its part of the
+        residual, the rule's two statistics). q, k and v, the convolution's
+        channels, the gates' up-projections, beta, A_log and dt_bias all
+        come a HEAD at a time, so a contiguous split of the heads over
+        chips is a split of every tensor but the two gates'
+        down-projections, which are whole on each chip as a latent's is."""
+        b, s, d = h.shape
+        dt, f32 = self.dtype, jnp.float32
+        hh, dh, rank = self.kda_heads, self.kda_head_dim, self.kda_gate_rank
+        with jax.named_scope("mixer.kda.proj"):
+            parts = [jnp.einsum("bsd,dhk->bshk", h, proj(name, (d, hh, dh)))
+                     for name in ("query", "key", "value")]
+            low = jnp.einsum("bsd,dgr->bsgr", h,
+                             proj("gates_down", (d, 2, rank)))
+            beta = jnp.einsum("bsd,dh->bsh", h, proj("in_proj_b", (d, hh)),
+                              preferred_element_type=f32)
+        with jax.named_scope("mixer.kda.conv"):
+            # q, k and v each through its own channels, one after another,
+            # and rounded once here: the float32 sums, the SiLU and their
+            # cotangents of all three side by side were 1.6 GB of a layer's
+            # backward at 8192 tokens
+            conv = self.param("conv", _init(), (3, hh * dh, self.conv_width),
+                              f32)
+            q, k, v = (jax.nn.silu(gated_delta.causal_conv(
+                x.reshape(b, s, hh * dh), conv[i])).astype(dt).reshape(
+                    b, s, hh, dh) for i, x in enumerate(parts))
+        with jax.named_scope("mixer.kda.gate"):
+            a_log = self.param("A_log", _log_uniform(1.0, 16.0), (hh,), f32)
+            dt_bias = self.param(
+                "dt_bias", _log_uniform(1e-3, 1e-1,
+                                        lambda x: jnp.log(jnp.expm1(x))),
+                (hh, dh), f32)
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(jnp.einsum(
+                "bsr,rhk->bshk", low[:, :, 0], proj("decay_up",
+                                                    (rank, hh, dh)),
+                preferred_element_type=f32) + dt_bias)
+        with jax.named_scope("mixer.kda.scan"):
+            unit = lambda x: x.astype(f32) * jax.lax.rsqrt(jnp.sum(
+                jnp.square(x.astype(f32)), -1, keepdims=True) + 1e-6)
+            o, stats = gated_delta.gated_delta_rule(
+                (unit(q) * dh ** -0.5).astype(dt), unit(k).astype(dt), v, g,
+                jax.nn.sigmoid(beta), use_kernel=self.use_flash)
+        with jax.named_scope("mixer.kda.out"):
+            gate = jnp.einsum("bsr,rhk->bshk", low[:, :, 1],
+                              proj("gate_up", (rank, hh, dh)),
+                              preferred_element_type=f32)
+            y = (RMSNorm(self.eps, name="norm_kda")(o.astype(f32))
+                 * jax.nn.sigmoid(gate)).astype(dt)
+            out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hh, dh, d)))
+        return out, stats
+
     def _mamba2(self, h, proj):
         """The Mamba-2 mixer on the normed input h: (its part of the
         residual, the scan's two statistics). The projection's columns are
@@ -449,7 +517,8 @@ class SparseDecoderLayer(nn.Module):
         a head-parallel group (the latent is not sharded); kv_up, query and
         out are cut by heads. The 192-wide key is built by laying the one
         rotary key beside every head's own part: the band kernels then read
-        it as any key."""
+        it as any key. In a layer without positions (``use_rope`` False)
+        that key part, and q's last ``rope_head_dim``, go UNTURNED."""
         b, s, d = h.shape
         dt = self.dtype
         hd, dr, dc = self.head_dim, self.rope_head_dim, self.latent_dim
@@ -463,7 +532,8 @@ class SparseDecoderLayer(nn.Module):
         with jax.named_scope("attn.latent"):
             q = jnp.einsum("bsd,dhk->bshk", h,
                            proj("query", (d, self.heads, hd)))
-            turn = lambda x: rope(x, self.rope_theta, positions)
+            turn = (lambda x: rope(x, self.rope_theta, positions)) \
+                if self.use_rope else (lambda x: x)
             q = jnp.concatenate([q[..., :dn], turn(q[..., dn:])], axis=-1)
             k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
                 turn(down[:, :, None, dc:]), (b, s, self.heads, dr))],
@@ -565,7 +635,7 @@ class SparseDecoderLayer(nn.Module):
                                               jnp.float32).astype(dt)
         if self.router_input not in ("attn_norm", "moe_norm"):
             raise ValueError("router_input %r" % (self.router_input,))
-        if self.mixer not in ("attention", "gated_delta", "mamba2"):
+        if self.mixer not in ("attention", "gated_delta", "mamba2", "kda"):
             raise ValueError("mixer %r" % (self.mixer,))
         if self.parts not in ("both", "mixer", "ffn"):
             raise ValueError("parts %r" % (self.parts,))
@@ -575,20 +645,22 @@ class SparseDecoderLayer(nn.Module):
         has_mixer, has_ffn = self.parts != "ffn", self.parts != "mixer"
         linear = has_mixer and self.mixer == "gated_delta"
         state_space = has_mixer and self.mixer == "mamba2"
-        if (linear or state_space) and (self.streams or self.select_topk
-                                        or self.window):
+        delta = has_mixer and self.mixer == "kda"
+        if (linear or state_space or delta) and (
+                self.streams or self.select_topk or self.window):
             raise ValueError("a %s layer takes no mask" % (
-                "gated-delta-rule" if linear else "Mamba-2"))
+                "gated-delta-rule" if linear else
+                "Mamba-2" if state_space else "Kimi-Delta-Attention"))
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError("router_scoring %r" % (self.router_scoring,))
-        if self.latent_dim and (
+        if self.latent_dim and not delta and (
                 linear or state_space or self.streams or self.select_topk
-                or self.qk_norm or self.attn_gate or not self.use_rope
+                or self.qk_norm or self.attn_gate
                 or self.kv_heads != self.heads):
             raise ValueError(
-                "a latent-attention layer is causal softmax attention with a "
-                "rotary part, as many key-value heads as query heads, and "
-                "no selection, block mask, q/k norm or gate")
+                "a latent-attention layer is causal softmax attention with "
+                "as many key-value heads as query heads, and no selection, "
+                "block mask, q/k norm or gate")
         dense = self.experts_held == 0
         if self.parts != "both" and (self.sandwich_norm or (
                 self.router_input == "attn_norm" and not dense)):
@@ -605,6 +677,8 @@ class SparseDecoderLayer(nn.Module):
                 mixed, counted = self._gated_delta(h, proj)
             elif state_space:
                 mixed, counted = self._mamba2(h, proj)
+            elif delta:
+                mixed, counted = self._kda(h, proj)
             elif self.latent_dim:
                 mixed, counted = self._latent_attention(h, proj,
                                                         positions), None
@@ -626,8 +700,8 @@ class SparseDecoderLayer(nn.Module):
                     index_loss=kl.mean())
         if self.streams:
             counters = dict(counters, pairs_attended=counted.sum())
-        if linear or state_space:
-            prefix = "gdn_" if linear else "ssd_"
+        if linear or state_space or delta:
+            prefix = "gdn_" if linear else "ssd_" if state_space else "kda_"
             counters = dict(counters, **{prefix + n: v
                                          for n, v in counted.items()})
         if m is None:
@@ -675,7 +749,8 @@ class SparseDecoder(nn.Module):
     qk_norm: bool = False
     index_loss_weight: float = 1.0
     block_length: int = 0           # > 0: trained by diffusion over blocks
-    #: per layer: 0 = attention, 1 = gated delta rule, 2 = Mamba-2
+    #: per layer: 0 = attention, 1 = gated delta rule, 2 = Mamba-2, 3 = Kimi
+    #: Delta Attention
     mixer_layout: Sequence[int] = ()
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
@@ -706,6 +781,9 @@ class SparseDecoder(nn.Module):
     ssm_state: int = 0
     ssm_chunk: int = ssd.CHUNK
     ssm_first_head: int = 0
+    kda_heads: int = 0              # a Kimi-Delta-Attention layer's, held here
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0
 
     def parts(self):
         """Per layer: "both", "mixer" or "ffn" (``part_layout``)."""
@@ -717,7 +795,7 @@ class SparseDecoder(nn.Module):
         """Per layer: its mixer's kind (``mixer_layout``); of a layer that
         is a feed-forward part alone, the kind it does not have."""
         layout = tuple(self.mixer_layout) + (0,) * self.num_layers
-        return tuple(("attention", "gated_delta", "mamba2")[flag]
+        return tuple(("attention", "gated_delta", "mamba2", "kda")[flag]
                      for flag in layout[:self.num_layers])
 
     def dense_layers(self):
@@ -732,6 +810,7 @@ class SparseDecoder(nn.Module):
         return dict(selects=self.selects(),
                     gated_delta=any(self.gated_delta_layers()),
                     ssd=any(self.mamba_layers()),
+                    kda=any(self.kda_layers()),
                     routed=self.experts_held > 0,
                     scored=self.experts_held > 0
                     and self.router_scoring == "sigmoid")
@@ -744,6 +823,11 @@ class SparseDecoder(nn.Module):
     def mamba_layers(self):
         """Per layer: whether its mixer is a Mamba-2 state-space mixer."""
         return tuple(part != "ffn" and mixer == "mamba2"
+                     for part, mixer in zip(self.parts(), self.mixers()))
+
+    def kda_layers(self):
+        """Per layer: whether its mixer is Kimi Delta Attention."""
+        return tuple(part != "ffn" and mixer == "kda"
                      for part, mixer in zip(self.parts(), self.mixers()))
 
     def select_layers(self):
@@ -766,6 +850,7 @@ class SparseDecoder(nn.Module):
         per_layer = []
         linear = self.gated_delta_layers()
         state_space = self.mamba_layers()
+        delta = self.kda_layers()
         parts, mixers = self.parts(), self.mixers()
         dense = self.dense_layers()
         counted = self.counted()
@@ -809,6 +894,8 @@ class SparseDecoder(nn.Module):
                 ssm_groups=self.ssm_groups, ssm_state=self.ssm_state,
                 ssm_chunk=self.ssm_chunk,
                 ssm_first_head=self.ssm_first_head,
+                kda_heads=self.kda_heads, kda_head_dim=self.kda_head_dim,
+                kda_gate_rank=self.kda_gate_rank,
                 name="layer_%d" % i)(x, *positions_arg)
             # a layer without the part counts zeros beside those with it
             if dense[i] or parts[i] == "mixer":
@@ -823,6 +910,9 @@ class SparseDecoder(nn.Module):
             if any(state_space) and not state_space[i]:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in SSD_COUNTERS})
+            if any(delta) and not delta[i]:
+                counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
+                                             for n in KDA_COUNTERS})
             per_layer.append(counters)
         return x, per_layer
 
@@ -893,28 +983,30 @@ class SparseDecoder(nn.Module):
 
 
 def counter_names(selects=False, block_diffusion=False, gated_delta=False,
-                  routed=True, scored=False, ssd=False):
+                  routed=True, scored=False, ssd=False, kda=False):
     """The per-layer counters of a model: the routing's (none where no
     layer holds an expert; a sigmoid router's two with them) and, by what
     the model does, the selection's, the two-stream attention's, the
-    gated delta rule's or the SSD scan's."""
+    gated delta rule's, the SSD scan's or Kimi Delta Attention's."""
     return ((COUNTERS if routed else ())
             + (ROUTE_COUNTERS if scored else ())
             + (SELECT_COUNTERS if selects else ())
             + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ())
             + (GATED_DELTA_COUNTERS if gated_delta else ())
-            + (SSD_COUNTERS if ssd else ()))
+            + (SSD_COUNTERS if ssd else ())
+            + (KDA_COUNTERS if kda else ()))
 
 
 def init_counters(num_layers, selects=False, block_diffusion=False,
                   gated_delta=False, routed=True, loop_steps=1,
-                  scored=False, ssd=False):
+                  scored=False, ssd=False, kda=False):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
     model with a selecting layer, the selection's; for one trained by
     diffusion over blocks, the attention's pairs and the scalar
-    ``loss_tokens``; for one with gated-delta-rule or Mamba-2 layers, the
-    scan's two (from zero: a log decay is never positive, a size never
+    ``loss_tokens``; for one with gated-delta-rule, Mamba-2 or
+    Kimi-Delta-Attention layers, the scan's two (from zero: a log decay is
+    never positive, a size never
     negative); for a looped one, ``LOOP_COUNTERS``, ``[loop_steps]`` each."""
     # one buffer each: the trainer donates its state to the step
     scalars = ("steps",) + (("loss_tokens",) if block_diffusion else ())
@@ -922,7 +1014,7 @@ def init_counters(num_layers, selects=False, block_diffusion=False,
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
          for n in counter_names(selects, block_diffusion, gated_delta,
-                                routed, scored, ssd)},
+                                routed, scored, ssd, kda)},
         **{n: jnp.zeros((loop_steps,), jnp.float32) for n in per_pass},
         **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 

@@ -55,6 +55,11 @@ SCOPES = {
     "ssm.scan": ("mixer", 57),
     "ssm.norm": ("mixer", 57),
     "ssm.out": ("mixer", 57),
+    "mixer.kda.proj": ("mixer", 61),
+    "mixer.kda.conv": ("mixer", 61),
+    "mixer.kda.gate": ("mixer", 61),
+    "mixer.kda.scan": ("mixer", 61),
+    "mixer.kda.out": ("mixer", 61),
     "lm_head": ("head_loss", 27),
     "loss.next_token": ("head_loss", 59),
     "loss.block_diffusion": ("head_loss", 39),

@@ -394,9 +394,15 @@ def test_a_latent_layer_refuses_what_it_cannot_be(kanana):
     model = fam.build_model(cfg, {})
     ids = jnp.zeros((1, 16), jnp.int32)
     for attrs in (dict(qk_norm=True), dict(attn_gate=True),
-                  dict(kv_heads=1), dict(rope_layout=(0, 0, 0))):
+                  dict(kv_heads=1)):
         with pytest.raises(ValueError, match="latent-attention layer"):
             model.clone(**attrs).init(jax.random.PRNGKey(0), ids)
+    # since PR 61 a latent layer may go without positions: its rotary key
+    # part is then read unturned (tests/test_kimi_linear_decoder.py)
+    n = cfg["num_hidden_layers"]
+    bare = model.clone(rope_layout=(0,) * n).init(jax.random.PRNGKey(0), ids)
+    assert jax.tree_util.tree_structure(bare) == jax.tree_util.tree_structure(
+        model.init(jax.random.PRNGKey(0), ids))
     with pytest.raises(ValueError, match="router_scoring"):
         model.clone(router_scoring="tanh").init(jax.random.PRNGKey(0), ids)
 
