@@ -53,6 +53,30 @@ def equations_outside_kernels(jaxpr):
                 yield from equations_outside_kernels(sub)
 
 
+def chunk_local_algebra(jaxpr, chunk, sub, dk, dv, scope=""):
+    """What a jaxpr holds of a vector-decay delta rule's chunk-local algebra
+    (ops/gated_delta.py) OUTSIDE its kernels' bodies, in the equations whose
+    name stack holds ``scope``: a loop (`scan`, `while`: the plain path's
+    `lax.map`s), an `exp` over a diagonal block's [sub, sub, dk] or a
+    chunk's [chunk, chunk] pairs, an array laid a (head, chunk) — [..., n,
+    chunk, chunk] (A, T) or [heads, n, chunk, dk or dv] (U, Wk, Qg, Kg).
+    One line a finding."""
+    found = []
+    for eqn in equations_outside_kernels(jaxpr):
+        if scope not in str(eqn.source_info.name_stack):
+            continue
+        name = eqn.primitive.name
+        if name in ("scan", "while"):
+            found.append(name)
+        for v in eqn.invars + eqn.outvars:
+            shape = tuple(getattr(v.aval, "shape", ()))
+            if shape[-3:] == (sub, sub, dk) or shape[-2:] == (chunk, chunk):
+                found.append("%s %s" % (name, shape))
+            elif len(shape) == 4 and shape[2:] in ((chunk, dk), (chunk, dv)):
+                found.append("%s %s" % (name, shape))
+    return found
+
+
 def _tiny_config(config):
     """A configuration of the benchmark at its `tiny` sizes."""
     cfg = harness.load_json(os.path.join(REPO, "benchmark", "configs",
@@ -66,12 +90,13 @@ def _program_digest(traced):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def traced_gradient(config, remat):
+def traced_gradient(config, remat, kernels=False):
     """(parameter tree of shapes, sha256's first 16 hex digits of the
     gradient's jaxpr — the whole traced program, loss and counters) of a
     sparse-decoder configuration at its `tiny` sizes: equal text is an
     equal program, so equal bits on any machine. The model's own parameter
-    tree must be the one `to_program` gives."""
+    tree must be the one `to_program` gives. ``kernels``: the Pallas
+    kernels forced (the program a TPU traces), not the CPU's plain paths."""
     cfg = _tiny_config(config)
     ref = harness.load_module("reference", config)
     fam = harness.load_module("program", cfg["family"])
@@ -79,6 +104,8 @@ def traced_gradient(config, remat):
     batch = jax.eval_shape(lambda: fam.make_batch(
         cfg, {"seq_len": 32}, jax.random.PRNGKey(1), 2))
     model = fam.build_model(cfg, {"remat": remat})
+    if kernels:
+        model = model.clone(use_flash=True)
     _, own, extra, loss_fn = sparse_decoder.create_model_and_loss(model)
     params = jax.eval_shape(lambda w: fam.to_program(w, cfg)[0], w)
     assert (jax.tree_util.tree_structure(own)

@@ -579,6 +579,25 @@ EXPERTS_WALK_USED_TILES_SINCE_PR_47 = {
 }
 
 
+#: the same digest of Qwen3-Next's gradient WITH THE KERNELS FORCED — the
+#: program a TPU traces: the SCALAR rule's `gdn_fwd` / `gdn_bwd` round their
+#: chunk-local operands in `jax.numpy` — computed on the commit before PR 62
+#: (48d3016), which gave the vector form kernels that form a chunk's
+#: operands themselves and left the scalar form to the letter. (The plain
+#: paths' digests of this model are tests/test_looped_decoder.py's.)
+SCALAR_RULE_ON_ITS_KERNELS_BEFORE_PR_62 = {
+    ("qwen3-next-80b-a3b", True): "a4d330d638ae285b",
+    ("qwen3-next-80b-a3b", False): "c90471517fe8957c",
+}
+
+
+@pytest.mark.parametrize("config,remat",
+                         sorted(SCALAR_RULE_ON_ITS_KERNELS_BEFORE_PR_62))
+def test_the_scalar_rule_on_its_kernels_is_the_program_it_was(config, remat):
+    _, traced = traced_gradient(config, remat, kernels=True)
+    assert traced == SCALAR_RULE_ON_ITS_KERNELS_BEFORE_PR_62[(config, remat)]
+
+
 @pytest.mark.parametrize("config,remat",
                          sorted(EXPERTS_WALK_USED_TILES_SINCE_PR_47))
 def test_accepted_models_give_bit_for_bit_what_they_gave(config, remat):

@@ -3,9 +3,15 @@ Attention's, one rate a key channel) against the recurrence a token at a
 time:
 
 - the chunked rule, forward and every gradient (q, k, v, the log decay,
-  beta), `lax.scan` path and the kernels in the interpreter, at decays
-  strong enough that `exp(-gamma)` WOULD overflow float32 (gamma below -100
-  inside a chunk), every result finite;
+  beta), `lax.scan` path and the kernels in the interpreter (which form a
+  chunk's operands themselves: one head, a program's group of heads, 8 and
+  16 heads, exactly two chunks, a ragged tail), at decays strong enough
+  that `exp(-gamma)` WOULD overflow float32 (gamma below -100 inside a
+  chunk, and below -200), every result finite; the kernels against the
+  plain path in bfloat16;
+- the gradient's program on the kernel path holds ``kda_fwd`` and
+  ``kda_bwd`` once each and nothing of the chunk-local algebra outside
+  them;
 - a log decay constant over the channels gives what the scalar entry gives,
   and the scalar entry still runs `gdn_*` where the vector one runs `kda_*`;
 - no exponential of the rule's jaxpr is taken of a positive number;
@@ -21,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_kernels import pallas_call_names
+from jaxpr_kernels import chunk_local_algebra, pallas_call_names
 
 from edl_tpu.ops import gated_delta as gd
 
@@ -65,31 +71,44 @@ def _inputs(s, strength, b=1, h=2, dk=16, dv=8, seed=0):
     return (q, k, v, a, beta), jax.random.normal(ks[5], (b, s, h, dv))
 
 
-@pytest.fixture(scope="module", params=[0.1, 4.0],
-                ids=["mild", "forgetting"])
+#: (tokens, decay's strength, heads): the first two are the file's own; the
+#: kernel path serves `KDA_HEADS` heads a program and the plain path
+#: `HEAD_GROUP` at a time, so 1 head, one group of 8 and two; 64 tokens are
+#: exactly two chunks, 80 leave a ragged tail; at strength 8 gamma falls
+#: below -200 inside a chunk
+CASES = {"mild": (80, 0.1, 2), "forgetting": (80, 4.0, 2),
+         "one-head": (80, 4.0, 1), "group-of-8": (80, 0.1, 8),
+         "two-groups": (80, 4.0, 16), "two-chunks": (64, 0.1, 2),
+         "two-chunks-forgetting": (64, 4.0, 2),
+         "gamma-under-200": (80, 8.0, 2)}
+
+
+@pytest.fixture(scope="module", params=sorted(CASES))
 def case(request):
-    args, w = _inputs(80, request.param)
+    s, strength, h = CASES[request.param]
+    args, w = _inputs(s, strength, h=h)
     want = token_rule(*args)
     grads = jax.grad(lambda *a: jnp.sum(token_rule(*a) * w),
                      argnums=range(5))(*args)
-    return request.param, args, w, want, grads
+    return strength, args, w, want, grads
 
 
 @pytest.mark.parametrize("use_kernel", [False, True],
                          ids=["scan", "kernels"])
 def test_chunked_rule_matches_the_recurrence(case, use_kernel):
     strength, args, w, want, want_grads = case
+    s, h = args[0].shape[1:3]
     got, stats = gd.gated_delta_rule(*args, chunk=CHUNK,
                                      use_kernel=use_kernel)
     assert got.dtype == args[2].dtype and bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(got, want, atol=2e-6)
     # the statistic is the chunks' own: the most negative cumulative sum
-    a = np.pad(np.asarray(args[3]), ((0, 0), (0, 16), (0, 0), (0, 0)))
-    low = a.reshape(1, 3, CHUNK, 2, 16).sum(axis=2).min()
+    a = np.pad(np.asarray(args[3]), ((0, 0), (0, -s % CHUNK), (0, 0), (0, 0)))
+    low = a.reshape(1, -1, CHUNK, h, 16).sum(axis=2).min()
     assert float(stats["chunk_log_decay_min"]) == pytest.approx(low,
                                                                 rel=1e-6)
     if strength > 1:            # exp(-gamma) would be inf in float32
-        assert low < -100.0
+        assert low < (-200.0 if strength > 4 else -100.0)
     assert 0.0 < float(stats["state_absmax"]) < 10.0
     grads = jax.grad(lambda *a: jnp.sum(gd.gated_delta_rule(
         *a, chunk=CHUNK, use_kernel=use_kernel)[0] * w),
@@ -100,6 +119,68 @@ def test_chunked_rule_matches_the_recurrence(case, use_kernel):
         np.testing.assert_allclose(
             g, want_g, atol=1e-5 * float(jnp.abs(want_g).max()),
             rtol=1e-4, err_msg=name)
+
+
+def _distance(got, want):
+    got, want = (x.astype(jnp.float32) for x in (got, want))
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+@pytest.mark.parametrize("dtype,near", [(jnp.float32, 2e-6),
+                                        (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("strength", [0.1, 4.0], ids=["mild", "forgetting"])
+def test_kernels_give_what_the_plain_path_gives(dtype, near, strength):
+    """The kernels form what `chunk_operands_vector` forms, at its rounding
+    points, and their hand-written cotangents are JAX's of the plain path:
+    forward and every gradient, relative L2; in bfloat16 the two round the
+    cotangents at different places, and both stay as near the float32
+    recurrence."""
+    (q, k, v, a, beta), w = _inputs(80, strength, h=4)
+    args = tuple(x.astype(dtype) for x in (q, k, v)) + (a, beta)
+
+    def run(use_kernel):
+        def loss(*a):
+            o = gd.gated_delta_rule(*a, chunk=CHUNK, use_kernel=use_kernel)[0]
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.value_and_grad(loss, argnums=range(5), has_aux=True)(*args)
+
+    ((_, o_k), g_k), ((_, o_p), g_p) = run(True), run(False)
+    assert o_k.dtype == dtype
+    assert _distance(o_k, o_p) < near
+    want = jax.grad(lambda *a: jnp.sum(token_rule(*a) * w),
+                    argnums=range(5))(q, k, v, a, beta)
+    for name, got, plain, exact in zip(("q", "k", "v", "log decay", "beta"),
+                                       g_k, g_p, want):
+        assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))), name
+        assert _distance(got, plain) < 10 * near, name
+        assert _distance(got, exact) < max(1.25 * _distance(plain, exact),
+                                           10 * near), name
+
+
+def test_the_chunk_local_algebra_is_inside_the_kernels():
+    """Value and gradient of the rule on the kernel path: one ``kda_fwd``,
+    one ``kda_bwd``, and outside their bodies no loop, no `exp` over a
+    block's [16, 16, dk] or a chunk's [chunk, chunk] pairs and no array laid
+    a (head, chunk) — the operands U, Wk, Qg, Kg, A and their cotangents
+    exist in VMEM alone. The plain path holds every one of them."""
+    args, w = _inputs(80, 0.5, h=8)
+
+    def traced(use_kernel):
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda *a: jnp.sum(gd.gated_delta_rule(
+                *a, chunk=CHUNK, use_kernel=use_kernel)[0] * w),
+            argnums=range(5)))(*args).jaxpr
+
+    kernels = traced(True)
+    assert sorted(pallas_call_names(kernels)) == [gd.KDA_BWD_NAME,
+                                                  gd.KDA_FWD_NAME]
+    assert chunk_local_algebra(kernels, CHUNK, gd.SUB, 16, 8) == []
+    plain = chunk_local_algebra(traced(False), CHUNK, gd.SUB, 16, 8)
+    assert pallas_call_names(traced(False)) == []
+    for what in ("scan", "exp (3, 2, 16, 16, 16)", "(8, 3, 32, 32)",
+                 "(8, 3, 32, 16)", "(8, 3, 32, 8)"):
+        assert any(what in line for line in plain), (what, sorted(set(plain)))
 
 
 def test_state_is_carried_across_chunks(case):
@@ -180,14 +261,25 @@ def test_no_exponent_formed_is_positive(monkeypatch):
         return exp(x)
 
     monkeypatch.setattr(gd.jnp, "exp", watched)
-    for g in (a, a[..., 0]):
-        del seen[:]
-        grads = jax.grad(lambda *args: jnp.sum(gd.gated_delta_rule(
-            *args, chunk=CHUNK, use_kernel=False)[0] * w),
-            argnums=range(5))(q, k, v, g, beta)
-        jax.block_until_ready(grads)
-        jax.effects_barrier()
-        assert len(seen) >= 5 and max(seen) <= 0.0
+    # the vector form's kernels (the interpreter runs their `exp`s as it
+    # runs any other; their jitted callers are traced anew, and once more
+    # after, without the watch), then both forms' plain paths
+    kernels = (gd._kda_forward, gd._kda_backward)
+    try:
+        for g, use_kernel in ((a, True), (a, False), (a[..., 0], False)):
+            del seen[:]
+            for f in kernels:
+                f.clear_cache()
+            grads = jax.grad(lambda *args: jnp.sum(gd.gated_delta_rule(
+                *args, chunk=CHUNK, use_kernel=use_kernel)[0] * w),
+                argnums=range(5))(q, k, v, g, beta)
+            jax.block_until_ready(grads)
+            jax.effects_barrier()
+            assert len(seen) >= (30 if use_kernel else 5)
+            assert max(seen) <= 0.0
+    finally:
+        for f in kernels:
+            f.clear_cache()
 
 
 def _pairs_as_they_stand(x, y, gam):

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jaxpr_kernels import gradient_jaxpr, gradient_kernel_calls
+from jaxpr_kernels import (chunk_local_algebra, gradient_jaxpr,
+                           gradient_kernel_calls)
 
 from benchmark.lib import harness, kernel_readers
 from edl_tpu.models import sparse_decoder
@@ -183,11 +184,19 @@ def test_remat_runs_kda_fwd_once_a_layer(kimi):
     """Under remat the layer saves the rule's result and states
     (`gated_delta.KDA_SAVED_UNDER_REMAT`, in the family's one policy): the
     gradient holds `kda_fwd` once a KDA layer, as without remat, none of the
-    scalar rule's kernels, and the latent layer's band kernel twice."""
+    scalar rule's kernels, and the latent layer's band kernel twice. And
+    nothing of the rule's chunk-local algebra outside a kernel: its
+    residuals are its own arguments, so the layer's backward rebuilds none
+    of it (PR 62)."""
     cfg, _, fam, w, batch = kimi
     assert set(gated_delta.KDA_SAVED_UNDER_REMAT) <= set(
         sparse_decoder.SAVED_UNDER_REMAT)
+    lin = cfg["linear_attn_config"]
     for remat in (True, False):
+        assert chunk_local_algebra(
+            gradient_jaxpr(fam, cfg, w, batch, remat), gated_delta.CHUNK,
+            gated_delta.SUB, lin["head_dim"], lin["head_dim"],
+            scope="mixer.kda.scan") == []
         calls = gradient_kernel_calls(fam, cfg, w, batch, remat)
         assert calls[gated_delta.KDA_FWD_NAME] == 4
         assert calls[gated_delta.KDA_BWD_NAME] == 4
