@@ -1,7 +1,11 @@
 """Sparse decoder family: a causal LM whose layers are a token MIXER, a
 top-k mixture of experts, or — every model but one — the first followed by
-the second, with RMSNorm, no biases in the matrices and an untied output
-head. What differs between the models of the family is said by attributes.
+the second, with RMSNorm, no biases in the matrices and an output head that
+is a matrix of its own or, under ``tie_embeddings``, the embedding itself:
+ONE [rows held, d_model] matrix read by the gather at the bottom and by the
+product at the top, its gradient the sum of the two uses, so that a
+vocabulary slice cuts both at once. What differs between the models of the
+family is said by attributes.
 Per layer, what it holds (``part_layout``: a mixer AND a feed-forward part,
 each behind a norm of its own — the default —, or ONE of the two behind the
 layer's one norm, x + f(norm(x))) and the mixer's kind (``mixer_layout``):
@@ -14,7 +18,11 @@ a head-wise RMSNorm under a low-rank sigmoid gate on the way out), or a
 MAMBA-2 state-space mixer (``ops/ssd.py``: a [head width, state size]
 float32 matrix a head, decayed by a scalar, B and C shared by the heads of
 a group, behind a causal depthwise convolution WITH a bias and in front of
-a gated RMSNorm over each group). For an attention
+a gated RMSNorm over each group), or a GATED SHORT CONVOLUTION (LFM2's:
+one in-projection to three streams B, C and x, C * conv(B * x) with a causal
+depthwise convolution of ``conv_width`` taps and no bias, an out-projection;
+no recurrent state, no norm and no activation of its own, so its result is
+CUBIC in the layer's normed input). For an attention
 layer: rotary positions or none, over the whole head or its leading
 ``rotary_dim``; which keys a query reads — the full causal prefix, a causal
 window, or the ``select_topk`` keys a learned indexer chose
@@ -44,7 +52,8 @@ positions, as it comes —, and values and the result are ``v_head_dim``
 wide. The
 router may score with a sigmoid and choose by score + a bias
 (``router_scoring``, ``routed_scaling``:
-``parallel/moe.py:route_sigmoid_top_k``), and the shared expert may go
+``parallel/moe.py:route_sigmoid_top_k``; ``router_norm_eps`` is what the
+model adds to the chosen scores' sum), and the shared expert may go
 without its gate (``shared_expert_gate``). The defaults are SmallThinker's.
 
 A model with ``loop_steps`` U > 1 is a LOOPED decoder (arXiv:2510.25741):
@@ -127,6 +136,11 @@ SSD_COUNTERS = ("ssd_chunk_log_decay_min", "ssd_state_absmax")
 #: any other): the same two of the rule at a vector decay, the minimum over
 #: the key channels too
 KDA_COUNTERS = ("kda_chunk_log_decay_min", "kda_state_absmax")
+#: and, in a model with a gated-short-convolution layer, per such layer (0
+#: for any other): the largest |C * z| any step formed, float32, before it is
+#: rounded for the out-projection (a running maximum: what says the cubic
+#: product stays inside bfloat16's range)
+SHORTCONV_COUNTERS = ("conv_gate_absmax",)
 #: and, in a looped model, per PASS (``[loop_steps]`` each, not per layer):
 #: the mean over the predicted tokens of the exit distribution p(u) (sums to
 #: 1 over the passes, a running sum over the steps) and of pass u's own
@@ -142,6 +156,7 @@ _RUNNING = {"load_max": jnp.maximum, "gdn_state_absmax": jnp.maximum,
             "ssd_chunk_log_decay_min": jnp.minimum,
             "kda_state_absmax": jnp.maximum,
             "kda_chunk_log_decay_min": jnp.minimum,
+            "conv_gate_absmax": jnp.maximum,
             "loop_stream_rms_max": jnp.maximum}
 #: what a layer under remat keeps for its backward, the one policy of every
 #: model of the family (a name that no layer of a model emits saves
@@ -248,7 +263,8 @@ def _log_uniform(low, high, transform=jnp.log):
 
 class SparseDecoderLayer(nn.Module):
     """h = norm(x); x' = x + mixer(h), attention, the gated delta rule,
-    Kimi Delta Attention or a Mamba-2 state-space mixer (``mixer``);
+    Kimi Delta Attention, a Mamba-2 state-space mixer or a gated short
+    convolution (``mixer``);
     u = norm(x'); out = x' + held experts(u) [+ shared expert(u)], routed on
     h or on u (``router_input``);
     with no expert held, out = x' + dense feed-forward(u), no router and no
@@ -278,7 +294,8 @@ class SparseDecoderLayer(nn.Module):
     expert_activation: str = "relu"     # or "silu"
     qk_norm: bool = False
     streams: Optional[Tuple[int, int]] = None   # the two-stream block mask
-    mixer: str = "attention"            # or "gated_delta", "mamba2", "kda"
+    #: or "gated_delta", "mamba2", "kda", "shortconv"
+    mixer: str = "attention"
     gdn_key_heads: int = 0              # key heads of a gated-delta layer
     gdn_value_heads: int = 0            # its value heads, held here
     gdn_head_dim: int = 0               # the width of both
@@ -294,6 +311,7 @@ class SparseDecoderLayer(nn.Module):
     v_head_dim: int = 0                 # its values' width; 0: head_dim
     router_scoring: str = "softmax"     # or "sigmoid" (score + bias)
     routed_scaling: float = 1.0         # on a sigmoid router's weights
+    router_norm_eps: float = 1e-20      # beside its chosen scores' sum
     shared_expert_gate: bool = True     # a sigmoid gate on the shared expert
     parts: str = "both"                 # or "mixer", "ffn": that one alone
     expert_gated: bool = True           # False: experts of two matrices
@@ -318,7 +336,8 @@ class SparseDecoderLayer(nn.Module):
                     x.reshape(b * s, d), router,
                     self.param("router_bias", nn.initializers.zeros,
                                (self.num_experts,), jnp.float32),
-                    self.experts_per_token, self.routed_scaling)
+                    self.experts_per_token, self.routed_scaling,
+                    self.router_norm_eps)
             return moe.route_top_k(x.reshape(b * s, d), router,
                                    self.experts_per_token) + ({},)
 
@@ -459,6 +478,31 @@ class SparseDecoderLayer(nn.Module):
                  * jax.nn.sigmoid(gate)).astype(dt)
             out = jnp.einsum("bshk,hkd->bsd", y, proj("out", (hh, dh, d)))
         return out, stats
+
+    def _short_conv(self, h, proj):
+        """The gated short convolution on the normed input h: (its part of
+        the residual, the largest |C * z|). B, C and x are bfloat16 products
+        of h; B * x, the taps' sums and C * z are float32 (`causal_conv`'s),
+        and the CUBIC product is rounded ONCE, where it enters the
+        out-projection. The channels are the model's width, whole on every
+        chip of a deployment: nothing here is a head's."""
+        d = h.shape[-1]
+        f32 = jnp.float32
+        with jax.named_scope("mixer.conv.in_proj"):
+            bcx = jnp.einsum("bsd,dgc->bsgc", h,
+                             proj("in_proj_bcx", (d, 3, d)))
+        with jax.named_scope("mixer.conv.gate"):
+            taps = self.param(
+                "conv", lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, dtype, -shape[1] ** -0.5, shape[1] ** -0.5),
+                (d, self.conv_width), f32)
+            gated = bcx[:, :, 1].astype(f32) * gated_delta.causal_conv(
+                bcx[:, :, 0].astype(f32) * bcx[:, :, 2].astype(f32), taps)
+            top = jnp.max(jnp.abs(jax.lax.stop_gradient(gated)))
+        with jax.named_scope("mixer.conv.out"):
+            out = jnp.einsum("bsc,cd->bsd", gated.astype(self.dtype),
+                             proj("out", (d, d)))
+        return out, {"conv_gate_absmax": top}
 
     def _mamba2(self, h, proj):
         """The Mamba-2 mixer on the normed input h: (its part of the
@@ -635,7 +679,8 @@ class SparseDecoderLayer(nn.Module):
                                               jnp.float32).astype(dt)
         if self.router_input not in ("attn_norm", "moe_norm"):
             raise ValueError("router_input %r" % (self.router_input,))
-        if self.mixer not in ("attention", "gated_delta", "mamba2", "kda"):
+        if self.mixer not in ("attention", "gated_delta", "mamba2", "kda",
+                              "shortconv"):
             raise ValueError("mixer %r" % (self.mixer,))
         if self.parts not in ("both", "mixer", "ffn"):
             raise ValueError("parts %r" % (self.parts,))
@@ -646,11 +691,16 @@ class SparseDecoderLayer(nn.Module):
         linear = has_mixer and self.mixer == "gated_delta"
         state_space = has_mixer and self.mixer == "mamba2"
         delta = has_mixer and self.mixer == "kda"
-        if (linear or state_space or delta) and (
+        conv = has_mixer and self.mixer == "shortconv"
+        if (linear or state_space or delta or conv) and (
                 self.streams or self.select_topk or self.window):
             raise ValueError("a %s layer takes no mask" % (
                 "gated-delta-rule" if linear else
-                "Mamba-2" if state_space else "Kimi-Delta-Attention"))
+                "Mamba-2" if state_space else
+                "Kimi-Delta-Attention" if delta else "short-convolution"))
+        if conv and (self.latent_dim or self.qk_norm or self.attn_gate):
+            raise ValueError("a short-convolution layer has no query, key "
+                             "or value: no latent, q/k norm or gate")
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError("router_scoring %r" % (self.router_scoring,))
         if self.latent_dim and not delta and (
@@ -679,6 +729,8 @@ class SparseDecoderLayer(nn.Module):
                 mixed, counted = self._mamba2(h, proj)
             elif delta:
                 mixed, counted = self._kda(h, proj)
+            elif conv:
+                mixed, counted = self._short_conv(h, proj)
             elif self.latent_dim:
                 mixed, counted = self._latent_attention(h, proj,
                                                         positions), None
@@ -704,6 +756,8 @@ class SparseDecoderLayer(nn.Module):
             prefix = "gdn_" if linear else "ssd_" if state_space else "kda_"
             counters = dict(counters, **{prefix + n: v
                                          for n, v in counted.items()})
+        if conv:
+            counters = dict(counters, **counted)
         if m is None:
             return x, counters
         m = m.reshape(b, s, d)
@@ -750,7 +804,7 @@ class SparseDecoder(nn.Module):
     index_loss_weight: float = 1.0
     block_length: int = 0           # > 0: trained by diffusion over blocks
     #: per layer: 0 = attention, 1 = gated delta rule, 2 = Mamba-2, 3 = Kimi
-    #: Delta Attention
+    #: Delta Attention, 4 = gated short convolution
     mixer_layout: Sequence[int] = ()
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
@@ -770,6 +824,7 @@ class SparseDecoder(nn.Module):
     v_head_dim: int = 0
     router_scoring: str = "softmax"
     routed_scaling: float = 1.0
+    router_norm_eps: float = 1e-20
     shared_expert_gate: bool = True
     #: per layer: 0 = a mixer and a feed-forward part, 1 = the mixer alone,
     #: 2 = the feed-forward part alone
@@ -784,6 +839,7 @@ class SparseDecoder(nn.Module):
     kda_heads: int = 0              # a Kimi-Delta-Attention layer's, held here
     kda_head_dim: int = 0
     kda_gate_rank: int = 0
+    tie_embeddings: bool = False    # the head reads the embedding's rows
 
     def parts(self):
         """Per layer: "both", "mixer" or "ffn" (``part_layout``)."""
@@ -795,7 +851,8 @@ class SparseDecoder(nn.Module):
         """Per layer: its mixer's kind (``mixer_layout``); of a layer that
         is a feed-forward part alone, the kind it does not have."""
         layout = tuple(self.mixer_layout) + (0,) * self.num_layers
-        return tuple(("attention", "gated_delta", "mamba2", "kda")[flag]
+        return tuple(("attention", "gated_delta", "mamba2", "kda",
+                      "shortconv")[flag]
                      for flag in layout[:self.num_layers])
 
     def dense_layers(self):
@@ -811,6 +868,7 @@ class SparseDecoder(nn.Module):
                     gated_delta=any(self.gated_delta_layers()),
                     ssd=any(self.mamba_layers()),
                     kda=any(self.kda_layers()),
+                    shortconv=any(self.short_conv_layers()),
                     routed=self.experts_held > 0,
                     scored=self.experts_held > 0
                     and self.router_scoring == "sigmoid")
@@ -828,6 +886,11 @@ class SparseDecoder(nn.Module):
     def kda_layers(self):
         """Per layer: whether its mixer is Kimi Delta Attention."""
         return tuple(part != "ffn" and mixer == "kda"
+                     for part, mixer in zip(self.parts(), self.mixers()))
+
+    def short_conv_layers(self):
+        """Per layer: whether its mixer is a gated short convolution."""
+        return tuple(part != "ffn" and mixer == "shortconv"
                      for part, mixer in zip(self.parts(), self.mixers()))
 
     def select_layers(self):
@@ -851,6 +914,7 @@ class SparseDecoder(nn.Module):
         linear = self.gated_delta_layers()
         state_space = self.mamba_layers()
         delta = self.kda_layers()
+        conv = self.short_conv_layers()
         parts, mixers = self.parts(), self.mixers()
         dense = self.dense_layers()
         counted = self.counted()
@@ -872,22 +936,24 @@ class SparseDecoder(nn.Module):
                 index_heads=self.index_heads, index_dim=self.index_dim,
                 router_input=self.router_input,
                 expert_activation=self.expert_activation,
-                qk_norm=self.qk_norm, streams=streams,
+                qk_norm=self.qk_norm and not conv[i], streams=streams,
                 mixer=mixers[i],
                 gdn_key_heads=self.gdn_key_heads,
                 gdn_value_heads=self.gdn_value_heads,
                 gdn_head_dim=self.gdn_head_dim, conv_width=self.conv_width,
-                attn_gate=self.attn_gate, rotary_dim=self.rotary_dim,
+                attn_gate=self.attn_gate and not conv[i],
+                rotary_dim=self.rotary_dim,
                 zero_centered_norm=self.zero_centered_norm,
                 shared_expert_width=0 if dense[i]
                 else self.shared_expert_width,
                 dense_width=self.dense_width,
                 sandwich_norm=self.sandwich_norm,
-                latent_dim=self.latent_dim,
+                latent_dim=0 if conv[i] else self.latent_dim,
                 rope_head_dim=self.rope_head_dim,
                 v_head_dim=self.v_head_dim,
                 router_scoring=self.router_scoring,
                 routed_scaling=self.routed_scaling,
+                router_norm_eps=self.router_norm_eps,
                 shared_expert_gate=self.shared_expert_gate,
                 parts=parts[i], expert_gated=self.expert_gated,
                 ssm_heads=self.ssm_heads, ssm_head_dim=self.ssm_head_dim,
@@ -913,16 +979,24 @@ class SparseDecoder(nn.Module):
             if any(delta) and not delta[i]:
                 counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
                                              for n in KDA_COUNTERS})
+            if any(conv) and not conv[i]:
+                counters = dict(counters, **{n: jnp.zeros((), jnp.float32)
+                                             for n in SHORTCONV_COUNTERS})
             per_layer.append(counters)
         return x, per_layer
 
-    def _head(self, x, streams=None):
-        """The final norm and the untied head: float32 logits."""
+    def _head(self, x, streams=None, embed=None):
+        """The final norm and the head — a matrix of its own, or ``embed``
+        [rows held, d_model] where the model ties them: float32 logits."""
         with jax.named_scope("norm"):
             x = RMSNorm(self.eps, self.zero_centered_norm,
                         name="norm_final")(x)
         with jax.named_scope("loss.block_diffusion" if streams
                              else "lm_head"):
+            if embed is not None:
+                return x, jnp.einsum("bsd,vd->bsv", x,
+                                     embed.astype(self.dtype),
+                                     preferred_element_type=jnp.float32)
             head = self.param("lm_head", _init(),
                               (self.d_model, self.vocab_size), jnp.float32)
             return x, jnp.einsum("bsd,dv->bsv", x, head.astype(self.dtype),
@@ -971,35 +1045,39 @@ class SparseDecoder(nn.Module):
         stacked = lambda per_layer: {
             n: jnp.stack([c[n] for c in per_layer]) for n in names}
         if self.loop_steps > 1:
-            if streams or self.selects():
-                raise ValueError("a looped model takes no block mask and no "
-                                 "learned selection")
+            if streams or self.selects() or self.tie_embeddings:
+                raise ValueError("a looped model takes no block mask, no "
+                                 "learned selection and no tied head")
             logits, scores, per_layer, loop = self._looped(x, positions)
             return logits, scores, dict(stacked(per_layer), **loop)
         x, per_layer = self._stack(x, positions, streams)
         if streams:                 # the clean half fed keys and values
             x = x[:, :streams[1]]
-        return self._head(x, streams)[1], stacked(per_layer)
+        return self._head(x, streams, embed if self.tie_embeddings
+                          else None)[1], stacked(per_layer)
 
 
 def counter_names(selects=False, block_diffusion=False, gated_delta=False,
-                  routed=True, scored=False, ssd=False, kda=False):
+                  routed=True, scored=False, ssd=False, kda=False,
+                  shortconv=False):
     """The per-layer counters of a model: the routing's (none where no
     layer holds an expert; a sigmoid router's two with them) and, by what
     the model does, the selection's, the two-stream attention's, the
-    gated delta rule's, the SSD scan's or Kimi Delta Attention's."""
+    gated delta rule's, the SSD scan's, Kimi Delta Attention's or the
+    gated short convolution's."""
     return ((COUNTERS if routed else ())
             + (ROUTE_COUNTERS if scored else ())
             + (SELECT_COUNTERS if selects else ())
             + (BLOCK_DIFFUSION_COUNTERS if block_diffusion else ())
             + (GATED_DELTA_COUNTERS if gated_delta else ())
             + (SSD_COUNTERS if ssd else ())
-            + (KDA_COUNTERS if kda else ()))
+            + (KDA_COUNTERS if kda else ())
+            + (SHORTCONV_COUNTERS if shortconv else ()))
 
 
 def init_counters(num_layers, selects=False, block_diffusion=False,
                   gated_delta=False, routed=True, loop_steps=1,
-                  scored=False, ssd=False, kda=False):
+                  scored=False, ssd=False, kda=False, shortconv=False):
     """The counters a trainer carries in its extra state: ``{"counters":
     {name: [L] float32, "steps": scalar}}`` — the routing's and, for a
     model with a selecting layer, the selection's; for one trained by
@@ -1007,14 +1085,15 @@ def init_counters(num_layers, selects=False, block_diffusion=False,
     ``loss_tokens``; for one with gated-delta-rule, Mamba-2 or
     Kimi-Delta-Attention layers, the scan's two (from zero: a log decay is
     never positive, a size never
-    negative); for a looped one, ``LOOP_COUNTERS``, ``[loop_steps]`` each."""
+    negative); for one with gated-short-convolution layers, the gate's
+    maximum; for a looped one, ``LOOP_COUNTERS``, ``[loop_steps]`` each."""
     # one buffer each: the trainer donates its state to the step
     scalars = ("steps",) + (("loss_tokens",) if block_diffusion else ())
     per_pass = LOOP_COUNTERS if loop_steps > 1 else ()
     return {"counters": dict(
         {n: jnp.zeros((num_layers,), jnp.float32)
          for n in counter_names(selects, block_diffusion, gated_delta,
-                                routed, scored, ssd, kda)},
+                                routed, scored, ssd, kda, shortconv)},
         **{n: jnp.zeros((loop_steps,), jnp.float32) for n in per_pass},
         **{n: jnp.zeros((), jnp.float32) for n in scalars})}
 

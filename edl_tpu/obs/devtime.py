@@ -60,6 +60,9 @@ SCOPES = {
     "mixer.kda.gate": ("mixer", 61),
     "mixer.kda.scan": ("mixer", 61),
     "mixer.kda.out": ("mixer", 61),
+    "mixer.conv.in_proj": ("mixer", 63),
+    "mixer.conv.gate": ("mixer", 63),
+    "mixer.conv.out": ("mixer", 63),
     "lm_head": ("head_loss", 27),
     "loss.next_token": ("head_loss", 59),
     "loss.block_diffusion": ("head_loss", 39),

@@ -243,7 +243,7 @@ def route_top_k(h, router, k):
     return idx, jax.nn.softmax(top, axis=-1)
 
 
-def route_sigmoid_top_k(h, router, bias, k, scaling=1.0):
+def route_sigmoid_top_k(h, router, bias, k, scaling=1.0, norm_eps=1e-20):
     """The router of a layer that scores with a SIGMOID and chooses by
     score + bias (DeepSeek-V3's, one group): z = h router in float32 over
     ALL experts, sc = sigmoid(z); the k chosen are the largest of
@@ -251,7 +251,11 @@ def route_sigmoid_top_k(h, router, bias, k, scaling=1.0):
     gradient reaches it, being read under the stop-gradient the choice is
     made under); their weights come from the UNBIASED scores, normalised
     over the chosen k — held here or not — and times ``scaling``:
-    w_e = scaling * sc_e / (sum over the chosen of sc + 1e-20). The choice
+    w_e = scaling * sc_e / (sum over the chosen of sc + ``norm_eps``) —
+    DeepSeek-V3's 1e-20 unless the model publishes another; it is the
+    published model's constant and nothing more: k sigmoid scores sum to
+    about k / 2, so no test tells 1e-6 from 1e-20 (under 1e-5 of the
+    gradient in float32, tests/test_lfm2_decoder.py). The choice
     is saved under remat as :func:`route_top_k`'s. Returns (idx [T, k]
     int32, w [T, k], counters: float32 scalars ``route_bias_flips`` — the
     (token, slot) choices that the k largest of sc alone would not have
@@ -266,7 +270,7 @@ def route_sigmoid_top_k(h, router, bias, k, scaling=1.0):
         plain + bias.astype(jnp.float32), k)[1].astype(jnp.int32),
         SAVED_UNDER_REMAT[0])
     top = jnp.take_along_axis(sc, idx, axis=-1)
-    w = scaling * top / (top.sum(axis=-1, keepdims=True) + 1e-20)
+    w = scaling * top / (top.sum(axis=-1, keepdims=True) + norm_eps)
     unbiased = lax.top_k(plain, k)[1]
     kept = (idx[:, :, None] == unbiased[:, None, :]).any(axis=-1)
     return idx, w, {

@@ -567,17 +567,22 @@ def test_arrays_crossed_is_none_without_the_tag(world, monkeypatch):
 def test_benchmark_names_arrays_crossed_once_after_what_was_there():
     """Appended LAST by PR 54; what later PRs append (PR 57's six `ssd_*`,
     then PR 59's ten model-parts metrics, tests/test_devtime.py, then
-    PR 61's six `kda_*`) follows it."""
+    PR 61's six `kda_*`, then PR 63's three `shortconv_gate_*`) follows
+    it."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
     assert names.count("grow_put_arrays_crossed") == 1
     at = names.index("grow_put_arrays_crossed")
     assert at > names.index("moe_route_weight_sum")     # PR 53's last
-    assert all(n.startswith("ssd_") for n in names[at + 1:-16])
+    # counted from `at`, so what a later PR appends moves nothing here
+    assert all(n.startswith("ssd_") for n in names[at + 1:at + 7])
     assert all(m["layer"] == "model parts"
-               for m in bench["per_layer"][-16:-6])
-    assert all(n.startswith("kda_") for n in names[-6:])
+               for m in bench["per_layer"][at + 7:at + 17])
+    assert all(n.startswith("kda_") for n in names[at + 17:at + 23])
+    assert all(n.startswith("shortconv_gate_")
+               for n in names[at + 23:at + 26])
+    assert len(names) >= at + 26
     assert bench["per_layer"][at] == {
         "name": "grow_put_arrays_crossed", "unit": "count",
         "better": "lower", "source": "program_span",
