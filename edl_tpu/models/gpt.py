@@ -16,8 +16,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import PartitionSpec as P
+
+from edl_tpu.ops.cross_entropy import next_ids, token_cross_entropy
 
 
 class CausalSelfAttention(nn.Module):
@@ -414,8 +415,7 @@ def create_gpt_pipeline(pp, num_layers=4, d_model=64, num_heads=4,
             [y, jnp.zeros((b, 1), y.dtype)], axis=1)
         tgt = jax.lax.dynamic_slice(
             y_pad, (0, shard_idx * s_loc + 1), (b, s_loc))
-        ce = optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), tgt)
+        ce = token_cross_entropy(logits.astype(jnp.float32), tgt)
         glob_pos = shard_idx * s_loc + jnp.arange(s_loc)
         valid = (glob_pos < s_glob - 1).astype(jnp.float32)
         return (ce * valid[None]).sum() / (b * (s_glob - 1))
@@ -461,7 +461,8 @@ def gpt_tiny(**kw):
 
 def create_model_and_loss(model=None, dummy_batch=1, dummy_seq=16, **kw):
     """(model, params, loss_fn) for ElasticTrainer — next-token
-    cross-entropy over batch["input_ids"] (shift inside)."""
+    cross-entropy over batch["input_ids"] (shift inside, of the TARGETS:
+    the logits are read whole and the last row's loss is dropped)."""
     model = model or gpt_tiny(**kw)
     dummy = jnp.zeros((dummy_batch, dummy_seq), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), dummy)["params"]
@@ -469,11 +470,11 @@ def create_model_and_loss(model=None, dummy_batch=1, dummy_seq=16, **kw):
     def loss_fn(params, batch, rng):
         ids = batch["input_ids"]
         logits = model.apply({"params": params}, ids)
-        # predict token t+1 from prefix <= t; integer-label form avoids
-        # materializing a [b, s, vocab] one-hot at LM vocab sizes
+        # predict token t+1 from prefix <= t: no slice and no gather of the
+        # [b, s, vocab] logits, and no one-hot of that size in memory
         with jax.named_scope("loss.next_token"):
-            return optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1], ids[:, 1:]).mean()
+            return token_cross_entropy(
+                logits, next_ids(ids))[:, :-1].mean()
 
     return model, params, loss_fn
 

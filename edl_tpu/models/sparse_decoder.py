@@ -95,13 +95,13 @@ from typing import Any, Optional, Sequence, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
 from edl_tpu.ops import block_diffusion_attention as bd_attention
 from edl_tpu.ops import gated_delta, sparse_attention, ssd
 from edl_tpu.ops.attention import (attention_context,
                                    block_diffusion_attention,
                                    selected_attention)
+from edl_tpu.ops.cross_entropy import next_ids, token_cross_entropy
 from edl_tpu.parallel import moe
 
 #: what a layer counts about its routing each step (float32 scalars);
@@ -1127,7 +1127,7 @@ def _block_diffusion_loss(model, params, batch):
     """(loss, step counters): the stream [x_t ; x_0] with both copies of
     token i at position i, the head on the noised half, and the mean over
     rows x T of weight * cross-entropy against the position's OWN clean
-    token (no shift)."""
+    token (no shift: every row of the logits against its own id)."""
     ids, noisy = batch["input_ids"], batch["noisy_ids"]
     t_len = ids.shape[1]
     logits, counters = model.apply(
@@ -1135,8 +1135,7 @@ def _block_diffusion_loss(model, params, batch):
         jnp.tile(jnp.arange(t_len), 2), (model.block_length, t_len))
     with jax.named_scope("loss.block_diffusion"):
         weight = batch["loss_weight"].astype(jnp.float32)
-        loss = jnp.mean(weight * optax.softmax_cross_entropy_with_integer_labels(
-            logits, ids))
+        loss = jnp.mean(weight * token_cross_entropy(logits, ids))
         counters = dict(counters,
                         loss_tokens=jnp.sum(weight > 0).astype(jnp.float32))
     return loss, counters
@@ -1150,14 +1149,14 @@ def _exit_expectation_loss(model, params, batch):
     is left; the loss is the mean over the predicted tokens of
     sum_u p_i(u) l_i(u) - beta H(p_i) (arXiv:2510.25741's first-stage
     objective under a uniform prior over exits), worked out from log p so
-    that a gate near 0 or 1 gives no log of zero."""
+    that a gate near 0 or 1 gives no log of zero. The shift is in the
+    TARGETS: every row of the four passes' logits is read where it lies,
+    and the row with nothing to predict leaves the [passes, B, T] result."""
     ids = batch["input_ids"]
     logits, scores, counters = model.apply({"params": params}, ids)
     with jax.named_scope("loss.exit_expectation"):
-        ce = optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :, :-1],
-            jnp.broadcast_to(ids[None, :, 1:],
-                             logits.shape[:2] + (ids.shape[1] - 1,)))
+        ce = token_cross_entropy(logits, jnp.broadcast_to(
+            next_ids(ids), logits.shape[:-1]))[..., :-1]
         scores = scores[:, :, :-1]      # of the positions that predict
         stay = jnp.cumsum(jax.nn.log_sigmoid(-scores), axis=0)
         log_p = jnp.concatenate([
@@ -1174,8 +1173,10 @@ def _exit_expectation_loss(model, params, batch):
 def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
     """(model, params, extra_state, loss_fn) for ElasticTrainer with
     ``has_aux=True``: next-token cross-entropy over batch["input_ids"]
-    (shift inside) and, where a layer selects its keys, ``index_loss_weight``
-    times the mean over those layers of their index loss; for a model with a
+    (shift inside, of the TARGETS: the logits are read whole and the last
+    row's loss is dropped) and, where a layer selects its keys,
+    ``index_loss_weight`` times the mean over those layers of their index
+    loss; for a model with a
     ``block_length``, the block-diffusion loss over batch["input_ids"]
     (clean), batch["noisy_ids"] and batch["loss_weight"] (m / t, float32:
     :func:`block_diffusion_noise`); for a looped model, the expectation of
@@ -1199,8 +1200,8 @@ def create_model_and_loss(model, dummy_batch=1, dummy_seq=16):
         ids = batch["input_ids"]
         logits, counters = model.apply({"params": params}, ids)
         with jax.named_scope("loss.next_token"):
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1], ids[:, 1:]).mean()
+            loss = token_cross_entropy(
+                logits, next_ids(ids))[:, :-1].mean()
             if model.selects():
                 loss = loss + model.index_loss_weight * (
                     counters["index_loss"].sum()

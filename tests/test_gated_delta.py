@@ -561,7 +561,12 @@ def test_expert_shares_add_up_with_the_shared_expert_counted_once(qwen):
 #: there (from PR 42's hashes, which PRs 43–46 had kept), and that the new
 #: passes compute what the old ones did is shown by arithmetic, not by text
 #: (tests/test_sparse_decoder.py::
-#: test_used_tile_passes_match_the_whole_size_gathers). Equal text is an
+#: test_used_tile_passes_match_the_whole_size_gathers) — and ANEW AT PR 64,
+#: for every language model pinned in the tests: the LOSS alone changed
+#: there (`ops/cross_entropy.py:token_cross_entropy` over all rows of the
+#: logits, no slice and no gather; no other line of the models did), and
+#: that it computes what optax's did on the sliced logits is
+#: tests/test_cross_entropy.py's to show. Equal text is an
 #: equal program, so equal bits on any machine: a later PR that adds a model
 #: must leave these as they are. (Keye's program without remat is PR 55's:
 #: its thresholds hand the attention the kept set and the indexer's lse
@@ -570,32 +575,33 @@ def test_expert_shares_add_up_with_the_shared_expert_counted_once(qwen):
 #: computes is held to the dense path and to the parent's bits by
 #: tests/test_sparse_attention.py. Under remat the text did not change.)
 EXPERTS_WALK_USED_TILES_SINCE_PR_47 = {
-    ("smallthinker-21b-a3b", True): "7f62c26c04482d5b",
-    ("smallthinker-21b-a3b", False): "222815ff99d1c7f3",
-    ("keye-vl2-30b-a3b", True): "40c9ea25f2f5142d",
-    ("keye-vl2-30b-a3b", False): "7711bc7de1624e21",
-    ("sdar-30b-a3b-chat", True): "26147cff5d362138",
-    ("sdar-30b-a3b-chat", False): "92e05048e83f5b6d",
+    ("smallthinker-21b-a3b", True): "3f32de6669464c9e",
+    ("smallthinker-21b-a3b", False): "9d02f591f4c0cca8",
+    ("keye-vl2-30b-a3b", True): "177819187c02b43d",
+    ("keye-vl2-30b-a3b", False): "60ef72db093e95c9",
+    ("sdar-30b-a3b-chat", True): "ba3ad333b5e67745",
+    ("sdar-30b-a3b-chat", False): "d1f70aae0138f189",
 }
 
 
 #: the same digest of Qwen3-Next's gradient WITH THE KERNELS FORCED — the
 #: program a TPU traces: the SCALAR rule's `gdn_fwd` / `gdn_bwd` round their
-#: chunk-local operands in `jax.numpy` — computed on the commit before PR 62
-#: (48d3016), which gave the vector form kernels that form a chunk's
-#: operands themselves and left the scalar form to the letter. (The plain
-#: paths' digests of this model are tests/test_looped_decoder.py's.)
-SCALAR_RULE_ON_ITS_KERNELS_BEFORE_PR_62 = {
-    ("qwen3-next-80b-a3b", True): "a4d330d638ae285b",
-    ("qwen3-next-80b-a3b", False): "c90471517fe8957c",
+#: chunk-local operands in `jax.numpy`. PR 62, which gave the vector form
+#: kernels that form a chunk's operands themselves, left the scalar form
+#: to the letter (the digests of 48d3016 then); these are PR 64's, whose
+#: loss is new and whose rule is not. (The plain paths' digests of this
+#: model are tests/test_looped_decoder.py's.)
+SCALAR_RULE_ON_ITS_KERNELS = {
+    ("qwen3-next-80b-a3b", True): "0dc038c0c6607b60",
+    ("qwen3-next-80b-a3b", False): "757df546501bedd4",
 }
 
 
 @pytest.mark.parametrize("config,remat",
-                         sorted(SCALAR_RULE_ON_ITS_KERNELS_BEFORE_PR_62))
+                         sorted(SCALAR_RULE_ON_ITS_KERNELS))
 def test_the_scalar_rule_on_its_kernels_is_the_program_it_was(config, remat):
     _, traced = traced_gradient(config, remat, kernels=True)
-    assert traced == SCALAR_RULE_ON_ITS_KERNELS_BEFORE_PR_62[(config, remat)]
+    assert traced == SCALAR_RULE_ON_ITS_KERNELS[(config, remat)]
 
 
 @pytest.mark.parametrize("config,remat",

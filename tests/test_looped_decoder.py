@@ -519,18 +519,22 @@ def test_family_refuses_a_program_without_the_loop(monkeypatch):
 #: programs changed on purpose there, and that the new passes compute what
 #: the old ones did is shown by arithmetic, not by text
 #: (tests/test_sparse_decoder.py::
-#: test_used_tile_passes_match_the_whole_size_gathers). A later PR that adds
+#: test_used_tile_passes_match_the_whole_size_gathers). ALL of these were
+#: recorded anew at PR 64, which changed the LOSS alone (every row of the
+#: logits against shifted targets, `ops/cross_entropy.py`; what it computes
+#: is tests/test_cross_entropy.py's to show). A later PR that adds
 #: a model must leave these the same program. SmallThinker, Keye and SDAR
 #: are pinned beside them by tests/test_gated_delta.py. Ouro-2.6B, dense
-#: through the same block, holds no expert: its hashes are the commit
-#: before PR 47's (cdb8dcc), which this tree still gives
+#: through the same block, holds no expert: PR 47 left its program as the
+#: commit before it (cdb8dcc) gave it, and only PR 64's loss has changed
+#: it since
 EXPERTS_WALK_USED_TILES_SINCE_PR_47 = {
-    ("qwen3-next-80b-a3b", True): "edbb12ba3b995b82",
-    ("qwen3-next-80b-a3b", False): "ed4a7b979dd9c02f",
+    ("qwen3-next-80b-a3b", True): "b9f93eb6607a08aa",
+    ("qwen3-next-80b-a3b", False): "4a86af7fc5eefb37",
 }
-DENSE_TRACED_AT_PR_46 = {
-    ("ouro-2.6b", True): "5fc3d5b01d848853",
-    ("ouro-2.6b", False): "137c50419c432a84",
+DENSE_TRACED = {
+    ("ouro-2.6b", True): "886383d22eb53540",
+    ("ouro-2.6b", False): "01aba6af6c10bad1",
 }
 
 
@@ -549,7 +553,7 @@ def test_accepted_models_give_bit_for_bit_what_they_gave(config, remat):
     assert traced == EXPERTS_WALK_USED_TILES_SINCE_PR_47[(config, remat)]
 
 
-@pytest.mark.parametrize("config,remat", sorted(DENSE_TRACED_AT_PR_46))
+@pytest.mark.parametrize("config,remat", sorted(DENSE_TRACED))
 def test_a_model_without_experts_traces_as_before_the_experts_changed(
         config, remat):
     """Ouro-2.6B runs `moe.dense_ffn` and no plan, no row pass: a change
@@ -558,4 +562,4 @@ def test_a_model_without_experts_traces_as_before_the_experts_changed(
     names = _leaves(params)
     assert any("ffn_" in name for name in names)
     assert not any("experts_" in name for name in names)
-    assert traced == DENSE_TRACED_AT_PR_46[(config, remat)]
+    assert traced == DENSE_TRACED[(config, remat)]
